@@ -48,7 +48,6 @@ class HeavyHitterConfig:
     # CMS update implementation: "xla" (scatter) or "pallas" (dense tile
     # kernels, ops.cms_pallas — same bucket scheme/state, so the choice is
     # purely a per-hardware performance call; bench.py cms measures both).
-    # On CPU the pallas path runs in interpret mode (tests only).
     cms_impl: str = "xla"
     # Feed the table merge only 2*capacity candidates — the batch's top
     # groups by plane-0 sum PLUS every group whose key is already
@@ -176,20 +175,18 @@ def _cms_add(config: HeavyHitterConfig):
         from ..ops import cms_pallas
 
         # Derive the width tile from the config so any width the xla impl
-        # accepts works here too (the conservative kernel pads the row
-        # dimension itself, so batch size is unconstrained).
+        # accepts works here too (the kernels pad the key dimension
+        # themselves, so batch size is unconstrained). The kernels are
+        # compiled; a test that wants them interpreted on the CPU says so
+        # itself (pltpu.force_tpu_interpret_mode).
         if config.width % 128:
             raise ValueError(
                 f"cms_impl='pallas' needs width % 128 == 0, got {config.width}"
             )
-        tile = next(t for t in (2048, 1024, 512, 256, 128)
-                    if config.width % t == 0)
-        interpret = jax.default_backend() == "cpu"
+        tile = 256 if config.width % 256 == 0 else 128
         if config.conservative:
-            return partial(cms_pallas.cms_add_conservative_pallas,
-                           tile=min(tile, 512), interpret=interpret)
-        return partial(cms_pallas.cms_add_pallas, tile=tile,
-                       interpret=interpret)
+            return partial(cms_pallas.cms_add_conservative_pallas, tile=tile)
+        return partial(cms_pallas.cms_add_pallas, tile=tile)
     if config.cms_impl != "xla":
         raise ValueError(f"unknown cms_impl {config.cms_impl!r}")
     return (cms_ops.cms_add_conservative if config.conservative

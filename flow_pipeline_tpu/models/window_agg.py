@@ -296,10 +296,12 @@ class WindowAggregator:
                     all_counts.append(counts_np[d, :g])
             else:
                 g = int(n)  # first host sync for this chunk
-                # slice on device: transfer only the g real group rows
-                all_keys.append(np.asarray(keys[:g]))
-                all_sums.append(np.asarray(sums[:g]))
-                all_counts.append(np.asarray(counts[:g]))
+                # slice on the HOST, after the transfer: keys[:g] on the
+                # device is a fresh XLA program for every distinct g, so
+                # compilations would grow with the chunk count
+                all_keys.append(np.asarray(keys)[:g])
+                all_sums.append(np.asarray(sums)[:g])
+                all_counts.append(np.asarray(counts)[:g])
         self._merge_partials(np.concatenate(all_keys),
                              np.concatenate(all_sums),
                              np.concatenate(all_counts))

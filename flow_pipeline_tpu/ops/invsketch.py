@@ -20,7 +20,7 @@ exact sum.
 
 dtype note: the key-recovery planes are uint64 BY CONSTRUCTION (a lane
 times a count does not fit any smaller exact dtype), so this module
-requires jax x64 mode (``jax.experimental.enable_x64`` or the
+requires jax x64 mode (``jax.enable_x64`` or the
 ``jax_enable_x64`` config) — the init helper raises a clear error
 otherwise. The production home of this family is the host dataplane
 (hostsketch/engine.py numpy twin + native/hostsketch.cc, reached
@@ -58,7 +58,7 @@ def _require_x64(arr) -> None:
     if arr.dtype != jnp.uint64:
         raise TypeError(
             "invertible-sketch planes must be uint64; enable jax x64 "
-            "mode (jax.experimental.enable_x64) — without it jnp "
+            "mode (jax.enable_x64) — without it jnp "
             "silently downcasts to uint32 and every cell past 2^32 is "
             f"garbage (got {arr.dtype})")
 

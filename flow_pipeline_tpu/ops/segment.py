@@ -251,8 +251,8 @@ def hash_groupby(keys, values, valid):
 
     Returns (unique_keys, sums, counts, n_groups, collided). Real groups
     occupy a contiguous slot prefix exactly as in sort_groupby (padding
-    hashes to the sentinel pair and sorts last), so ``keys[:n_groups]``
-    device slicing keeps working. Callers MUST honor ``collided`` (re-run
+    hashes to the sentinel pair and sorts last), so the host's
+    ``[:n_groups]`` prefix slice keeps working. Callers MUST honor ``collided`` (re-run
     via sort_groupby) to preserve bit-exactness; see hash_groupby_float
     for the probability argument.
     """

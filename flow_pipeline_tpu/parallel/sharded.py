@@ -23,19 +23,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:  # newer jax exports it at top level with the check_vma kwarg
-    from jax import shard_map
-except ImportError:  # pragma: no cover - depends on installed jax
-    # older builds ship the experimental module, where the same knob is
-    # spelled check_rep — adapt so call sites stay on the current API
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    def shard_map(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_compat(f, **kw)
 
 from ..models import ddos as ddos_mod
 from ..models import dense_top as dense_mod

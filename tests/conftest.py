@@ -7,9 +7,8 @@ before jax is imported anywhere.
 
 import os
 
-# Force CPU (shared helper: utils.platform documents why the env var alone
-# is not enough in this environment). Two concurrent test runs must never
-# race for the single real TPU chip. XLA_FLAGS must be set before import.
+# Tests run on the CPU (utils.platform.force_cpu: the env var for child
+# processes, the config for this one). XLA_FLAGS must be set before import.
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (

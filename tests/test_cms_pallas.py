@@ -3,13 +3,17 @@
 The strongest property: both kernels are exact drop-ins for their XLA
 twins on the SAME sketch state — identical bucket scheme (ops.cms), so
 linear/conservative updates must match cms_add / cms_add_conservative
-cell-for-cell, and ops.cms.cms_query serves either path. On TPU the same
-kernels run compiled; bench.py cms compares the paths on hardware.
+cell-for-cell, and ops.cms.cms_query serves either path. Interpret mode
+is asked for HERE (interpret=True, or force_tpu_interpret_mode around a
+model step); no production selector turns it on. chip_smoke.py's
+cms_kernels stage compiles the same kernels on the chip at the
+processor's default shapes and checks them bit for bit.
 """
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from flow_pipeline_tpu.ops.cms import (
     cms_add,
@@ -77,8 +81,8 @@ class TestLinearKernel:
 
 class TestConservativeKernel:
     @pytest.mark.parametrize("n,planes,depth,width,tile,chunk",
-                             [(64, 1, 2, 256, 128, 32),
-                              (128, 3, 4, 512, 128, 64)])
+                             [(64, 1, 2, 256, 128, 128),
+                              (300, 3, 4, 512, 256, 128)])
     def test_matches_xla_conservative(self, rng, n, planes, depth, width,
                                       tile, chunk):
         keys, values, valid = make_inputs(rng, n, planes)
@@ -103,7 +107,7 @@ class TestConservativeKernel:
             lin = cms_add_pallas(lin, keys, values, valid, tile=128,
                                  interpret=True)
             cu = cms_add_conservative_pallas(cu, keys, values, valid,
-                                             tile=128, chunk=64,
+                                             tile=128, chunk=128,
                                              interpret=True)
         e_lin = np.asarray(cms_query(lin, keys))
         e_cu = np.asarray(cms_query(cu, keys))
@@ -115,7 +119,7 @@ class TestConservativeKernel:
         keys, values, _ = make_inputs(rng, 64, 1)
         counts = cms_add_conservative_pallas(
             cms_init(1, 2, 256), keys, values, jnp.zeros(64, bool),
-            tile=128, chunk=32, interpret=True,
+            tile=128, chunk=128, interpret=True,
         )
         assert float(jnp.sum(counts)) == 0.0
 
@@ -123,7 +127,7 @@ class TestConservativeKernel:
         keys, values, valid = make_inputs(rng, 200, 1)
         counts = cms_add_conservative_pallas(
             cms_init(1, 4, 512), keys, values, valid,
-            tile=128, chunk=40, interpret=True,
+            tile=128, chunk=128, interpret=True,
         )
         est = np.asarray(cms_query(counts, keys))[:, 0]
         v = np.asarray(valid)
@@ -135,7 +139,7 @@ class TestConservativeKernel:
         keys, values, valid = make_inputs(rng, 50, 1)
         got = cms_add_conservative_pallas(
             cms_init(1, 2, 256), keys, values, valid,
-            tile=128, chunk=64, interpret=True,
+            tile=128, chunk=128, interpret=True,
         )
         want = cms_add_conservative(cms_init(1, 2, 256), keys, values, valid)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -162,8 +166,9 @@ class TestModelDispatch:
             cfg = HeavyHitterConfig(batch_size=512, width=1 << 10,
                                     capacity=64, cms_impl=impl)
             m = HeavyHitterModel(cfg)
-            for b in batches:
-                m.update(b)
+            with pltpu.force_tpu_interpret_mode():
+                for b in batches:
+                    m.update(b)
             tops.append(m.top(10))
             ests.append(np.asarray(hh_estimates(m.state, config=cfg)))
         for k in tops[0]:
@@ -195,6 +200,8 @@ class TestModelDispatch:
         cfg = HeavyHitterConfig(batch_size=1000, width=1920, capacity=32,
                                 cms_impl="pallas")
         m = HeavyHitterModel(cfg)
-        m.update(FlowGenerator(ZipfProfile(n_keys=30), seed=3).batch(1500))
+        with pltpu.force_tpu_interpret_mode():
+            m.update(FlowGenerator(ZipfProfile(n_keys=30),
+                                   seed=3).batch(1500))
         top = m.top(5)
         assert top["valid"].any()

@@ -39,8 +39,8 @@ def test_dryrun_multichip_small_mesh():
 
 @pytest.fixture
 def tiny_bench(monkeypatch):
-    """Shrink every bench workload and skip the host probe (tests always
-    run on the forced-CPU backend)."""
+    """Shrink every bench workload; tests run on the forced-CPU backend
+    (conftest), so the platform is known without selecting it."""
     monkeypatch.setattr(bench, "_PLATFORM", "cpu")
     monkeypatch.setattr(bench, "HH_BATCH", 512)
     monkeypatch.setattr(bench, "HH_STAGED", 2)
@@ -60,13 +60,15 @@ def _last_json(capsys) -> dict:
     return json.loads(lines[-1])
 
 
-def test_bench_main_staging(tiny_bench, monkeypatch, capsys):
-    """`python bench.py` — the artifact the driver records every round."""
-    monkeypatch.setattr(bench, "_SKIP_E2E_IN_MAIN", True)  # e2e below
+def test_bench_main_staging(tiny_bench, capsys):
+    """`python bench.py` — the artifact the driver records every round
+    (flagship step + the e2e legs it carries), naming its platform."""
     bench.main()
     out = _last_json(capsys)
     assert out["value"] > 0
     assert out["platform"] == "cpu"
+    assert "(cpu, 1 device)" in out["metric"]
+    assert out["e2e_flows_per_sec"] > 0
 
 
 def test_bench_e2e_staging(tiny_bench, capsys):

@@ -120,7 +120,7 @@ class TestTwinParity:
         assert np.array_equal(v1, v2)
 
     def test_jnp_twins_match_numpy(self):
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         with enable_x64():
             import jax.numpy as jnp
@@ -142,7 +142,7 @@ class TestTwinParity:
             assert np.array_equal(v1, v2)
 
     def test_jnp_valid_mask_matches_sliced(self):
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         with enable_x64():
             import jax.numpy as jnp
@@ -163,7 +163,7 @@ class TestTwinParity:
             assert np.array_equal(np.asarray(kc), ref.keycheck)
 
     def test_jnp_merge_is_element_sum(self):
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         with enable_x64():
             import jax.numpy as jnp
@@ -206,7 +206,7 @@ class TestTwinParity:
                 native.hs_inv_update(nat.cms, nat.keysum, nat.keycheck,
                                      keys, vals, None, threads=2)
             _assert_states_equal(ref, nat)
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         with enable_x64():
             import jax.numpy as jnp
@@ -263,6 +263,12 @@ class TestTwinParity:
                                 dtype=np.uint64).astype(np.uint32)
             vals = rng.integers(0, max(vmax, 1),
                                 size=(n, 2)).astype(np.float32)
+            # the count plane (last) is a group's row count, >= 1 for
+            # every row a group table can hold: a zero-count row adds
+            # value mass the key-recovery planes never see, so a bucket
+            # it shares with one real key still looks pure and the
+            # decoded value depends on which depth row peels first
+            vals[:, -1] = np.maximum(vals[:, -1], 1.0)
             ref = HostInvState(
                 cms=np.zeros((2, 2, 128), np.uint64),
                 keysum=np.zeros((2, 128, 3), np.uint64),
