@@ -35,7 +35,6 @@ Modes (default ``hh`` is what the driver records):
                                  # during full-rate ingest + paired
                                  # serve-on/off ingest A/B
     python bench.py sweep        # batch x width x impl tuning sweep
-    python bench.py trace [dir]  # jax.profiler device trace of the step
 """
 
 from __future__ import annotations
@@ -136,7 +135,6 @@ FUSED_PAIRS = 3
 FUSED_THREAD_POINTS = (1, 2, 4, 8)
 SWEEP_BATCHES_CPU = (16384,)
 SWEEP_STEPS = 24
-TRACE_BATCH = 16384
 SHARDED_PER_CHIP = 16384
 SHARDED_STEPS = 24
 
@@ -2854,37 +2852,6 @@ def bench_sweep() -> None:
     print(json.dumps(_sweep_hh_sketch_ab()))
 
 
-def bench_trace(logdir: str = "/tmp/flowtpu_trace") -> None:
-    """Capture a device trace of the flagship step (obs.tracing wrapping
-    jax.profiler) — the VERDICT-prescribed way to find the on-chip
-    limiter (sort vs scatter vs feed). View with TensorBoard/xprof."""
-    import jax
-    import jax.numpy as jnp
-
-    from flow_pipeline_tpu.gen import FlowGenerator, ZipfProfile
-    from flow_pipeline_tpu.models import heavy_hitter as hh
-    from flow_pipeline_tpu.obs.tracing import device_trace
-
-    BATCH = TRACE_BATCH
-    config = hh.HeavyHitterConfig(
-        key_cols=("src_addr", "dst_addr"), batch_size=BATCH,
-        width=1 << 16, capacity=1024,
-    )
-    gen = FlowGenerator(ZipfProfile(n_keys=100_000, alpha=1.1), seed=0)
-    b = gen.batch(BATCH)
-    cols = {k: jax.device_put(jnp.asarray(v))
-            for k, v in b.device_columns(hh.input_cols(config)).items()}
-    valid = jax.device_put(jnp.ones(BATCH, bool))
-    state = hh.hh_update(hh.hh_init(config), cols, valid, config=config)
-    jax.block_until_ready(state)  # compile outside the trace
-    with device_trace(logdir):
-        for _ in range(8):
-            state = hh.hh_update(state, cols, valid, config=config)
-        jax.block_until_ready(state)
-    print(json.dumps({"metric": "device trace captured", "logdir": logdir,
-                      "steps": 8, "platform": _PLATFORM}))
-
-
 def bench_sharded(n_devices: int = 8) -> None:
     """Multi-chip flagship step over an n-device mesh: aggregate flows/sec
     across shards plus the window-close merge cost (psum + table fold over
@@ -3092,9 +3059,6 @@ if __name__ == "__main__":
             bench_sweep()
         elif mode == "kernels":
             bench_kernels()
-        elif mode == "trace":
-            bench_trace(
-                sys.argv[2] if len(sys.argv) > 2 else "/tmp/flowtpu_trace")
         else:
             print(json.dumps({"error": f"unknown mode {mode}"}))
             _rc = 2

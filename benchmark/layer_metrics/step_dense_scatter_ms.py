@@ -1,0 +1,10 @@
+"""Device time of one fused step under the kernel scope dense_scatter (the
+dense port scatters): median over the step's executions in the traced
+window. Source: profiler trace, XLA Ops self times by scope
+(kernel_scopes.py)."""
+
+from benchmark import kernel_scopes
+
+
+def read(run):
+    return kernel_scopes.scope_ms_p50(run, "dense_scatter")

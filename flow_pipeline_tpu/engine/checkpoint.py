@@ -34,7 +34,26 @@ from typing import Any
 
 import numpy as np
 
+from ..obs.trace import TRACER
 from ..utils import fsutil
+
+
+def _to_host(obj: Any, span: dict) -> Any:
+    """The same tree with every device leaf brought to the host, in the
+    order ``_encode`` walks it; counts what it copied into ``span``."""
+    if isinstance(obj, dict):
+        return {k: _to_host(v, span) for k, v in obj.items()}
+    if isinstance(obj, tuple) and hasattr(obj, "_fields"):  # NamedTuple
+        return type(obj)(*(_to_host(getattr(obj, f), span)
+                           for f in obj._fields))
+    if isinstance(obj, (list, tuple)):
+        return type(obj)(_to_host(v, span) for v in obj)
+    if isinstance(obj, (str, int, float, bool, np.ndarray)) or obj is None:
+        return obj
+    arr = np.asarray(obj)
+    span["bytes"] += arr.nbytes
+    span["leaves"] += 1
+    return arr
 
 
 def _encode(obj: Any, arrays: dict[str, np.ndarray], path: str) -> Any:
@@ -102,42 +121,49 @@ def save_checkpoint(path: str, state: Any) -> None:
     leaves a complete old or complete new checkpoint on disk."""
     parent = os.path.dirname(os.path.abspath(path)) or "."
     os.makedirs(parent, exist_ok=True)
-    arrays: dict[str, np.ndarray] = {}
-    meta = _encode(state, arrays, "r")
+    with TRACER.span("ckpt_d2h", bytes=0, leaves=0) as span:
+        state = _to_host(state, span)
     tmp = tempfile.mkdtemp(prefix=".ckpt-", dir=parent)
     try:
         # serialize in memory, publish through the one durable-write
         # idiom (write tmp -> fsync -> replace -> dir fsync): numpy's
         # own savez path never fsyncs
-        buf = io.BytesIO()
-        np.savez_compressed(buf, **arrays)
-        fsutil.write_bytes_durable(os.path.join(tmp, "arrays.npz"),
-                                   buf.getvalue())
-        fsutil.write_bytes_durable(os.path.join(tmp, "meta.json"),
-                                   json.dumps(meta).encode("utf-8"))
-        if os.path.isdir(path):
-            old = path + ".old"
-            # a crash between the renames below can leave a stale .old;
-            # clear it or every future snapshot fails with ENOTEMPTY
-            if os.path.isdir(old):
+        with TRACER.span("ckpt_serialize") as span:
+            arrays: dict[str, np.ndarray] = {}
+            meta = _encode(state, arrays, "r")
+            buf = io.BytesIO()
+            np.savez_compressed(buf, **arrays)
+            npz = buf.getvalue()
+            meta_json = json.dumps(meta).encode("utf-8")
+            span["raw_bytes"] = sum(a.nbytes for a in arrays.values())
+            span["npz_bytes"] = len(npz)
+        with TRACER.span("ckpt_write"):
+            fsutil.write_bytes_durable(os.path.join(tmp, "arrays.npz"), npz)
+            fsutil.write_bytes_durable(os.path.join(tmp, "meta.json"),
+                                       meta_json)
+            if os.path.isdir(path):
+                old = path + ".old"
+                # a crash between the renames below can leave a stale .old;
+                # clear it or every future snapshot fails with ENOTEMPTY
+                if os.path.isdir(old):
+                    fsutil.rmtree(old)
+                fsutil.rename(path, old)
+                fsutil.rename(tmp, path)
                 fsutil.rmtree(old)
-            fsutil.rename(path, old)
-            fsutil.rename(tmp, path)
-            fsutil.rmtree(old)
-        else:
-            fsutil.rename(tmp, path)
-            # a crash between the two renames of a PREVIOUS save leaves
-            # the predecessor under .old with no primary; now that a
-            # complete new checkpoint is published (rename above), the
-            # stale .old is superseded — clear it AFTER publishing so
-            # no crash window is ever left with neither tree
-            if os.path.isdir(path + ".old"):
-                fsutil.rmtree(path + ".old")
-        # directory-entry barrier: the renames above (and the .old
-        # cleanup) are durable only once the parent directory is —
-        # without this a power loss after the ack could silently revert
-        # an acked checkpoint to its predecessor
-        fsutil.fsync_dir(parent)
+            else:
+                fsutil.rename(tmp, path)
+                # a crash between the two renames of a PREVIOUS save leaves
+                # the predecessor under .old with no primary; now that a
+                # complete new checkpoint is published (rename above), the
+                # stale .old is superseded — clear it AFTER publishing so
+                # no crash window is ever left with neither tree
+                if os.path.isdir(path + ".old"):
+                    fsutil.rmtree(path + ".old")
+            # directory-entry barrier: the renames above (and the .old
+            # cleanup) are durable only once the parent directory is —
+            # without this a power loss after the ack could silently revert
+            # an acked checkpoint to its predecessor
+            fsutil.fsync_dir(parent)
     except BaseException:
         # flowlint: disable=durability-protocol -- best-effort cleanup of the unpublished staging dir on a failed save; no ack references it, resurrection after a crash is harmless garbage
         shutil.rmtree(tmp, ignore_errors=True)

@@ -48,6 +48,7 @@ from ..models.spread import SpreadModel
 from ..models.window_agg import WindowAggregator
 from ..models.window_agg import _cached_update as _cached_wagg_update
 from ..obs import get_logger
+from ..obs.trace import TRACER
 from ..schema.batch import FlowBatch, lane_width
 from ..ops.segment import (
     _hash_grouped,
@@ -145,88 +146,100 @@ def _cached_step(hh_specs, dense_cfgs, ddos_cfgs, wagg_cfgs):
             return None
         return jnp.maximum(to_f32(cols[cfg.scale_col]), 1.0)
 
+    # jax.named_scope names below are a contract with the trace readers
+    # (benchmark/kernel_scopes.py, docs/OBSERVABILITY.md): device time is
+    # followed per scope from one compilation to the next, where XLA's own
+    # instruction numbering is not stable. They change HLO metadata only.
     def step(states, cols, valid, valid_hh, valid_dd):
         hh_states, dense_tots, ddos_states = states
 
         chain_results: dict[int, tuple] = {}
         for members in chains:
-            parent_cfg = hh_specs[members[-1]][1]
-            full_lanes = hh._key_lanes(cols, parent_cfg.key_cols)
-            n = full_lanes.shape[0]
-            sort_lanes = []
-            for m in members:
-                h1, h2 = hash_lanes(hh._key_lanes(
-                    cols, hh_specs[m][1].key_cols))
-                sort_lanes.append(jnp.where(valid_hh, h1, _SENTINEL))
-                sort_lanes.append(jnp.where(valid_hh, h2, _SENTINEL))
-            out = lax.sort(sort_lanes + [lax.iota(jnp.int32, n)],
-                           num_keys=2 * len(members))
-            perm = out[-1]
-            sh = jnp.stack(out[:-1], axis=1)
-            sk = jnp.where(valid_hh[:, None], full_lanes.astype(jnp.uint32),
-                           _SENTINEL)[perm]
-            sv = jnp.stack([to_f32(cols[c]) for c in hh_vals], axis=1)
-            r = rate_of(cols, parent_cfg)  # members share scale_col
-            if r is not None:
-                sv = sv * r[:, None]
-            sv = jnp.where(valid_hh[:, None], sv, 0.0)[perm]
-            sc = valid_hh[perm].astype(jnp.int32)
-            for level, m in enumerate(members):
-                width = sum(
-                    lane_width(c) for c in hh_specs[m][1].key_cols)
-                uniq, sums, counts, _ = _hash_grouped(
-                    sh[:, :2 * (level + 1)], sk[:, :width], sv, sc, False)
-                chain_results[m] = (uniq, sums, counts)
+            with jax.named_scope("hh_chain_sort"):
+                parent_cfg = hh_specs[members[-1]][1]
+                full_lanes = hh._key_lanes(cols, parent_cfg.key_cols)
+                n = full_lanes.shape[0]
+                sort_lanes = []
+                for m in members:
+                    h1, h2 = hash_lanes(hh._key_lanes(
+                        cols, hh_specs[m][1].key_cols))
+                    sort_lanes.append(jnp.where(valid_hh, h1, _SENTINEL))
+                    sort_lanes.append(jnp.where(valid_hh, h2, _SENTINEL))
+                out = lax.sort(sort_lanes + [lax.iota(jnp.int32, n)],
+                               num_keys=2 * len(members))
+                perm = out[-1]
+                sh = jnp.stack(out[:-1], axis=1)
+                sk = jnp.where(valid_hh[:, None],
+                               full_lanes.astype(jnp.uint32),
+                               _SENTINEL)[perm]
+                sv = jnp.stack([to_f32(cols[c]) for c in hh_vals], axis=1)
+                r = rate_of(cols, parent_cfg)  # members share scale_col
+                if r is not None:
+                    sv = sv * r[:, None]
+                sv = jnp.where(valid_hh[:, None], sv, 0.0)[perm]
+                sc = valid_hh[perm].astype(jnp.int32)
+                for level, m in enumerate(members):
+                    width = sum(
+                        lane_width(c) for c in hh_specs[m][1].key_cols)
+                    uniq, sums, counts, _ = _hash_grouped(
+                        sh[:, :2 * (level + 1)], sk[:, :width], sv, sc,
+                        False)
+                    chain_results[m] = (uniq, sums, counts)
 
         if need_b:
-            # One dst-keyed hash sort serves the top-dst-IP sketch AND the
-            # DDoS per-dst accumulate under their own row masks: masks
-            # apply to the GATHERED rows, so the dual-mask planes cost
-            # gathers, not extra sort lanes (ops.segment.hash_sort).
-            dst = cols["dst_addr"].astype(jnp.uint32)
-            vb = valid_hh if hh_b else jnp.zeros_like(valid_hh)
-            vd = (valid_dd if ddos_cfgs
-                  else jnp.zeros_like(valid_hh))
-            va = vb | vd
-            n = dst.shape[0]
-            sh_b, perm = hash_sort(dst, va)
-            sk_b = jnp.where(va[:, None], dst, _SENTINEL)[perm]
-            vbp, vdp = vb[perm], vd[perm]
-            planes, cnts = [], []
-            if hh_b:
-                b_cfg = next(cfg for plan, cfg in hh_specs
-                             if plan[0] == "B")
-                rb = rate_of(cols, b_cfg)
-                for c in hh_vals:
-                    p = to_f32(cols[c])
-                    if rb is not None:
-                        p = p * rb
-                    planes.append(jnp.where(vbp, p[perm], 0.0))
-                cnts.append(vbp.astype(jnp.int32))
-            for dcfg in ddos_cfgs[:1]:  # detectors share cadence+col set
-                p = to_f32(cols[dcfg.value_col])
-                rd = rate_of(cols, dcfg)
-                if rd is not None:
-                    p = p * rd
-                planes.append(jnp.where(vdp, p[perm], 0.0))
-                cnts.append(vdp.astype(jnp.int32))
-            sv_b = jnp.stack(planes, axis=1)
-            sc_b = jnp.stack(cnts, axis=1)  # [N, nc]
-            seg = presorted_segments(sh_b)
-            sums_b = jax.ops.segment_sum(sv_b, seg, num_segments=n)
-            cnt_b = jax.ops.segment_sum(sc_b, seg, num_segments=n)
-            # min, not max: rows masked for NEITHER consumer keep their
-            # sentinel keys and may share a hash segment with real rows
-            # only on a ~2^-64 hash collision — min lets the real key win
-            uniq_b = jax.ops.segment_min(sk_b, seg, num_segments=n)
+            with jax.named_scope("dst_sort"):
+                # One dst-keyed hash sort serves the top-dst-IP sketch AND
+                # the DDoS per-dst accumulate under their own row masks:
+                # masks apply to the GATHERED rows, so the dual-mask planes
+                # cost gathers, not extra sort lanes
+                # (ops.segment.hash_sort).
+                dst = cols["dst_addr"].astype(jnp.uint32)
+                vb = valid_hh if hh_b else jnp.zeros_like(valid_hh)
+                vd = (valid_dd if ddos_cfgs
+                      else jnp.zeros_like(valid_hh))
+                va = vb | vd
+                n = dst.shape[0]
+                sh_b, perm = hash_sort(dst, va)
+                sk_b = jnp.where(va[:, None], dst, _SENTINEL)[perm]
+                vbp, vdp = vb[perm], vd[perm]
+                planes, cnts = [], []
+                if hh_b:
+                    b_cfg = next(cfg for plan, cfg in hh_specs
+                                 if plan[0] == "B")
+                    rb = rate_of(cols, b_cfg)
+                    for c in hh_vals:
+                        p = to_f32(cols[c])
+                        if rb is not None:
+                            p = p * rb
+                        planes.append(jnp.where(vbp, p[perm], 0.0))
+                    cnts.append(vbp.astype(jnp.int32))
+                for dcfg in ddos_cfgs[:1]:  # detectors share cadence+col set
+                    p = to_f32(cols[dcfg.value_col])
+                    rd = rate_of(cols, dcfg)
+                    if rd is not None:
+                        p = p * rd
+                    planes.append(jnp.where(vdp, p[perm], 0.0))
+                    cnts.append(vdp.astype(jnp.int32))
+                sv_b = jnp.stack(planes, axis=1)
+                sc_b = jnp.stack(cnts, axis=1)  # [N, nc]
+                seg = presorted_segments(sh_b)
+                sums_b = jax.ops.segment_sum(sv_b, seg, num_segments=n)
+                cnt_b = jax.ops.segment_sum(sc_b, seg, num_segments=n)
+                # min, not max: rows masked for NEITHER consumer keep their
+                # sentinel keys and may share a hash segment with real rows
+                # only on a ~2^-64 hash collision — min lets the real key
+                # win
+                uniq_b = jax.ops.segment_min(sk_b, seg, num_segments=n)
 
             def consume_b(plane_ix, cnt_ix, nplanes):
-                counts = cnt_b[:, cnt_ix]
-                real = counts > 0
-                s = jnp.where(real[:, None],
-                              sums_b[:, plane_ix:plane_ix + nplanes], 0.0)
-                u = jnp.where(real[:, None], uniq_b, _SENTINEL)
-                return u, s, counts
+                with jax.named_scope("dst_sort"):
+                    counts = cnt_b[:, cnt_ix]
+                    real = counts > 0
+                    s = jnp.where(
+                        real[:, None],
+                        sums_b[:, plane_ix:plane_ix + nplanes], 0.0)
+                    u = jnp.where(real[:, None], uniq_b, _SENTINEL)
+                    return u, s, counts
 
         new_hh = []
         for i, ((plan, cfg), st) in enumerate(zip(hh_specs, hh_states)):
@@ -235,33 +248,39 @@ def _cached_step(hh_specs, dense_cfgs, ddos_cfgs, wagg_cfgs):
             elif i in chain_results:
                 uniq, sums, counts = chain_results[i]
             else:
-                lanes = hh._key_lanes(cols, cfg.key_cols)
-                vals = jnp.stack(
-                    [to_f32(cols[c]) for c in cfg.value_cols], axis=1)
-                r = rate_of(cols, cfg)
-                if r is not None:
-                    vals = vals * r[:, None]
-                uniq, sums, counts = hash_groupby_float(
-                    lanes, vals, valid_hh)
-            sums3 = jnp.concatenate(
-                [sums, counts.astype(jnp.float32)[:, None]], axis=1)
-            new_hh.append(
-                hh._apply_grouped(st, uniq, sums3, counts > 0, cfg))
+                with jax.named_scope("hh_group_own"):
+                    lanes = hh._key_lanes(cols, cfg.key_cols)
+                    vals = jnp.stack(
+                        [to_f32(cols[c]) for c in cfg.value_cols], axis=1)
+                    r = rate_of(cols, cfg)
+                    if r is not None:
+                        vals = vals * r[:, None]
+                    uniq, sums, counts = hash_groupby_float(
+                        lanes, vals, valid_hh)
+            # one scope per family, by its index among the hh families
+            with jax.named_scope(f"hh_table_merge_{i}"):
+                sums3 = jnp.concatenate(
+                    [sums, counts.astype(jnp.float32)[:, None]], axis=1)
+                new_hh.append(
+                    hh._apply_grouped(st, uniq, sums3, counts > 0, cfg))
 
-        new_dense = tuple(
-            dense_update(t, cols, valid_hh, config=c)
-            for t, c in zip(dense_tots, dense_cfgs)
-        )
+        with jax.named_scope("dense_scatter"):
+            new_dense = tuple(
+                dense_update(t, cols, valid_hh, config=c)
+                for t, c in zip(dense_tots, dense_cfgs)
+            )
 
         new_ddos = []
         for dcfg, dst_state in zip(ddos_cfgs, ddos_states):
             plane_ix = 2 if hh_b else 0
             cnt_ix = 1 if hh_b else 0
             u, s, counts = consume_b(plane_ix, cnt_ix, 1)
-            new_ddos.append(_accumulate_grouped(
-                dst_state, u, s[:, 0], counts > 0, dcfg))
+            with jax.named_scope("ddos_accumulate"):
+                new_ddos.append(_accumulate_grouped(
+                    dst_state, u, s[:, 0], counts > 0, dcfg))
 
-        wagg_parts = tuple(fn(cols, valid) for fn in wagg_fns)
+        with jax.named_scope("wagg_groupby"):
+            wagg_parts = tuple(fn(cols, valid) for fn in wagg_fns)
         return (tuple(new_hh), new_dense, tuple(new_ddos)), wagg_parts
 
     return jax.jit(step, donate_argnums=(0,))
@@ -371,6 +390,45 @@ class FusedPipeline:
             add("dst_addr", d.config.value_col, *scale_of(d.config))
         return tuple(cols)
 
+    def compiled_step_text(self) -> str:
+        """Optimized HLO text of this pipeline's step on the default
+        backend. A profiler trace names device ops by XLA's instruction
+        numbering (``%fusion.535``); each instruction's ``op_name`` here
+        holds the kernel scope it belongs to (``hh_chain_sort``, ...).
+
+        The executable that runs may have come from the persistent cache,
+        which is keyed without metadata: a hit may have been compiled
+        from a source with other scope names (same program, same
+        instruction numbering). So this compiles the step again, as a jit
+        of its own, under a cache key that includes the metadata: a full
+        compile the first time for a source, a cache hit after. Not for
+        the dispatch loop."""
+        def shape(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+        padded, mask = FlowBatch.empty(0).pad_to(self._bs)
+        cols = {k: shape(v)
+                for k, v in padded.device_columns(self._cols).items()}
+        valid = shape(mask)
+        states = jax.tree_util.tree_map(shape, (
+            tuple(w.model.state for _, w in self._hh),
+            tuple(w.model.totals for _, w in self._dense),
+            tuple(d.state for _, d in self._ddos)))
+        inner = self._step.__wrapped__
+
+        def step(*args):  # a new function: JAX memoizes lowerings by it
+            return inner(*args)
+
+        lowered = jax.jit(step, donate_argnums=(0,)).lower(
+            states, cols, valid, valid, valid)
+        keyed = "jax_compilation_cache_include_metadata_in_key"
+        before = getattr(jax.config, keyed)
+        jax.config.update(keyed, True)
+        try:
+            return lowered.compile().as_text()
+        finally:
+            jax.config.update(keyed, before)
+
     # ---- host lifecycle ---------------------------------------------------
 
     def _split_parts(self, batch: FlowBatch):
@@ -475,16 +533,21 @@ class FusedPipeline:
         bs = self._bs
         for start in range(0, len(part), bs):
             chunk = part.slice(start, start + bs)
-            if do_hh:
+            if do_hh and self._spread:
                 # host-side spread fold per chunk (see __init__): the
                 # chunk is <= one model batch, so model.update makes
                 # exactly one grouped pass over it
-                for _, w in self._spread:
-                    w.model.update(chunk)
-            padded, mask = chunk.pad_to(bs)
-            host_cols = padded.device_columns(self._cols)
-            cols = {k: jnp.asarray(v) for k, v in host_cols.items()}
-            valid = jnp.asarray(mask)
+                with TRACER.span("spread_fold"):
+                    for _, w in self._spread:
+                        w.model.update(chunk)
+            with TRACER.span("lane_build", rows=len(chunk), padded=bs):
+                padded, mask = chunk.pad_to(bs)
+                host_cols = padded.device_columns(self._cols)
+            with TRACER.span("h2d", cols=len(host_cols) + 1) as span:
+                cols = {k: jnp.asarray(v) for k, v in host_cols.items()}
+                valid = jnp.asarray(mask)
+                span["bytes"] = mask.nbytes + sum(
+                    v.nbytes for v in host_cols.values())
             zeros = (jnp.zeros_like(valid)
                      if not (do_hh and do_dd) else None)
             states = (
@@ -492,11 +555,15 @@ class FusedPipeline:
                 tuple(w.model.totals for _, w in self._dense),
                 tuple(d.state for _, d in self._ddos),
             )
-            new_states, wagg_parts = self._step(
-                states, cols, valid,
-                valid if do_hh else zeros,
-                valid if do_dd else zeros,
-            )
+            # one span per device step: their count inside one "apply" is
+            # device steps per batch, rows/padded the step's fill
+            with TRACER.span("step_dispatch", rows=len(chunk), padded=bs,
+                             do_hh=do_hh, do_dd=do_dd):
+                new_states, wagg_parts = self._step(
+                    states, cols, valid,
+                    valid if do_hh else zeros,
+                    valid if do_dd else zeros,
+                )
             new_hh, new_dense, new_ddos = new_states
             for (_, w), st in zip(self._hh, new_hh):
                 w.model.state = st

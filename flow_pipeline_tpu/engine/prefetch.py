@@ -29,6 +29,7 @@ from typing import Optional
 
 from ..guard import register_guard_metrics
 from ..obs import get_logger
+from ..obs.trace import TRACER
 
 log = get_logger("prefetch")
 
@@ -104,25 +105,29 @@ class PrefetchConsumer:
         # while an in-flight round was already past its fetch), and a
         # premature None makes stop_when_idle callers abandon the tail.
         started_before = self._started
-        while True:
-            if self._error is not None:
-                raise self._error
-            try:
-                batch = self._batches.get(timeout=self.idle_sleep)
-                self._track_bytes(-batch.nbytes())
-                return batch
-            except queue.Empty:
-                if not self._thread.is_alive():
-                    # the thread may have died DURING our get() — re-check
-                    # the error before calling it end-of-stream, or the
-                    # crash-the-worker semantics silently become a clean
-                    # exit for stop_when_idle callers
-                    if self._error is not None:
-                        raise self._error
-                    return None
-                if self._idle.is_set() and \
-                        self._completed_start > started_before:
-                    return None
+        # depth 0 when asked = the feed is the bottleneck; a full queue =
+        # the worker is
+        with TRACER.span("poll_wait", depth=self._batches.qsize()):
+            while True:
+                if self._error is not None:
+                    raise self._error
+                try:
+                    batch = self._batches.get(timeout=self.idle_sleep)
+                    self._track_bytes(-batch.nbytes())
+                    return batch
+                except queue.Empty:
+                    if not self._thread.is_alive():
+                        # the thread may have died DURING our get() —
+                        # re-check the error before calling it
+                        # end-of-stream, or the crash-the-worker semantics
+                        # silently become a clean exit for stop_when_idle
+                        # callers
+                        if self._error is not None:
+                            raise self._error
+                        return None
+                    if self._idle.is_set() and \
+                            self._completed_start > started_before:
+                        return None
 
     def commit(self, partition: int, next_offset: int) -> None:
         """Queue the commit for the owner thread (kafka-python consumers

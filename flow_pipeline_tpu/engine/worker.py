@@ -445,9 +445,16 @@ class StreamWorker:
         return self.fused.prepare(batch)
 
     def _process(self, batch, prep=None) -> bool:
+        self._trace_chunk = getattr(batch, "chunk_id", -1)
+        # one "apply" span per polled batch: everything the batch makes
+        # the loop do (device steps, drain, flush, checkpoint, publish)
+        # nests inside it
+        with TRACER.span("apply", chunk=self._trace_chunk) as span:
+            return self._process_batch(batch, prep, span)
+
+    def _process_batch(self, batch, prep, span: dict) -> bool:
         t0 = time.perf_counter()
         t0_wall = time.time()
-        self._trace_chunk = getattr(batch, "chunk_id", -1)
         guard = self.guard
         if guard.armed:
             # watermark lag = age of the backlog head (bus produce time
@@ -499,8 +506,7 @@ class StreamWorker:
         self.m_flows.inc(len(batch))
         self.m_batches.inc()
         self.m_proc.observe((time.perf_counter() - t0) * 1e6)
-        TRACER.record("apply", t0_wall, time.time(),
-                      chunk=self._trace_chunk, rows=len(batch))
+        span["rows"] = len(batch)
         if batch.last_offset >= 0:
             prev = self._covered.get(batch.partition, 0)
             self._covered[batch.partition] = max(prev, batch.last_offset + 1)
@@ -688,16 +694,15 @@ class StreamWorker:
                     export_ts: Optional[float] = None,
                     chunk: int = -1) -> None:
         t0 = time.perf_counter()
-        t0_wall = time.time()
-        rows = self._materialize(rows)
-        n = self._row_count(rows) if n is None else n
-        for sink in self.sinks:
-            sink.write(table, rows)
+        with TRACER.span("flush", chunk=chunk, table=table) as span:
+            rows = self._materialize(rows)
+            n = self._row_count(rows) if n is None else n
+            for sink in self.sinks:
+                sink.write(table, rows)
+            span["rows"] = n
         if self.flusher is not None:
             self.stages.observe("flushing", (time.perf_counter() - t0) * 1e6)
         now = time.time()
-        TRACER.record("flush", t0_wall, now, chunk=chunk, table=table,
-                      rows=n)
         if export_ts is not None:
             # flow-export-timestamp -> sink-commit latency: how stale the
             # serving tables are relative to the traffic they describe.
@@ -736,21 +741,29 @@ class StreamWorker:
     def snapshot_and_commit(self) -> None:
         """Snapshot open state, then commit covered offsets. Order matters:
         state must be durable before the bus forgets the input."""
-        if self.flusher is not None:
-            # the snapshot no longer contains windows handed to the
-            # flusher; their rows must be IN the sinks before the state
-            # and offsets that forget them become durable — a flush
-            # failure raises here and the step dies uncommitted (replay)
-            self.flusher.drain()
-        if self.config.checkpoint_path:
-            save_checkpoint(self.config.checkpoint_path, self._state())
+        state = None
+        with TRACER.span("ckpt_state", chunk=self._trace_chunk):
+            if self.flusher is not None:
+                # the snapshot no longer contains windows handed to the
+                # flusher; their rows must be IN the sinks before the
+                # state and offsets that forget them become durable — a
+                # flush failure raises here and the step dies uncommitted
+                # (replay)
+                self.flusher.drain()
+            if self.config.checkpoint_path:
+                state = self._state()
+        if state is not None:
+            # ckpt_d2h, ckpt_serialize, ckpt_write: inside save_checkpoint
+            save_checkpoint(self.config.checkpoint_path, state)
         self._emitted_since_snapshot = False
-        for partition, next_off in sorted(self._covered.items()):
-            self.consumer.commit(partition, next_off)
-        if isinstance(self.consumer, PrefetchConsumer):
-            # commits execute on the feed thread; wait so the protocol's
-            # ordering (state durable -> offsets committed) stays true
-            self.consumer.flush_commits()
+        with TRACER.span("ckpt_commit", chunk=self._trace_chunk):
+            for partition, next_off in sorted(self._covered.items()):
+                self.consumer.commit(partition, next_off)
+            if isinstance(self.consumer, PrefetchConsumer):
+                # commits execute on the feed thread; wait so the
+                # protocol's ordering (state durable -> offsets
+                # committed) stays true
+                self.consumer.flush_commits()
         if hasattr(self.consumer, "lag"):
             self.m_lag.set(self.consumer.lag())
 

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import TRACER
 from ..ops.hostgroup import _lex_regroup
 from ..ops.segment import hash_groupby, sort_groupby
 from ..utils.shards import local_device_blocks
@@ -142,6 +143,19 @@ def _cached_update_exact(window_seconds: int, key_cols: tuple,
         return sort_groupby(keys, values, valid)
 
     return update
+
+
+def _to_host(arr, stacked: bool) -> np.ndarray:
+    # stacked (sharded) partials may live on non-addressable devices
+    # under multi-host — read only the local shards
+    return local_device_blocks(arr) if stacked else np.asarray(arr)
+
+
+def _first_read(partial) -> np.ndarray:
+    """The first host read a drain makes of a device partial: its
+    collision flag where it has one, else its group count."""
+    return _to_host(partial[4] if len(partial) == 5 else partial[3],
+                    partial[0].ndim == 3)
 
 
 # Device partials queued before a host fold is forced. The bound exists to
@@ -261,50 +275,60 @@ class WindowAggregator:
         pending, self._pending_partials = self._pending_partials, []
         if not pending:
             return
+        with TRACER.span("wagg_wait"):
+            # the first host read of the oldest partial: blocks until the
+            # device step that produced it has finished
+            head = _first_read(pending[0][0])
         all_keys, all_sums, all_counts = [], [], []
-        for partial, fallback in pending:
-            if len(partial) == 5:
-                keys, sums, counts, n, collided = partial
-                # stacked (sharded) flags may live on non-addressable
-                # devices under multi-host — read only the local shards
-                coll_np = (local_device_blocks(collided)
-                           if keys.ndim == 3 else np.asarray(collided))
-                if bool(np.any(coll_np)):
-                    # a 64-bit grouping-hash collision (~2^-64/chunk):
-                    # recompute this chunk lexicographically
-                    if fallback is None:
-                        raise RuntimeError(
-                            "hash-grouped partial collided and no exact "
-                            "fallback was provided")
-                    keys, sums, counts, n = fallback()[:4]
-            else:
-                keys, sums, counts, n = partial
-            if keys.ndim == 3:  # stacked per-chip partials (sharded variant)
-                # Multi-host: each process can only read ITS devices'
-                # shards, and only needs to — the per-chip partials are
-                # independent, and each host folds its own share into its
-                # window store (partial rows merge downstream by key, the
+        with TRACER.span("wagg_d2h") as span:
+            nbytes = 0
+            for i, (partial, fallback) in enumerate(pending):
+                if i:
+                    head = _first_read(partial)
+                nbytes += head.nbytes
+                keys, sums, counts, n = partial[:4]
+                if len(partial) == 5:
+                    if bool(np.any(head)):
+                        # a 64-bit grouping-hash collision (~2^-64/chunk):
+                        # recompute this chunk lexicographically
+                        if fallback is None:
+                            raise RuntimeError(
+                                "hash-grouped partial collided and no "
+                                "exact fallback was provided")
+                        keys, sums, counts, n = fallback()[:4]
+                    ns = _to_host(n, keys.ndim == 3)
+                    nbytes += ns.nbytes
+                else:
+                    ns = head
+                # stacked per-chip partials (sharded variant), multi-host:
+                # each process can only read ITS devices' shards, and only
+                # needs to — the per-chip partials are independent, and
+                # each host folds its own share into its window store
+                # (partial rows merge downstream by key, the
                 # consumer-group contract; see parallel.multihost).
-                ns = local_device_blocks(n)
-                keys_np = local_device_blocks(keys)
-                sums_np = local_device_blocks(sums)
-                counts_np = local_device_blocks(counts)
+                # Slices happen on the HOST, after the transfer: keys[:g]
+                # on the device is a fresh XLA program for every distinct
+                # g, so compilations would grow with the chunk count
+                stacked = keys.ndim == 3
+                keys_np = _to_host(keys, stacked)
+                sums_np = _to_host(sums, stacked)
+                counts_np = _to_host(counts, stacked)
+                nbytes += keys_np.nbytes + sums_np.nbytes + counts_np.nbytes
+                if not stacked:
+                    ns, keys_np, sums_np, counts_np = (
+                        ns[None], keys_np[None], sums_np[None],
+                        counts_np[None])
                 for d in range(keys_np.shape[0]):
                     g = int(ns[d])
                     all_keys.append(keys_np[d, :g])
                     all_sums.append(sums_np[d, :g])
                     all_counts.append(counts_np[d, :g])
-            else:
-                g = int(n)  # first host sync for this chunk
-                # slice on the HOST, after the transfer: keys[:g] on the
-                # device is a fresh XLA program for every distinct g, so
-                # compilations would grow with the chunk count
-                all_keys.append(np.asarray(keys)[:g])
-                all_sums.append(np.asarray(sums)[:g])
-                all_counts.append(np.asarray(counts)[:g])
-        self._merge_partials(np.concatenate(all_keys),
-                             np.concatenate(all_sums),
-                             np.concatenate(all_counts))
+            span["bytes"] = nbytes
+        with TRACER.span("wagg_fold") as span:
+            keys = np.concatenate(all_keys)
+            span["groups"] = len(keys)
+            self._merge_partials(keys, np.concatenate(all_sums),
+                                 np.concatenate(all_counts))
 
     def _merge_partials(self, keys, plane_sums, counts) -> None:
         """Fold device partial aggregates (keys + 16-bit value planes +

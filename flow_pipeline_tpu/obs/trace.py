@@ -12,11 +12,12 @@ writes on an unhandled error.
 
 Modes (``-obs.trace``, env fallback ``FLOWTPU_TRACE``):
 
-- ``off``    — recording disabled; ``span()`` costs one attribute read.
-- ``ring``   — the production default: spans land in the bounded ring,
-               oldest overwritten (the flight-recorder contract). The
-               bench A/B (``bench.py flowtrace``) holds this under 2%
-               of e2e throughput.
+- ``off``    — recording disabled; ``span()`` costs one attribute read
+               and enters no profiler annotation.
+- ``ring``   — the production default: spans land in the bounded ring
+               (``RING_CAPACITY``), oldest overwritten (the
+               flight-recorder contract). The bench A/B (``bench.py
+               flowtrace``) holds this under 2% of e2e throughput.
 - ``always`` — every span is retained (unbounded list): full traces for
                CI parity legs and short diagnostic runs, NOT for
                production streams.
@@ -26,6 +27,13 @@ Export is Chrome trace-event JSON (the ``traceEvents`` array of ``ph:
 or chrome://tracing; spans carrying the same ``chunk`` arg line up
 across thread tracks, which is exactly the cross-thread causality the
 aggregate summaries erase.
+
+A ``span()`` is also a ``jax.profiler.TraceAnnotation`` of the same
+name, so under any profiler session (an operator's, the benchmark's
+traced run) the program's spans sit in the ``.xplane.pb`` on the device
+trace's clock, beside the device ops. JAX is never imported from here: a
+process that has not loaded it (the mesh coordinator) has no profiler to
+annotate.
 """
 
 from __future__ import annotations
@@ -35,15 +43,32 @@ from __future__ import annotations
 # guarded by one lock per recorder, and the mode latch is a
 # single-writer configure() read by GIL-atomic loads on the hot path)
 
-import contextlib
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Optional
 
 TRACE_MODES = ("off", "ring", "always")
+
+# A 51 s window at four times the dispatch loop's span rate (~15 spans a
+# batch, ~20 batches/s) with room to spare; ~10 MB when full.
+RING_CAPACITY = 65536
+
+# flowlint: unguarded -- idempotent latch: every writer stores the same class object
+_ANNOTATION = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` once this process has loaded JAX,
+    else None."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _ANNOTATION = getattr(profiler, "TraceAnnotation", None)
+    return _ANNOTATION
 
 # One process-wide chunk-id mint: Consumer.poll stamps every decoded
 # FlowBatch, and the id rides PreparedBatch -> executor queue -> worker
@@ -55,11 +80,48 @@ def next_chunk_id() -> int:
     return next(_CHUNK_IDS)
 
 
+class _Span:
+    """One ``TraceRecorder.span()`` in flight (a class, not a generator:
+    the dispatch loop enters ~15 of these a batch)."""
+
+    __slots__ = ("_recorder", "_name", "_chunk", "_args", "_t0",
+                 "_annotation")
+
+    def __init__(self, recorder, name, chunk, args):
+        self._recorder = recorder
+        self._name = name
+        self._chunk = chunk
+        self._args = args
+        # flowlint: unguarded -- a span belongs to the one thread that enters it
+        self._t0 = None
+        # flowlint: unguarded -- a span belongs to the one thread that enters it
+        self._annotation = None
+
+    def __enter__(self) -> dict:
+        recorder = self._recorder
+        if recorder._mode == "off" or recorder.paused:
+            return self._args
+        annotation = _trace_annotation()
+        self._t0 = time.time()
+        if annotation is not None:
+            self._annotation = annotation(self._name)
+            self._annotation.__enter__()
+        return self._args
+
+    def __exit__(self, *exc) -> bool:
+        if self._t0 is not None:
+            if self._annotation is not None:
+                self._annotation.__exit__(*exc)
+            self._recorder.record(self._name, self._t0, time.time(),
+                                  self._chunk, **self._args)
+        return False
+
+
 class TraceRecorder:
     """Fixed-size span ring buffer (mode "ring") or unbounded span list
     (mode "always"), safe to record into from any thread."""
 
-    def __init__(self, capacity: int = 8192,
+    def __init__(self, capacity: int = RING_CAPACITY,
                  mode: Optional[str] = None):
         if capacity < 1:
             raise ValueError("trace ring capacity must be >= 1")
@@ -120,17 +182,12 @@ class TraceRecorder:
             self._ring[self._next] = ev
             self._next = (self._next + 1) % self.capacity
 
-    @contextlib.contextmanager
     def span(self, name: str, chunk: Optional[int] = None, **args):
-        """Record the wrapped block as one span. Near-free when off."""
-        if self._mode == "off" or self.paused:
-            yield
-            return
-        t0 = time.time()
-        try:
-            yield
-        finally:
-            self.record(name, t0, time.time(), chunk, **args)
+        """Context manager: record the wrapped block as one span, and as
+        a profiler annotation of the same name. ``with ... as a`` binds
+        the span's ``args`` dict: the block may add what it knows only at
+        its end (``bytes``, ``rows``). Near-free when off."""
+        return _Span(self, name, chunk, args)
 
     # ---- export -----------------------------------------------------------
 
@@ -142,13 +199,24 @@ class TraceRecorder:
             out = self._ring[self._next:] + self._ring[:self._next]
         return [ev for ev in out if ev is not None]
 
+    def whole_since(self, t: float) -> bool:
+        """True iff every span recorded since wall-clock ``t`` is still
+        held. Spans land in the order they END, so once the ring has
+        overwritten any, the window is whole only if the oldest span
+        still held ended at or before ``t``."""
+        with self._lock:
+            if self._dropped == 0:
+                return True
+            return self._ring[self._next][2] <= t
+
     def chrome_trace(self) -> dict:
         """The Chrome trace-event JSON object (Perfetto-loadable):
         complete ("ph": "X") events with microsecond timestamps, one
         ``tid`` per recording thread, chunk ids under ``args.chunk``."""
         events = []
         pid = os.getpid()
-        for name, t0, t1, thread, chunk, args in self.snapshot():
+        snap = self.snapshot()
+        for name, t0, t1, thread, chunk, args in snap:
             ev = {
                 "name": name,
                 "ph": "X",
@@ -172,6 +240,8 @@ class TraceRecorder:
                 "source": "flow-pipeline-tpu flowtrace",
                 "mode": self._mode,
                 "dropped_spans": dropped,
+                # whole_since(t) says whether a window is still held
+                "oldest_span_start": snap[0][1] if snap else None,
             },
         }
 

@@ -1,8 +1,8 @@
-"""Profiling/tracing hooks (SURVEY.md §5: the reference's closest analogue
-is GoFlow's per-stage latency summaries; here we add real device traces).
+"""Per-stage latency summaries (SURVEY.md §5: the reference's closest
+analogue is GoFlow's per-stage latency summaries). Device traces come from
+any ``jax.profiler`` session: the program's spans (``obs/trace.py``) and
+the fused step's named scopes are in it.
 
-- ``device_trace``: context manager around jax.profiler.trace — captures a
-  TensorBoard-loadable trace of everything the device executed.
 - ``StageTimer``: host-side per-stage wall-clock accumulation exposed as
   the flow_summary_*_time_us metric family the reference dashboards chart,
   PLUS the aggregable ``flow_stage_duration_us`` histogram (cumulative
@@ -26,23 +26,6 @@ MAX_STAGES = 64
 OVERFLOW_STAGE = "other"
 
 STAGE_HISTOGRAM = "flow_stage_duration_us"
-
-
-@contextlib.contextmanager
-def device_trace(logdir: str):
-    """Capture a jax.profiler trace into ``logdir`` (view with TensorBoard
-    or xprof). Usage:
-
-        with device_trace("/tmp/trace"):
-            run_some_batches()
-    """
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 class StageTimer:

@@ -10,8 +10,6 @@ from __future__ import annotations
 
 from typing import Optional
 
-import time
-
 from ..obs.trace import TRACER, next_chunk_id
 from ..obs.tracing import StageTimer
 from ..schema import wire
@@ -67,25 +65,18 @@ class Consumer:
                 if span is None:
                     continue
                 data, first, last, produced = span
-                t0 = time.time()
-                with _STAGES.stage("consume_decode"):
-                    batch = FlowBatch.from_wire(data)
-                batch.partition = p
+                batch = self._traced_decode(FlowBatch.from_wire, data, p)
                 batch.first_offset = first
                 batch.last_offset = last
                 batch.produced_at = produced
                 self.positions[p] = last + 1
-                self._trace_decode(batch, t0)
                 return batch
             with _STAGES.stage("consume_fetch"):
                 msgs = self.bus.fetch(self.topic, p, self.positions[p],
                                       max_messages)
             if not msgs:
                 continue
-            t0 = time.time()
-            with _STAGES.stage("consume_decode"):
-                batch = self._decode(msgs)
-            batch.partition = p
+            batch = self._traced_decode(self._decode, msgs, p)
             batch.first_offset = msgs[0].offset
             batch.last_offset = msgs[-1].offset
             # flowguard lag signal (the span path gets this inline; the
@@ -93,17 +84,22 @@ class Consumer:
             batch.produced_at = self.bus.produced_at(
                 self.topic, p, msgs[0].offset)
             self.positions[p] = msgs[-1].offset + 1
-            self._trace_decode(batch, t0)
             return batch
         return None
 
     @staticmethod
-    def _trace_decode(batch: FlowBatch, t0: float) -> None:
+    def _traced_decode(decode, payload, partition: int) -> FlowBatch:
         """Mint the flowtrace chunk id (decode is where a chunk is born)
-        and record the decode span under it."""
-        batch.chunk_id = next_chunk_id()
-        TRACER.record("decode", t0, time.time(), chunk=batch.chunk_id,
-                      rows=len(batch), partition=batch.partition)
+        and decode under a span that carries it."""
+        chunk = next_chunk_id()
+        with TRACER.span("decode", chunk=chunk,
+                         partition=partition) as span:
+            with _STAGES.stage("consume_decode"):
+                batch = decode(payload)
+            span["rows"] = len(batch)
+        batch.chunk_id = chunk
+        batch.partition = partition
+        return batch
 
     def _rotation(self):
         # rotate start partition so one hot partition cannot starve others

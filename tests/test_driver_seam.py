@@ -49,7 +49,6 @@ def tiny_bench(monkeypatch):
     monkeypatch.setattr(bench, "SWEEP_BATCHES_CPU", (512,))
     monkeypatch.setattr(bench, "SWEEP_STEPS", 2)
     monkeypatch.setattr(bench, "HH_SKETCH_PAIRS", 1)
-    monkeypatch.setattr(bench, "TRACE_BATCH", 512)
     monkeypatch.setattr(bench, "SHARDED_PER_CHIP", 256)
     monkeypatch.setattr(bench, "SHARDED_STEPS", 2)
     return bench
@@ -149,12 +148,6 @@ def test_bench_kernels_staging(tiny_bench, capsys):
     assert out["metric"] == "r19 fused-kernel microbench"
     for key in ("inv_ns_per_row", "cms_ns_per_row", "lanes_ns_per_row"):
         assert out[key] > 0
-
-
-def test_bench_trace_staging(tiny_bench, capsys, tmp_path):
-    bench.bench_trace(str(tmp_path / "trace"))
-    out = _last_json(capsys)
-    assert out["metric"] == "device trace captured"
 
 
 def test_bench_sharded_staging(tiny_bench, capsys):

@@ -188,18 +188,3 @@ class TestTracing:
         worker.run(stop_when_idle=True)
         assert worker.stages._summaries["processing"]._count > 0
         assert worker.stages._summaries["flushing"]._count > 0
-
-    def test_device_trace_writes_profile(self, tmp_path):
-        import jax.numpy as jnp
-
-        from flow_pipeline_tpu.obs.tracing import device_trace
-
-        logdir = str(tmp_path / "trace")
-        with device_trace(logdir):
-            jnp.ones(8).sum().block_until_ready()
-        import glob
-        import os
-
-        assert glob.glob(os.path.join(logdir, "**", "*.pb"),
-                         recursive=True) or glob.glob(
-            os.path.join(logdir, "**", "*.trace.json.gz"), recursive=True)
