@@ -2972,7 +2972,7 @@ def _bench_sharded_exact_merge(mesh, n_devices: int, per_chip: int) -> None:
         agg = ShardedWindowAggregator(cfg, mesh)
         part = agg._sharded(*staged[0])  # warm/compile
         jax.block_until_ready(part[0])
-        agg._pending_partials.append((part, None))
+        agg._pending_partials.append((part, None, None))
         agg._drain()
         t_update = t_drain = 0.0
         for i in range(chunks):
@@ -2980,7 +2980,7 @@ def _bench_sharded_exact_merge(mesh, n_devices: int, per_chip: int) -> None:
             part = agg._sharded(*staged[i % len(staged)])
             jax.block_until_ready(part[0])
             t_update += time.perf_counter() - t0
-            agg._pending_partials.append((part, None))
+            agg._pending_partials.append((part, None, None))
             if len(agg._pending_partials) >= threshold:
                 t0 = time.perf_counter()
                 agg._drain()

@@ -350,6 +350,7 @@ class FusedPipeline:
         self._hh_specs = tuple(
             (_hh_plan(w.config), w.config) for _, w in self._hh)
         self._cols = self._column_union()
+        self._behind = False  # the batch in hand fills a device step
         # The compiled step is cached on the static spec, NOT per instance:
         # every bench sample / supervisor restart builds a fresh pipeline,
         # and a per-instance jit would recompile the whole fused graph
@@ -478,6 +479,9 @@ class FusedPipeline:
         if len(batch) == 0:
             return
         parts, wm = self._split_parts(batch)
+        # a batch that fills a device step: the source holds a backlog
+        # (WindowAggregator._min_slot)
+        self._behind = len(batch) >= self._bs
         for slot, sub, part in parts:
             do_hh = self._advance_hh(slot, len(part))
             do_dd = self._advance_ddos(sub, len(part))
@@ -576,6 +580,9 @@ class FusedPipeline:
                 # chunk re-runs its own lexicographic groupby at drain
                 # time (flows_5m stays bit-exact). Closes over the HOST
                 # columns so pending fallbacks don't pin device buffers
-                # (see WindowAggregator._exact_fallback).
-                m.add_partial(out, fallback=m._exact_fallback(
-                    host_cols, mask))
+                # (see WindowAggregator._exact_fallback). With a slot
+                # bound the flush probe leaves this partial on the
+                # device until the next step is queued behind it.
+                m.add_partial(
+                    out, fallback=m._exact_fallback(host_cols, mask),
+                    min_slot=m._min_slot(host_cols, mask, self._behind))

@@ -158,20 +158,17 @@ def _first_read(partial) -> np.ndarray:
                     partial[0].ndim == 3)
 
 
-# Device partials queued before a host fold is forced. The bound exists to
-# cap device memory (each pending partial pins ~batch_size padded rows of
-# keys+sums+counts per chip) while keeping dispatch ASYNC — a drain
-# np.asarray-syncs the device pipeline, so draining every chunk would
-# serialize host fold against device step. Throughput does not push the
-# value higher: `bench.py sharded 8` measures the vectorized host fold at
-# ~8-9% of step time at this threshold (7.7ms/chunk) and ~4ms/chunk at
-# threshold 1 — per-chunk fold cost is roughly flat-to-better at small
-# thresholds, so 32 is sized to memory + async slack alone: 32 x 8192
-# rows x ~10 int32 lanes ≈ 10 MB/chip worst case for the partials.
-# Collision-fallback closures add to that budget: the single-chip paths
-# deliberately stash HOST numpy columns (no HBM cost; ~10-20 MB host),
-# while the sharded paths retain their global device column refs — about
-# another ~1x the partial footprint per chip until drain.
+# Device partials queued before add_partial forces a host fold: the bound
+# for callers that never probe (a flush-free update() loop, the sharded
+# paths, bench.py). It caps what the queue pins: each pending partial
+# holds ~batch_size padded rows of keys+sums+counts on the device (~10
+# int32 lanes: 1.4 MB at 32768 rows, per chip) and one collision-fallback
+# closure (the single-chip paths stash HOST numpy columns, no HBM; the
+# sharded paths retain their global device column refs, about another 1x
+# the partial's footprint per chip). The worker's per-batch probe
+# (pop_closed) keeps the queue far below it: a partial queued with a
+# host-known slot bound is folded one step behind (two pending at most),
+# one queued without is folded by the probe that follows it.
 DRAIN_PENDING_MAX = 32
 
 
@@ -190,9 +187,10 @@ class WindowAggregator:
         # windows: timeslot -> {key tuple -> uint64 [**values, count]}
         self.windows: dict[int, dict[tuple, np.ndarray]] = {}
         self.watermark = 0  # max time_received seen
-        # device partials not yet folded into `windows`: jax dispatch is
-        # async, so keeping results as device arrays until a flush needs
-        # them lets the next chunk's sort overlap the previous transfer
+        # device partials not yet folded into `windows`, oldest first:
+        # (partial, fallback, min_slot). jax dispatch is async, so a
+        # partial stays a device array until a reader needs it; min_slot
+        # is the host-known lower bound on its timeslots (None: unknown)
         self._pending_partials: list = []
         # host-grouped rows not yet folded (engine.hostfused's path),
         # with the min timeslot seen so the per-batch flush probe can
@@ -219,13 +217,14 @@ class WindowAggregator:
         if len(batch) == 0:
             return
         bs = self.config.batch_size
+        behind = len(batch) >= bs
         for start in range(0, len(batch), bs):  # chunk arbitrary batch sizes
-            self._update_chunk(batch.slice(start, start + bs))
+            self._update_chunk(batch.slice(start, start + bs), behind)
         wm = int(batch.columns["time_received"].max())
         if wm > self.watermark:
             self.watermark = wm
 
-    def _update_chunk(self, batch: FlowBatch) -> None:
+    def _update_chunk(self, batch: FlowBatch, behind: bool) -> None:
         padded, mask = batch.pad_to(self.config.batch_size)
         host_cols = padded.device_columns(
             ["time_received", *group_cols(self.config),
@@ -234,7 +233,26 @@ class WindowAggregator:
         cols = {name: jnp.asarray(arr) for name, arr in host_cols.items()}
         valid = jnp.asarray(mask)
         self.add_partial(self._update(cols, valid),
-                         fallback=self._exact_fallback(host_cols, mask))
+                         fallback=self._exact_fallback(host_cols, mask),
+                         min_slot=self._min_slot(host_cols, mask, behind))
+
+    def _min_slot(self, host_cols: dict, mask,
+                  behind: bool) -> Optional[int]:
+        """The slot bound to queue one chunk's partial with (add_partial).
+
+        ``behind``: the polled batch filled a whole device step, so the
+        source held at least that much and the step bounds the loop. The
+        bound is then the minimum over the chunk's valid rows (late rows
+        included) of the same uint32 words the device floors to this
+        model's window, and the drain lags. A part-full batch means the
+        source ran dry: the loop keeps up, and polling again before its
+        step has finished would only cut the traffic into more, emptier
+        padded steps (and checkpoints, which count batches). It gets no
+        bound, so the probe waits for its step as it always did."""
+        if not behind:
+            return None
+        lo = int(host_cols["time_received"][mask].astype(np.uint32).min())
+        return lo - lo % self.config.window_seconds
 
     def _exact_fallback(self, host_cols: dict, mask):
         """Deferred exact recompute for one chunk. Closes over the HOST
@@ -251,21 +269,31 @@ class WindowAggregator:
 
         return run
 
-    def add_partial(self, partial, fallback=None) -> None:
+    def add_partial(self, partial, fallback=None,
+                    min_slot: Optional[int] = None) -> None:
         """Queue one device partial — (keys, sums, counts, n) exact, or
         (keys, sums, counts, n, collided) hash-grouped — for the next
         drain. ``fallback`` is a zero-arg callable producing the EXACT
         partial for the same chunk; it runs at drain time iff the
         chunk's (lazy, device-resident) collision flag fires, keeping
-        flows_5m bit-exact without syncing per chunk. Single entry point
-        for both the per-model path and the fused pipeline, so the
-        deferral bound lives in one place: a flush-free caller (huge
-        update() loops) must not pin unbounded padded buffers on device."""
-        self._pending_partials.append((partial, fallback))
+        flows_5m bit-exact without syncing per chunk. ``min_slot`` is a
+        lower bound on the partial's timeslots that the caller knows on
+        the host (_min_slot; None: unknown, or a loop that is keeping
+        up); with it the per-batch flush probe proves
+        "nothing closable" without reading the device and folds this
+        partial one step late, without it the probe drains everything.
+        Single entry point for both the per-model path and the fused
+        pipeline, so the deferral bound lives in one place: a flush-free
+        caller (huge update() loops) must not pin unbounded padded
+        buffers on device."""
+        self._pending_partials.append((partial, fallback, min_slot))
         if len(self._pending_partials) >= DRAIN_PENDING_MAX:
             self._drain()
 
     def _drain(self) -> None:
+        """Fold everything pending into ``windows``. Every reader of the
+        store (a close, a forced flush, the checkpoint, the query and
+        mesh surfaces) calls this first."""
         if self._pending_host:
             pending_h, self._pending_host = self._pending_host, []
             self._min_pending_slot = None
@@ -273,16 +301,31 @@ class WindowAggregator:
                 np.concatenate([k for k, _ in pending_h]),
                 np.concatenate([v for _, v in pending_h]))
         pending, self._pending_partials = self._pending_partials, []
+        self._fold_partials(pending)
+
+    def _drain_lagged(self) -> None:
+        """The probe's drain when nothing is closable: fold every device
+        partial but the newest. The host read then blocks only until
+        the step BEFORE the one just dispatched has finished, so the
+        copy, the fold and the next batch's preparation run under that
+        step; only rows of an open window wait, for one batch."""
+        pending = self._pending_partials[:-1]
+        del self._pending_partials[:-1]
+        self._fold_partials(pending)
+
+    def _fold_partials(self, pending: list) -> None:
+        """Fold partials already taken off the queue, oldest first."""
         if not pending:
             return
-        with TRACER.span("wagg_wait"):
+        with TRACER.span("wagg_wait", folded=len(pending),
+                         left=len(self._pending_partials)):
             # the first host read of the oldest partial: blocks until the
             # device step that produced it has finished
             head = _first_read(pending[0][0])
         all_keys, all_sums, all_counts = [], [], []
         with TRACER.span("wagg_d2h") as span:
             nbytes = 0
-            for i, (partial, fallback) in enumerate(pending):
+            for i, (partial, fallback, _) in enumerate(pending):
                 if i:
                     head = _first_read(partial)
                 nbytes += head.nbytes
@@ -419,21 +462,23 @@ class WindowAggregator:
 
     def _nothing_closable(self) -> bool:
         """Cheap proof that flush(force=False) would emit nothing, WITHOUT
-        forcing a fold of the pending queues. flush() runs after every
-        batch but windows close hundreds of batches apart; skipping the
-        per-batch drain keeps the fold cadence at DRAIN_PENDING_MAX.
-        Device partials are opaque until synced, so any pending partial
-        means "maybe closable"; host-grouped rows carry their min slot."""
-        if self._pending_partials:
+        reading the device or folding the pending queues. flush() runs
+        after every batch but windows close hundreds of batches apart,
+        and a read of the newest partial waits for the step just
+        dispatched. Host-grouped rows carry their min slot, and so does
+        a device partial queued with one; a device partial without is
+        opaque until synced, so it means "maybe closable"."""
+        bounds = [lo for _, _, lo in self._pending_partials]
+        if None in bounds:
             return False
-        cand = min(self.windows) if self.windows else None
-        if self._min_pending_slot is not None and (
-                cand is None or self._min_pending_slot < cand):
-            cand = self._min_pending_slot
-        if cand is None:
+        if self._min_pending_slot is not None:
+            bounds.append(self._min_pending_slot)
+        if self.windows:
+            bounds.append(min(self.windows))
+        if not bounds:
             return True
         limit = self.watermark - self.config.allowed_lateness
-        return cand + self.config.window_seconds > limit
+        return min(bounds) + self.config.window_seconds > limit
 
     def pop_closed(self, force: bool = False) -> list[tuple[int, dict]]:
         """Detach finalized windows (all, if force) as (slot, store)
@@ -442,6 +487,7 @@ class WindowAggregator:
         so row building (rows_from_stores) can run on another thread
         (ingest.flush) while updates continue."""
         if not force and self._nothing_closable():
+            self._drain_lagged()
             return []
         self._drain()
         slots = sorted(self.windows) if force else self.closed_slots()

@@ -56,6 +56,7 @@ SPAN_ARGS = {
     "poll_wait": ("depth",), "apply": ("rows",),
     "lane_build": ("rows", "padded"), "h2d": ("bytes", "cols"),
     "step_dispatch": ("rows", "padded", "do_hh", "do_dd"),
+    "wagg_wait": ("folded", "left"),
     "wagg_d2h": ("bytes",), "wagg_fold": ("groups",),
     "ckpt_d2h": ("bytes", "leaves"),
     "ckpt_serialize": ("raw_bytes", "npz_bytes"),
@@ -182,6 +183,21 @@ def test_step_dispatch_counts_steps_and_fill(traced_run):
     assert max(per_apply) >= 2
     assert sum(s[5]["rows"] for s in steps) == sum(
         a[5]["rows"] for a in applies)
+
+
+def test_wagg_wait_says_whether_the_drain_lagged(traced_run):
+    spans, calls = traced_run
+    waits = [s for s in spans if s[0] == "wagg_wait"]
+    assert {s[5]["left"] for s in waits} == {0, 1}
+    assert all(s[5]["folded"] >= 1 for s in waits)
+    # a drain inside a checkpoint is a reader's: it leaves nothing
+    for s in waits:
+        if any(t0 <= s[1] and s[2] <= t1 for t0, t1 in calls):
+            assert s[5]["left"] == 0, s
+    # flows_5m is the only aggregator: one partial a device step, each
+    # folded exactly once
+    steps = [s for s in spans if s[0] == "step_dispatch"]
+    assert sum(s[5]["folded"] for s in waits) == len(steps)
 
 
 def test_checkpoint_bytes_are_what_was_written(traced_run):
