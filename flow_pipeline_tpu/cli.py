@@ -153,7 +153,8 @@ def _build_models(vals):
         if mesh:
             from .parallel import ShardedWindowAggregator
 
-            models["flows_5m"] = ShardedWindowAggregator(cfg, mesh)
+            models["flows_5m"] = ShardedWindowAggregator(
+                cfg, mesh, name="flows_5m")
         else:
             models["flows_5m"] = WindowAggregator(cfg)
     # -hh.sketch=auto (the r19 default): CASCADE families — those whose
@@ -199,7 +200,7 @@ def _build_models(vals):
                       for _, other in hh_families)
         return "invertible" if cascade else "table"
 
-    def windowed_hh(key_cols):
+    def windowed_hh(name, key_cols):
         cfg = HeavyHitterConfig(
             key_cols=key_cols,
             batch_size=batch,
@@ -224,7 +225,7 @@ def _build_models(vals):
 
             return WindowedHeavyHitter(cfg, k=vals["sketch.topk"],
                                        model_cls=ShardedHeavyHitter,
-                                       mesh=mesh)
+                                       mesh=mesh, name=name)
         return WindowedHeavyHitter(cfg, k=vals["sketch.topk"])
 
     # top_talkers (5-tuple) + top src/dst IP tables (ref: viz.json "Top
@@ -232,7 +233,7 @@ def _build_models(vals):
     # direction) — the set collected above so auto sketch resolution
     # sees every family before any is built.
     for name, key_cols in hh_families:
-        models[name] = windowed_hh(key_cols)
+        models[name] = windowed_hh(name, key_cols)
     if vals["model.ports"]:
         # Top src/dst port tables (ref: viz.json top port panels). The
         # 2^16 port space fits a dense EXACT accumulator — one segment
@@ -248,7 +249,7 @@ def _build_models(vals):
 
                 models[name] = WindowedHeavyHitter(
                     cfg, k=vals["sketch.topk"],
-                    model_cls=ShardedDenseTopK, mesh=mesh,
+                    model_cls=ShardedDenseTopK, mesh=mesh, name=name,
                 )
             else:
                 models[name] = WindowedHeavyHitter(
@@ -259,7 +260,7 @@ def _build_models(vals):
             from .parallel import ShardedDDoSDetector
 
             models["ddos_alerts"] = ShardedDDoSDetector(
-                DDoSConfig(batch_size=batch), mesh
+                DDoSConfig(batch_size=batch), mesh, name="ddos_alerts"
             )
         else:
             models["ddos_alerts"] = DDoSDetector(DDoSConfig(batch_size=batch))

@@ -11,6 +11,16 @@ close:
 This is the design SURVEY.md §5 calls for: "shard the stream across chips,
 per-chip count-min/space-saving sketches, psum-style merge across ICI —
 sketches are commutative monoids, so merge == allreduce".
+
+What a device trace and the flight recorder see of it (names are a
+contract, docs/OBSERVABILITY.md): every sharded program is jitted from a
+function named for its family and model (``mesh_hh_update_top_talkers``,
+``mesh_dense_merge_top_src_ports``, ``mesh_ddos_close``,
+``mesh_wagg_update``), so an ``XLA Modules`` event of any chip says whose
+it is; the host side records ``mesh_update`` round one model's update,
+``mesh_shard`` inside it round each global step's pad, column build and
+host->device placement (with the rows each chip got), ``mesh_merge``
+round each close collective and ``mesh_drain`` round the flows_5m drain.
 """
 
 from __future__ import annotations
@@ -36,9 +46,55 @@ from ..models.window_agg import (
     _cached_update_exact,
     group_cols,
 )
+from ..obs.trace import TRACER
 from ..ops import topk as topk_ops
 from ..schema.batch import FlowBatch
 from .mesh import DATA_AXIS, make_mesh, shard_batch_columns
+
+
+def _program(name: str):
+    """Name the function a sharded program is jitted from: the compiled
+    module is ``jit_<name>`` in a device trace and in the compile log,
+    and its ops carry ``<name>`` as their scope."""
+
+    def rename(fn):
+        def scoped(*args):
+            with jax.named_scope(name):
+                return fn(*args)
+
+        scoped.__name__ = scoped.__qualname__ = name
+        return scoped
+
+    return rename
+
+
+def _sharded_steps(model, batch: FlowBatch, col_names, step) -> None:
+    """One model's update of ``batch`` under a mesh (a ``mesh_update``
+    span): each global step's columns are padded to
+    ``model.global_batch`` rows, built and placed row-sharded over the
+    mesh (a ``mesh_shard`` span), then handed to ``step(cols, valid)``,
+    which dispatches the model's sharded program. ``chip_rows`` is the
+    valid rows each chip gets, from the host's mask (``pad_to`` pads at
+    the end, so a part-full step fills the leading chips and leaves the
+    rest idle)."""
+    gb = model.global_batch
+    with TRACER.span("mesh_update", model=model.name, steps=0) as update:
+        for start in range(0, len(batch), gb):
+            with TRACER.span("mesh_shard", model=model.name) as span:
+                padded, mask = batch.slice(start, start + gb).pad_to(gb)
+                cols = padded.device_columns(col_names)
+                chip_rows = mask.reshape(model.n_dev, -1).sum(axis=1)
+                span["rows"] = int(chip_rows.sum())
+                span["chip_rows"] = chip_rows.tolist()
+                span["bytes"] = mask.nbytes + sum(
+                    v.nbytes for v in cols.values())
+                cols, valid = shard_batch_columns(model.mesh, cols, mask)
+            step(cols, valid)
+            update["steps"] += 1
+
+
+def _tree_bytes(tree) -> int:
+    return sum(x.nbytes for x in jax.tree.leaves(tree))
 
 
 # ---------------------------------------------------------------------------
@@ -53,10 +109,12 @@ def stack_state(state: hh.HHState, n_dev: int) -> hh.HHState:
     )
 
 
-def sharded_hh_update(mesh: Mesh, config: hh.HeavyHitterConfig):
+def sharded_hh_update(mesh: Mesh, config: hh.HeavyHitterConfig,
+                      name: str = "hh"):
     """Build the jitted SPMD update: (stacked_state, global cols, valid) ->
     stacked_state. No collectives — pure per-chip work."""
 
+    @_program(f"mesh_hh_update_{name}")
     def per_chip(state, cols, valid):
         state = jax.tree.map(lambda x: x[0], state)  # strip device axis
         new = hh.hh_update.__wrapped__(state, cols, valid, config=config)
@@ -75,12 +133,14 @@ def sharded_hh_update(mesh: Mesh, config: hh.HeavyHitterConfig):
     return jax.jit(fn, donate_argnums=(0,))
 
 
-def sharded_hh_merge(mesh: Mesh, config: hh.HeavyHitterConfig):
+def sharded_hh_merge(mesh: Mesh, config: hh.HeavyHitterConfig,
+                     name: str = "hh"):
     """Build the jitted window-close merge: stacked per-chip states ->
     one replicated merged state. psum for the CMS, all_gather + fold for
     the candidate table."""
     n_dev = mesh.devices.size
 
+    @_program(f"mesh_hh_merge_{name}")
     def per_chip(state):
         cms = lax.psum(state.cms[0], DATA_AXIS)
         tk = lax.all_gather(state.table_keys[0], DATA_AXIS)  # [n_dev, C, W]
@@ -112,12 +172,14 @@ class ShardedHeavyHitter:
 
     snapshot_kind = "windowed_hh"  # worker checkpoint dispatch tag
 
-    def __init__(self, config: hh.HeavyHitterConfig, mesh: Mesh | None = None):
+    def __init__(self, config: hh.HeavyHitterConfig, mesh: Mesh | None = None,
+                 name: str = "hh"):
         self.config = config
+        self.name = name  # in the programs' names and the spans' args
         self.mesh = mesh if mesh is not None else make_mesh()
         self.n_dev = self.mesh.devices.size
-        self._update = sharded_hh_update(self.mesh, config)
-        self._merge = sharded_hh_merge(self.mesh, config)
+        self._update = sharded_hh_update(self.mesh, config, name)
+        self._merge = sharded_hh_merge(self.mesh, config, name)
         self.state = stack_state(hh.hh_init(config), self.n_dev)
         # stacked state starts replicated; reshard onto the device axis
         sharding = NamedSharding(self.mesh, P(DATA_AXIS))
@@ -130,12 +192,8 @@ class ShardedHeavyHitter:
         return self.config.batch_size * self.n_dev
 
     def update(self, batch: FlowBatch) -> None:
-        gb = self.global_batch
-        for start in range(0, len(batch), gb):
-            padded, mask = batch.slice(start, start + gb).pad_to(gb)
-            cols = padded.device_columns(hh.input_cols(self.config))
-            cols, valid = shard_batch_columns(self.mesh, cols, mask)
-            self.state = self._update(self.state, cols, valid)
+        _sharded_steps(self, batch, hh.input_cols(self.config),
+                       self.update_device_columns)
 
     def update_device_columns(self, cols, valid) -> None:
         """Update from already-placed global arrays of exactly global_batch
@@ -144,7 +202,12 @@ class ShardedHeavyHitter:
         self.state = self._update(self.state, cols, valid)
 
     def merged_state(self) -> hh.HHState:
-        return self._merge(self.state)
+        # every reader goes on to read the merged state on the host, so
+        # waiting here costs nothing and the span holds the collective
+        # (and whatever the chips still had queued before it)
+        with TRACER.span("mesh_merge", model=self.name,
+                         bytes=_tree_bytes(self.state)):
+            return jax.block_until_ready(self._merge(self.state))
 
     def local_state(self) -> dict[str, np.ndarray]:
         """This process's device shards of the stacked state, as numpy —
@@ -195,6 +258,7 @@ def _sharded_window_update(mesh, window_seconds, key_cols, value_cols):
     chip's collision flag fires."""
     base = _cached_update(window_seconds, key_cols, value_cols)
 
+    @_program("mesh_wagg_update")
     def per_chip(cols, valid):
         keys, sums, counts, n, collided = base.__wrapped__(cols, valid)
         # Globalize the collision flag (any-chip OR via pmax): every host
@@ -222,6 +286,7 @@ def _sharded_window_update_exact(mesh, window_seconds, key_cols, value_cols):
     """Lexicographic per-chip window-agg step — the collision fallback."""
     base = _cached_update_exact(window_seconds, key_cols, value_cols)
 
+    @_program("mesh_wagg_update_exact")
     def per_chip(cols, valid):
         keys, sums, counts, n = base.__wrapped__(cols, valid)
         return keys[None], sums[None], counts[None], n[None]
@@ -249,8 +314,9 @@ class ShardedWindowAggregator(WindowAggregator):
     """
 
     def __init__(self, config: WindowAggConfig = WindowAggConfig(),
-                 mesh: Mesh | None = None):
+                 mesh: Mesh | None = None, name: str = "wagg"):
         super().__init__(config)
+        self.name = name
         self.mesh = mesh if mesh is not None else make_mesh()
         self.n_dev = self.mesh.devices.size
         self._sharded = _sharded_window_update(
@@ -269,23 +335,25 @@ class ShardedWindowAggregator(WindowAggregator):
     def update(self, batch: FlowBatch) -> None:
         if len(batch) == 0:
             return
-        gb = self.global_batch
-        for start in range(0, len(batch), gb):
-            self._update_sharded_chunk(batch.slice(start, start + gb))
+        # stacked partials stay on device until a flush drains them
+        _sharded_steps(self, batch,
+                       ["time_received", *group_cols(self.config),
+                        *self.config.value_cols],
+                       self.update_device_columns)
         wm = int(batch.columns["time_received"].max())
         if wm > self.watermark:
             self.watermark = wm
 
-    def _update_sharded_chunk(self, batch: FlowBatch) -> None:
-        padded, mask = batch.pad_to(self.global_batch)
-        cols = padded.device_columns(
-            ["time_received", *group_cols(self.config),
-             *self.config.value_cols]
-        )
-        cols, valid = shard_batch_columns(self.mesh, cols, mask)
-        # stacked partials stay on device until a flush drains them
-        self.add_partial(self._sharded(cols, valid),
-                         fallback=lambda: self._sharded_exact(cols, valid))
+    def _fold_partials(self, pending: list) -> None:
+        """The drain under a mesh. The per-model path queues partials
+        with no slot bound, so the worker's per-batch probe folds every
+        one at once (``left`` 0) and waits for the program it has just
+        dispatched; the wagg_* spans nest inside."""
+        if not pending:
+            return
+        with TRACER.span("mesh_drain", partials=len(pending),
+                         left=len(self._pending_partials)):
+            super()._fold_partials(pending)
 
     def update_device_columns(self, cols, valid,
                               watermark: Optional[int] = None) -> None:
@@ -318,13 +386,15 @@ class ShardedDDoSDetector(ddos_mod.DDoSDetector):
     """
 
     def __init__(self, config: ddos_mod.DDoSConfig = ddos_mod.DDoSConfig(),
-                 mesh: Mesh | None = None):
+                 mesh: Mesh | None = None, name: str = "ddos"):
         super().__init__(config)
+        self.name = name
         self.mesh = mesh if mesh is not None else make_mesh()
         self.n_dev = self.mesh.devices.size
         spec_obj = self.spec
         cfg = config
 
+        @_program("mesh_ddos_update")
         def acc_per_chip(state, cols, valid):
             state = jax.tree.map(lambda x: x[0], state)
             new = ddos_mod.ddos_accumulate.__wrapped__(
@@ -344,6 +414,7 @@ class ShardedDDoSDetector(ddos_mod.DDoSDetector):
             donate_argnums=(0,),
         )
 
+        @_program("mesh_ddos_close")
         def close_per_chip(state):
             s = jax.tree.map(lambda x: x[0], state)
             rates = lax.psum(s.rates, DATA_AXIS)
@@ -388,23 +459,23 @@ class ShardedDDoSDetector(ddos_mod.DDoSDetector):
         return self.config.batch_size * self.n_dev
 
     def _accumulate(self, batch: FlowBatch) -> None:
-        gb = self.global_batch
-        for start in range(0, len(batch), gb):
-            padded, mask = batch.slice(start, start + gb).pad_to(gb)
-            cols = padded.device_columns(
-                ddos_mod.ddos_input_cols(self.config))
-            cols, valid = shard_batch_columns(self.mesh, cols, mask)
+        def step(cols, valid):
             self.state = self._acc(self.state, cols, valid)
 
+        _sharded_steps(self, batch, ddos_mod.ddos_input_cols(self.config),
+                       step)
+
     def close_sub_window(self) -> list[dict]:
-        self.state, z_stack, rates_stack = self._close(self.state)
-        # every chip computed the same merged scores; read chip 0's replicas
-        return self._emit_alerts(
-            np.asarray(z_stack)[0],
-            np.asarray(rates_stack)[0],
-            self.state.hist[0],
-            self.state.addrs[0],
-        )
+        s = self.state
+        with TRACER.span("mesh_merge", model=self.name,
+                         bytes=s.rates.nbytes + s.wmax.nbytes
+                         + s.addrs.nbytes):
+            self.state, z_stack, rates_stack = self._close(s)
+            # every chip computed the same merged scores; read chip 0's
+            # replicas
+            z, rates = np.asarray(z_stack)[0], np.asarray(rates_stack)[0]
+        return self._emit_alerts(z, rates, self.state.hist[0],
+                                 self.state.addrs[0])
 
 
 # ---------------------------------------------------------------------------
@@ -420,12 +491,14 @@ class ShardedDenseTopK(dense_mod.DenseTopKModel):
     are inherited; only placement and the merge differ."""
 
     def __init__(self, config: dense_mod.DenseTopConfig,
-                 mesh: Mesh | None = None):
+                 mesh: Mesh | None = None, name: str = "dense"):
         super().__init__(config)
+        self.name = name
         self.mesh = mesh if mesh is not None else make_mesh()
         self.n_dev = self.mesh.devices.size
         cfg = config
 
+        @_program(f"mesh_dense_update_{name}")
         def per_chip(totals, cols, valid):
             new = dense_mod.dense_update.__wrapped__(
                 totals[0], cols, valid, config=cfg
@@ -440,6 +513,16 @@ class ShardedDenseTopK(dense_mod.DenseTopKModel):
             ),
             donate_argnums=(0,),
         )
+
+        # per-chip planes sum exactly in int32: each chip's lo is
+        # normalized < 2^16, so n_dev * 2^16 is far from overflow, and
+        # the hi planes stay within the same 2^47 budget documented in
+        # models.dense_top (now shared across chips)
+        @_program(f"mesh_dense_merge_{name}")
+        def merge(totals):
+            return jnp.sum(totals, axis=0)
+
+        self._merge = jax.jit(merge)
         sharding = NamedSharding(self.mesh, P(DATA_AXIS))
         self.totals = jax.device_put(
             jnp.zeros((self.n_dev,) + self.totals.shape, jnp.int32),
@@ -451,20 +534,16 @@ class ShardedDenseTopK(dense_mod.DenseTopKModel):
         return self.config.batch_size * self.n_dev
 
     def update(self, batch: FlowBatch) -> None:
-        gb = self.global_batch
-        for start in range(0, len(batch), gb):
-            padded, mask = batch.slice(start, start + gb).pad_to(gb)
-            cols = padded.device_columns(
-                dense_mod.dense_input_cols(self.config))
-            cols, valid = shard_batch_columns(self.mesh, cols, mask)
+        def step(cols, valid):
             self.totals = self._update(self.totals, cols, valid)
 
+        _sharded_steps(self, batch, dense_mod.dense_input_cols(self.config),
+                       step)
+
     def _merged_totals(self):
-        # per-chip planes sum exactly in int32: each chip's lo is
-        # normalized < 2^16, so n_dev * 2^16 is far from overflow, and
-        # the hi planes stay within the same 2^47 budget documented in
-        # models.dense_top (now shared across chips)
-        return jnp.sum(self.totals, axis=0)
+        with TRACER.span("mesh_merge", model=self.name,
+                         bytes=self.totals.nbytes):
+            return jax.block_until_ready(self._merge(self.totals))
 
     def reset(self) -> None:
         sharding = NamedSharding(self.mesh, P(DATA_AXIS))
