@@ -21,8 +21,14 @@ native:
 test:
 	python -m pytest tests/ -x -q -m "not slow"
 
+# The benchmark's own CPU dry run: BENCHMARK.json's command at the tiny
+# manifest's size. It proves the harness and the processor's feed path
+# run end to end; a CPU run gives no rate (PERF.md). The cells
+# themselves need the chip.
 bench:
-	python bench.py
+	JAX_PLATFORMS=cpu python3 -m benchmark.run \
+		--manifest benchmark/tests/fixtures/BENCHMARK.tiny.json \
+		--workload tiny-catchup --seed 2147483659 --seconds 3 --trace 0
 
 # Static analysis (tools/flowlint): jit-purity, uint64 dtype-flow, lock
 # annotations, lock-order cycles, flag registry, ctypes<->C ABI
@@ -84,8 +90,7 @@ invertible-parity:
 fused-parity:
 	$(MAKE) -C native
 	JAX_PLATFORMS=cpu python -m pytest tests/test_fusedplane.py \
-		"tests/test_hostfused.py::TestLaneBuilders" \
-		"tests/test_driver_seam.py::test_bench_fused_staging" -v
+		"tests/test_hostfused.py::TestLaneBuilders" -v
 
 # Oracle-exactness of the flowmesh (mesh/): N in {1,2,4} in-process
 # meshes vs a single-worker oracle over the identical key-hash-sharded

@@ -164,9 +164,8 @@ def _build_models(vals):
     # the invertible sketch: their decode sets are small (a src/dst-IP
     # family groups 3-4x under its 5-tuple parent, far below the
     # depth*width peel budget) and the admission machinery they'd
-    # otherwise pay is pure hot-path cost (BENCH_r16: 67% of host_fused
-    # on the table leg, 0% invertible). ROOT families keep the table
-    # sketch. The flip engages only where the invertible family can
+    # otherwise pay is pure hot-path cost, which the invertible sketch
+    # does not have. ROOT families keep the table sketch. The flip engages only where the invertible family can
     # actually serve: the host sketch dataplane, no device mesh —
     # elsewhere auto means table, so a default worker never degrades to
     # the per-model numpy path. -hh.sketch=table|invertible overrides

@@ -166,7 +166,7 @@ def _key_lanes_into(cols: dict, key_cols) -> np.ndarray:
     ``np.concatenate`` pass (ROADMAP 4a: the concat's temporaries were
     most of the residual host_group share on the fused leg, where lane
     extraction IS the prepare half). Same words as _key_lanes_np by
-    construction; ``bench.py fused`` carries the paired A/B."""
+    construction."""
     lanes = [_u32_lane(cols[name]) for name in key_cols]
     n = lanes[0].shape[0]
     total = sum(1 if a.ndim == 1 else a.shape[1] for a in lanes)

@@ -75,9 +75,9 @@ class WorkerConfig:
     # the jitted CMS/top-K apply (engine.hostfused/_cached_apply — the
     # TPU dataplane and the pre-r8 CPU path); "host" executes it in the
     # native threaded uint64 engine behind the same apply seam —
-    # bit-exact on the integer envelope (tests/test_hostsketch.py) and
-    # the big remaining CPU lever (device_apply was ~66% of e2e wall,
-    # BENCH_r06). Requires the host-grouped pipeline (CPU backend or
+    # bit-exact on the integer envelope (tests/test_hostsketch.py): on a
+    # CPU the jitted apply is the largest share of the wall time.
+    # Requires the host-grouped pipeline (CPU backend or
     # host_assist="on"); falls back to device with a warning otherwise.
     sketch_backend: str = "device"
     # Ingest dataplane (flow_pipeline_tpu.ingest): "pipelined" runs the

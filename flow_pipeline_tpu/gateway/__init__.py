@@ -2,10 +2,9 @@
 
 The reference pipeline's read surface is Grafana hitting ClickHouse — a
 dedicated read tier decoupled from ingest. flowserve (r14) still serves
-every snapshot from the dataplane's own cores: on the 2-core bench box
-readers and the worker time-slice the same CPUs
-(reader_contention_pct 56, p99 70ms vs p50 3.3ms). flowgate moves the
-read tier OFF the dataplane by construction:
+every snapshot from the dataplane's own cores: readers and the worker
+time-slice the same CPUs. flowgate moves the read tier OFF the
+dataplane by construction:
 
 - the publisher side (worker or mesh coordinator) grows a
   **subscription feed** (:mod:`.feed`): between versions it ships

@@ -38,7 +38,7 @@ FEED_HISTORY = 64
 
 # ...and a cumulative BYTE budget on the same chain: under saturated
 # ingest every CMS tile is dirty and a delta is ~full-snapshot sized
-# (megabytes — bench.py records the ratio), so a count-only bound
+# (megabytes), so a count-only bound
 # could hold 64 snapshots' worth of encoded bytes resident (the r17
 # journal lesson, on RAM instead of disk). Evicting the oldest links
 # past the budget just widens the full-resync window — the fallback
@@ -61,9 +61,8 @@ class SnapshotFeed:
         # construction: each append chains from the previous _state
         self._deltas: deque = deque(maxlen=history)  # guarded-by: _lock
         self._delta_bytes_held = 0  # guarded-by: _lock
-        # shipping-cost ledger (bench reads it): per-transition encoded
-        # sizes — the honest bytes-per-publish evidence for delta vs
-        # full shipping
+        # shipping-cost ledger: per-transition encoded sizes — the
+        # honest bytes-per-publish evidence for delta vs full shipping
         self._stats = {"publishes": 0, "full_bytes": 0,  # guarded-by: _lock
                        "delta_bytes": 0, "deltas": 0}
 

@@ -1,10 +1,9 @@
 """Host dataplane runtime: everything between "decoded batch" and
 "device step".
 
-Five rounds of e2e budgets said the same thing (BENCH_r05: host_group
-49.6% of wall, flushing 34.3%): the host side of the pipeline had no
-runtime of its own — one thread did grouping, the device step, window
-flushing and sink writes in strict sequence. This package gives it one,
+The host side of the pipeline had no runtime of its own — one thread
+did grouping, the device step, window flushing and sink writes in
+strict sequence, and grouping and flushing held most of its time. This package gives it one,
 shaped like the partitioned pre-aggregation front-ends of the streaming
 top-K literature (PAPERS.md: arxiv 2511.16797, 2504.16896 — a sharded
 pre-aggregation stage FEEDING the sketch, never a global sort on the

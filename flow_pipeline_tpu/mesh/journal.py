@@ -31,11 +31,10 @@ Record kinds (``mesh/coordinator.py`` appends, ``replay()`` yields):
              record of a fresh file that atomically replaces the old
              one — every superseded record (every carry an accepted
              submission replaced, every sub folded into an
-             already-merged window) is dropped. BENCH_r17 measured 379
-             MB for 35 records precisely because each ``sub`` carries
-             its full envelope (CMS planes included); compaction is
-             what lets a long-running mesh journal at production
-             cadence. Recovery from a compacted journal is bit-exact
+             already-merged window) is dropped. Each ``sub`` carries
+             its full envelope (CMS planes included: megabytes a
+             record); compaction is what lets a long-running mesh
+             journal at production cadence. Recovery from a compacted journal is bit-exact
              vs replaying the uncompacted history (tests/test_chaos.py
              pins it).
 

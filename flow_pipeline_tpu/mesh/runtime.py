@@ -1,7 +1,7 @@
 """flowmesh in-process runtime: N members + coordinator on one box.
 
-The harness behind ``cli.py pipeline -mesh.workers N``, ``bench.py
-mesh`` and ``make mesh-parity``: flows are sharded by KEY-HASH across
+The harness behind ``cli.py pipeline -mesh.workers N`` and ``make
+mesh-parity``: flows are sharded by KEY-HASH across
 bus partitions (every row of a flow key lands on the same partition, so
 per-shard sketches see each key's complete substream), N MeshMember
 threads consume their assigned partitions, and the coordinator merges

@@ -47,7 +47,8 @@ class HeavyHitterConfig:
     conservative: bool = True
     # CMS update implementation: "xla" (scatter) or "pallas" (dense tile
     # kernels, ops.cms_pallas — same bucket scheme/state, so the choice is
-    # purely a per-hardware performance call; bench.py cms measures both).
+    # purely a per-hardware performance call; no cell of the benchmark
+    # runs "pallas": ROADMAP A7).
     cms_impl: str = "xla"
     # Feed the table merge only 2*capacity candidates — the batch's top
     # groups by plane-0 sum PLUS every group whose key is already
@@ -60,17 +61,18 @@ class HeavyHitterConfig:
     # under-counting them ~25x on near-uniform streams (VERDICT r4 #4).
     # Only ADMISSION loosens: a NEW key must rank in some batch's top
     # 2*capacity to enter, adding at most one batch's rank-2C value per
-    # round to the Misra-Gries dropped-mass bound. Default ON: +68% step
-    # throughput with zero top-20 error at the flagship config (100k-key
-    # alpha=1.1 Zipf, 32k batches — flatter than real flow traffic).
+    # round to the Misra-Gries dropped-mass bound. Default ON: the table
+    # merges are the largest part of the fused step (PERF.md §5), and
+    # the top-K gate of tests/test_models.py holds with it on a Zipf
+    # stream flatter than real flow traffic.
     table_prefilter: bool = True
     # Top-K table admission rule: "est" (default) is space-saving
     # admission via ops.topk.topk_merge_est — a NEW key enters with its
     # CMS estimate so table values upper-bound true totals; "plain" is
     # the pre-r4 batch-sum merge (ops.topk.topk_merge), which silently
-    # under-counts keys admitted mid-window. "plain" exists for the A/B:
-    # `bench.py sweep` quantifies what the est admission's extra planes
-    # cost on the hot path (VERDICT #2).
+    # under-counts keys admitted mid-window. "plain" exists for the A/B
+    # of what the est admission's extra planes cost on the hot path
+    # (VERDICT #2); no cell of the benchmark runs it.
     table_admission: str = "est"
     # Serving-side sampling correction: multiply every value plane by
     # max(<scale_col>, 1) per row, so ranked bytes/packets estimate the
@@ -81,8 +83,8 @@ class HeavyHitterConfig:
     # mocker (rate 1) outputs are unchanged.
     scale_col: str | None = "sampling_rate"
     # Sketch family (-hh.sketch): "table" keeps the CMS + top-K
-    # admission table (prefilter -> admission CMS query -> table merge —
-    # ~56% of the fused native pass, BENCH_r11); "invertible" replaces
+    # admission table (prefilter -> admission CMS query -> table merge,
+    # the larger part of an update); "invertible" replaces
     # the whole admission path with key-recovery planes folded next to
     # the CMS buckets (keysum/keycheck u64 wrap sums — ops/invsketch,
     # hostsketch/engine np_inv_*, native hs_inv_*): update is one pure

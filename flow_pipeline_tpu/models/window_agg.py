@@ -160,7 +160,7 @@ def _first_read(partial) -> np.ndarray:
 
 # Device partials queued before add_partial forces a host fold: the bound
 # for callers that never probe (a flush-free update() loop, the sharded
-# paths, bench.py). It caps what the queue pins: each pending partial
+# paths). It caps what the queue pins: each pending partial
 # holds ~batch_size padded rows of keys+sums+counts on the device (~10
 # int32 lanes: 1.4 MB at 32768 rows, per chip) and one collision-fallback
 # closure (the single-chip paths stash HOST numpy columns, no HBM; the

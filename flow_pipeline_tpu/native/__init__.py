@@ -370,7 +370,7 @@ def missing_features() -> list[str]:
 
 def reload() -> bool:
     """Re-attempt loading (e.g. after a caller built the library); returns
-    availability. Used by bench.py's fresh-box auto-build."""
+    availability."""
     global _TRIED
     _TRIED = False
     return available()

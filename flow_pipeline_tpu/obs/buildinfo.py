@@ -1,15 +1,14 @@
 """flow_build_info: one constant-1 gauge whose labels pin what
 actually ran.
 
-Bench artifacts and dashboards routinely need to answer "was the fused
+Benchmark results and dashboards routinely need to answer "was the fused
 native pass really engaged? which trace mode? host or device sketch?"
 after the fact — and the honest answer lives in process state
 (capabilities(), TRACER.mode, the worker config), not in the command
 line someone believes was used. Publishing it as an info-style gauge
 (the ``prometheus_build_info`` convention: value 1, identity in the
 labels) lets a dashboard join any panel against the exact runtime that
-produced it, and lets `bench.py` record the same identity in its
-artifacts.
+produced it.
 
 Labels:
 

@@ -86,8 +86,7 @@ class Summary:
     quantile series — how the reference's perfs dashboards break the
     NFDelaySummary panel down ``by (router)``. The unlabeled form is the
     plain single-series summary it always was, and ``_sum``/``_count``
-    stay the ACROSS-ALL-LABELS totals (bench.py's stage budget reads
-    them).
+    stay the ACROSS-ALL-LABELS totals.
 
     Label values can be attacker-controlled (the collector labels by
     spoofable UDP source address) and each label set pins a full sample

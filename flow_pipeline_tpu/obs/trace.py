@@ -16,8 +16,8 @@ Modes (``-obs.trace``, env fallback ``FLOWTPU_TRACE``):
                and enters no profiler annotation.
 - ``ring``   — the production default: spans land in the bounded ring
                (``RING_CAPACITY``), oldest overwritten (the
-               flight-recorder contract). The bench A/B (``bench.py
-               flowtrace``) holds this under 2% of e2e throughput.
+               flight-recorder contract). On the chip its cost cannot
+               be told from ``off`` (PERF.md §6, PR 24).
 - ``always`` — every span is retained (unbounded list): full traces for
                CI parity legs and short diagnostic runs, NOT for
                production streams.

@@ -3,8 +3,7 @@
 N threads, each with one keep-alive HTTP connection, issue queries
 back-to-back (closed loop: the next request waits for the previous
 response — the honest client model for "how many concurrent readers can
-this sustain"). Shared by ``bench.py serve`` (the measured artifact) and
-``make serve-load`` (the CI smoke leg).
+this sustain"). ``make serve-load`` (the CI smoke leg) drives it.
 """
 
 from __future__ import annotations
@@ -13,7 +12,7 @@ from __future__ import annotations
 # (each worker thread owns its private _Worker stats; aggregation reads
 # them only after join() — no shared mutable state while running)
 # flowlint: net-checked
-# (a load generator with an unbounded read wedges the whole bench when
+# (a load generator with an unbounded read wedges the whole run when
 # the server under test hangs — exactly the condition being measured)
 
 import http.client
@@ -29,9 +28,7 @@ DEFAULT_ENDPOINTS = (
 
 
 class _Worker:
-    """Per-thread private stats (plain class, not a dataclass: the
-    reader subprocess spec-loads this file without a sys.modules entry,
-    which the dataclass machinery requires)."""
+    """Per-thread private stats."""
 
     def __init__(self):
         self.latencies: list = []
@@ -69,9 +66,8 @@ def sample_ages(host: str, port: int, stop: threading.Event,
                 interval: float = 0.1) -> tuple[threading.Thread, list]:
     """Started snapshot-age sampler: polls /query/version every
     ``interval`` until ``stop`` and appends ``age_seconds`` to the
-    returned list — the freshness evidence `bench.py serve` and
-    `make serve-load` both assert over. join() the thread after
-    setting ``stop``."""
+    returned list — the freshness evidence `make serve-load` asserts
+    over. join() the thread after setting ``stop``."""
     ages: list = []
 
     def drive() -> None:
@@ -154,74 +150,8 @@ def run_load(host: str, port: int, threads: int = 8,
     }
 
 
-def merge_stats(parts: list[dict]) -> dict:
-    """Aggregate per-process run_load summaries: qps sums (concurrent
-    windows), latency quantiles take the worst process (conservative —
-    exact pooling would need the raw samples)."""
-    parts = [p for p in parts if p]
-    if not parts:
-        return {"qps": 0.0, "p50_ms": 0.0, "p99_ms": 0.0, "requests": 0,
-                "errors": 0, "codes": {}, "threads": 0,
-                "duration_s": 0.0}
-    codes: dict[str, int] = {}
-    for p in parts:
-        for c, n in p["codes"].items():
-            codes[c] = codes.get(c, 0) + n
-    return {
-        "qps": round(sum(p["qps"] for p in parts), 1),
-        "p50_ms": max(p["p50_ms"] for p in parts),
-        "p99_ms": max(p["p99_ms"] for p in parts),
-        "requests": sum(p["requests"] for p in parts),
-        "errors": sum(p["errors"] for p in parts),
-        "codes": codes,
-        "threads": sum(p["threads"] for p in parts),
-        "duration_s": max(p["duration_s"] for p in parts),
-    }
-
-
-# Child bootstrap: spec-load THIS file directly so a reader process
-# never imports the flow_pipeline_tpu package (whose import chain pulls
-# jax — seconds of CPU that, on a small box, would throttle the very
-# serving path the reader is supposed to measure).
-_CHILD_BOOT = """
-import importlib.util, json, sys
-spec = importlib.util.spec_from_file_location("loadgen", sys.argv[1])
-m = importlib.util.module_from_spec(spec)
-sys.modules["loadgen"] = m
-spec.loader.exec_module(m)
-print(json.dumps(m.run_load(sys.argv[2], int(sys.argv[3]),
-                            threads=int(sys.argv[4]),
-                            duration=float(sys.argv[5]),
-                            endpoints=tuple(sys.argv[6].split(",")))))
-"""
-
-
-def run_load_procs(host: str, port: int, procs: int = 2,
-                   threads: int = 4, duration: float = 2.0,
-                   endpoints=DEFAULT_ENDPOINTS) -> dict:
-    """run_load fanned over ``procs`` reader SUBPROCESSES (x ``threads``
-    connections each). In-process reader threads share the server's GIL
-    — beyond a few, the measurement throttles ITSELF; separate
-    interpreter processes are the honest client model for "N concurrent
-    readers", which is exactly what `bench.py serve` measures."""
-    import json as _json
-    import subprocess
-    import sys as _sys
-
-    cmd = [_sys.executable, "-c", _CHILD_BOOT, __file__, host,
-           str(port), str(threads), str(duration), ",".join(endpoints)]
-    ps = [subprocess.Popen(cmd, stdout=subprocess.PIPE)
-          for _ in range(procs)]
-    parts = []
-    for p in ps:
-        out, _ = p.communicate(timeout=duration + 120)
-        if p.returncode == 0 and out:
-            parts.append(_json.loads(out))
-    return merge_stats(parts)
-
-
 def main(argv=None) -> int:
-    """Subprocess entry: HOST PORT [THREADS] [DURATION] [ENDPOINTS] ->
+    """Command-line entry: HOST PORT [THREADS] [DURATION] [ENDPOINTS] ->
     one JSON summary line on stdout."""
     import json as _json
     import sys as _sys

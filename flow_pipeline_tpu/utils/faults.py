@@ -41,9 +41,7 @@ so a chaos leg is a reproducible test, not a flake generator:
 
 - **Off mode is one attribute read**: call sites guard with
   ``if FAULTS.active and FAULTS.should_fail("site"): ...`` — with no
-  plan configured, the seam costs a single attribute load (the
-  ``bench.py chaos`` paired A/B pins the engaged-but-never-firing cost
-  under 2% as well).
+  plan configured, the seam costs a single attribute load.
 
 Known sites (kept in :data:`KNOWN_SITES` so a typo'd plan fails loudly
 instead of silently injecting nothing): ``sink.write``,

@@ -1,7 +1,7 @@
 """Platform selection and compile-cache placement.
 
-One rule, for every entry point that runs device code (cli, bench.py,
-__graft_entry__.py, chip_smoke.py): an explicit CPU request —
+One rule, for every entry point that runs device code (cli,
+__graft_entry__.py, chip_smoke.py, benchmark/): an explicit CPU request —
 ``-processor.backend cpu``, or ``JAX_PLATFORMS`` naming only ``cpu`` —
 pins the CPU; otherwise the process requires a TPU and exits non-zero
 with one line when JAX's default backend is anything else. There is no

@@ -1,10 +1,10 @@
 // libflowdecode fused dataplane: decode -> group -> sketch in ONE pass.
 //
 // After r08 the host-backend stage budget is dominated by host_group
-// (43.1%) and host_sketch (37.1%, BENCH_r08.json): every decoded batch
-// still round-trips through Python/numpy between grouping, the
-// per-family cascade regroup (engine/hostfused.py _fam_plan), and the
-// sketch engine. The data-plane heavy-hitter literature does detection
+// and host_sketch: every decoded batch still round-trips through
+// Python/numpy between grouping, the per-family cascade regroup
+// (engine/hostfused.py _fam_plan), and the sketch engine. The
+// data-plane heavy-hitter literature does detection
 // in a single pass over the stream (HashPipe, arXiv:1611.04825) — this
 // file is the host analogue: one native call takes a decoded chunk's
 // key lanes + value planes and

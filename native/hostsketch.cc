@@ -1,9 +1,9 @@
 // libflowdecode hostsketch: native host-resident sketch engine.
 //
 // The jitted sketch step (CMS scatter + heavy-hitter table merge) is the
-// dominant CPU cost once the host dataplane is pipelined (~66% of e2e
-// wall, BENCH_r06). Hardware offload is the established answer when the
-// general-purpose path saturates (FPGA sketch acceleration,
+// dominant CPU cost once the host dataplane is pipelined. Hardware
+// offload is the established answer when the general-purpose path
+// saturates (FPGA sketch acceleration,
 // arXiv:2504.16896; in-dataplane heavy hitters, arXiv:1611.04825); the
 // CPU-host analogue is this engine: multi-threaded uint64 count-min
 // update (plain + conservative), CMS point query, and the space-saving

@@ -1,0 +1,138 @@
+"""The benchmark's seam: `BENCHMARK.json`'s command run as the driver runs
+it, at the tiny manifest's size on the CPU.
+
+`benchmark/` decides every PR on the chip, and it reaches the program
+through `cli.processor_main -listen.feed`, the in-process bus, the
+result line's `window.dataplane` and the tracer's spans. A change that
+breaks one of them should fail here, not come back from the chip as a
+refused PR. Nothing under `benchmark/` is imported: each cell is run
+once as a subprocess and its last line of output is what is judged. All
+of it is one file, so that xdist's `--dist loadfile` gives it one worker.
+
+A CPU dry run gives counts and correctness, never a rate: no value is
+compared with anything here but for being a finite number.
+"""
+
+import json
+import math
+import os
+import subprocess
+import sys
+from typing import NamedTuple
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TINY = "benchmark/tests/fixtures/BENCHMARK.tiny.json"
+
+
+class Cell(NamedTuple):
+    ledger: str      # the ledger's cell of the same traffic kind
+    dataplane: str   # what the worker picks for it
+    seed: int
+    devices: int     # virtual CPU devices; 0: the backend's one
+
+
+# 2^31+26 loses one flow of the mesh cell at the tiny capacity (PERF.md
+# §6, PR 27)
+CELLS = {
+    "tiny-catchup": Cell("estate-catchup", "FusedPipeline", 2**31 + 11, 0),
+    "tiny-live": Cell("estate-live", "FusedPipeline", 2**31 + 11, 0),
+    "tiny-mesh4-catchup": Cell("estate-mesh4-catchup", "ShardedPipeline",
+                               2**31 + 27, 4),
+}
+TRACED_CELL = "tiny-catchup"
+# they read the `XLA Modules` line of a /device:TPU plane: a CPU trace has
+# none, and a CPU number never goes under a device metric's name
+TPU_PLANE_ONLY = ("step_device_ms_p50", "fused_step_roofline")
+
+
+def _manifest(rel):
+    with open(os.path.join(ROOT, rel)) as f:
+        return json.load(f)
+
+
+def _listed(entries, cell):
+    return [e["name"] for e in entries
+            if cell in e.get("workloads", [cell])]
+
+
+@pytest.fixture(scope="module")
+def dry_run():
+    """``dry_run(cell, trace)`` -> (exit code, stdout lines, stderr's
+    end); each (cell, trace) runs once a module."""
+    command = _manifest("BENCHMARK.json")["command"]
+    if command[0] == "python3":
+        command = [sys.executable, *command[1:]]
+    done = {}
+
+    def run(cell, trace=0):
+        if (cell, trace) not in done:
+            env = dict(os.environ, JAX_PLATFORMS="cpu")
+            env.pop("XLA_FLAGS", None)  # conftest's eight devices
+            if CELLS[cell].devices:
+                env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
+                                    f"{CELLS[cell].devices}")
+            p = subprocess.run(
+                [*command, "--manifest", TINY, "--workload", cell,
+                 "--seed", str(CELLS[cell].seed), "--seconds", "3",
+                 "--trace", str(trace)],
+                cwd=ROOT, env=env, capture_output=True, text=True,
+                timeout=300)
+            lines = [ln for ln in p.stdout.splitlines() if ln.strip()]
+            done[cell, trace] = (p.returncode, lines, p.stderr[-2000:])
+        return done[cell, trace]
+
+    return run
+
+
+def _result(dry_run, cell, trace=0):
+    rc, lines, err = dry_run(cell, trace)
+    assert rc == 0 and lines, err
+    line = json.loads(lines[-1])
+    assert isinstance(line, dict)
+    return line
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_dry_run_ends_in_one_correct_result_line(dry_run, cell):
+    line = _result(dry_run, cell)
+    assert set(line) >= {"correct", "attempted", "failed", "metrics",
+                         "device"}
+    assert line["correct"] is True, [c for c in line["checks"]
+                                     if not c["ok"]]
+    assert line["failed"] == 0 < line["attempted"]
+    assert line["device"]["platform"] == "cpu"
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_worker_picks_the_dataplane_the_ledgers_cell_runs(dry_run,
+                                                              cell):
+    # chosen by StreamWorker from the configuration's processor flags,
+    # not by this test: what tier-1's own default worker is not
+    assert (_result(dry_run, cell)["window"]["dataplane"]
+            == CELLS[cell].dataplane)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_end_to_end_metrics_are_the_ones_the_ledger_judges(dry_run, cell):
+    metrics = _result(dry_run, cell)["metrics"]
+    judged = _listed(_manifest("BENCHMARK.json")["end_to_end"],
+                     CELLS[cell].ledger)
+    assert sorted(metrics) == sorted(judged)
+    for name, m in metrics.items():
+        assert isinstance(m["value"], (int, float)), (name, m)
+        assert math.isfinite(m["value"]), (name, m)
+
+
+@pytest.mark.parametrize(
+    "metric", _listed(_manifest(TINY)["per_layer"], TRACED_CELL))
+def test_traced_dry_run_reads_every_layer_metric(dry_run, metric):
+    """A metric its reader cannot read is left out of the line, and the
+    ledger then holds a ``null`` for the PR."""
+    line = _result(dry_run, TRACED_CELL, trace=1)
+    assert line["correct"] is True
+    if metric in TPU_PLANE_ONLY:
+        assert metric not in line["metrics"]
+    else:
+        assert math.isfinite(line["metrics"][metric]["value"])

@@ -107,8 +107,7 @@ class TestMetrics:
     def test_summary_labels(self):
         """Labeled summaries: per-label-set windows/quantiles render as
         their own series (the per-router delay panels), while _sum/_count
-        keep aggregating across labels (bench.py's stage budget reads
-        them)."""
+        keep aggregating across labels."""
         reg = MetricsRegistry()
         s = reg.summary("delay_s", "d")
         for v in (1.0, 3.0):

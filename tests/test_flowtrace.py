@@ -434,9 +434,14 @@ class TestConcurrentScrape:
         bodies = []
         url = f"http://127.0.0.1:{server.port}/metrics"
         try:
-            while any(t.is_alive() for t in threads):
+            # scrape, then look: on a loaded box the writers can be done
+            # before this thread is first scheduled
+            while True:
+                alive = any(t.is_alive() for t in threads)
                 with urllib.request.urlopen(url) as r:
                     bodies.append(r.read().decode())
+                if not alive:
+                    break
             for t in threads:
                 t.join()
             with urllib.request.urlopen(url) as r:

@@ -362,9 +362,8 @@ class TestSnapshotFeed:
 
     def test_byte_budget_evicts_oldest_links(self):
         """Count-only retention holds ~FEED_HISTORY full-snapshot-sized
-        frames when every CMS tile is dirty (delta ~= full — bench.py
-        records the ratio): the byte budget evicts the oldest links
-        first, widening the full-resync window instead of growing
+        frames when every CMS tile is dirty (delta ~= full): the byte
+        budget evicts the oldest links first, widening the full-resync window instead of growing
         resident memory (the r17 journal lesson, on RAM)."""
         store = self._store_at([1])
         feed = SnapshotFeed(store, history_bytes=0)  # hold no deltas

@@ -101,16 +101,6 @@ class TestSelectPlatform:
         gen.assert_not_called()
         assert "a TPU is required" in str(exc.value.code)
 
-    def test_bench_applies_the_same_rule(self, monkeypatch):
-        import bench
-
-        monkeypatch.setattr(bench, "_PLATFORM", None)
-        with mock.patch.dict(os.environ, {"JAX_PLATFORMS": ""}), \
-                mock.patch.object(jax, "devices", _devices("cpu")), \
-                pytest.raises(SystemExit):
-            bench._select_platform()
-        assert bench._PLATFORM is None  # nothing ran, nothing labelled
-
 
 class TestCompileCache:
     def test_env_dir_set_means_code_sets_nothing(self):

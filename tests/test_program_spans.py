@@ -11,7 +11,9 @@ ring refuses to call a window whole once its start has been overwritten.
 
 import glob
 import os
+import sys
 import threading
+import time
 
 import jax
 import numpy as np
@@ -75,17 +77,45 @@ def _models(spread=True):
     return models
 
 
+# the calls snapshot_and_commit makes while none of its spans is open:
+# path arithmetic, makedirs, mkdtemp and the lag gauge, 111 of the
+# 3,500-16,000 a checkpoint of this size makes. The lightest of the five
+# parts, ckpt_state, brings 180 or more when it is moved out of its span
+UNTILED_CALLS_MAX = 144
+
+
 def _worker(tmp_path, snapshot_calls):
+    """A worker whose every snapshot_and_commit call appends (start,
+    end, thread, calls made outside any span) to ``snapshot_calls``.
+    The calls are counted by a profile hook on the worker's thread, so
+    that "the spans are the call" is judged by what ran between them
+    and not by how long a loaded box took over it."""
     real = StreamWorker.snapshot_and_commit
+    enter = trace_mod._Span.__enter__.__code__
+    leave = trace_mod._Span.__exit__.__code__
 
     def timed(self):
-        import time
+        open_spans = untiled = 0
 
+        def hook(frame, event, _arg):
+            nonlocal open_spans, untiled
+            if event == "call" and frame.f_code is enter:
+                open_spans += 1
+            elif event == "return" and frame.f_code is leave:
+                open_spans -= 1
+            elif event in ("call", "c_call") and not open_spans:
+                untiled += 1
+
+        before = sys.getprofile()
         t0 = time.time()
+        sys.setprofile(hook)
         try:
             return real(self)
         finally:
-            snapshot_calls.append((t0, time.time()))
+            sys.setprofile(before)
+            snapshot_calls.append((t0, time.time(),
+                                   threading.current_thread().name,
+                                   untiled))
 
     worker = StreamWorker(
         Consumer(_stream_to_bus(make_stream()), fixedlen=True),
@@ -100,8 +130,8 @@ def _worker(tmp_path, snapshot_calls):
 @pytest.fixture(scope="module")
 def traced_run(tmp_path_factory):
     """Spans of one worker run (8 batches, 3 window slots, a checkpoint
-    every 2 batches and after each close) under ``always``, with the
-    wall-clock interval of every snapshot_and_commit call."""
+    every 2 batches and after each close) under ``always``, with what
+    ``_worker`` notes of every snapshot_and_commit call."""
     calls: list = []
     TRACER.configure("always")
     try:
@@ -157,17 +187,22 @@ def test_poll_wait_lies_outside_apply(traced_run):
 def test_ckpt_spans_tile_their_snapshot_and_commit(traced_run, name):
     spans, calls = traced_run
     assert len(calls) >= 3
-    for t0, t1 in calls:
+    for t0, t1, thread, untiled in calls:
         parts = sorted((s for s in spans if s[0] in CKPT_SPANS
                         and t0 <= s[1] and s[2] <= t1), key=lambda s: s[1])
         assert [s[0] for s in parts] == list(CKPT_SPANS)
         mine = next(s for s in parts if s[0] == name)
+        assert mine[3] == thread  # the worker's own, which made the call
         i = parts.index(mine)
         if i:  # no overlap with the part before
             assert parts[i - 1][2] <= mine[1]
-        # the five parts are the call: what lies between them is a few
-        # statements, not work
-        assert sum(s[2] - s[1] for s in parts) >= 0.9 * (t1 - t0) - 2e-3
+        # the five parts are the call: what runs between them is a few
+        # statements, not work. Counted, not timed: the share of a ~40 ms
+        # CPU checkpoint that one descheduling between two spans takes
+        # says nothing of the program. What a count cannot see, one slow
+        # call outside the spans, the chip's sum check does (PERF.md §5:
+        # the five checkpoint_*_ms_p50 against checkpoint_ms_p50)
+        assert 0 < untiled <= UNTILED_CALLS_MAX
 
 
 def test_step_dispatch_counts_steps_and_fill(traced_run):
@@ -192,7 +227,7 @@ def test_wagg_wait_says_whether_the_drain_lagged(traced_run):
     assert all(s[5]["folded"] >= 1 for s in waits)
     # a drain inside a checkpoint is a reader's: it leaves nothing
     for s in waits:
-        if any(t0 <= s[1] and s[2] <= t1 for t0, t1 in calls):
+        if any(t0 <= s[1] and s[2] <= t1 for t0, t1, *_ in calls):
             assert s[5]["left"] == 0, s
     # flows_5m is the only aggregator: one partial a device step, each
     # folded exactly once

@@ -1,7 +1,7 @@
 """flowlint runner: rule orchestration + reporting.
 
-Scope: the whole ``flow_pipeline_tpu`` package plus ``bench.py`` and
-``tests/`` (flag tokens in tests must be real flags too); the
+Scope: the whole ``flow_pipeline_tpu`` package plus ``tests/`` (flag
+tokens in tests must be real flags too); the
 abi-contract rule additionally reads ``native/*.cc``. Exit status: 0 =
 clean, 1 = findings, so ``make lint`` and CI gate on it directly.
 ``--json`` emits one machine-readable document (file/line/rule/message
@@ -32,7 +32,7 @@ from .core import (
     suppression_findings,
 )
 
-DEFAULT_SUBDIRS = ("flow_pipeline_tpu", "bench.py", "tests")
+DEFAULT_SUBDIRS = ("flow_pipeline_tpu", "tests")
 # (rule name, check entrypoint) in the canonical order. Checks are pure
 # reads over the parsed SourceFiles, so run_lint fans them out on a
 # thread pool; THIS tuple's order is what keeps output deterministic.
