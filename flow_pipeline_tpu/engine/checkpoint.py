@@ -5,7 +5,10 @@ current offset", ref: README.md:115); a sketch worker additionally needs
 the open-window device state so a restart resumes without double counting
 (SURVEY.md §5). A checkpoint is a directory with:
 
-- ``arrays.npz``   every device/host array leaf (numpy, compressed)
+- ``arrays.npz``   every device/host array leaf (numpy, members stored:
+  the planes are float32 counters, and deflating them was all but the
+  whole cost of a checkpoint for half the bytes; ``load_checkpoint``
+  reads the deflated members of older checkpoints too)
 - ``meta.json``    consumer positions, window dicts, scalars, tree layout
 
 Writes follow the full durable-publish protocol via ``utils/fsutil``
@@ -132,7 +135,7 @@ def save_checkpoint(path: str, state: Any) -> None:
             arrays: dict[str, np.ndarray] = {}
             meta = _encode(state, arrays, "r")
             buf = io.BytesIO()
-            np.savez_compressed(buf, **arrays)
+            np.savez(buf, **arrays)
             npz = buf.getvalue()
             meta_json = json.dumps(meta).encode("utf-8")
             span["raw_bytes"] = sum(a.nbytes for a in arrays.values())

@@ -123,6 +123,82 @@ class TestCheckpointResume:
         inner = got["windows"][1699999800][(65000, 65001)]
         np.testing.assert_array_equal(inner, [1, 2, 3])
 
+    @pytest.mark.parametrize("case", [
+        "roundtrip", "stored", "deflated", "deflated_old"])
+    def test_checkpoint_format(self, tmp_path, case):
+        """One format is written (ZIP_STORED members), two are read: a
+        mixed tree restores bit for bit, and a checkpoint whose
+        arrays.npz a pre-PR-30 build deflated loads to the same tree,
+        from <path> and from <path>.old."""
+        import os
+        import zipfile
+        from typing import NamedTuple
+
+        import jax.numpy as jnp
+
+        from flow_pipeline_tpu.engine.checkpoint import (
+            load_checkpoint,
+            save_checkpoint,
+        )
+        from flow_pipeline_tpu.obs.trace import TRACER
+
+        class Sketch(NamedTuple):
+            planes: object
+            table: object
+            folds: int
+
+        rng = np.random.default_rng(30)
+        planes = rng.random((3, 4, 256), np.float32)
+        planes[:, :, 100:] = 0.0  # partly filled, as a young window's are
+        table = rng.integers(0, 2**63, (64, 5), dtype=np.uint64)
+        state = {
+            "covered": {"0": 17, "1": 2**40},
+            "models": {"hh": Sketch(jnp.asarray(planes), table, 7),
+                       "empty": np.zeros((0, 3), np.int32)},
+            "parts": [np.float32(1.5), (np.arange(6, dtype=np.int16), None)],
+            "rate": 0.25, "name": "w0", "on": True,
+        }
+        path = str(tmp_path / "ckpt")
+        TRACER.configure("always")
+        try:
+            save_checkpoint(path, state)
+            spans = TRACER.snapshot()
+        finally:
+            TRACER.configure("off")
+        npz = os.path.join(path, "arrays.npz")
+        if case == "stored":
+            with zipfile.ZipFile(npz) as z:
+                assert z.namelist() and all(
+                    i.compress_type == zipfile.ZIP_STORED
+                    for i in z.infolist())
+            (ser,) = [s[5] for s in spans if s[0] == "ckpt_serialize"]
+            assert ser["npz_bytes"] == os.path.getsize(npz)
+            assert ser["npz_bytes"] >= ser["raw_bytes"] > planes.nbytes
+        if case.startswith("deflated"):
+            # the parent's format: the same members, deflated
+            with np.load(npz) as z:
+                members = {k: z[k] for k in z.files}
+            np.savez_compressed(npz, **members)
+            with zipfile.ZipFile(npz) as z:
+                assert all(i.compress_type == zipfile.ZIP_DEFLATED
+                           for i in z.infolist())
+            assert os.path.getsize(npz) < planes.nbytes
+        if case == "deflated_old":
+            os.rename(path, path + ".old")
+
+        got = load_checkpoint(path)
+        assert got["covered"] == state["covered"]
+        assert (got["rate"], got["name"], got["on"]) == (0.25, "w0", True)
+        hh = got["models"]["hh"]  # a NamedTuple comes back as its fields
+        assert set(hh) == {"planes", "table", "folds"} and hh["folds"] == 7
+        for want, have in ((planes, hh["planes"]), (table, hh["table"]),
+                           (state["models"]["empty"], got["models"]["empty"]),
+                           (state["parts"][0], got["parts"][0]),
+                           (state["parts"][1][0], got["parts"][1][0])):
+            assert have.dtype == want.dtype and have.shape == want.shape
+            assert have.tobytes() == want.tobytes()
+        assert isinstance(got["parts"], list) and got["parts"][1][1] is None
+
     def test_kill_mid_window_resume_no_loss_no_double(self, tmp_path):
         """Fault injection: worker dies between snapshots; a fresh worker
         restores and the merged output still matches the oracle exactly."""
