@@ -1,0 +1,8 @@
+"""Bytes of the arrays one checkpoint serializes, the window store's among
+them, in MB: median. Source: ckpt_serialize's raw_bytes."""
+
+from benchmark import program_spans
+
+
+def read(run):
+    return program_spans.p50_arg(run, "ckpt_serialize", "raw_bytes", 1e-6)

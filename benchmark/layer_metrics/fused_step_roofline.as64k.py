@@ -1,0 +1,5 @@
+"""The fused step's least possible time over its measured device time, in per
+cent; no kernel is new, and roofline.py's bytes serve unchanged. The reader
+is fused_step_roofline's own."""
+
+from benchmark.layer_metrics.fused_step_roofline import read  # noqa: F401

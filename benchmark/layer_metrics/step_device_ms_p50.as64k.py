@@ -1,0 +1,9 @@
+"""Device time of one execution of the fused step (estate-catchup's shapes:
+the AS labels change no shape): median over the traced window. Source:
+profiler trace, device plane, as step_device_ms_p50 reads it."""
+
+from benchmark import reduce
+
+
+def read(run):
+    return None if run.trace is None else reduce.p50(run.trace.step_ms)

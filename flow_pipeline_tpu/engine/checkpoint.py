@@ -139,6 +139,7 @@ def save_checkpoint(path: str, state: Any) -> None:
             npz = buf.getvalue()
             meta_json = json.dumps(meta).encode("utf-8")
             span["raw_bytes"] = sum(a.nbytes for a in arrays.values())
+            span["members"] = len(arrays)
             span["npz_bytes"] = len(npz)
         with TRACER.span("ckpt_write"):
             fsutil.write_bytes_durable(os.path.join(tmp, "arrays.npz"), npz)
@@ -186,7 +187,14 @@ def load_checkpoint(path: str) -> Any:
     checkpoint under .old, which is still a consistent snapshot."""
     if not os.path.isdir(path) and os.path.isdir(path + ".old"):
         path = path + ".old"
-    with open(os.path.join(path, "meta.json")) as f:
-        meta = json.load(f)
-    arrays = np.load(os.path.join(path, "arrays.npz"))
-    return _decode(meta, arrays)
+    with TRACER.span("ckpt_load") as span:
+        with open(os.path.join(path, "meta.json")) as f:
+            meta = json.load(f)
+        # one zip member read an array: a checkpoint of before the window
+        # store's array form has a member a group, and is slow to read
+        arrays = np.load(os.path.join(path, "arrays.npz"))
+        state = _decode(meta, arrays)
+        span["members"] = len(arrays.files)
+        span["bytes"] = sum(os.path.getsize(os.path.join(path, name))
+                            for name in ("arrays.npz", "meta.json"))
+    return state

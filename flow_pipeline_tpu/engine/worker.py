@@ -9,11 +9,14 @@ partitions — the sarama consumer-group model (ref: inserter/inserter.go:
 
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
 from dataclasses import dataclass
 from typing import Any, Optional, Sequence
+
+import numpy as np
 
 from ..families import registry
 from ..guard import GuardConfig, GuardController
@@ -885,23 +888,54 @@ def _kind_matches(model, ms: dict, name: str) -> bool:
 
 
 def save_wagg_state(model) -> dict:
+    """The window store's persistent form: for each open window its keys
+    as one ``[G, lanes]`` uint32 array and its sums as one
+    ``[G, nvals + 1]`` uint64 array, rows in the store's order. The
+    checkpoint's count of npz members and its ``meta.json`` then do not
+    grow with G, where an array a group and a JSON list a key made a
+    window of 6x10^4 groups cost seconds to write and a minute to read."""
     model._drain()  # fold pending device partials first: the snapshot
     # must cover everything the committed offsets cover
+    lanes = model.store_key_lanes
+    width = len(model.config.value_cols) + 1
+    with TRACER.span("wagg_state", windows=len(model.windows)) as span:
+        stores = [{
+            "slot": slot,
+            "keys": np.fromiter(
+                itertools.chain.from_iterable(store), np.uint32,
+                len(store) * lanes).reshape(len(store), lanes),
+            "sums": np.array(list(store.values()), np.uint64).reshape(
+                len(store), width),
+        } for slot, store in model.windows.items()]
+        span["groups"] = sum(len(s["keys"]) for s in stores)
     return {
         "kind": "window_agg",
-        "windows": model.windows,
+        "stores": stores,
         "watermark": model.watermark,
     }
 
 
 def restore_wagg_state(model, ms: dict, name: str) -> None:
-    windows = {
-        int(slot): {k: v for k, v in store.items()}
-        for slot, store in ms["windows"].items()
-    }
+    """Reads both forms a checkpoint may hold: ``stores`` (above), and
+    the ``windows`` dict of key tuples that builds before it wrote, which
+    stays readable so that an operator upgrades across a restart. The old
+    form is slow by nature (an npz member and a JSON list a group to
+    open and rebuild); nothing restores it faster than it was."""
     want = model.store_key_lanes
-    bad = next((k for store in windows.values()
-                for k in store if len(k) != want), None)
+    if "stores" in ms:
+        got = {int(s["keys"].shape[1]) for s in ms["stores"]}
+        windows = {
+            int(s["slot"]): dict(zip(
+                map(tuple, s["keys"].tolist()),
+                # rows of one owned array: the fold adds into them
+                np.array(s["sums"], dtype=np.uint64)))
+            for s in ms["stores"]
+        }
+    else:
+        windows = {int(slot): dict(store)
+                   for slot, store in ms["windows"].items()}
+        got = {len(k) for store in windows.values() for k in store}
+    bad = next((n for n in got if n != want), None)
     if bad is not None:
         # a checkpoint from a different grouping layout (e.g.
         # pre-sampling builds without the rate lane): restoring
@@ -910,7 +944,7 @@ def restore_wagg_state(model, ms: dict, name: str) -> None:
         log.warning(
             "checkpoint window keys have %d lanes, model "
             "%r expects %d; skipping its window state",
-            len(bad), name, want)
+            bad, name, want)
     else:
         model.windows = windows
     model.watermark = ms["watermark"]
@@ -944,8 +978,6 @@ def restore_hh_state(model, ms: dict, name: str) -> None:
             model.model.config.hh_sketch)
         return
     if inv_cfg:
-        import numpy as np
-
         from ..models.heavy_hitter import InvState
 
         # numpy, NOT jnp: without x64 a jnp.asarray
@@ -977,8 +1009,6 @@ def save_spread_state(model) -> dict:
 def restore_spread_state(model, ms: dict, name: str) -> None:
     if not _kind_matches(model, ms, name):
         return
-    import numpy as np
-
     from ..models.spread import SpreadState
 
     # numpy, NOT jnp: spread state is host-resident by

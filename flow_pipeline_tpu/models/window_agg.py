@@ -372,6 +372,7 @@ class WindowAggregator:
             span["groups"] = len(keys)
             self._merge_partials(keys, np.concatenate(all_sums),
                                  np.concatenate(all_counts))
+            span["store_groups"] = sum(map(len, self.windows.values()))
 
     def _merge_partials(self, keys, plane_sums, counts) -> None:
         """Fold device partial aggregates (keys + 16-bit value planes +
@@ -527,9 +528,22 @@ def rows_from_stores(config: WindowAggConfig,
                      stores: list[tuple[int, dict]]) -> dict[str, np.ndarray]:
     """Columnar flush rows from popped (slot, store) pairs — the second
     half of flush(), a pure function so the ingest flusher can run it off
-    the worker thread. Vectorized: one lexsort + reduceat per slot
-    instead of a Python dict loop per key (the old per-key loop was the
-    dominant flush cost at 10k+ groups/window)."""
+    the worker thread. A call that has stores to turn into rows (a
+    close, not the per-batch probe that popped nothing) is one
+    ``wagg_rows`` span."""
+    if not stores:
+        return _rows_from_stores(config, stores)
+    with TRACER.span("wagg_rows") as span:
+        rows = _rows_from_stores(config, stores)
+        span["rows"] = len(rows["timeslot"])
+    return rows
+
+
+def _rows_from_stores(config: WindowAggConfig,
+                      stores: list[tuple[int, dict]]) -> dict[str, np.ndarray]:
+    """Vectorized: one lexsort + reduceat per slot instead of a Python
+    dict loop per key (the old per-key loop was the dominant flush cost
+    at 10k+ groups/window)."""
     scaled = config.scale_col is not None
     nvals = len(config.value_cols)
     ts_parts, key_parts, val_parts, scaled_parts = [], [], [], []

@@ -143,12 +143,12 @@ class MultihostPipeline:
 
     def snapshot(self, path: str) -> None:
         from ..engine.checkpoint import save_checkpoint
+        from ..engine.worker import save_wagg_state
 
-        self.wagg._drain()  # snapshot must cover everything ingested
         save_checkpoint(path, {
             "batches_done": self.batches_done,
-            "wagg": {"windows": self.wagg.windows,
-                     "watermark": self.wagg.watermark},
+            # drains first: the snapshot covers everything ingested
+            "wagg": save_wagg_state(self.wagg),
             "hh": {name: m.local_state() for name, m in self.hh.items()},
         })
 
@@ -156,16 +156,13 @@ class MultihostPipeline:
         """Rehydrate this process's share; returns the number of batches
         the snapshot covers (the resume offset), or None if absent."""
         from ..engine.checkpoint import checkpoint_exists, load_checkpoint
+        from ..engine.worker import restore_wagg_state
 
         if not checkpoint_exists(path):
             return None
         snap = load_checkpoint(path)
         self.batches_done = snap["batches_done"]
-        self.wagg.windows = {
-            int(slot): dict(store)
-            for slot, store in snap["wagg"]["windows"].items()
-        }
-        self.wagg.watermark = snap["wagg"]["watermark"]
+        restore_wagg_state(self.wagg, snap["wagg"], self.wagg.name)
         for name, local in snap["hh"].items():
             self.hh[name].load_local_state(local)
         return self.batches_done

@@ -24,6 +24,8 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TINY = "benchmark/tests/fixtures/BENCHMARK.tiny.json"
+# a manifest of its own beside it: estate-as64k-catchup's twin (ISSUE 31)
+TINY_AS = "benchmark/tests/fixtures/BENCHMARK.tiny-as.json"
 
 
 class Cell(NamedTuple):
@@ -31,6 +33,7 @@ class Cell(NamedTuple):
     dataplane: str   # what the worker picks for it
     seed: int
     devices: int     # virtual CPU devices; 0: the backend's one
+    manifest: str = TINY
 
 
 # 2^31+26 loses one flow of the mesh cell at the tiny capacity (PERF.md
@@ -42,9 +45,16 @@ CELLS = {
                                2**31 + 27, 4),
 }
 TRACED_CELL = "tiny-catchup"
+# 256 ASes a side at the tiny size: run once, traced, for what the exact
+# path counts
+AS_CELL = "tiny-as-catchup"
+TRACED = {TRACED_CELL: CELLS[TRACED_CELL],
+          AS_CELL: Cell("estate-as64k-catchup", "FusedPipeline", 2**31 + 11,
+                        0, TINY_AS)}
 # they read the `XLA Modules` line of a /device:TPU plane: a CPU trace has
 # none, and a CPU number never goes under a device metric's name
-TPU_PLANE_ONLY = ("step_device_ms_p50", "fused_step_roofline")
+TPU_PLANE_ONLY = ("step_device_ms_p50", "fused_step_roofline",
+                  "step_device_ms_p50.as64k", "fused_step_roofline.as64k")
 
 
 def _manifest(rel):
@@ -68,14 +78,15 @@ def dry_run():
 
     def run(cell, trace=0):
         if (cell, trace) not in done:
+            spec = CELLS.get(cell) or TRACED[cell]
             env = dict(os.environ, JAX_PLATFORMS="cpu")
             env.pop("XLA_FLAGS", None)  # conftest's eight devices
-            if CELLS[cell].devices:
+            if spec.devices:
                 env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                                    f"{CELLS[cell].devices}")
+                                    f"{spec.devices}")
             p = subprocess.run(
-                [*command, "--manifest", TINY, "--workload", cell,
-                 "--seed", str(CELLS[cell].seed), "--seconds", "3",
+                [*command, "--manifest", spec.manifest, "--workload", cell,
+                 "--seed", str(spec.seed), "--seconds", "3",
                  "--trace", str(trace)],
                 cwd=ROOT, env=env, capture_output=True, text=True,
                 timeout=300)
@@ -125,14 +136,34 @@ def test_end_to_end_metrics_are_the_ones_the_ledger_judges(dry_run, cell):
         assert math.isfinite(m["value"]), (name, m)
 
 
-@pytest.mark.parametrize(
-    "metric", _listed(_manifest(TINY)["per_layer"], TRACED_CELL))
-def test_traced_dry_run_reads_every_layer_metric(dry_run, metric):
+@pytest.mark.parametrize("cell,metric", [
+    (cell, metric) for cell, spec in TRACED.items()
+    for metric in _listed(_manifest(spec.manifest)["per_layer"], cell)])
+def test_traced_dry_run_reads_every_layer_metric(dry_run, cell, metric):
     """A metric its reader cannot read is left out of the line, and the
     ledger then holds a ``null`` for the PR."""
-    line = _result(dry_run, TRACED_CELL, trace=1)
+    line = _result(dry_run, cell, trace=1)
     assert line["correct"] is True
     if metric in TPU_PLANE_ONLY:
         assert metric not in line["metrics"]
     else:
         assert math.isfinite(line["metrics"][metric]["value"])
+
+
+def test_the_as_twin_is_the_ledgers_cell_at_the_tiny_size():
+    """Every per-layer metric the ledger's cell reports, and no other."""
+    assert (_listed(_manifest(TINY_AS)["per_layer"], AS_CELL)
+            == _listed(_manifest("BENCHMARK.json")["per_layer"],
+                       TRACED[AS_CELL].ledger))
+
+
+def test_the_as_twin_folds_a_large_store_into_a_checkpoint_of_few_members(
+        dry_run):
+    line = _result(dry_run, AS_CELL, trace=1)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["window"]["dataplane"] == TRACED[AS_CELL].dataplane
+    value = {name: m["value"] for name, m in line["metrics"].items()}
+    # 16 ASes a side make 256 groups at most; 256 a side fill a batch
+    assert value["fold_groups_per_batch"] > 256
+    assert value["close_rows_flows5m"] > 256
+    assert value["store_groups_p50"] > 10 * value["checkpoint_members_p50"]

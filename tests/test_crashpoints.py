@@ -34,6 +34,8 @@ import pytest
 from flow_pipeline_tpu.engine.checkpoint import (checkpoint_exists,
                                                  load_checkpoint,
                                                  save_checkpoint)
+from flow_pipeline_tpu.engine.worker import (restore_wagg_state,
+                                             save_wagg_state)
 from flow_pipeline_tpu.gateway.delta import encode_full
 from flow_pipeline_tpu.history.archive import (ArchiveReader,
                                                ArchiveWriter,
@@ -41,6 +43,7 @@ from flow_pipeline_tpu.history.archive import (ArchiveReader,
 from flow_pipeline_tpu.mesh.journal import (JOURNAL_FILE,
                                             CoordinatorJournal,
                                             replay_journal)
+from flow_pipeline_tpu.models import WindowAggregator
 from flow_pipeline_tpu.sink.resilient import ResilientSink, replay_deadletter
 from flow_pipeline_tpu.utils import crashsim, fsutil
 
@@ -245,11 +248,65 @@ def _check_checkpoint(croot: str, acked: list) -> None:
             "acked checkpoint restored a torn state"
 
 
+# ---- scenario: the window store's array form in a checkpoint ----------------
+
+
+def _wagg(step: int) -> WindowAggregator:
+    """An aggregator two batches apart: step 2 has added to every group
+    of step 1's open window and opened 40 groups more."""
+    agg = WindowAggregator()
+    lanes = agg.store_key_lanes
+    for i in range(200 + 40 * (step - 1)):
+        agg._fold_rows(
+            np.array([[T0, *[i + 7 * j for j in range(lanes)]]], np.uint32),
+            np.array([[1500 * step, 3 * step, step]], np.uint64))
+    agg.watermark = T0 + 60 * step
+    return agg
+
+
+def _run_checkpoint_wagg(root: str, rec: fsutil.OpRecorder) -> None:
+    path = os.path.join(root, "ckpt", "snap")
+    with fsutil.observed(rec):
+        for step in (1, 2):
+            save_checkpoint(path, {"models": {
+                "flows_5m": save_wagg_state(_wagg(step))}})
+            rec.mark(f"w{step}")
+
+
+def _restored_wagg_step(path: str):
+    """The step whose store the checkpoint restores to, group for group;
+    None if it is neither's."""
+    got = WindowAggregator()
+    restore_wagg_state(got, load_checkpoint(path)["models"]["flows_5m"],
+                       "flows_5m")
+    for step in (1, 2):
+        want = _wagg(step)
+        if (got.watermark == want.watermark
+                and got.windows.keys() == want.windows.keys()
+                and all(got.windows[s].keys() == st.keys()
+                        and all(np.array_equal(got.windows[s][k], v)
+                                for k, v in st.items())
+                        for s, st in want.windows.items())):
+            return step
+    return None
+
+
+def _check_checkpoint_wagg(croot: str, acked: list) -> None:
+    path = os.path.join(croot, "ckpt", "snap")
+    if not acked and not checkpoint_exists(path):
+        return  # crashed before anything was published: fine
+    step = _restored_wagg_step(path)  # loads completely or raises
+    assert step is not None, "restored store matches neither saved state"
+    if "w2" in acked:
+        assert step == 2, "acked checkpoint w2 did not restore"
+
+
 _SCENARIOS = {
     "journal": (_run_journal, _check_journal),
     "deadletter": (_run_dlq, _check_dlq),
     "archive": (_run_archive, _check_archive),
     "checkpoint": (_run_checkpoint, _check_checkpoint),
+    "checkpoint_wagg": (_run_checkpoint_wagg, _check_checkpoint_wagg),
 }
 
 
@@ -300,6 +357,8 @@ class TestBarrierMutations:
         ("deadletter", "replace"),
         ("checkpoint", "fsync"), ("checkpoint", "fsync_dir"),
         ("checkpoint", "replace"),
+        ("checkpoint_wagg", "fsync"), ("checkpoint_wagg", "fsync_dir"),
+        ("checkpoint_wagg", "replace"),
         # the archive publishes by append+rotate, never by replace
         ("archive", "fsync"), ("archive", "fsync_dir"),
     ]

@@ -45,8 +45,10 @@ WORKER_SPANS = {
     "wagg_wait": "apply",
     "wagg_d2h": "apply",
     "wagg_fold": "apply",
+    "wagg_rows": "apply",
     "flush": "apply",
     "ckpt_state": "apply",
+    "wagg_state": "apply",
     "ckpt_d2h": "apply",
     "ckpt_serialize": "apply",
     "ckpt_write": "apply",
@@ -59,9 +61,10 @@ SPAN_ARGS = {
     "lane_build": ("rows", "padded"), "h2d": ("bytes", "cols"),
     "step_dispatch": ("rows", "padded", "do_hh", "do_dd"),
     "wagg_wait": ("folded", "left"),
-    "wagg_d2h": ("bytes",), "wagg_fold": ("groups",),
+    "wagg_d2h": ("bytes",), "wagg_fold": ("groups", "store_groups"),
+    "wagg_rows": ("rows",), "wagg_state": ("windows", "groups"),
     "ckpt_d2h": ("bytes", "leaves"),
-    "ckpt_serialize": ("raw_bytes", "npz_bytes"),
+    "ckpt_serialize": ("raw_bytes", "npz_bytes", "members"),
     "decode": ("rows", "partition"), "flush": ("rows", "table"),
 }
 SCOPES = ("hh_chain_sort", "dst_sort", "hh_table_merge", "dense_scatter",
