@@ -52,9 +52,13 @@ class HeavyHitterConfig:
     cms_impl: str = "xla"
     # Feed the table merge only 2*capacity candidates — the batch's top
     # groups by plane-0 sum PLUS every group whose key is already
-    # RESIDENT in the table (cheap hash-membership test against the
-    # current table keys) — shrinking its sort from (capacity + batch)
-    # rows to 3*capacity. The CMS still counts EVERY row (estimates
+    # RESIDENT in the table — shrinking its sort from (capacity + batch)
+    # rows to 3*capacity. Residency is hash membership against the
+    # current table keys: on the device one dense [capacity, groups]
+    # compare of 32-bit hashes (_resident: no dependent gathers); the
+    # host twins (hostsketch/engine.py, native hs_hh_prefilter) keep a
+    # binary search in the sorted hashes, the right algorithm on a host,
+    # and give the same mask. The CMS still counts EVERY row (estimates
     # unaffected). Resident keys therefore accumulate their increments
     # every round, exactly like the unfiltered merge — the r4 prefilter
     # starved residents that didn't rank per batch, silently
@@ -195,6 +199,23 @@ def _cms_add(config: HeavyHitterConfig):
             else cms_ops.cms_add)
 
 
+def _resident(th, gh, row_valid):
+    """[N] bool: the valid groups whose hash ``gh`` [N] uint32 is one of
+    the table's hashes ``th`` [C] uint32 (empty slots hash like any row).
+
+    One dense compare, OR-reduced over the table: C x N independent
+    uint32 compares, which XLA fuses into one pass that writes no [C, N]
+    predicate. The table is the major axis, so the reduction combines
+    whole vectors of groups element-wise and crosses no lanes (on a v5e
+    the other orientation and a form blocked by 128 table rows read the
+    same). sort(th) + searchsorted + ts[pos] == gh gives the same mask,
+    but searchsorted is a loop of ceil(log2(C + 1)) gathers, each waiting
+    for the one before: 2.1 ms a family on a v5e at C = 1,024, N = 32,768
+    where this takes 0.04 (PERF.md §6, PR 32). The prefilter's static
+    guard keeps C < N / 2 wherever this runs."""
+    return (th[:, None] == gh[None, :]).any(axis=0) & row_valid
+
+
 def _apply_grouped(state: HHState, uniq, sums, row_valid,
                    config: HeavyHitterConfig) -> HHState:
     """CMS + table merge over pre-aggregated groups (the post-sort half of
@@ -209,13 +230,14 @@ def _apply_grouped(state: HHState, uniq, sums, row_valid,
         # the config docstring). Membership rides one 32-bit hash lane:
         # a resident's hash is in the table's hash set by construction
         # (no false negatives); a false positive (~C/2^32 per group)
-        # merely spends one of the 2C candidate slots on a loser.
+        # merely spends one of the 2C candidate slots on a loser. The
+        # test is dense (_resident): a binary search in the sorted hashes
+        # is a chain of dependent gathers on the device; the host twins
+        # keep theirs.
         c = config.capacity
         th, _ = hash_lanes(state.table_keys)
         gh, _ = hash_lanes(uniq)
-        ts = jnp.sort(th)
-        pos = jnp.clip(jnp.searchsorted(ts, gh), 0, c - 1)
-        resident = (ts[pos] == gh) & row_valid
+        resident = _resident(th, gh, row_valid)
         metric = jnp.where(row_valid, sums[:, 0], -jnp.inf)
         metric = jnp.where(resident, jnp.inf, metric)
         _, sel = jax.lax.top_k(metric, 2 * c)

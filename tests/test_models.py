@@ -1,6 +1,10 @@
 """Model-level tests: heavy-hitter top-K vs exact oracle (the <=1% error
 gate from BASELINE.json) and DDoS spike detection on injected attacks."""
 
+from functools import partial
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -11,7 +15,10 @@ from flow_pipeline_tpu.models import (
     HeavyHitterConfig,
     HeavyHitterModel,
 )
+from flow_pipeline_tpu.models import heavy_hitter as hh
 from flow_pipeline_tpu.models.oracle import topk_exact
+from flow_pipeline_tpu.ops.segment import hash_lanes
+from flow_pipeline_tpu.ops.topk import topk_init
 from flow_pipeline_tpu.schema.batch import FlowBatch
 
 
@@ -451,3 +458,197 @@ class TestSpaceSavingAdmissionSeeded:
         evictions = drive_admission_rounds(rounds)
         # the adversarial case must actually be exercised, not vacuous
         assert evictions > 20
+
+
+# ---- the prefilter's residency test (PR 32) --------------------------------
+
+
+def _resident_sorted(th, gh, row_valid):
+    """The form `_resident` replaced, kept as the reference: binary search
+    of every group hash in the table's sorted hashes (`jnp.searchsorted`
+    runs as a loop of dependent gathers), then one gather and a compare."""
+    ts = jnp.sort(th)
+    pos = jnp.clip(jnp.searchsorted(ts, gh), 0, th.shape[0] - 1)
+    return (ts[pos] == gh) & row_valid
+
+
+def _loop_primitives(jaxpr) -> list:
+    """Names of the while / scan primitives anywhere in a jaxpr, the
+    jaxprs nested in its equations' parameters included (a `jit` inside
+    the traced function, such as `jnp.searchsorted`, hides its loop in
+    one)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("while", "scan"):
+            found.append(eqn.primitive.name)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)  # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    found.extend(_loop_primitives(sub))
+    return found
+
+
+def _hashes(rng, n):
+    # strictly inside (0, 2^32 - 1), so a case can put hashes outside
+    return rng.integers(1, 2**32 - 1, n, dtype=np.uint32)
+
+
+def _case_empty_table(rng, c, n):
+    # every slot the all-sentinel row: one hash, C times; a group that is
+    # the sentinel row itself hashes like the slots, as it always did
+    keys, _ = topk_init(c, 4, 3)
+    th = np.asarray(hash_lanes(keys)[0])
+    gh = _hashes(rng, n)
+    gh[5] = th[0]
+    return th, gh, np.ones(n, bool)
+
+
+def _case_full_table(rng, c, n):
+    th = _hashes(rng, c)
+    gh = _hashes(rng, n)
+    at = rng.permutation(n)[:c]
+    gh[at] = th  # every resident appears in the batch
+    return th, gh, rng.random(n) < 0.8
+
+
+def _case_guard_edge(rng, c, n):
+    # N = 2 C + 1: the smallest batch for which the prefilter arms
+    n = 2 * c + 1
+    th = _hashes(rng, c)
+    gh = _hashes(rng, n)
+    gh[::3] = th[rng.integers(0, c, len(gh[::3]))]
+    return th, gh, rng.random(n) < 0.5
+
+
+def _case_invalid_residents(rng, c, n):
+    # rows whose hash is in the table but which are not valid groups
+    th = _hashes(rng, c)
+    gh = _hashes(rng, n)
+    gh[:c] = th
+    valid = np.ones(n, bool)
+    valid[:c:2] = False
+    return th, gh, valid
+
+
+def _case_repeated_hash(rng, c, n):
+    th = _hashes(rng, c)
+    th[c // 2:] = th[: c - c // 2]  # every hash twice
+    th[:7] = th[0]
+    gh = _hashes(rng, n)
+    gh[10:20] = th[0]
+    gh[20:30] = th[c // 3]
+    return th, gh, np.ones(n, bool)
+
+
+def _case_outside_the_table(rng, c, n):
+    # group hashes below the smallest and above the largest table hash:
+    # where the binary search returned 0 and C (clipped to C - 1)
+    th = rng.integers(1000, 2**32 - 1000, c, dtype=np.uint32)
+    gh = _hashes(rng, n)
+    gh[:8] = np.arange(8, dtype=np.uint32)            # below all, 0 too
+    gh[8:16] = np.uint32(2**32 - 1) - np.arange(8, dtype=np.uint32)
+    gh[16] = th.min()
+    gh[17] = th.max()
+    return th, gh, np.ones(n, bool)
+
+
+def _case_extreme_table(rng, c, n):
+    # the table holds 0 and 2^32 - 1 themselves
+    th = _hashes(rng, c)
+    th[0], th[1] = 0, np.uint32(2**32 - 1)
+    gh = _hashes(rng, n)
+    gh[:4] = (0, 2**32 - 1, 1, 2**32 - 2)
+    return th, gh, np.ones(n, bool)
+
+
+class TestResidencyDense:
+    """`models.heavy_hitter._resident` (PR 32): the same mask as the sorted
+    search it replaced, bit for bit, and no loop left on the device."""
+
+    FAMILIES = {
+        "top_talkers": ("src_addr", "dst_addr", "src_port", "dst_port",
+                        "proto"),
+        "top_src_ips": ("src_addr",),
+        "top_dst_ips": ("dst_addr",),
+    }
+
+    @pytest.mark.parametrize("shape", [(64, 512), (128, 2048)],
+                             ids=lambda s: f"C{s[0]}xN{s[1]}")
+    @pytest.mark.parametrize("case", [
+        _case_empty_table, _case_full_table, _case_guard_edge,
+        _case_invalid_residents, _case_repeated_hash,
+        _case_outside_the_table, _case_extreme_table,
+    ], ids=lambda f: f.__name__[6:])
+    def test_mask_is_the_sorted_searchs_and_numpys(self, case, shape):
+        th, gh, valid = case(np.random.default_rng(41), *shape)
+        args = (jnp.asarray(th), jnp.asarray(gh), jnp.asarray(valid))
+        got = np.asarray(jax.jit(hh._resident)(*args))
+        want = np.isin(gh, th) & valid
+        assert want.any() and not want.all()  # the case bites both ways
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(
+            got, np.asarray(jax.jit(_resident_sorted)(*args)))
+
+    @pytest.mark.parametrize("family", sorted(FAMILIES))
+    def test_state_bit_equal_to_the_sorted_forms_after_every_step(
+            self, family, monkeypatch):
+        cfg = HeavyHitterConfig(
+            key_cols=self.FAMILIES[family], batch_size=2048,
+            width=1 << 12, capacity=128)
+        g = FlowGenerator(ZipfProfile(n_keys=20000, alpha=1.1), seed=42)
+        batches = [g.batch(2048 - 9 * (i % 3)) for i in range(20)]
+
+        def steps(update):
+            state = hh.hh_init(cfg)
+            for b in batches:
+                padded, mask = b.pad_to(cfg.batch_size)
+                cols = {k: jnp.asarray(v) for k, v in
+                        padded.device_columns(hh.input_cols(cfg)).items()}
+                state = update(state, cols, jnp.asarray(mask),
+                               config=cfg)
+                yield [np.asarray(x) for x in state]
+
+        # the reference: the same update traced with the parent's form in
+        # _resident's place (a jit of its own: hh_update's trace is cached)
+        monkeypatch.setattr(hh, "_resident", _resident_sorted)
+        ref_update = jax.jit(hh.hh_update.__wrapped__,
+                             static_argnames=("config",))
+        want = list(steps(ref_update))
+        monkeypatch.undo()
+        residents = 0
+        for i, (new, ref) in enumerate(zip(steps(hh.hh_update), want)):
+            for name, a, b in zip(hh.HHState._fields, new, ref):
+                np.testing.assert_array_equal(a, b, err_msg=f"{name} "
+                                              f"after step {i + 1}")
+            residents += int((new[1] != 0xFFFFFFFF).any(axis=1).sum())
+        assert residents > 20 * cfg.capacity // 2  # the table was in use
+
+    @staticmethod
+    def _apply_grouped_jaxpr(key_cols, n=32768):
+        """`_apply_grouped` at the default configuration's shapes (the
+        processor's defaults at the benchmark's batch of 32,768)."""
+        cfg = HeavyHitterConfig(key_cols=key_cols, batch_size=n)
+        assert n > 2 * cfg.capacity  # the prefilter arms
+        shape = jax.ShapeDtypeStruct
+        state = jax.tree_util.tree_map(
+            lambda a: shape(a.shape, a.dtype), hh.hh_init(cfg))
+        planes = len(cfg.value_cols) + 1
+        return jax.make_jaxpr(partial(hh._apply_grouped, config=cfg))(
+            state, shape((n, hh.key_width(cfg)), np.uint32),
+            shape((n, planes), np.float32), shape((n,), np.bool_)).jaxpr
+
+    @pytest.mark.parametrize("family", sorted(FAMILIES))
+    def test_no_loop_left_in_apply_grouped(self, family):
+        # the jaxpr, not a platform's HLO: a CPU expands scatters into
+        # loops of its own
+        jaxpr = self._apply_grouped_jaxpr(self.FAMILIES[family])
+        assert _loop_primitives(jaxpr) == []
+
+    def test_the_walk_finds_the_sorted_forms_loop(self, monkeypatch):
+        # what the test above would say of the parent: searchsorted's
+        # while sits inside a nested jit, and the walk reaches it
+        monkeypatch.setattr(hh, "_resident", _resident_sorted)
+        jaxpr = self._apply_grouped_jaxpr(("src_addr",))
+        assert "while" in _loop_primitives(jaxpr) \
+            or "scan" in _loop_primitives(jaxpr)
