@@ -146,6 +146,33 @@ def _build_models(vals):
         from .parallel import make_mesh
 
         mesh = make_mesh(n_mesh)
+    # -window.slide: the ranked tables slide (engine/windowed.py); the
+    # ring holds single-chip device states, so refuse loudly where the
+    # states are sharded replicas or live in the host engine
+    slide = vals.get("window.slide", 0)
+    if slide:
+        from .models.oracle import SECONDS_PER_SLOT
+
+        if slide < 0 or SECONDS_PER_SLOT % slide:
+            raise ValueError(
+                f"-window.slide must divide the {SECONDS_PER_SLOT} s "
+                f"window, got {slide}")
+        for flag, on, why in (
+                ("-processor.mesh", n_mesh,
+                 "per-chip replicas of every sketch"),
+                ("-sketch.backend host",
+                 vals.get("sketch.backend", "device") == "host",
+                 "sketch state resident in the host engine"),
+                ("-spread.enabled", vals.get("spread.enabled"),
+                 "host-resident register planes"),
+                ("-mesh.role", vals.get("mesh.role"),
+                 "windows merged and extracted at the coordinator")):
+            if on:
+                raise ValueError(
+                    f"-window.slide does not support {flag} ({why}): "
+                    f"the ring of sub-window states is a single chip's "
+                    f"device state")
+
     models = {}
     if vals["model.flows5m"]:
         cfg = WindowAggConfig(batch_size=batch,
@@ -225,7 +252,8 @@ def _build_models(vals):
             return WindowedHeavyHitter(cfg, k=vals["sketch.topk"],
                                        model_cls=ShardedHeavyHitter,
                                        mesh=mesh, name=name)
-        return WindowedHeavyHitter(cfg, k=vals["sketch.topk"])
+        return WindowedHeavyHitter(cfg, k=vals["sketch.topk"],
+                                   slide_seconds=slide, slide_name=name)
 
     # top_talkers (5-tuple) + top src/dst IP tables (ref: viz.json "Top
     # source/destination IPs"; per-address windowed HH, one per
@@ -253,6 +281,7 @@ def _build_models(vals):
             else:
                 models[name] = WindowedHeavyHitter(
                     cfg, k=vals["sketch.topk"], model_cls=DenseTopKModel,
+                    slide_seconds=slide, slide_name=name,
                 )
     if vals["model.ddos"]:
         if mesh:
@@ -348,6 +377,14 @@ def _processor_flags(fs: FlagSet) -> FlagSet:
     fs.integer("sketch.capacity", 1024, "Top-K table capacity")
     fs.integer("sketch.topk", 100, "Rows emitted per window")
     fs.integer("window.lateness", 0, "Allowed lateness seconds")
+    fs.integer("window.slide", 0,
+               "Slide of the ranked tables in seconds, a divisor of the "
+               "300 s window: every table's rows for the last 300 s are "
+               "emitted at each slide end from a ring of 300/slide "
+               "sub-window sketch states on the device (flows_5m and "
+               "ddos_alerts are unchanged). 0 = tumbling windows. Not "
+               "with -processor.mesh, -sketch.backend host, "
+               "-spread.enabled or -mesh.role: refused at start-up")
     fs.boolean("archive.raw", False, "Archive full-fidelity rows to "
                                      "flows_raw on sinks that support it")
     fs.integer("feed.prefetch", 2, "Decoded batches fetched ahead of the "

@@ -11,6 +11,17 @@ the open-window device state so a restart resumes without double counting
   reads the deflated members of older checkpoints too)
 - ``meta.json``    consumer positions, window dicts, scalars, tree layout
 
+A state may hold ``Member`` leaves: parts that never change once made (a
+sliding window's closed sub-window states, ``engine/windowed.py``). Each
+is written once, as ``<path>.members/<file>.npz`` under the same durable
+idiom, before the first checkpoint that names it; the checkpoint carries
+its name alone, ``load_checkpoint`` reads it back in the leaf's place,
+and a member no checkpoint names any more is removed after the
+checkpoint that dropped it has been published durably. A crash between
+a member's write and its checkpoint leaves a file nothing names (the
+replay writes it again); one between a checkpoint and the removal
+leaves files the next save removes.
+
 Writes follow the full durable-publish protocol via ``utils/fsutil``
 (this was the one durable surface with ZERO fsyncs before flowtorn):
 each payload is written with write→fsync→replace→dir-fsync inside a
@@ -41,6 +52,53 @@ from ..obs.trace import TRACER
 from ..utils import fsutil
 
 
+class Member:
+    """A leaf of a checkpoint's state that is a file of its own: ``file``
+    names it, ``sub`` is the sub-window it holds (for the span),
+    ``arrays`` its content ({name: array}) until it has been written."""
+
+    __slots__ = ("file", "sub", "arrays", "written")
+
+    def __init__(self, file: str, sub: int, arrays: dict | None = None,
+                 written: bool = False):
+        self.file, self.sub = file, sub
+        self.arrays, self.written = arrays, written
+
+
+def _members_dir(path: str) -> str:
+    return path + ".members"
+
+
+def _write_member(path: str, member: Member) -> None:
+    """One closed state, durable before any checkpoint names it."""
+    parent = _members_dir(path)
+    if not os.path.isdir(parent):
+        os.makedirs(parent, exist_ok=True)
+        fsutil.fsync_dir(os.path.dirname(parent))
+    with TRACER.span("ckpt_member", sub=member.sub) as span:
+        buf = io.BytesIO()
+        np.savez(buf, **{k: np.asarray(v)
+                         for k, v in member.arrays.items()})
+        data = buf.getvalue()
+        span["bytes"] = len(data)
+        fsutil.write_bytes_durable(
+            os.path.join(parent, member.file + ".npz"), data)
+    member.arrays, member.written = None, True
+
+
+def _prune_members(path: str, named: list) -> None:
+    """Remove the members the checkpoint just published does not name."""
+    parent = _members_dir(path)
+    if not os.path.isdir(parent):
+        return
+    keep = {m.file + ".npz" for m in named}
+    stale = [n for n in os.listdir(parent) if n not in keep]
+    for name in stale:
+        fsutil.remove(os.path.join(parent, name))
+    if stale:
+        fsutil.fsync_dir(parent)
+
+
 def _to_host(obj: Any, span: dict) -> Any:
     """The same tree with every device leaf brought to the host, in the
     order ``_encode`` walks it; counts what it copied into ``span``."""
@@ -51,7 +109,8 @@ def _to_host(obj: Any, span: dict) -> Any:
                            for f in obj._fields))
     if isinstance(obj, (list, tuple)):
         return type(obj)(_to_host(v, span) for v in obj)
-    if isinstance(obj, (str, int, float, bool, np.ndarray)) or obj is None:
+    if isinstance(obj, (str, int, float, bool, np.ndarray, Member)) \
+            or obj is None:
         return obj
     arr = np.asarray(obj)
     span["bytes"] += arr.nbytes
@@ -59,14 +118,20 @@ def _to_host(obj: Any, span: dict) -> Any:
     return arr
 
 
-def _encode(obj: Any, arrays: dict[str, np.ndarray], path: str) -> Any:
-    """Recursively split a state object into JSON-able structure + arrays."""
+def _encode(obj: Any, arrays: dict[str, np.ndarray], path: str,
+            members: list | None = None) -> Any:
+    """Recursively split a state object into JSON-able structure + arrays
+    + the ``Member`` leaves it names (``members``: a checkpoint's alone;
+    the mesh codec's payloads hold none)."""
+    if isinstance(obj, Member):
+        members.append(obj)
+        return {"__kind__": "member", "file": obj.file}
     if isinstance(obj, dict):
         return {
             "__kind__": "dict",
             "items": [
-                [_encode(k, arrays, f"{path}.k{i}"),
-                 _encode(v, arrays, f"{path}.v{i}")]
+                [_encode(k, arrays, f"{path}.k{i}", members),
+                 _encode(v, arrays, f"{path}.v{i}", members)]
                 for i, (k, v) in enumerate(obj.items())
             ],
         }
@@ -75,14 +140,15 @@ def _encode(obj: Any, arrays: dict[str, np.ndarray], path: str) -> Any:
             "__kind__": "namedtuple",
             "name": type(obj).__name__,
             "fields": {
-                f: _encode(getattr(obj, f), arrays, f"{path}.{f}")
+                f: _encode(getattr(obj, f), arrays, f"{path}.{f}", members)
                 for f in obj._fields
             },
         }
     if isinstance(obj, (list, tuple)):
         return {
             "__kind__": "list" if isinstance(obj, list) else "tuple",
-            "items": [_encode(v, arrays, f"{path}.{i}") for i, v in enumerate(obj)],
+            "items": [_encode(v, arrays, f"{path}.{i}", members)
+                      for i, v in enumerate(obj)],
         }
     if isinstance(obj, (str, int, float, bool)) or obj is None:
         return obj
@@ -92,21 +158,27 @@ def _encode(obj: Any, arrays: dict[str, np.ndarray], path: str) -> Any:
     return {"__kind__": "array", "ref": path}
 
 
-def _decode(spec: Any, arrays) -> Any:
+def _decode(spec: Any, arrays, members_dir: str | None = None) -> Any:
     if isinstance(spec, dict) and "__kind__" in spec:
         kind = spec["__kind__"]
         if kind == "dict":
             return {
-                _freeze(_decode(k, arrays)): _decode(v, arrays)
+                _freeze(_decode(k, arrays, members_dir)):
+                _decode(v, arrays, members_dir)
                 for k, v in spec["items"]
             }
         if kind == "namedtuple":
-            return {f: _decode(v, arrays) for f, v in spec["fields"].items()}
+            return {f: _decode(v, arrays, members_dir)
+                    for f, v in spec["fields"].items()}
         if kind in ("list", "tuple"):
-            items = [_decode(v, arrays) for v in spec["items"]]
+            items = [_decode(v, arrays, members_dir)
+                     for v in spec["items"]]
             return items if kind == "list" else tuple(items)
         if kind == "array":
             return arrays[spec["ref"]]
+        if kind == "member":
+            return dict(np.load(os.path.join(members_dir,
+                                             spec["file"] + ".npz")))
         raise ValueError(f"unknown kind {kind}")
     return spec
 
@@ -133,7 +205,8 @@ def save_checkpoint(path: str, state: Any) -> None:
         # own savez path never fsyncs
         with TRACER.span("ckpt_serialize") as span:
             arrays: dict[str, np.ndarray] = {}
-            meta = _encode(state, arrays, "r")
+            members: list[Member] = []
+            meta = _encode(state, arrays, "r", members)
             buf = io.BytesIO()
             np.savez(buf, **arrays)
             npz = buf.getvalue()
@@ -141,6 +214,9 @@ def save_checkpoint(path: str, state: Any) -> None:
             span["raw_bytes"] = sum(a.nbytes for a in arrays.values())
             span["members"] = len(arrays)
             span["npz_bytes"] = len(npz)
+        for member in members:  # durable before the checkpoint names it
+            if not member.written:
+                _write_member(path, member)
         with TRACER.span("ckpt_write"):
             fsutil.write_bytes_durable(os.path.join(tmp, "arrays.npz"), npz)
             fsutil.write_bytes_durable(os.path.join(tmp, "meta.json"),
@@ -168,6 +244,7 @@ def save_checkpoint(path: str, state: Any) -> None:
             # without this a power loss after the ack could silently revert
             # an acked checkpoint to its predecessor
             fsutil.fsync_dir(parent)
+            _prune_members(path, members)
     except BaseException:
         # flowlint: disable=durability-protocol -- best-effort cleanup of the unpublished staging dir on a failed save; no ack references it, resurrection after a crash is harmless garbage
         shutil.rmtree(tmp, ignore_errors=True)
@@ -185,6 +262,7 @@ def load_checkpoint(path: str) -> Any:
     Falls back to ``<path>.old`` when the primary is missing: a crash
     between save_checkpoint's two renames leaves only the previous
     checkpoint under .old, which is still a consistent snapshot."""
+    primary = path  # members stay beside it whichever tree is read
     if not os.path.isdir(path) and os.path.isdir(path + ".old"):
         path = path + ".old"
     with TRACER.span("ckpt_load") as span:
@@ -193,7 +271,7 @@ def load_checkpoint(path: str) -> Any:
         # one zip member read an array: a checkpoint of before the window
         # store's array form has a member a group, and is slow to read
         arrays = np.load(os.path.join(path, "arrays.npz"))
-        state = _decode(meta, arrays)
+        state = _decode(meta, arrays, _members_dir(primary))
         span["members"] = len(arrays.files)
         span["bytes"] = sum(os.path.getsize(os.path.join(path, name))
                             for name in ("arrays.npz", "meta.json"))

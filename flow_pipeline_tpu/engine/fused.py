@@ -302,7 +302,7 @@ class FusedPipeline(WindowLifecycle):
                 batch_sizes.add(m.config.batch_size)
             elif type(m) is WindowedHeavyHitter and type(m.model) in (
                     HeavyHitterModel, DenseTopKModel, SpreadModel):
-                whh_windows.add(m.window_seconds)
+                whh_windows.add((m.window_seconds, m.slot_seconds))
                 batch_sizes.add(m.config.batch_size)
             elif type(m) is DDoSDetector:
                 subs.add(m.config.sub_window_seconds)
@@ -344,7 +344,10 @@ class FusedPipeline(WindowLifecycle):
                 self._whh.append(m)
         first = next(iter(models.values()))
         self._bs = first.config.batch_size
-        self._window_seconds = (self._whh[0].window_seconds
+        # the grain the wrappers' slot rolls at: their window, or under
+        # -window.slide their slide (a multiple of the detector's
+        # sub-window cuts no batch the detector does not cut)
+        self._window_seconds = (self._whh[0].slot_seconds
                                 if self._whh else None)
         self._sub_seconds = (self._ddos[0][1].config.sub_window_seconds
                              if self._ddos else None)

@@ -21,8 +21,9 @@ from ..schema.batch import FlowBatch
 
 class WindowLifecycle:
     """Mixin. The pipeline provides ``_whh`` (WindowedHeavyHitter
-    wrappers, all of one ``_window_seconds``) and ``_ddos`` ((name,
-    detector) pairs, all of one ``_sub_seconds``)."""
+    wrappers, all of one ``_window_seconds``: the grain their slot
+    rolls at, which under ``-window.slide`` is the slide) and ``_ddos``
+    ((name, detector) pairs, all of one ``_sub_seconds``)."""
 
     _whh: list
     _ddos: list
@@ -72,12 +73,11 @@ class WindowLifecycle:
         cur = self._whh[0].current_slot
         if cur is None:
             for w in self._whh:
-                w.current_slot = slot
+                w.open(slot)
             return True
         if slot > cur:
             for w in self._whh:
-                w._close()
-                w.current_slot = slot
+                w.roll(slot)
             return True
         if slot < cur:
             for w in self._whh:

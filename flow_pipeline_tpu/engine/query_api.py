@@ -180,10 +180,10 @@ class QueryServer:
         # syncs; pull it current before reading (we hold worker.lock)
         self.worker.sync_sketch_states()
         k = int(q.get("k", 10))
-        top = model.model.top(k)
+        top = model.top(k)
         return {
             "model": name,
-            "window_start": model.current_slot,
+            "window_start": model.window_start,
             "rows": rows_to_records(top),
         }
 

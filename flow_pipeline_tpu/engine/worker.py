@@ -950,12 +950,29 @@ def restore_wagg_state(model, ms: dict, name: str) -> None:
     model.watermark = ms["watermark"]
 
 
+def _with_ring(model, ms: dict) -> dict:
+    """Under a slide the checkpoint also names the ring's closed
+    sub-window states, each a member written once (SubWindowRing)."""
+    if model.ring is not None:
+        ms["ring"] = model.ring.checkpoint_state()
+    return ms
+
+
+def _restore_ring(model, ms: dict, name: str) -> None:
+    if model.ring is None:
+        if ms.get("ring"):
+            log.warning("checkpoint holds a sliding window's ring for "
+                        "model %r, which runs tumbling; dropping it", name)
+    elif "ring" in ms:
+        model.ring.restore(ms["ring"])
+
+
 def save_hh_state(model) -> dict:
-    return {
+    return _with_ring(model, {
         "kind": "windowed_hh",
         "hh": model.model.state,
         "current_slot": model.current_slot,
-    }
+    })
 
 
 def restore_hh_state(model, ms: dict, name: str) -> None:
@@ -996,6 +1013,7 @@ def restore_hh_state(model, ms: dict, name: str) -> None:
             table_vals=jnp.asarray(hh["table_vals"]),
         )
     model.current_slot = ms["current_slot"]
+    _restore_ring(model, ms, name)
 
 
 def save_spread_state(model) -> dict:
@@ -1024,11 +1042,11 @@ def restore_spread_state(model, ms: dict, name: str) -> None:
 
 
 def save_dense_state(model) -> dict:
-    return {
+    return _with_ring(model, {
         "kind": "windowed_dense",
         "totals": model.model.totals,
         "current_slot": model.current_slot,
-    }
+    })
 
 
 def restore_dense_state(model, ms: dict, name: str) -> None:
@@ -1038,3 +1056,4 @@ def restore_dense_state(model, ms: dict, name: str) -> None:
 
     model.model.totals = jnp.asarray(ms["totals"])
     model.current_slot = ms["current_slot"]
+    _restore_ring(model, ms, name)

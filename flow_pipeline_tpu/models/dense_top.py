@@ -29,12 +29,14 @@ of swapping keys whose totals differ by less than that); the REPORTED
 values are recombined exactly from the planes in uint64 on the host.
 
 The model implements the surface WindowedHeavyHitter drives
-(update/top/reset), so the tumbling-window lifecycle, worker flushes and
-ranked sink tables are shared with the sketch models unchanged.
+(update/top/reset), so the window lifecycle (tumbling or sliding),
+worker flushes and ranked sink tables are shared with the sketch models
+unchanged.
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from functools import partial
 
@@ -42,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.fold import fold_planes, named_program
 from ..schema.batch import FlowBatch
 
 
@@ -167,6 +170,21 @@ def _top_from_totals(totals, config: DenseTopConfig,
     return out
 
 
+@functools.lru_cache(maxsize=None)
+def dense_fold_program(name: str, n: int):
+    """The jitted fold of ``n`` totals arrays into one: the sum the
+    four-chip close runs over its replicas
+    (``parallel.sharded.ShardedDenseTopK``), over the sub-windows of a
+    sliding window's ring (see ``heavy_hitter.hh_fold_program``)."""
+
+    @named_program(name)
+    def fold(states):
+        with jax.named_scope("slide_fold_planes"):
+            return fold_planes(jnp.stack(states))
+
+    return jax.jit(fold)
+
+
 class DenseTopKModel:
     """Host wrapper with the HeavyHitterModel surface (update/top/reset),
     so WindowedHeavyHitter can drive it interchangeably."""
@@ -203,3 +221,25 @@ class DenseTopKModel:
 
     def reset(self) -> None:
         self.totals = jnp.zeros_like(self.totals)
+
+    # ---- a sliding window's ring (engine.windowed.SubWindowRing) ------
+
+    def window_state(self):
+        return self.totals
+
+    def empty_state(self):
+        return jnp.zeros_like(self.totals)
+
+    def fold_program(self, name: str, n: int):
+        return dense_fold_program(name, n)
+
+    def top_from(self, totals, k: int | None = None):
+        return _top_from_totals(totals, self.config, k)
+
+    @staticmethod
+    def state_arrays(totals) -> dict:
+        return {"totals": totals}
+
+    @staticmethod
+    def state_from_arrays(arrays: dict):
+        return jnp.asarray(arrays["totals"])

@@ -17,11 +17,14 @@ mass; ranking uses the table's accumulated sums.
 Window semantics mirror the exact aggregator: the model is windowed by the
 driver (engine/) which calls ``flush`` at watermark close — same tumbling
 5-minute windows as the reference's flows_5m rollup
-(ref: compose/clickhouse/create.sh:96).
+(ref: compose/clickhouse/create.sh:96), or under ``-window.slide`` one
+model per sub-window, their states folded at every slide
+(engine/windowed.py; ``hh_fold_program``).
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from functools import partial
 from typing import NamedTuple
@@ -32,6 +35,7 @@ import numpy as np
 
 from ..ops import cms as cms_ops
 from ..ops import topk as topk_ops
+from ..ops.fold import fold_planes, fold_tables, named_program
 from ..ops.segment import hash_groupby_float, hash_lanes
 from ..schema.batch import FlowBatch, lane_width
 
@@ -354,6 +358,29 @@ def _inv_top_from_state(state: InvState, config: HeavyHitterConfig,
     return out
 
 
+@functools.lru_cache(maxsize=None)
+def hh_fold_program(name: str, n: int):
+    """The jitted fold of ``n`` states into one (a tuple of ``n``
+    ``HHState`` in, oldest first): the monoid the four-chip close runs
+    over its replicas (``parallel.sharded.sharded_hh_merge``; bodies in
+    ``ops/fold.py``), over the sub-windows of a sliding window's ring
+    (``engine.windowed.SubWindowRing``). The compiled module is
+    ``jit_<name>``; the two scopes are a contract with the trace readers
+    (docs/OBSERVABILITY.md). Cached on (name, n) as the step is cached
+    on its spec: pipelines are rebuilt freely."""
+
+    @named_program(name)
+    def fold(states):
+        stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+        with jax.named_scope("slide_fold_planes"):
+            cms = fold_planes(stacked.cms)
+        with jax.named_scope("slide_fold_tables"):
+            tk, tv = fold_tables(stacked.table_keys, stacked.table_vals)
+        return HHState(cms=cms, table_keys=tk, table_vals=tv)
+
+    return jax.jit(fold)
+
+
 class HeavyHitterModel:
     """Host wrapper: feed batches, extract top-K at window close."""
 
@@ -442,3 +469,31 @@ class HeavyHitterModel:
 
     def reset(self) -> None:
         self.state = hh_init(self.config)
+
+    # ---- a sliding window's ring (engine.windowed.SubWindowRing) ------
+
+    def window_state(self) -> HHState:
+        return self.state
+
+    def empty_state(self) -> HHState:
+        return hh_init(self.config)
+
+    def fold_program(self, name: str, n: int):
+        if self.config.hh_sketch != "table":
+            raise ValueError(
+                "a sliding window folds device states; hh_sketch="
+                f"{self.config.hh_sketch!r} keeps its planes on the host")
+        return hh_fold_program(name, n)
+
+    def top_from(self, state: HHState, k: int | None = None):
+        return _top_from_state(state, self.config,
+                               k or self.config.capacity)
+
+    @staticmethod
+    def state_arrays(state: HHState) -> dict:
+        return state._asdict()
+
+    @staticmethod
+    def state_from_arrays(arrays: dict) -> HHState:
+        return HHState(**{f: jnp.asarray(arrays[f])
+                          for f in HHState._fields})

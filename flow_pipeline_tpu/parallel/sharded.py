@@ -49,25 +49,10 @@ from ..models.window_agg import (
     group_cols,
 )
 from ..obs.trace import TRACER
-from ..ops import topk as topk_ops
+from ..ops.fold import fold_planes, fold_tables
+from ..ops.fold import named_program as _program
 from ..schema.batch import FlowBatch
 from .mesh import DATA_AXIS, make_mesh, shard_batch_columns
-
-
-def _program(name: str):
-    """Name the function a sharded program is jitted from: the compiled
-    module is ``jit_<name>`` in a device trace and in the compile log,
-    and its ops carry ``<name>`` as their scope."""
-
-    def rename(fn):
-        def scoped(*args):
-            with jax.named_scope(name):
-                return fn(*args)
-
-        scoped.__name__ = scoped.__qualname__ = name
-        return scoped
-
-    return rename
 
 
 def place_global_step(mesh: Mesh, batch: FlowBatch, start: int, rows: int,
@@ -152,18 +137,14 @@ def sharded_hh_merge(mesh: Mesh, config: hh.HeavyHitterConfig,
     """Build the jitted window-close merge: stacked per-chip states ->
     one replicated merged state. psum for the CMS, all_gather + fold for
     the candidate table."""
-    n_dev = mesh.devices.size
-
     @_program(f"mesh_hh_merge_{name}")
     def per_chip(state):
         cms = lax.psum(state.cms[0], DATA_AXIS)
         tk = lax.all_gather(state.table_keys[0], DATA_AXIS)  # [n_dev, C, W]
         tv = lax.all_gather(state.table_vals[0], DATA_AXIS)
-        mk, mv = tk[0], tv[0]
-        for d in range(1, n_dev):  # static fold: n_dev is compile-time
-            # topk_merge self-filters sentinel (empty-slot) rows
-            cand_valid = jnp.ones(tk[d].shape[0], bool)
-            mk, mv = topk_ops.topk_merge(mk, mv, tk[d], tv[d], cand_valid)
+        # the fold the sliding window's ring runs over its sub-windows
+        # (ops/fold.py); the plane sum is the psum above
+        mk, mv = fold_tables(tk, tv)
         return hh.HHState(cms=cms, table_keys=mk, table_vals=mv)
 
     state_spec = hh.HHState(
@@ -540,7 +521,7 @@ class ShardedDenseTopK(dense_mod.DenseTopKModel):
         # models.dense_top (now shared across chips)
         @_program(f"mesh_dense_merge_{name}")
         def merge(totals):
-            return jnp.sum(totals, axis=0)
+            return fold_planes(totals)
 
         self._merge = jax.jit(merge)
         sharding = NamedSharding(self.mesh, P(DATA_AXIS))

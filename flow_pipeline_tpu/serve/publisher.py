@@ -62,7 +62,8 @@ def hh_view_parts(m: WindowedHeavyHitter):
     from ..hostsketch.state import frozen_cms
     from .snapshot import FrozenCms
 
-    planes = m.model.state.cms
+    # under a slide the window a reader sees is the ring's fold
+    planes = (m.model.state if m.ring is None else m.view_state()).cms
     if not isinstance(planes, np.ndarray):
         # device-backend jax array: hh_update DONATES its state arg,
         # so the next batch deletes these buffers on TPU/GPU — the
@@ -127,18 +128,20 @@ def _family_from_model(name: str, m: WindowedHeavyHitter) -> FamilyView:
     worker.lock and has synced sketch states, so ``m.model.state`` /
     ``.totals`` are current; ``top(depth)`` is the SAME extraction the
     locked query path runs, so a snapshot-served k-row answer is the
-    locked answer's exact prefix. The per-kind view parts come from the
+    locked answer's exact prefix. Under ``-window.slide`` both read the
+    window that ends with the open sub-window: the ring's kept fold of
+    its closed states merged with the open one (``SubWindowRing.view``:
+    two states a publish, not K). The per-kind view parts come from the
     family registry's serve_capture hook (unknown snapshot kinds fall
     back to the dense shape, as before)."""
     depth = m.k
-    rows = m.model.top(depth)
+    rows = m.top(depth)
     fam = registry.family_for_snapshot(m.model.snapshot_kind) \
         or registry.family("dense")
     cms, lanes, regs = registry.hook(fam, "serve_capture")(m)
     return FamilyView(
         name=name, kind=fam.kind,
-        window_start=(int(m.current_slot)
-                      if m.current_slot is not None else None),
+        window_start=m.window_start,
         depth=int(len(rows["valid"])), rows=rows, key_lanes=lanes,
         cms=cms, value_cols=tuple(getattr(m.config, "value_cols", ())),
         regs=regs)

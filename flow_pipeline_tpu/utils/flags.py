@@ -40,7 +40,7 @@ KNOWN_FLAGS = frozenset({
     # flowspread (models/spread.py) — distinct-count detectors
     "spread.enabled", "spread.depth", "spread.width", "spread.regs",
     "spread.capacity", "spread.topk",
-    "window.lateness", "archive.raw", "feed.prefetch",
+    "window.lateness", "window.slide", "archive.raw", "feed.prefetch",
     "ingest.mode", "ingest.shards", "ingest.depth", "ingest.flush_queue",
     "ingest.native_group", "ingest.fused", "ingest.threads",
     "checkpoint.path", "flush.count", "metrics.addr", "sink", "in",

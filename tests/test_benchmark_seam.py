@@ -26,6 +26,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TINY = "benchmark/tests/fixtures/BENCHMARK.tiny.json"
 # a manifest of its own beside it: estate-as64k-catchup's twin (ISSUE 31)
 TINY_AS = "benchmark/tests/fixtures/BENCHMARK.tiny-as.json"
+# and estate-sliding-catchup's (ISSUE 33)
+TINY_SLIDING = "benchmark/tests/fixtures/BENCHMARK.tiny-sliding.json"
 
 
 class Cell(NamedTuple):
@@ -34,6 +36,7 @@ class Cell(NamedTuple):
     seed: int
     devices: int     # virtual CPU devices; 0: the backend's one
     manifest: str = TINY
+    control: str = ""  # controls compared beside the program's result
 
 
 # 2^31+26 loses one flow of the mesh cell at the tiny capacity (PERF.md
@@ -48,13 +51,21 @@ TRACED_CELL = "tiny-catchup"
 # 256 ASes a side at the tiny size: run once, traced, for what the exact
 # path counts
 AS_CELL = "tiny-as-catchup"
+# -window.slide 30 at the tiny size: run once, traced, with both controls
+SLIDING_CELL = "tiny-sliding-catchup"
+SLIDING_KIND = "ranked_bytes_sliding"
 TRACED = {TRACED_CELL: CELLS[TRACED_CELL],
           AS_CELL: Cell("estate-as64k-catchup", "FusedPipeline", 2**31 + 11,
-                        0, TINY_AS)}
+                        0, TINY_AS),
+          SLIDING_CELL: Cell("estate-sliding-catchup", "FusedPipeline",
+                             2**31 + 11, 0, TINY_SLIDING,
+                             f"bf16,bf16:{SLIDING_KIND}")}
 # they read the `XLA Modules` line of a /device:TPU plane: a CPU trace has
 # none, and a CPU number never goes under a device metric's name
 TPU_PLANE_ONLY = ("step_device_ms_p50", "fused_step_roofline",
-                  "step_device_ms_p50.as64k", "fused_step_roofline.as64k")
+                  "step_device_ms_p50.as64k", "fused_step_roofline.as64k",
+                  "step_device_ms_p50.sliding", "fused_step_roofline.sliding",
+                  "slide_fold_device_ms_per_slide", "slide_fold_roofline")
 
 
 def _manifest(rel):
@@ -87,7 +98,8 @@ def dry_run():
             p = subprocess.run(
                 [*command, "--manifest", spec.manifest, "--workload", cell,
                  "--seed", str(spec.seed), "--seconds", "3",
-                 "--trace", str(trace)],
+                 "--trace", str(trace),
+                 *(["--control", spec.control] if spec.control else [])],
                 cwd=ROOT, env=env, capture_output=True, text=True,
                 timeout=300)
             lines = [ln for ln in p.stdout.splitlines() if ln.strip()]
@@ -150,11 +162,12 @@ def test_traced_dry_run_reads_every_layer_metric(dry_run, cell, metric):
         assert math.isfinite(line["metrics"][metric]["value"])
 
 
-def test_the_as_twin_is_the_ledgers_cell_at_the_tiny_size():
+@pytest.mark.parametrize("cell", [AS_CELL, SLIDING_CELL])
+def test_a_twin_is_the_ledgers_cell_at_the_tiny_size(cell):
     """Every per-layer metric the ledger's cell reports, and no other."""
-    assert (_listed(_manifest(TINY_AS)["per_layer"], AS_CELL)
+    assert (_listed(_manifest(TRACED[cell].manifest)["per_layer"], cell)
             == _listed(_manifest("BENCHMARK.json")["per_layer"],
-                       TRACED[AS_CELL].ledger))
+                       TRACED[cell].ledger))
 
 
 def test_the_as_twin_folds_a_large_store_into_a_checkpoint_of_few_members(
@@ -167,3 +180,39 @@ def test_the_as_twin_folds_a_large_store_into_a_checkpoint_of_few_members(
     assert value["fold_groups_per_batch"] > 256
     assert value["close_rows_flows5m"] > 256
     assert value["store_groups_p50"] > 10 * value["checkpoint_members_p50"]
+
+
+def test_the_sliding_twin_slides_on_the_fused_path(dry_run):
+    """`-window.slide 30` through cli.processor_main: the fused dataplane,
+    several slides inside the window, every one of them with rows, each
+    closed sub-window written once and the checkpoint a window's size."""
+    line = _result(dry_run, SLIDING_CELL, trace=1)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["window"]["dataplane"] == TRACED[SLIDING_CELL].dataplane
+    checks = {c["name"]: c for c in line["checks"]}
+    assert checks["slide_windows_missing"]["value"] == 0
+    assert checks["topk_bytes_max_rel_err"]["ok"]
+    value = {name: m["value"] for name, m in line["metrics"].items()}
+    assert value["slides_in_window"] >= 5 > value["window_closes_in_window"]
+    assert value["slide_rows_per_close"] > 100
+    assert value["compiles_in_window"] == 0
+    # ten sub-window states on the device, one in a checkpoint
+    assert value["ring_mb_on_device"] > 5 * value[
+        "checkpoint_raw_mb_p50.sliding"]
+    assert 0 < value["checkpoint_member_mb_p50"] < value[
+        "checkpoint_raw_mb_p50.sliding"]
+
+
+@pytest.mark.parametrize("control", TRACED[SLIDING_CELL].control.split(","))
+def test_the_sliding_twins_controls_come_out_not_correct(dry_run, control):
+    """The reference one precision step down, in the program's place,
+    fails the sliding tables' limit at the tiny size too; lowered for
+    the sliding kind alone, it fails nothing else."""
+    line = _result(dry_run, SLIDING_CELL, trace=1)
+    found = next(c for c in line["controls"] if c["control"] == control)
+    assert found["correct"] is False
+    failed = {c["name"] for c in found["checks"] if not c["ok"]}
+    assert "topk_bytes_max_rel_err" in failed
+    assert "slide_windows_missing" not in failed
+    if ":" in control:
+        assert failed == {"topk_bytes_max_rel_err"}

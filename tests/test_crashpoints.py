@@ -14,7 +14,9 @@ code over each, asserting the docs/FAULT_TOLERANCE.md invariants:
 - archive: every committed version reconstructs bit-equal, and a
   missing version is an honest HistoryGapError, never damaged data;
 - checkpoint: an acked save restores exactly; mid-save crashes restore
-  the complete predecessor.
+  the complete predecessor; a sliding window's ring (members written
+  once beside the checkpoint that names them) comes back whole from
+  every crash state.
 
 The ``TestBarrierMutations`` half is the dynamic prong of the
 ``make lint-mutation`` durability gate: ``fsutil.suppressed(kind)``
@@ -31,7 +33,9 @@ import os
 import numpy as np
 import pytest
 
-from flow_pipeline_tpu.engine.checkpoint import (checkpoint_exists,
+from flow_pipeline_tpu.engine import checkpoint as ckpt
+from flow_pipeline_tpu.engine.checkpoint import (Member,
+                                                 checkpoint_exists,
                                                  load_checkpoint,
                                                  save_checkpoint)
 from flow_pipeline_tpu.engine.worker import (restore_wagg_state,
@@ -301,7 +305,66 @@ def _check_checkpoint_wagg(croot: str, acked: list) -> None:
         assert step == 2, "acked checkpoint w2 did not restore"
 
 
+# ---- scenario: a sliding window's ring in a checkpoint ----------------------
+#
+# The closed sub-window states are members, each written once, before
+# the first checkpoint that names it, and removed after the first one
+# that no longer does (engine/checkpoint.py::Member).
+
+
+def _sub_state(sub: int) -> dict:
+    return {"cms": np.full((2, 8), sub, np.float32),
+            "table_keys": np.arange(sub, sub + 6, dtype=np.uint32)}
+
+
+def _ring_state(step: int, members: dict) -> dict:
+    """Step ``step``'s checkpoint: the open state and a ring of the two
+    sub-windows before it. ``members`` is the ring's own record, as
+    SubWindowRing keeps it from one checkpoint to the next."""
+    subs = [s for s in (step - 1, step) if s >= 1]
+    for sub in subs:
+        members.setdefault(sub, Member(f"top.{sub}", sub, _sub_state(sub)))
+    return {"step": step, "open": np.arange(4, dtype=np.uint64) * step,
+            "ring": {"subs": subs, "members": [members[s] for s in subs]}}
+
+
+def _run_checkpoint_ring(root: str, rec: fsutil.OpRecorder) -> None:
+    path = os.path.join(root, "ckpt", "snap")
+    members: dict = {}
+    with fsutil.observed(rec):
+        for step in (1, 2, 3):  # 3 drops sub-window 1's member
+            save_checkpoint(path, _ring_state(step, members))
+            rec.mark(f"r{step}")
+
+
+def _restored_ring_step(path: str):
+    """The step the checkpoint at ``path`` restores to, its ring read
+    back whole and bit for bit; raises where a member it names is not
+    there or is torn."""
+    got = load_checkpoint(path)
+    step = got["step"]
+    want = _ring_state(step, {})
+    assert np.array_equal(got["open"], want["open"]), "open state torn"
+    assert got["ring"]["subs"] == want["ring"]["subs"]
+    for sub, arrays in zip(got["ring"]["subs"], got["ring"]["members"]):
+        for name, value in _sub_state(sub).items():
+            assert np.array_equal(arrays[name], value), \
+                f"member of sub-window {sub} not bit-exact"
+    return step
+
+
+def _check_checkpoint_ring(croot: str, acked: list) -> None:
+    path = os.path.join(croot, "ckpt", "snap")
+    if not acked and not checkpoint_exists(path):
+        return  # crashed before anything was published: fine
+    step = _restored_ring_step(path)
+    newest = max((int(label[1:]) for label in acked), default=0)
+    assert step in (newest, newest + 1), \
+        f"acked checkpoint r{newest} restored step {step}"
+
+
 _SCENARIOS = {
+    "checkpoint_ring": (_run_checkpoint_ring, _check_checkpoint_ring),
     "journal": (_run_journal, _check_journal),
     "deadletter": (_run_dlq, _check_dlq),
     "archive": (_run_archive, _check_archive),
@@ -359,6 +422,8 @@ class TestBarrierMutations:
         ("checkpoint", "replace"),
         ("checkpoint_wagg", "fsync"), ("checkpoint_wagg", "fsync_dir"),
         ("checkpoint_wagg", "replace"),
+        ("checkpoint_ring", "fsync"), ("checkpoint_ring", "fsync_dir"),
+        ("checkpoint_ring", "replace"),
         # the archive publishes by append+rotate, never by replace
         ("archive", "fsync"), ("archive", "fsync_dir"),
     ]
@@ -430,3 +495,63 @@ class TestCheckpointMidSave:
         litter = [n for n in os.listdir(tmp_path)
                   if n.startswith(".ckpt-")]
         assert litter == []
+
+
+# ---- satellite: the ring's members, the two windows round a checkpoint -----
+
+
+class TestCheckpointRingMembers:
+
+    def test_crash_between_member_write_and_its_checkpoint(self, tmp_path):
+        """A slide's member is on disk and the process dies before the
+        checkpoint that would name it: the restart reads the
+        predecessor, whose ring does not hold it; the replay closes the
+        sub-window again, writes the member again over the orphan, and
+        the next checkpoint names it."""
+        path = str(tmp_path / "snap")
+        members: dict = {}
+        save_checkpoint(path, _ring_state(1, members))
+        orphan = Member("top.2", 2, {"cms": np.zeros((2, 8), np.float32),
+                                     "table_keys": np.zeros(6, np.uint32)})
+        ckpt._write_member(path, orphan)  # ...and the crash
+        assert sorted(os.listdir(path + ".members")) == \
+            ["top.1.npz", "top.2.npz"]
+        assert _restored_ring_step(path) == 1
+        # the restart: members it restored are written, the rest are new
+        restarted = {1: Member("top.1", 1, written=True)}
+        save_checkpoint(path, _ring_state(2, restarted))
+        assert restarted[2].written
+        assert _restored_ring_step(path) == 2  # the replay's, bit-exact
+
+    def test_crash_between_checkpoint_and_removal(self, tmp_path,
+                                                  monkeypatch):
+        """The checkpoint that drops a member is in place and the process
+        dies before the removal: the stale file is named by nothing,
+        harms nothing, and the next save removes it."""
+        path = str(tmp_path / "snap")
+        members: dict = {}
+        for step in (1, 2):
+            save_checkpoint(path, _ring_state(step, members))
+        real = ckpt._prune_members
+        monkeypatch.setattr(ckpt, "_prune_members", lambda *a: None)
+        save_checkpoint(path, _ring_state(3, members))  # drops top.1
+        monkeypatch.setattr(ckpt, "_prune_members", real)
+        assert sorted(os.listdir(path + ".members")) == \
+            ["top.1.npz", "top.2.npz", "top.3.npz"]
+        assert _restored_ring_step(path) == 3
+        save_checkpoint(path, _ring_state(4, members))
+        assert sorted(os.listdir(path + ".members")) == \
+            ["top.3.npz", "top.4.npz"]
+        assert _restored_ring_step(path) == 4
+
+    def test_a_member_is_written_once(self, tmp_path):
+        path = str(tmp_path / "snap")
+        members: dict = {}
+        rec = fsutil.OpRecorder()
+        with fsutil.observed(rec):
+            for step in (1, 2, 3):
+                save_checkpoint(path, _ring_state(step, members))
+        writes = [op[1] for op in rec.ops
+                  if op[0] == "replace" and ".members" in op[2]]
+        assert [os.path.basename(w) for w in writes] == \
+            ["top.1.npz.tmp", "top.2.npz.tmp", "top.3.npz.tmp"]
