@@ -9,7 +9,6 @@ partitions — the sarama consumer-group model (ref: inserter/inserter.go:
 
 from __future__ import annotations
 
-import itertools
 import os
 import threading
 import time
@@ -22,7 +21,7 @@ from ..families import registry
 from ..guard import GuardConfig, GuardController
 from ..models.ddos import DDoSDetector
 from ..models.heavy_hitter import HHState
-from ..models.window_agg import WindowAggregator
+from ..models.window_agg import WindowAggregator, WindowStore
 from ..obs import REGISTRY, get_logger
 from ..obs.trace import TRACER
 from ..obs.tracing import StageTimer
@@ -890,23 +889,18 @@ def _kind_matches(model, ms: dict, name: str) -> bool:
 def save_wagg_state(model) -> dict:
     """The window store's persistent form: for each open window its keys
     as one ``[G, lanes]`` uint32 array and its sums as one
-    ``[G, nvals + 1]`` uint64 array, rows in the store's order. The
-    checkpoint's count of npz members and its ``meta.json`` then do not
-    grow with G, where an array a group and a JSON list a key made a
-    window of 6x10^4 groups cost seconds to write and a minute to read."""
+    ``[G, nvals + 1]`` uint64 array: the store's own two arrays
+    (WindowStore.snapshot), rows in key order. The checkpoint's count of
+    npz members and its ``meta.json`` then do not grow with G, where an
+    array a group and a JSON list a key made a window of 6x10^4 groups
+    cost seconds to write and a minute to read."""
     model._drain()  # fold pending device partials first: the snapshot
     # must cover everything the committed offsets cover
-    lanes = model.store_key_lanes
-    width = len(model.config.value_cols) + 1
     with TRACER.span("wagg_state", windows=len(model.windows)) as span:
-        stores = [{
-            "slot": slot,
-            "keys": np.fromiter(
-                itertools.chain.from_iterable(store), np.uint32,
-                len(store) * lanes).reshape(len(store), lanes),
-            "sums": np.array(list(store.values()), np.uint64).reshape(
-                len(store), width),
-        } for slot, store in model.windows.items()]
+        stores = []
+        for slot, store in model.windows.items():
+            keys, sums = store.snapshot()
+            stores.append({"slot": slot, "keys": keys, "sums": sums})
         span["groups"] = sum(len(s["keys"]) for s in stores)
     return {
         "kind": "window_agg",
@@ -916,25 +910,17 @@ def save_wagg_state(model) -> dict:
 
 
 def restore_wagg_state(model, ms: dict, name: str) -> None:
-    """Reads both forms a checkpoint may hold: ``stores`` (above), and
-    the ``windows`` dict of key tuples that builds before it wrote, which
-    stays readable so that an operator upgrades across a restart. The old
+    """Reads both forms a checkpoint may hold: ``stores`` (above), adopted
+    after one sort, and the ``windows`` dict of key tuples that builds
+    before it wrote, which stays readable so that an operator upgrades
+    across a restart and is turned into the two arrays here. The old
     form is slow by nature (an npz member and a JSON list a group to
     open and rebuild); nothing restores it faster than it was."""
     want = model.store_key_lanes
     if "stores" in ms:
         got = {int(s["keys"].shape[1]) for s in ms["stores"]}
-        windows = {
-            int(s["slot"]): dict(zip(
-                map(tuple, s["keys"].tolist()),
-                # rows of one owned array: the fold adds into them
-                np.array(s["sums"], dtype=np.uint64)))
-            for s in ms["stores"]
-        }
     else:
-        windows = {int(slot): dict(store)
-                   for slot, store in ms["windows"].items()}
-        got = {len(k) for store in windows.values() for k in store}
+        got = {len(k) for store in ms["windows"].values() for k in store}
     bad = next((n for n in got if n != want), None)
     if bad is not None:
         # a checkpoint from a different grouping layout (e.g.
@@ -945,8 +931,15 @@ def restore_wagg_state(model, ms: dict, name: str) -> None:
             "checkpoint window keys have %d lanes, model "
             "%r expects %d; skipping its window state",
             bad, name, want)
+    elif "stores" in ms:
+        model.windows = {
+            int(s["slot"]): WindowStore.from_rows(s["keys"], s["sums"])
+            for s in ms["stores"]}
     else:
-        model.windows = windows
+        model.windows = {
+            int(slot): WindowStore.from_rows(list(store),
+                                             list(store.values()))
+            for slot, store in ms["windows"].items() if store}
     model.watermark = ms["watermark"]
 
 

@@ -100,19 +100,12 @@ def hh_payload(state) -> dict:
     }
 
 
-def wagg_payload(store: dict) -> dict:
-    """One window-store dict {key tuple -> uint64 [values..., count]} ->
-    columnar (keys [G, L] uint32, vals [G, V] uint64) payload."""
-    if not store:
-        return {"kind": "wagg",
-                "keys": np.zeros((0, 0), np.uint32),
-                "vals": np.zeros((0, 0), np.uint64)}
-    lanes = len(next(iter(store)))
-    keys = np.fromiter((x for key in store for x in key), dtype=np.uint64,
-                       count=len(store) * lanes).reshape(len(store), lanes)
-    vals = np.stack([np.asarray(v, dtype=np.uint64)
-                     for v in store.values()])
-    return {"kind": "wagg", "keys": keys.astype(np.uint32), "vals": vals}
+def wagg_payload(store) -> dict:
+    """One window's store (models.window_agg.WindowStore) -> columnar
+    (keys [G, L] uint32, vals [G, V] uint64) payload: its two arrays,
+    the sums copied so that a fold after this does not reach them."""
+    keys, vals = store.snapshot()
+    return {"kind": "wagg", "keys": keys, "vals": vals}
 
 
 def dense_payload(totals) -> dict:

@@ -32,6 +32,7 @@ import numpy as np
 
 from ..hostsketch.engine import np_cms_query
 from ..models.heavy_hitter import HeavyHitterConfig, key_width
+from ..models.window_agg import WindowStore
 from ..ops.hostgroup import _lex_regroup
 from ..schema.batch import lane_width
 
@@ -41,23 +42,21 @@ _SENTINEL = np.uint32(0xFFFFFFFF)
 # ---- exact window aggregates ----------------------------------------------
 
 
-def merge_wagg(payloads: list[dict], config=None) -> dict:
+def merge_wagg(payloads: list[dict], config=None) -> WindowStore:
     """Fold wagg payloads (keys [G, L] u32, vals [G, V] u64) into one
-    window-store dict {key tuple -> uint64 vec} — per-key sums, exact.
+    window store — per-key sums, exact, by the sort the worker's own
+    fold starts with.
 
     ``config`` is unused (the fold is shape-generic) but accepted so
     every registered family's merge hook shares one signature
     (families/registry.py)."""
     real = [p for p in payloads if len(p["keys"])]
     if not real:
-        return {}
-    keys = np.concatenate([p["keys"].astype(np.uint32) for p in real])
-    vals = np.concatenate([p["vals"].astype(np.uint64) for p in real])
-    order, starts = _lex_regroup(keys)
-    uniq = keys[order][starts]
-    sums = np.add.reduceat(vals[order], starts, axis=0)
-    return {tuple(int(x) for x in uniq[i]): sums[i]
-            for i in range(len(starts))}
+        return WindowStore(np.zeros((0, 0), np.uint32),
+                           np.zeros((0, 0), np.uint64))
+    return WindowStore.from_rows(
+        np.concatenate([p["keys"] for p in real]),
+        np.concatenate([p["vals"] for p in real]))
 
 
 # ---- heavy-hitter sketch state --------------------------------------------

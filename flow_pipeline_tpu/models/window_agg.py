@@ -2,7 +2,8 @@
 
 Device side: per-batch exact partial aggregates via ``ops.sort_groupby``
 keyed on (timeslot, *key columns). Host side: a window store merges partials
-into per-timeslot dicts with uint64 accumulators and flushes closed windows.
+into one WindowStore a timeslot (sorted key rows beside a uint64 sums array)
+and flushes closed windows.
 
 Semantics match the reference's flows_5m materialized view exactly
 (5-minute tumbling windows over TimeReceived, keys (SrcAS, DstAS, EType),
@@ -35,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.trace import TRACER
-from ..ops.hostgroup import _lex_regroup
+from ..ops.hostgroup import _lex_regroup, _run_starts
 from ..ops.segment import hash_groupby, sort_groupby
 from ..utils.shards import local_device_blocks
 from ..schema.batch import FlowBatch, lane_width
@@ -158,6 +159,109 @@ def _first_read(partial) -> np.ndarray:
                     partial[0].ndim == 3)
 
 
+def _packed(keys: np.ndarray) -> np.ndarray:
+    """Each key row as one big-endian byte string: numpy orders and
+    searches those bytewise, which is the rows' lexicographic order."""
+    return np.ascontiguousarray(keys, ">u4").view(
+        np.dtype((np.void, 4 * keys.shape[1]))).ravel()
+
+
+def _insert_rows(arr: np.ndarray, at: np.ndarray,
+                 rows: np.ndarray) -> np.ndarray:
+    """``np.insert(arr, at, rows, axis=0)`` moving each row as one
+    item, not lane by lane."""
+    row = np.dtype((np.void, arr.itemsize * arr.shape[1]))
+    return np.insert(arr.view(row).ravel(), at,
+                     np.ascontiguousarray(rows).view(row).ravel()
+                     ).view(arr.dtype).reshape(-1, arr.shape[1])
+
+
+def _sorted_run(keys, sums) -> tuple[np.ndarray, np.ndarray]:
+    """Rows in any order, a key perhaps more than once, as unique rows
+    in lexicographic order with the sums of equal keys added: one sort."""
+    keys = np.ascontiguousarray(keys, np.uint32)
+    sums = np.asarray(sums, np.uint64)
+    if not len(keys):
+        return keys, sums
+    order, starts = _lex_regroup(keys)
+    return keys[order[starts]], np.add.reduceat(sums[order], starts, axis=0)
+
+
+class WindowStore:
+    """One window's groups as two arrays: ``key_rows`` [G, lanes] uint32,
+    rows unique and in lexicographic order, and ``sums`` [G, nvals + 1]
+    uint64 (values, then count) row for row beside them. The form the
+    checkpoint writes, the mesh ships and a close turns into rows.
+
+    ``key_rows`` is never written in place (an insert replaces it);
+    ``sums`` is, by every fold. Read like a mapping of key tuples to sums
+    rows: ``len``, ``[key]``, iteration over the keys, ``items()``."""
+
+    def __init__(self, key_rows: np.ndarray, sums: np.ndarray):
+        """Adopts ``key_rows`` (C-contiguous, sorted, unique) and
+        ``sums``."""
+        self.key_rows = key_rows
+        self.sums = sums
+        self._search = None  # _packed(key_rows), once a fold needs it
+
+    @classmethod
+    def from_rows(cls, keys, sums) -> "WindowStore":
+        """A store of rows in any order (_sorted_run)."""
+        return cls(*_sorted_run(keys, sums))
+
+    def _find(self, keys: np.ndarray, packed: np.ndarray):
+        """Where each of ``keys`` stands or would stand, and whether it
+        is there: a binary search on the packed rows, then the keys
+        themselves compared."""
+        if self._search is None:
+            self._search = _packed(self.key_rows)
+        pos = np.searchsorted(self._search, packed)
+        # a key past the last row compares with the last, and differs
+        at = np.minimum(pos, len(self.key_rows) - 1)
+        return pos, (self.key_rows[at] == keys).all(axis=1)
+
+    def merge(self, keys: np.ndarray, sums: np.ndarray) -> int:
+        """Add a sorted run of unique rows: one indexed add into the
+        rows that are there (unique keys, so no index repeats) and one
+        insert of those that are not. Returns how many were inserted."""
+        packed = _packed(keys)
+        if not len(self):
+            self.key_rows, self.sums, self._search = keys, sums, packed
+            return len(keys)
+        pos, hit = self._find(keys, packed)
+        self.sums[pos[hit]] += sums[hit]
+        if hit.all():
+            return 0
+        new = ~hit
+        at = pos[new]
+        self.key_rows = _insert_rows(self.key_rows, at, keys[new])
+        self.sums = _insert_rows(self.sums, at, sums[new])
+        self._search = np.insert(self._search, at, packed[new])
+        return len(at)
+
+    def snapshot(self) -> tuple[np.ndarray, np.ndarray]:
+        """(key rows, sums) that no later fold writes into."""
+        return self.key_rows, self.sums.copy()
+
+    def __len__(self) -> int:
+        return len(self.key_rows)
+
+    def __iter__(self) -> Iterator[tuple]:
+        return map(tuple, self.key_rows.tolist())
+
+    def items(self) -> Iterator[tuple[tuple, np.ndarray]]:
+        return zip(self, self.sums)
+
+    def __getitem__(self, key: tuple) -> np.ndarray:
+        """The sums row of one key tuple (a view: the fold's own row)."""
+        row = np.array([key], np.uint32)
+        if len(self) and row.shape[1] == self.key_rows.shape[1]:
+            pos, hit = self._find(row, _packed(row))
+            if hit[0]:
+                return self.sums[pos[0]]
+        raise KeyError(key)
+
+
 # Device partials queued before add_partial forces a host fold: the bound
 # for callers that never probe (a flush-free update() loop, the sharded
 # paths). It caps what the queue pins: each pending partial
@@ -184,8 +288,8 @@ class WindowAggregator:
             )
         self.config = config
         self._update = _build_update(config)
-        # windows: timeslot -> {key tuple -> uint64 [**values, count]}
-        self.windows: dict[int, dict[tuple, np.ndarray]] = {}
+        # open windows: timeslot -> its groups
+        self.windows: dict[int, WindowStore] = {}
         self.watermark = 0  # max time_received seen
         # device partials not yet folded into `windows`, oldest first:
         # (partial, fallback, min_slot). jax dispatch is async, so a
@@ -206,8 +310,8 @@ class WindowAggregator:
 
     @property
     def store_key_lanes(self) -> int:
-        """Width of the window-store key tuples (excludes the timeslot,
-        which is the dict key) — restore uses this to reject checkpoints
+        """Width of the window stores' key rows (excludes the timeslot,
+        which ``windows`` is keyed by) — restore uses this to reject checkpoints
         written under a different grouping layout (e.g. pre-sampling
         builds without the rate lane)."""
         return sum(lane_width(n) for n in self.config.key_cols) + (
@@ -370,16 +474,16 @@ class WindowAggregator:
         with TRACER.span("wagg_fold") as span:
             keys = np.concatenate(all_keys)
             span["groups"] = len(keys)
-            self._merge_partials(keys, np.concatenate(all_sums),
-                                 np.concatenate(all_counts))
+            span["inserted"] = self._merge_partials(
+                keys, np.concatenate(all_sums), np.concatenate(all_counts))
             span["store_groups"] = sum(map(len, self.windows.values()))
 
-    def _merge_partials(self, keys, plane_sums, counts) -> None:
+    def _merge_partials(self, keys, plane_sums, counts) -> int:
         """Fold device partial aggregates (keys + 16-bit value planes +
-        counts) into the per-window host accumulators."""
+        counts) into the per-window stores; returns _fold_rows' count."""
         n = keys.shape[0]
         if n == 0:
-            return
+            return 0
         keys = keys.astype(np.uint32)
         plane_sums = plane_sums.astype(np.uint64)
         counts = counts.astype(np.uint64)
@@ -390,7 +494,7 @@ class WindowAggregator:
             vals[:, j] = plane_sums[:, 2 * j] + (
                 plane_sums[:, 2 * j + 1] << np.uint64(16))
         vals[:, nvals] = counts
-        self._fold_rows(keys, vals)
+        return self._fold_rows(keys, vals)
 
     def add_host_rows(self, keys, sums, counts) -> None:
         """Queue host-grouped EXACT rows for the window store.
@@ -404,7 +508,7 @@ class WindowAggregator:
 
         Rows are buffered and folded at the next drain (flush, snapshot,
         or every DRAIN_PENDING_MAX chunks): one lexsort over the whole
-        backlog beats per-chunk dict merges the same way the device
+        backlog beats per-chunk merges the same way the device
         partial queue does, at a few MB of host memory."""
         expect = 1 + self.store_key_lanes
         if keys.ndim != 2 or keys.shape[1] != expect:
@@ -424,35 +528,29 @@ class WindowAggregator:
         if len(self._pending_host) >= DRAIN_PENDING_MAX:
             self._drain()
 
-    def _fold_rows(self, keys, vals) -> None:
+    def _fold_rows(self, keys, vals) -> int:
         """Merge (slot, key) rows + uint64 value/count columns into the
-        per-window dicts.
+        windows' stores; returns how many of the rows were new to theirs.
 
-        Vectorized: the whole drain's rows are combined with ONE
-        lexsort + boundary reduceat, and Python-level dict work happens
-        only per UNIQUE (slot, key) row — measured 6-10x cheaper than the
-        previous per-row dict loop at the 8-device drain size (the host
-        fold was 20% of sharded step time, VERDICT r2 #6)."""
-        n = keys.shape[0]
-        if n == 0:
-            return
-        order = np.lexsort(keys.T[::-1])  # rows grouped by (slot, key)
-        sk = keys[order]
-        boundary = np.empty(n, dtype=bool)
-        boundary[0] = True
-        np.any(sk[1:] != sk[:-1], axis=1, out=boundary[1:])
-        starts = np.flatnonzero(boundary)
-        uniq = sk[starts]
-        sums = np.add.reduceat(vals[order], starts, axis=0)
-        for i in range(len(starts)):
-            slot = int(uniq[i, 0])
-            key = tuple(int(x) for x in uniq[i, 1:])
-            wstore = self.windows.setdefault(slot, {})
-            acc = wstore.get(key)
-            if acc is None:
-                wstore[key] = sums[i].copy()
+        The drain's rows (several slots, a key once a folded partial)
+        become one sorted run of unique rows with ONE lexsort + boundary
+        reduceat; each slot's stretch of it (one or two a drain) then
+        merges into that slot's store with array operations alone
+        (WindowStore.merge)."""
+        keys, sums = _sorted_run(keys, vals)
+        # in (slot, key) order: where each slot's rows start
+        slots, first = np.unique(keys[:, 0], return_index=True)
+        ends = [*first[1:].tolist(), len(keys)]
+        inserted = 0
+        for slot, a, b in zip(slots.tolist(), first.tolist(), ends):
+            rows = np.ascontiguousarray(keys[a:b, 1:])
+            store = self.windows.get(slot)
+            if store is None:
+                self.windows[slot] = WindowStore(rows, sums[a:b])
+                inserted += b - a
             else:
-                acc += sums[i]
+                inserted += store.merge(rows, sums[a:b])
+        return inserted
 
     def closed_slots(self) -> list[int]:
         self._drain()
@@ -481,7 +579,8 @@ class WindowAggregator:
         limit = self.watermark - self.config.allowed_lateness
         return min(bounds) + self.config.window_seconds > limit
 
-    def pop_closed(self, force: bool = False) -> list[tuple[int, dict]]:
+    def pop_closed(self, force: bool = False
+                   ) -> list[tuple[int, WindowStore]]:
         """Detach finalized windows (all, if force) as (slot, store)
         pairs. The popped stores are exclusively the caller's — late rows
         for them REOPEN fresh stores, emitted as additional partials —
@@ -515,7 +614,7 @@ class WindowAggregator:
         return rows_from_stores(self.config, self.pop_closed(force))
 
 
-def wagg_rows(store: dict, config: WindowAggConfig, k: int,
+def wagg_rows(store: WindowStore, config: WindowAggConfig, k: int,
               slot: int) -> dict[str, np.ndarray]:
     """Emitted rows for ONE merged window store — the wagg family's
     rows hook (families/registry.py), signature-compatible with the
@@ -525,7 +624,8 @@ def wagg_rows(store: dict, config: WindowAggConfig, k: int,
 
 
 def rows_from_stores(config: WindowAggConfig,
-                     stores: list[tuple[int, dict]]) -> dict[str, np.ndarray]:
+                     stores: list[tuple[int, WindowStore]]
+                     ) -> dict[str, np.ndarray]:
     """Columnar flush rows from popped (slot, store) pairs — the second
     half of flush(), a pure function so the ingest flusher can run it off
     the worker thread. A call that has stores to turn into rows (a
@@ -540,35 +640,30 @@ def rows_from_stores(config: WindowAggConfig,
 
 
 def _rows_from_stores(config: WindowAggConfig,
-                      stores: list[tuple[int, dict]]) -> dict[str, np.ndarray]:
-    """Vectorized: one lexsort + reduceat per slot instead of a Python
-    dict loop per key (the old per-key loop was the dominant flush cost
-    at 10k+ groups/window)."""
+                      stores: list[tuple[int, WindowStore]]
+                      ) -> dict[str, np.ndarray]:
+    """From each store's arrays as they are: its rows stand in key
+    order already, so the rows of one reference key (the rate lane is
+    the last) are neighbours and fold with one reduceat, no sort."""
     scaled = config.scale_col is not None
     nvals = len(config.value_cols)
     ts_parts, key_parts, val_parts, scaled_parts = [], [], [], []
     for slot, store in stores:
-        if not store:
+        if not len(store):
             continue
-        keys = np.fromiter(
-            (x for key in store for x in key), dtype=np.uint64,
-            count=len(store) * (len(next(iter(store)))),
-        ).reshape(len(store), -1)
-        vals = np.stack(list(store.values())).astype(np.uint64)
+        keys, vals = store.key_rows.astype(np.uint64), store.sums
         if scaled:
             base, rate = keys[:, :-1], np.maximum(keys[:, -1], 1)
             svals = vals[:, :nvals] * rate[:, None]
             # fold per-rate subgroups back to the reference key shape
-            # (shared exact-grouping helper — ops.hostgroup)
-            order, starts = _lex_regroup(base)
-            key_arr = base[order][starts]
-            val_arr = np.add.reduceat(vals[order], starts, axis=0)
-            scaled_arr = np.add.reduceat(svals[order], starts, axis=0)
+            starts = _run_starts(base)
+            key_arr = base[starts]
+            val_arr = np.add.reduceat(vals, starts, axis=0)
+            scaled_arr = np.add.reduceat(svals, starts, axis=0)
         else:
             # unscaled: scaled sums == raw sums (rate treated as 1)
-            order = np.lexsort(keys.T[::-1])
-            key_arr = keys[order]
-            val_arr = vals[order]
+            key_arr = keys
+            val_arr = vals
             scaled_arr = val_arr[:, :nvals].copy()
         ts_parts.append(np.full(len(key_arr), slot, np.uint64))
         key_parts.append(key_arr)

@@ -73,15 +73,18 @@ def _empty_groups(w: int, planes: list[np.ndarray]):
             np.zeros(0, np.int64))
 
 
+def _run_starts(sorted_lanes: np.ndarray) -> np.ndarray:
+    """Where each run of equal rows starts, in rows already sorted."""
+    boundary = np.empty(sorted_lanes.shape[0], dtype=bool)
+    boundary[0] = True
+    np.any(sorted_lanes[1:] != sorted_lanes[:-1], axis=1, out=boundary[1:])
+    return np.flatnonzero(boundary)
+
+
 def _lex_regroup(lanes: np.ndarray):
     """Exact lexicographic grouping — the 64-bit-collision fallback."""
-    n = lanes.shape[0]
     perm = np.lexsort(lanes.T[::-1])
-    sl = lanes[perm]
-    boundary = np.empty(n, dtype=bool)
-    boundary[0] = True
-    np.any(sl[1:] != sl[:-1], axis=1, out=boundary[1:])
-    return perm, np.flatnonzero(boundary)
+    return perm, _run_starts(lanes[perm])
 
 
 def grouping_perm(lanes: np.ndarray, exact: bool, h: np.ndarray = None,

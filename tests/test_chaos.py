@@ -29,7 +29,7 @@ from flow_pipeline_tpu.mesh import codec
 from flow_pipeline_tpu.mesh.journal import (CoordinatorJournal,
                                             replay_journal)
 from flow_pipeline_tpu.models.oracle import exact_groupby
-from flow_pipeline_tpu.models.window_agg import WindowAggConfig
+from flow_pipeline_tpu.models.window_agg import WindowAggConfig, WindowStore
 from flow_pipeline_tpu.schema.batch import FlowBatch
 from flow_pipeline_tpu.sink import MemorySink, ResilientSink
 from flow_pipeline_tpu.sink.resilient import (deadletter_files,
@@ -382,7 +382,7 @@ def _contrib(ranges, wm, closed=None, open_=None, final=False,
 
 def _wagg_win(key, val):
     return {"flows_5m": codec.wagg_payload(
-        {(key,): np.array([val, 1], np.uint64)})}
+        WindowStore.from_rows([(key,)], [(val, 1)]))}
 
 
 class TestCoordinatorRecovery:

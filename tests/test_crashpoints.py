@@ -287,7 +287,7 @@ def _restored_wagg_step(path: str):
         want = _wagg(step)
         if (got.watermark == want.watermark
                 and got.windows.keys() == want.windows.keys()
-                and all(got.windows[s].keys() == st.keys()
+                and all(set(got.windows[s]) == set(st)
                         and all(np.array_equal(got.windows[s][k], v)
                                 for k, v in st.items())
                         for s, st in want.windows.items())):

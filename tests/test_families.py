@@ -75,7 +75,7 @@ class TestHooks:
             is merge_ops.merge_hh
         assert registry.hook(registry.family("wagg"), "merge") \
             is merge_ops.merge_wagg
-        assert merge_ops.merge_wagg([], config=None) == {}
+        assert len(merge_ops.merge_wagg([], config=None)) == 0
 
     def test_resolve_caches(self):
         ref = registry.family("spread").merge

@@ -24,7 +24,7 @@ from flow_pipeline_tpu.mesh import merge as merge_ops
 from flow_pipeline_tpu.models.heavy_hitter import (HeavyHitterConfig,
                                                    hh_init)
 from flow_pipeline_tpu.models.oracle import exact_groupby
-from flow_pipeline_tpu.models.window_agg import WindowAggConfig
+from flow_pipeline_tpu.models.window_agg import WindowAggConfig, WindowStore
 from flow_pipeline_tpu.transport import Consumer, InProcessBus
 from flow_pipeline_tpu.utils.flags import KNOWN_FLAGS, FlagSet
 
@@ -182,7 +182,8 @@ class TestCodec:
     def test_wagg_store_round_trip(self):
         store = {(1, 2, 3, 7): np.array([10, 20, 5], np.uint64),
                  (9, 9, 9, 1): np.array([2**63, 1, 1], np.uint64)}
-        payload = codec.wagg_payload(store)
+        payload = codec.wagg_payload(
+            WindowStore.from_rows(list(store), list(store.values())))
         out = codec.decode(codec.encode(payload))
         merged = merge_ops.merge_wagg([out])
         assert set(merged) == set(store)
@@ -241,9 +242,9 @@ except ImportError:  # pragma: no cover - env without hypothesis
 
 class TestMerges:
     def test_wagg_merge_sums_by_key(self):
-        a = codec.wagg_payload({(1, 2): np.array([10, 1], np.uint64)})
-        b = codec.wagg_payload({(1, 2): np.array([5, 2], np.uint64),
-                                (3, 4): np.array([7, 1], np.uint64)})
+        a = codec.wagg_payload(WindowStore.from_rows([(1, 2)], [(10, 1)]))
+        b = codec.wagg_payload(WindowStore.from_rows(
+            [(3, 4), (1, 2)], [(7, 1), (5, 2)]))
         merged = merge_ops.merge_wagg([a, b])
         assert (merged[(1, 2)] == np.array([15, 3], np.uint64)).all()
         assert (merged[(3, 4)] == np.array([7, 1], np.uint64)).all()
@@ -336,7 +337,7 @@ def _contrib(ranges, wm, closed=None, open_=None, final=False,
 
 def _wagg_win(key, val):
     return {"flows_5m": codec.wagg_payload(
-        {(key,): np.array([val, 1], np.uint64)})}
+        WindowStore.from_rows([(key,)], [(val, 1)]))}
 
 
 class TestCoordinatorProtocol:

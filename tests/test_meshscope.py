@@ -26,7 +26,7 @@ from flow_pipeline_tpu.mesh import (ClockSync, InProcessMesh,
                                     TraceLane, aggregate_traces,
                                     estimate_offset, produce_sharded)
 from flow_pipeline_tpu.mesh import codec
-from flow_pipeline_tpu.models.window_agg import WindowAggConfig
+from flow_pipeline_tpu.models.window_agg import WindowAggConfig, WindowStore
 from flow_pipeline_tpu.obs import REGISTRY, MetricsServer
 from flow_pipeline_tpu.obs.buildinfo import BUILD_INFO, publish_build_info
 from flow_pipeline_tpu.obs.trace import TRACER
@@ -69,7 +69,7 @@ def _contrib(ranges, wm, closed=None, open_=None, final=False,
 
 def _wagg_win(key, val):
     return {"flows_5m": codec.wagg_payload(
-        {(key,): np.array([val, 1], np.uint64)})}
+        WindowStore.from_rows([(key,)], [(val, 1)]))}
 
 
 def _span(sub, chunk=7, slots=(300,)):

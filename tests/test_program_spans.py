@@ -61,7 +61,8 @@ SPAN_ARGS = {
     "lane_build": ("rows", "padded"), "h2d": ("bytes", "cols"),
     "step_dispatch": ("rows", "padded", "do_hh", "do_dd"),
     "wagg_wait": ("folded", "left"),
-    "wagg_d2h": ("bytes",), "wagg_fold": ("groups", "store_groups"),
+    "wagg_d2h": ("bytes",),
+    "wagg_fold": ("groups", "inserted", "store_groups"),
     "wagg_rows": ("rows",), "wagg_state": ("windows", "groups"),
     "ckpt_d2h": ("bytes", "leaves"),
     "ckpt_serialize": ("raw_bytes", "npz_bytes", "members"),

@@ -31,6 +31,7 @@ from flow_pipeline_tpu.engine.worker import (StreamWorker, restore_wagg_state,
                                              save_wagg_state)
 from flow_pipeline_tpu.gen import FlowGenerator, ZipfProfile
 from flow_pipeline_tpu.models import WindowAggregator
+from flow_pipeline_tpu.models.window_agg import WindowStore
 from flow_pipeline_tpu.obs.trace import TRACER
 from flow_pipeline_tpu.schema import wire
 from flow_pipeline_tpu.transport import InProcessBus
@@ -224,9 +225,8 @@ def _aggregator(groups: int, slots=(T0,), seed=5) -> WindowAggregator:
     for slot in slots:
         keys = rng.integers(0, 2**32, (groups, agg.store_key_lanes),
                             dtype=np.uint64)
-        agg.windows[slot] = {
-            tuple(int(x) for x in k):
-            rng.integers(0, 2**50, 3, dtype=np.uint64) for k in keys}
+        agg.windows[slot] = WindowStore.from_rows(
+            keys, rng.integers(0, 2**50, (groups, 3), dtype=np.uint64))
     agg.watermark = max(slots) + 17
     return agg
 
@@ -234,7 +234,7 @@ def _aggregator(groups: int, slots=(T0,), seed=5) -> WindowAggregator:
 def _assert_stores_equal(got: dict, want: dict):
     assert sorted(got) == sorted(want)
     for slot, store in want.items():
-        assert got[slot].keys() == store.keys()
+        assert set(got[slot]) == set(store)
         for key, acc in store.items():
             have = got[slot][key]
             assert have.dtype == np.uint64
@@ -285,15 +285,22 @@ def test_checkpoint_members_and_meta_do_not_grow_with_the_groups(tmp_path):
 def _parents_form(agg) -> dict:
     """What ``save_wagg_state`` returned before this form: the store
     itself, an npz member a group and a JSON list a key once encoded."""
-    return {"kind": "window_agg", "windows": agg.windows,
-            "watermark": agg.watermark}
+    return {"kind": "window_agg", "watermark": agg.watermark,
+            "windows": {slot: dict(store.items())
+                        for slot, store in agg.windows.items()}}
 
 
-@pytest.mark.parametrize("form", ["parent", "arrays"])
+@pytest.mark.parametrize("form", ["parent", "arrays", "arrays_unsorted"])
 def test_both_forms_restore_equal(tmp_path, form):
     agg = _aggregator(300, slots=(T0, T0 + SLOT))
     want = copy.deepcopy(agg.windows)
     state = _parents_form(agg) if form == "parent" else save_wagg_state(agg)
+    if form == "arrays_unsorted":
+        # as the builds wrote it whose store was a dict: rows in the
+        # order the keys were first seen, not in key order
+        for s in state["stores"]:
+            order = np.random.default_rng(7).permutation(len(s["keys"]))
+            s["keys"], s["sums"] = s["keys"][order], s["sums"][order]
     path = str(tmp_path / "ckpt")
     save_checkpoint(path, {"models": {"flows_5m": state}})
     with np.load(os.path.join(path, "arrays.npz")) as z:
@@ -309,14 +316,14 @@ def test_both_forms_restore_equal(tmp_path, form):
 def test_a_store_of_another_key_layout_is_skipped_loudly(tmp_path, form):
     agg = _aggregator(5)
     lanes = agg.store_key_lanes
-    agg.windows = {T0: {k[:-1]: v for k, v in agg.windows[T0].items()}}
+    store = agg.windows[T0]
+    agg.windows = {T0: WindowStore.from_rows(store.key_rows[:, :-1],
+                                             store.sums)}
     if form == "parent":
         state = _parents_form(agg)
     else:  # written by a build whose grouping had a lane less
-        state = {"kind": "window_agg", "watermark": agg.watermark,
-                 "stores": [{"slot": T0, "keys": np.array(
-                     list(agg.windows[T0]), np.uint32).reshape(5, lanes - 1),
-                     "sums": np.stack(list(agg.windows[T0].values()))}]}
+        state = save_wagg_state(agg)
+        assert state["stores"][0]["keys"].shape == (5, lanes - 1)
     path = str(tmp_path / "ckpt")
     save_checkpoint(path, {"m": state})
     fresh = WindowAggregator()
@@ -388,6 +395,11 @@ def test_fold_and_close_spans_count_groups_and_rows(whole, reference):
     folds = _args(spans, "wagg_fold")
     assert max(f["groups"] for f in folds) > AS_COUNT
     assert max(f["store_groups"] for f in folds) >= BIG
+    # every group of every window was new to its store once (a rate's
+    # row of a key is a group of its own, so at least the rows emitted)
+    assert all(0 <= f["inserted"] <= f["groups"] for f in folds)
+    assert sum(f["inserted"] for f in folds) >= sum(per_slot.values())
+    assert min(f["inserted"] for f in folds) < AS_COUNT
     assert sorted(s["rows"] for s in _args(spans, "wagg_rows")) \
         == sorted(per_slot.values())
     assert sorted(s["rows"] for s in _args(spans, "flush")

@@ -95,7 +95,7 @@ def build(path: str, window: int = 300):
 
 def stored_flows(agg) -> int:
     return sum(int(acc[-1]) for store in agg.windows.values()
-               for acc in store.values())
+               for _key, acc in store.items())
 
 
 def pending_flows(agg) -> int:
