@@ -1,0 +1,10 @@
+"""The SQL sink's record building (sink/base.py::rows_to_records: a Python
+step a row and column), summed over the tables one chunk flushed; median
+over the window's chunks that flushed. Source: the program's sink_records
+span, recorded by the sink inside its sink_put."""
+
+from benchmark import inside_spans
+
+
+def read(run):
+    return inside_spans.flush_ms_per_chunk(run, "sink_records")

@@ -457,7 +457,9 @@ class FusedPipeline(WindowLifecycle):
     def update(self, batch: FlowBatch) -> None:
         if len(batch) == 0:
             return
-        parts, wm = self._split_parts(batch)
+        with TRACER.span("split_parts") as span:
+            parts, wm = self._split_parts(batch)
+            span["parts"] = len(parts)
         # a batch that fills a device step: the source holds a backlog
         # (WindowAggregator._min_slot)
         self._behind = len(batch) >= self._bs

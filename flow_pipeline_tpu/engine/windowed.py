@@ -167,6 +167,9 @@ class WindowedHeavyHitter:
         self.window_seconds = window_seconds
         self.k = k
         self.model = model_cls(config, **model_kw)
+        # the table's name, for the close's span (a sharded backing
+        # model is given its own as ``name``)
+        self.name = model_kw.get("name", slide_name)
         # the grain ``current_slot`` rolls at: the window, or the slide
         self.slot_seconds = slide_seconds or window_seconds
         self.ring: SubWindowRing | None = None
@@ -285,16 +288,25 @@ class WindowedHeavyHitter:
             state = self.model.window_state()
             self._emit_slide(self.current_slot, state)
             self.ring.rotate(self.current_slot, state)
-        elif self.lazy_extract and hasattr(self.model, "top_lazy"):
-            self._pending.append(LazyWindowTop(
-                self.model.top_lazy(self.k), self.current_slot))
         else:
+            self._emit_window(self.current_slot)
+        self.model.reset()
+
+    def _emit_window(self, slot: int) -> None:
+        """Queue the rows of the tumbling window ``slot``: one extraction
+        (or, under lazy_extract, the handle that defers it to the
+        flusher), ``slide_close``'s twin."""
+        with TRACER.span("window_close", model=self.name,
+                         slot=slot) as span:
+            if self.lazy_extract and hasattr(self.model, "top_lazy"):
+                self._pending.append(LazyWindowTop(
+                    self.model.top_lazy(self.k), slot))
+                return
             top = self.model.top(self.k)
             top["timeslot"] = np.full(
-                len(top["valid"]), self.current_slot, dtype=np.uint64
-            )
+                len(top["valid"]), slot, dtype=np.uint64)
+            span["rows"] = int(top["valid"].sum())
             self._pending.append(top)
-        self.model.reset()
 
     def _emit_slide(self, sub: int, open_state) -> None:
         """Queue the rows of the window that ends with sub-window

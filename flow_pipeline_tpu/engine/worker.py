@@ -24,7 +24,7 @@ from ..models.heavy_hitter import HHState
 from ..models.window_agg import WindowAggregator, WindowStore
 from ..obs import REGISTRY, get_logger
 from ..obs.trace import TRACER
-from ..obs.tracing import StageTimer
+from ..obs.tracing import register_stage_histogram
 
 # Buckets for the window-end -> sink-commit latency histogram: seconds,
 # spanning "flushed within the batch" (~1s) to "stuck for an hour".
@@ -392,9 +392,15 @@ class StreamWorker:
         self._commit_watermark = 0.0
         # flowlint: unguarded -- worker thread only (set per _process step, read when queueing flush jobs)
         self._trace_chunk = -1
-        # per-stage breakdown (the reference charts the same
-        # flow_summary_*_time_us family for its collector stages)
-        self.stages = StageTimer()
+        # the bus's stamp on the newest batch applied (0.0: a transport
+        # that does not stamp): a publish reckons its snapshot's age
+        # from it
+        # flowlint: unguarded -- worker thread only (set per _process step, read by the serve publisher on that thread)
+        self.last_produced_at = 0.0
+        # flow_stage_duration_us on every worker's /metrics, whichever
+        # dataplane it picked (the dashboard's host_fused heatmap, the
+        # honesty test); the worker itself times no stage: its spans do
+        register_stage_histogram()
         if config.archive_raw:
             # fail fast on schema drift instead of crash-looping on 400s
             for sink in self.sinks:
@@ -464,14 +470,18 @@ class StreamWorker:
 
     def _process_batch(self, batch, prep, span: dict) -> bool:
         t0 = time.perf_counter()
-        t0_wall = time.time()
+        # age of the batch's head (bus produce time -> this pickup);
+        # unstamped transports (Kafka) report 0.0: the span then says
+        # nothing and the guard's ladder simply never engages for them
+        pa = self.last_produced_at = getattr(batch, "produced_at", 0.0)
+        age = 0.0
+        if pa > 0.0:
+            age = time.time() - pa
+            span["age_ms"] = age * 1e3
         guard = self.guard
         if guard.armed:
-            # watermark lag = age of the backlog head (bus produce time
-            # -> this pickup); unstamped transports (Kafka) report 0.0
-            # and the ladder simply never engages for them
-            pa = getattr(batch, "produced_at", 0.0)
-            guard.observe(t0_wall - pa if pa > 0.0 else 0.0)
+            # watermark lag, for the degradation ladder
+            guard.observe(age)
             if prep is None and guard.sample_shift > 0:
                 # serial path (no group thread): admit here instead
                 batch, _ = guard.admit(batch)
@@ -497,20 +507,19 @@ class StreamWorker:
             # irreducible at-least-once window as sink flushes (_process
             # below), not snapshot_every batches' worth of raw rows.
             self._emitted_since_snapshot |= archived
-        with self.stages.stage("processing"):
-            if len(batch) == 0:
-                pass  # fully shed upstream; offsets still commit below
-            elif prep is not None:
-                self.fused.apply(prep)  # prepare ran on the group thread
-            elif self.fused is not None:
-                self.fused.update(batch)
-            else:
-                for model in self.models.values():
-                    model.update(batch)
-            for name, model in self.models.items():
-                dropped = getattr(model, "late_flows_dropped", None)
-                if dropped:
-                    self.m_late.set(dropped, model=name)
+        if len(batch) == 0:
+            pass  # fully shed upstream; offsets still commit below
+        elif prep is not None:
+            self.fused.apply(prep)  # prepare ran on the group thread
+        elif self.fused is not None:
+            self.fused.update(batch)
+        else:
+            for model in self.models.values():
+                model.update(batch)
+        for name, model in self.models.items():
+            dropped = getattr(model, "late_flows_dropped", None)
+            if dropped:
+                self.m_late.set(dropped, model=name)
         self.batches_seen += 1
         self.flows_seen += len(batch)
         self.m_flows.inc(len(batch))
@@ -587,18 +596,7 @@ class StreamWorker:
 
     def flush_closed(self, force: bool = False) -> None:
         """Emit rows for closed (or all, when force) windows to the sinks."""
-        t0 = time.perf_counter()
-        emitted = self._flush_closed(force)
-        # Observe only flushes that DID something: this runs every batch
-        # but windows close hundreds of batches apart, so timing the
-        # no-ops would bury real flush latency below every exported
-        # quantile of the 1024-sample summary window. (The return value,
-        # not the shared snapshot flag: raw archiving sets that flag
-        # before the flush and would mask every mid-stream observation.)
-        # Under the async flusher the jobs time THEMSELVES into the same
-        # summary (_write_rows); timing the submit would double-count.
-        if emitted and self.flusher is None:
-            self.stages.observe("flushing", (time.perf_counter() - t0) * 1e6)
+        self._flush_closed(force)
 
     def sync_sketch_states(self) -> None:
         """Export host-backend sketch state into the models before a read
@@ -703,15 +701,22 @@ class StreamWorker:
     def _write_rows(self, table: str, rows, n: Optional[int],
                     export_ts: Optional[float] = None,
                     chunk: int = -1) -> None:
-        t0 = time.perf_counter()
+        # "flush_rows" and one "sink_put" a sink tile the "flush"
         with TRACER.span("flush", chunk=chunk, table=table) as span:
-            rows = self._materialize(rows)
-            n = self._row_count(rows) if n is None else n
+            with TRACER.span("flush_rows", chunk=chunk,
+                             table=table) as made:
+                rows = self._materialize(rows)
+                n = self._row_count(rows) if n is None else n
+                made["rows"] = n
             for sink in self.sinks:
-                sink.write(table, rows)
+                # a retry wrapper (ResilientSink) goes by the sink it
+                # guards: its retries and backoff are that sink's time
+                with TRACER.span("sink_put", chunk=chunk, table=table,
+                                 sink=type(getattr(sink, "inner",
+                                                   sink)).__name__,
+                                 rows=n):
+                    sink.write(table, rows)
             span["rows"] = n
-        if self.flusher is not None:
-            self.stages.observe("flushing", (time.perf_counter() - t0) * 1e6)
         now = time.time()
         if export_ts is not None:
             # flow-export-timestamp -> sink-commit latency: how stale the

@@ -28,18 +28,23 @@ OVERFLOW_STAGE = "other"
 STAGE_HISTOGRAM = "flow_stage_duration_us"
 
 
+def register_stage_histogram():
+    """The shared flow_stage_duration_us{stage=...} histogram: registered
+    eagerly so /metrics (and the dashboard honesty test) sees the family
+    before the first stage observation."""
+    return REGISTRY.histogram(
+        STAGE_HISTOGRAM,
+        "per-stage wall time histogram (us; aggregable across "
+        "instances, unlike the summary quantiles)")
+
+
 class StageTimer:
     """Named per-stage timers -> flow_summary_<stage>_time_us summaries
     + the shared flow_stage_duration_us{stage=...} histogram."""
 
     def __init__(self):
         self._summaries = {}
-        # registered eagerly so /metrics (and the dashboard honesty
-        # test) sees the family before the first stage observation
-        self._hist = REGISTRY.histogram(
-            STAGE_HISTOGRAM,
-            "per-stage wall time histogram (us; aggregable across "
-            "instances, unlike the summary quantiles)")
+        self._hist = register_stage_histogram()
 
     def _resolve(self, name: str) -> str:
         """Overflow guard: a caller minting unbounded stage names (e.g. a

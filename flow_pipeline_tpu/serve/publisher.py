@@ -42,6 +42,7 @@ from ..families import registry
 from ..models.heavy_hitter import key_width
 from ..models.window_agg import WindowAggregator
 from ..obs import get_logger
+from ..obs.trace import TRACER
 from .snapshot import FamilyView, RangeLedger, Snapshot, SnapshotStore
 
 log = get_logger("serve")
@@ -72,7 +73,8 @@ def hh_view_parts(m: WindowedHeavyHitter):
         # replaced, never mutated.) The expensive f32->u64 freeze
         # stays lazy either way — first estimate reader pays it.
         planes = np.asarray(planes)
-    return FrozenCms(lambda a=planes: frozen_cms(a)), key_width(m.config), \
+    return FrozenCms(lambda a=planes: frozen_cms(a),
+                     captured_bytes=planes.nbytes), key_width(m.config), \
         None
 
 
@@ -135,10 +137,17 @@ def _family_from_model(name: str, m: WindowedHeavyHitter) -> FamilyView:
     family registry's serve_capture hook (unknown snapshot kinds fall
     back to the dense shape, as before)."""
     depth = m.k
-    rows = m.top(depth)
-    fam = registry.family_for_snapshot(m.model.snapshot_kind) \
-        or registry.family("dense")
-    cms, lanes, regs = registry.hook(fam, "serve_capture")(m)
+    # one "publish_view" a family: the first of a publish blocks on the
+    # step in flight, and the capture is the planes' device->host copy
+    with TRACER.span("publish_view", model=name) as span:
+        rows = m.top(depth)
+        fam = registry.family_for_snapshot(m.model.snapshot_kind) \
+            or registry.family("dense")
+        cms, lanes, regs = registry.hook(fam, "serve_capture")(m)
+        span["rows"] = int(rows["valid"].sum())
+        span["bytes"] = (sum(v.nbytes for v in rows.values())
+                         + (cms.captured_bytes if cms is not None else 0)
+                         + (regs.nbytes if regs is not None else 0))
     return FamilyView(
         name=name, kind=fam.kind,
         window_start=m.window_start,
@@ -185,15 +194,29 @@ class WorkerServePublisher:
             m.current_slot != self._last_slots.get(name)
             for name, m in worker.models.items()
             if isinstance(m, WindowedHeavyHitter))
-        if self.store.current is None or closed or (
-                self.refresh > 0
-                and time.monotonic() - self._last_publish >= self.refresh):
-            self.publish(worker)
+        # how long ago the cadence came due: the loop asks once a batch,
+        # after its flush and checkpoint
+        late = time.monotonic() - (self._last_publish + self.refresh)
+        if self.store.current is None:
+            self.publish(worker, reason="first")
+        elif closed:
+            self.publish(worker, reason="close")
+        elif self.refresh > 0 and late >= 0:
+            self.publish(worker, reason="refresh", late_s=late)
 
-    def publish(self, worker) -> Snapshot:
+    def publish(self, worker, reason: str = "forced",
+                late_s: float = 0.0) -> Snapshot:
         """Build + swap one snapshot. Caller holds worker.lock (the
-        worker calls this from its own loop; tests may call it on a
-        quiesced worker)."""
+        worker calls this from its own loop, which says why: ``first``,
+        a window ``close``, the ``refresh`` cadence and how late it is;
+        finalize and tests on a quiesced worker call it unasked)."""
+        # "publish_view" a family + "publish_swap" tile it
+        with TRACER.span("snapshot_publish",
+                         chunk=getattr(worker, "_trace_chunk", None),
+                         reason=reason, late_ms=late_s * 1e3) as span:
+            return self._publish(worker, span)
+
+    def _publish(self, worker, span: dict) -> Snapshot:
         t0 = time.monotonic()
         worker.sync_sketch_states()
         families = {}
@@ -224,14 +247,25 @@ class WorkerServePublisher:
             # as a reserved pseudo-model key
             audit = dict(audit or {})
             audit["flowguard"] = guard.meta()
-        snap = self.store.publish(
-            watermark=watermark, flows_seen=worker.flows_seen,
-            source="worker", families=families,
-            ranges=self.ledger.freeze(),
-            # sketchwatch: the newest per-family close reports ride the
-            # snapshot (read under worker.lock here; served lock-free)
-            audit=audit)
+        with TRACER.span("publish_swap") as swap:
+            ranges = self.ledger.freeze()
+            snap = self.store.publish(
+                watermark=watermark, flows_seen=worker.flows_seen,
+                source="worker", families=families, ranges=ranges,
+                # sketchwatch: the newest per-family close reports ride
+                # the snapshot (read under worker.lock here; served
+                # lock-free)
+                audit=audit)
+            swap["ranges"] = sum(len(slots) for slots in ranges.values())
         self._last_publish = time.monotonic()
+        span.update(version=snap.version, flows_seen=snap.flows_seen,
+                    families=len(families))
+        # the snapshot's age at the swap: the bus's stamp on the newest
+        # batch applied -> now (left out for a transport that does not
+        # stamp)
+        produced_at = getattr(worker, "last_produced_at", 0.0)
+        if produced_at > 0.0:
+            span["age_ms"] = (snap.created - produced_at) * 1e3
         log.debug("flowserve published v%d (%.1f ms, %d families)",
                   snap.version, (self._last_publish - t0) * 1e3,
                   len(families))

@@ -88,9 +88,13 @@ class FrozenCms:
     released after the freeze (holding both would double the sketch
     footprint for the snapshot's lifetime)."""
 
-    __slots__ = ("_thunk", "_value", "_lock")
+    __slots__ = ("_thunk", "_value", "_lock", "captured_bytes")
 
-    def __init__(self, thunk=None, value: Optional[np.ndarray] = None):
+    def __init__(self, thunk=None, value: Optional[np.ndarray] = None,
+                 captured_bytes: int = 0):
+        # host bytes the publish copied to build this (the planes a
+        # thunk holds; 0 where the planes came ready): "publish_view"
+        self.captured_bytes = captured_bytes
         # flowlint: unguarded -- written at construction and cleared under _lock at memoization
         self._thunk = thunk
         # flowlint: unguarded -- memoized under _lock (double-checked; the post-build read is of an immutable array)

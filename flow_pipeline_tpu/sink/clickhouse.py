@@ -27,6 +27,7 @@ import numpy as np
 
 from . import ddl
 from .base import rows_to_records
+from ..obs.trace import TRACER
 from ..schema.batch import words_to_addr
 
 
@@ -117,9 +118,15 @@ class ClickHouseSink:
     }
 
     def write(self, table: str, rows) -> None:
-        records = rows_to_records(rows)
+        with TRACER.span("sink_records") as span:
+            records = rows_to_records(rows)
+            span["rows"] = len(records)
         if not records:
             return
+        with TRACER.span("sink_execute", rows=len(records)):
+            self._insert(table, records)
+
+    def _insert(self, table: str, records: list) -> None:
         ddl.assign_ranks(table, records)
         cols = ddl.TABLE_COLUMNS.get(table)
         if cols is not None:

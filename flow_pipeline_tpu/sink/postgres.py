@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+from ..obs.trace import TRACER
 from . import ddl
 from .base import rows_to_records
 
@@ -73,12 +74,15 @@ class PostgresSink:
                 cur.execute(stmt)
 
     def write(self, table: str, rows) -> None:
-        records = rows_to_records(rows)
+        with TRACER.span("sink_records") as span:
+            records = rows_to_records(rows)
+            span["rows"] = len(records)
         if not records or table not in _COLUMNS:
             return
-        sql, args = insert_sql(table, records)
-        with self._conn, self._conn.cursor() as cur:
-            cur.execute(sql, args)
+        with TRACER.span("sink_execute", rows=len(records)):
+            sql, args = insert_sql(table, records)
+            with self._conn, self._conn.cursor() as cur:
+                cur.execute(sql, args)
 
     def close(self) -> None:
         self._conn.close()

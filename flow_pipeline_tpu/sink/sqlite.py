@@ -12,6 +12,7 @@ import json
 import sqlite3
 import threading
 
+from ..obs.trace import TRACER
 from . import ddl
 from .base import rows_to_records
 
@@ -50,10 +51,15 @@ class SQLiteSink:
                     f'ALTER TABLE flows_5m ADD COLUMN "{col}" INTEGER')
 
     def write(self, table: str, rows) -> None:
-        records = rows_to_records(rows)
+        # "sink_records" + "sink_execute" tile this sink's "sink_put"
+        # (engine/worker.py::_write_rows); the span is here and not in
+        # rows_to_records, which the query threads call too
+        with TRACER.span("sink_records") as span:
+            records = rows_to_records(rows)
+            span["rows"] = len(records)
         if not records:
             return
-        with self._lock:
+        with TRACER.span("sink_execute", rows=len(records)), self._lock:
             cols = ddl.TABLE_COLUMNS.get(table)
             if cols is None:
                 self._conn.executemany(

@@ -11,15 +11,9 @@ from __future__ import annotations
 from typing import Optional
 
 from ..obs.trace import TRACER, next_chunk_id
-from ..obs.tracing import StageTimer
 from ..schema import wire
 from ..schema.batch import FlowBatch
 from .bus import InProcessBus
-
-# Per-stage feed-path timing (flow_summary_consume_*_time_us): the same
-# latency-summary family the reference charts for its collector stages.
-# Module-level — every consumer feeds the one process-wide registry.
-_STAGES = StageTimer()
 
 
 class Consumer:
@@ -58,25 +52,35 @@ class Consumer:
         path (one BusMessage per flow) is skipped entirely — it was the
         dominant consume-side cost at high rates."""
         for p in self._rotation():
+            # the flowtrace chunk id: minted before the fetch, so that a
+            # chunk's "fetch", "decode" and "apply" carry one id (a
+            # fetch that finds nothing spends one)
+            chunk = next_chunk_id()
             if self.fixedlen:
-                with _STAGES.stage("consume_fetch"):
+                with TRACER.span("fetch", chunk=chunk, partition=p,
+                                 rows=0) as fetched:
                     span = self.bus.fetch_span(
                         self.topic, p, self.positions[p], max_messages)
+                    if span is not None:
+                        fetched["rows"] = span[2] - span[1] + 1
                 if span is None:
                     continue
                 data, first, last, produced = span
-                batch = self._traced_decode(FlowBatch.from_wire, data, p)
+                batch = self._traced_decode(FlowBatch.from_wire, data, p,
+                                            chunk)
                 batch.first_offset = first
                 batch.last_offset = last
                 batch.produced_at = produced
                 self.positions[p] = last + 1
                 return batch
-            with _STAGES.stage("consume_fetch"):
+            with TRACER.span("fetch", chunk=chunk, partition=p,
+                             rows=0) as fetched:
                 msgs = self.bus.fetch(self.topic, p, self.positions[p],
                                       max_messages)
+                fetched["rows"] = len(msgs)
             if not msgs:
                 continue
-            batch = self._traced_decode(self._decode, msgs, p)
+            batch = self._traced_decode(self._decode, msgs, p, chunk)
             batch.first_offset = msgs[0].offset
             batch.last_offset = msgs[-1].offset
             # flowguard lag signal (the span path gets this inline; the
@@ -88,14 +92,13 @@ class Consumer:
         return None
 
     @staticmethod
-    def _traced_decode(decode, payload, partition: int) -> FlowBatch:
-        """Mint the flowtrace chunk id (decode is where a chunk is born)
-        and decode under a span that carries it."""
-        chunk = next_chunk_id()
+    def _traced_decode(decode, payload, partition: int,
+                       chunk: int) -> FlowBatch:
+        """Decode under a span that carries the chunk id its fetch
+        minted."""
         with TRACER.span("decode", chunk=chunk,
                          partition=partition) as span:
-            with _STAGES.stage("consume_decode"):
-                batch = decode(payload)
+            batch = decode(payload)
             span["rows"] = len(batch)
         batch.chunk_id = chunk
         batch.partition = partition

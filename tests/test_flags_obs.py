@@ -167,7 +167,10 @@ class TestTracing:
         rendered = REGISTRY.render()
         assert "flow_summary_decoding_time_us" in rendered
 
-    def test_worker_observes_stage_metrics(self):
+    def test_worker_records_a_span_for_each_stage(self):
+        """The worker's batch and flush stages are timed by its spans
+        (``apply``, ``flush``: obs/trace.py), no longer by stage
+        summaries beside them; ``flow_processing_time_us`` stays."""
         from flow_pipeline_tpu.engine import StreamWorker, WorkerConfig
         from flow_pipeline_tpu.gen import FlowGenerator, MockerProfile
         from flow_pipeline_tpu.models import WindowAggConfig, WindowAggregator
@@ -184,6 +187,16 @@ class TestTracing:
             [MemorySink()],
             WorkerConfig(poll_max=512),
         )
-        worker.run(stop_when_idle=True)
-        assert worker.stages._summaries["processing"]._count > 0
-        assert worker.stages._summaries["flushing"]._count > 0
+        from flow_pipeline_tpu.obs.trace import TRACER
+
+        before, mode = worker.m_proc._count, TRACER.mode
+        TRACER.configure("always")
+        try:
+            worker.run(stop_when_idle=True)
+            names = [s[0] for s in TRACER.snapshot()]
+        finally:
+            TRACER.configure(mode)
+        assert names.count("apply") == worker.batches_seen > 0
+        assert names.count("flush") > 0
+        assert worker.m_proc._count - before == worker.batches_seen
+        assert not hasattr(worker, "stages")

@@ -6,7 +6,10 @@ catalogue says; (b) the compiled fused step carries every kernel scope
 name in its HLO metadata and computes the same bits with and without
 them; (c) a jax.profiler session holds the program's spans as host-plane
 events; (d) ``off`` records nothing and enters no annotation; (e) the
-ring refuses to call a window whole once its start has been overwritten.
+ring refuses to call a window whole once its start has been overwritten;
+(f) the spans inside the layers the benchmark's hooks time from outside
+(ISSUE 35): fetch, the cut of a poll, the tumbling close, each sink's
+write and the publish, with a flow's age from the bus to the snapshot.
 """
 
 import glob
@@ -28,6 +31,9 @@ from flow_pipeline_tpu.engine.windowed import WindowedHeavyHitter
 from flow_pipeline_tpu.models.scan import scan_config, scan_model
 from flow_pipeline_tpu.obs import trace as trace_mod
 from flow_pipeline_tpu.obs.trace import RING_CAPACITY, TRACER, TraceRecorder
+from flow_pipeline_tpu.serve import ServeServer
+from flow_pipeline_tpu.serve.publisher import attach_worker
+from flow_pipeline_tpu.sink import ResilientSink, SQLiteSink
 from flow_pipeline_tpu.transport import Consumer
 
 from test_fused import BS, WINDOW, make_models, make_stream
@@ -53,11 +59,35 @@ WORKER_SPANS = {
     "ckpt_serialize": "apply",
     "ckpt_write": "apply",
     "ckpt_commit": "apply",
+    # ISSUE 35: span -> the span it nests in, directly
+    "split_parts": "apply",
+    "window_close": "apply",
+    "flush_rows": "flush",
+    "sink_put": "flush",
+    "sink_records": "sink_put",
+    "sink_execute": "sink_put",
+    "snapshot_publish": "apply",
+    "publish_view": "snapshot_publish",
+    "publish_swap": "snapshot_publish",
 }
+# which of them ISSUE 35 added (with "fetch", on the feed thread)
+INSIDE_SPANS = ("split_parts", "window_close", "flush_rows", "sink_put",
+                "sink_records", "sink_execute", "snapshot_publish",
+                "publish_view", "publish_swap")
+FEED_THREAD = "feed-prefetch"
 CKPT_SPANS = ("ckpt_state", "ckpt_d2h", "ckpt_serialize", "ckpt_write",
               "ckpt_commit")
 SPAN_ARGS = {
-    "poll_wait": ("depth",), "apply": ("rows",),
+    "poll_wait": ("depth",), "apply": ("rows", "age_ms"),
+    "fetch": ("rows", "partition"), "split_parts": ("parts",),
+    "window_close": ("model", "slot", "rows"),
+    "flush_rows": ("table", "rows"),
+    "sink_put": ("sink", "table", "rows"),
+    "sink_records": ("rows",), "sink_execute": ("rows",),
+    "snapshot_publish": ("version", "flows_seen", "families", "reason",
+                         "late_ms", "age_ms"),
+    "publish_view": ("model", "rows", "bytes"),
+    "publish_swap": ("ranges",),
     "lane_build": ("rows", "padded"), "h2d": ("bytes", "cols"),
     "step_dispatch": ("rows", "padded", "do_hh", "do_dd"),
     "wagg_wait": ("folded", "left"),
@@ -78,6 +108,9 @@ def _models(spread=True):
         models["portscan"] = scan_model(
             scan_config(depth=2, width=256, registers=16, capacity=32,
                         batch_size=BS), k=10)
+    for name, m in models.items():
+        if isinstance(m, WindowedHeavyHitter):
+            m.name = name  # as cli._build_models names them
     return models
 
 
@@ -88,7 +121,7 @@ def _models(spread=True):
 UNTILED_CALLS_MAX = 144
 
 
-def _worker(tmp_path, snapshot_calls):
+def _worker(tmp_path, snapshot_calls, consumer=None, models=None):
     """A worker whose every snapshot_and_commit call appends (start,
     end, thread, calls made outside any span) to ``snapshot_calls``.
     The calls are counted by a profile hook on the worker's thread, so
@@ -122,12 +155,17 @@ def _worker(tmp_path, snapshot_calls):
                                    untiled))
 
     worker = StreamWorker(
-        Consumer(_stream_to_bus(make_stream()), fixedlen=True),
-        _models(), [CollectSink()],
+        consumer or Consumer(_stream_to_bus(make_stream()), fixedlen=True),
+        models or _models(),
+        # sqlite as cli._make_sinks hands it over: behind the retry wrapper
+        [CollectSink(), ResilientSink(SQLiteSink())],
         WorkerConfig(poll_max=BS, snapshot_every=2, host_assist="off",
                      checkpoint_path=str(tmp_path / "ckpt")))
     assert type(worker.fused) is FusedPipeline
     worker.snapshot_and_commit = timed.__get__(worker)
+    # the query surface's publisher: its range ledger is a third sink; a
+    # refresh that is always due, so that every batch publishes
+    attach_worker(worker, refresh=1e-6)
     return worker
 
 
@@ -155,7 +193,7 @@ def _inside(inner, outer) -> bool:
 # ---- (a) the catalogue ---------------------------------------------------------
 
 
-@pytest.mark.parametrize("name", sorted(WORKER_SPANS) + ["decode"])
+@pytest.mark.parametrize("name", sorted(WORKER_SPANS) + ["decode", "fetch"])
 def test_span_occurs_with_its_args(traced_run, name):
     spans, _ = traced_run
     mine = [s for s in spans if s[0] == name]
@@ -173,9 +211,10 @@ def test_span_lies_inside_an_apply_on_the_worker_thread(traced_run, name):
     applies = [s for s in spans if s[0] == "apply"]
     assert len({s[3] for s in applies}) == 1
     final = max(a[2] for a in applies)  # finalize() runs after the loop
-    for s in spans:
-        if s[0] == name and s[1] < final:
-            assert any(_inside(s, a) for a in applies), s
+    mine = [s for s in spans if s[0] == name and s[1] < final]
+    assert mine
+    for s in mine:
+        assert any(_inside(s, a) for a in applies), s
 
 
 def test_poll_wait_lies_outside_apply(traced_run):
@@ -418,6 +457,303 @@ def test_tracer_imports_no_jax():
             "assert 'jax' not in sys.modules, 'the tracer imported jax'\n")
     subprocess.run([sys.executable, "-c", code], check=True, timeout=120,
                    cwd=os.path.dirname(os.path.dirname(__file__)))
+
+
+# ---- (f) inside the layers the hooks time from outside (ISSUE 35) ----------------
+
+
+def _children(spans, parent, name):
+    return sorted((s for s in spans if s[0] == name and _inside(s, parent)),
+                  key=lambda s: s[1])
+
+
+def _tiles(parts, whole) -> bool:
+    """In order, none overlapping the next, all inside ``whole``."""
+    return all(_inside(p, whole) for p in parts) and all(
+        a[2] <= b[1] for a, b in zip(parts, parts[1:]))
+
+
+@pytest.mark.parametrize("name", INSIDE_SPANS)
+def test_inside_span_nests_directly_in_its_parent(traced_run, name):
+    """Each new span lies in a span of the name the catalogue gives, on
+    that span's thread, after the loop too (finalize's flush and publish
+    run under no ``apply``)."""
+    spans, _ = traced_run
+    parent = WORKER_SPANS[name]
+    parents = [s for s in spans if s[0] == parent]
+    final = max(s[2] for s in spans if s[0] == "apply")
+    mine = [s for s in spans if s[0] == name
+            and (parent != "apply" or s[1] < final)]
+    assert mine
+    for s in mine:
+        assert any(_inside(s, p) for p in parents), s
+
+
+def test_no_program_span_takes_a_hooks_name(traced_run):
+    """The hook and the span sit in one .xplane.pb and one owner chain:
+    only ``decode``, which both had before ISSUE 35, is in both."""
+    from benchmark.sut import HOOKS
+
+    spans, _ = traced_run
+    hooks = {hook[3] for hook in HOOKS}
+    assert {"bus_fetch", "sink_write", "publish", "process"} <= hooks
+    assert {s[0] for s in spans} & hooks == {"decode"}
+
+
+def test_fetch_decode_and_apply_of_a_chunk_share_its_id(traced_run):
+    spans, _ = traced_run
+    fetched = {s[4]: s for s in spans if s[0] == "fetch" and s[5]["rows"]}
+    decoded = {s[4]: s for s in spans if s[0] == "decode"}
+    applies = [s for s in spans if s[0] == "apply"]
+    assert len(applies) == 8 == len(decoded)
+    for a in applies:
+        f, d = fetched[a[4]], decoded[a[4]]
+        assert f[3] == d[3] == FEED_THREAD != a[3]
+        assert f[2] <= d[1] and d[2] <= a[1]  # fetch, decode, then apply
+        assert f[5]["rows"] == d[5]["rows"] == a[5]["rows"]
+        assert f[5]["partition"] == d[5]["partition"] == 0
+        # the bus stamped the frames before the run: a batch is at least
+        # as old at pick-up as its decode took
+        assert a[5]["age_ms"] >= (d[2] - d[1]) * 1e3
+    # a fetch that found nothing says so, and spent an id of its own
+    empty = [s for s in spans if s[0] == "fetch" and not s[5]["rows"]]
+    assert empty and not {s[4] for s in empty} & set(decoded)
+
+
+def test_split_parts_counts_the_parts_of_a_poll(traced_run):
+    spans, _ = traced_run
+    cuts = [s for s in spans if s[0] == "split_parts"]
+    applies = [s for s in spans if s[0] == "apply"]
+    assert len(cuts) == len(applies)  # one a polled batch
+    # batch 5 holds late rows: a part of their own; the steps of an
+    # apply are at least its parts
+    assert max(s[5]["parts"] for s in cuts) >= 2
+    steps = [s for s in spans if s[0] == "step_dispatch"]
+    for cut, a in zip(cuts, applies):
+        assert _inside(cut, a)
+        assert sum(_inside(s, a) for s in steps) >= cut[5]["parts"] >= 1
+
+
+def test_a_tumbling_close_is_one_window_close_a_ranked_table(traced_run):
+    spans, _ = traced_run
+    closes = [s for s in spans if s[0] == "window_close"]
+    ranked = {"top_talkers", "top_src_ips", "top_dst_ips", "top_src_ports",
+              "portscan"}
+    by_slot: dict = {}
+    for s in closes:
+        by_slot.setdefault(s[5]["slot"], set()).add(s[5]["model"])
+        assert s[5]["rows"] >= 0
+    # three slots: two rolls in the stream and the forced close at its end
+    assert len(by_slot) == 3 and set(by_slot) == {6000, 6300, 6600}
+    assert all(models == ranked for models in by_slot.values())
+    assert not [s for s in spans if s[0] == "slide_close"]
+    # a roll's extraction runs inside the pipeline's update, before the
+    # batch's first device step
+    steps = [s for s in spans if s[0] == "step_dispatch"]
+    final = max(s[2] for s in spans if s[0] == "apply")
+    for a in (s for s in spans if s[0] == "apply"):
+        mine = [c for c in closes if _inside(c, a) and c[1] < final]
+        if mine:
+            first_step = min(s[1] for s in steps if _inside(s, a))
+            assert min(c[2] for c in mine) <= first_step
+
+
+def test_flush_rows_and_a_sink_put_a_sink_tile_the_flush(traced_run):
+    spans, _ = traced_run
+    flushes = [s for s in spans if s[0] == "flush"]
+    assert flushes
+    for f in flushes:
+        (made,) = _children(spans, f, "flush_rows")
+        puts = _children(spans, f, "sink_put")
+        assert [p[5]["sink"] for p in puts] == [
+            "CollectSink", "SQLiteSink", "RangeLedger"]
+        assert _tiles([made, *puts], f)
+        for part in (made, *puts):
+            assert part[4] == f[4]  # the chunk that closed the window
+            assert part[5]["table"] == f[5]["table"]
+            assert part[5]["rows"] == f[5]["rows"]
+
+
+def test_sink_records_and_sink_execute_tile_sqlites_sink_put(traced_run):
+    spans, _ = traced_run
+    puts = [s for s in spans if s[0] == "sink_put"]
+    wrote = 0
+    for put in puts:
+        records = _children(spans, put, "sink_records")
+        execute = _children(spans, put, "sink_execute")
+        if put[5]["sink"] != "SQLiteSink":
+            assert not records and not execute
+            continue
+        (records,) = records
+        assert records[5]["rows"] == put[5]["rows"]
+        if records[5]["rows"]:  # nothing to write: no statement
+            (execute,) = execute
+            assert execute[5]["rows"] == records[5]["rows"]
+            assert _tiles([records, execute], put)
+            wrote += 1
+    assert wrote >= 3
+    # every one of them is the sink's own, inside its sink_put
+    for name in ("sink_records", "sink_execute"):
+        for s in (s for s in spans if s[0] == name):
+            assert any(_inside(s, p) for p in puts), s
+
+
+def test_a_query_thread_records_no_sink_records(traced_run, tmp_path):
+    """``rows_to_records`` is the query threads' too: the span is in the
+    sink, so a reader's answer is not booked to the sinks' layer."""
+    worker = _worker(tmp_path, [])
+    worker.run(max_batches=2)
+    TRACER.configure("always")
+    try:
+        server, out = ServeServer(worker.serve.store, port=0), []
+        reader = threading.Thread(
+            name="query-reader",
+            target=lambda: out.append(server._respond("/query/topk?k=5",
+                                                      None)))
+        reader.start()
+        reader.join()
+        with TRACER.span("heard"):
+            pass
+        names = {s[0] for s in TRACER.snapshot()}
+    finally:
+        TRACER.configure("off")
+    assert b"200 OK" in out[0] and b'"rows"' in out[0]
+    assert names == {"heard"}
+
+
+def test_views_and_the_swap_tile_the_snapshot_publish(traced_run):
+    spans, _ = traced_run
+    publishes = [s for s in spans if s[0] == "snapshot_publish"]
+    ranked = ["top_talkers", "top_src_ips", "top_dst_ips", "top_src_ports",
+              "portscan"]
+    for p in publishes:
+        views = _children(spans, p, "publish_view")
+        (swap,) = _children(spans, p, "publish_swap")
+        assert [v[5]["model"] for v in views] == ranked
+        assert p[5]["families"] == len(ranked)
+        assert _tiles([*views, swap], p)
+        for v in views:
+            assert v[5]["bytes"] > 0 and 0 <= v[5]["rows"] <= 50
+        # a sketch family's capture is its count-min planes, the dense
+        # table's is its rows alone
+        by_model = {v[5]["model"]: v[5]["bytes"] for v in views}
+        assert by_model["top_talkers"] > 10 * by_model["top_src_ports"]
+    # the ledger's frozen slots grow with the closes
+    assert [s[5]["ranges"] for s in spans if s[0] == "publish_swap"][-1] >= 2
+
+
+def test_a_publish_says_why_how_late_and_how_old(traced_run):
+    spans, _ = traced_run
+    publishes = sorted((s for s in spans if s[0] == "snapshot_publish"),
+                       key=lambda s: s[1])
+    applies = [s for s in spans if s[0] == "apply"]
+    # one a batch (a refresh of 1 us is always due) and finalize's
+    assert len(publishes) == len(applies) + 1
+    reasons = [p[5]["reason"] for p in publishes]
+    assert reasons[0] == "first" and reasons[-1] == "forced"
+    assert reasons.count("close") >= 2 and "refresh" in reasons
+    versions = [p[5]["version"] for p in publishes]
+    assert versions == list(range(1, len(publishes) + 1))
+    for p, a in zip(publishes, applies):
+        assert p[4] == a[4] and _inside(p, a)  # the batch's chunk
+        assert p[5]["flows_seen"] > 0
+        if p[5]["reason"] == "refresh":
+            # asked once a batch, after the flush and the checkpoint
+            assert 0 <= p[5]["late_ms"] < (p[1] - a[1]) * 1e3 + 60e3
+        else:
+            assert p[5]["late_ms"] == 0
+        # older at the swap than its batch was at pick-up
+        assert p[5]["age_ms"] > a[5]["age_ms"]
+
+
+class _Unstamped(Consumer):
+    """A transport that does not stamp its messages (Kafka)."""
+
+    def poll(self, max_messages: int = 8192):
+        batch = super().poll(max_messages)
+        if batch is not None:
+            batch.produced_at = 0.0
+        return batch
+
+
+def test_age_is_left_out_for_an_unstamped_transport(tmp_path):
+    TRACER.configure("always")
+    try:
+        _worker(tmp_path, [], consumer=_Unstamped(
+            _stream_to_bus(make_stream()), fixedlen=True)).run(
+                stop_when_idle=True)
+        spans = TRACER.snapshot()
+    finally:
+        TRACER.configure("off")
+    for name in ("apply", "snapshot_publish"):
+        mine = [s for s in spans if s[0] == name]
+        assert len(mine) >= 8
+        assert not [s for s in mine if "age_ms" in s[5]]
+        assert all("rows" in s[5] or "reason" in s[5] for s in mine)
+
+
+def _sliding_models():
+    def hh(name, key_cols):
+        return WindowedHeavyHitter(
+            HeavyHitterConfig(key_cols=key_cols, batch_size=BS,
+                              width=1 << 10, capacity=128),
+            k=50, slide_seconds=100, slide_name=name)
+
+    models = _models(spread=False)
+    for name, key_cols in (("top_src_ips", ("src_addr",)),
+                           ("top_dst_ips", ("dst_addr",))):
+        models[name] = hh(name, key_cols)
+    del models["top_talkers"], models["top_src_ports"]
+    return models
+
+
+def test_under_a_slide_the_close_is_a_slide_close(tmp_path):
+    """``window_close`` is the tumbling branch's: under ``-window.slide``
+    its twin records, and it does not."""
+    TRACER.configure("always")
+    try:
+        _worker(tmp_path, [], models=_sliding_models()).run(
+            stop_when_idle=True)
+        spans = TRACER.snapshot()
+    finally:
+        TRACER.configure("off")
+    names = {s[0] for s in spans}
+    assert "slide_close" in names and "window_close" not in names
+    # the rest of the catalogue is a tumbling run's
+    assert {"fetch", "split_parts", "flush_rows", "sink_put",
+            "sink_records", "snapshot_publish", "publish_view",
+            "publish_swap"} <= names
+
+
+def test_off_enters_no_annotation_for_any_inside_span(monkeypatch,
+                                                      tmp_path):
+    entered = []
+
+    class Annotation:
+        def __init__(self, name):
+            entered.append(name)
+
+        def __enter__(self):
+            pass
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(trace_mod, "_ANNOTATION", Annotation)
+    TRACER.configure("off")
+    worker = _worker(tmp_path, [])
+    worker.run(stop_when_idle=True)
+    assert worker.batches_seen == 8 and worker.serve.store.current
+    assert TRACER.snapshot() == [] and entered == []
+    # the same run under "ring" enters one a span, the new ones among them
+    TRACER.configure("ring")
+    try:
+        _worker(tmp_path / "ring", []).run(stop_when_idle=True)
+        recorded = [s[0] for s in TRACER.snapshot()]
+    finally:
+        TRACER.configure("off")
+    assert sorted(entered) == sorted(recorded)
+    assert set(INSIDE_SPANS) | {"fetch"} <= set(entered)
 
 
 # ---- (e) the ring knows whether it still holds a window ------------------------------
