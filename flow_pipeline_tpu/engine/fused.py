@@ -242,7 +242,7 @@ def _cached_step(hh_specs, dense_cfgs, ddos_cfgs, wagg_cfgs):
                     u = jnp.where(real[:, None], uniq_b, _SENTINEL)
                     return u, s, counts
 
-        new_hh = []
+        new_hh, live = [], []
         for i, ((plan, cfg), st) in enumerate(zip(hh_specs, hh_states)):
             if plan[0] == "B":
                 uniq, sums, counts = consume_b(0, 0, 2)
@@ -264,6 +264,9 @@ def _cached_step(hh_specs, dense_cfgs, ddos_cfgs, wagg_cfgs):
                     [sums, counts.astype(jnp.float32)[:, None]], axis=1)
                 new_hh.append(
                     hh._apply_grouped(st, uniq, sums3, counts > 0, cfg))
+                # the bound _apply_grouped gathers under, a family
+                # (FusedPipeline.hh_live)
+                live.append(hh.live_rows(counts > 0))
 
         with jax.named_scope("dense_scatter"):
             new_dense = tuple(
@@ -282,7 +285,8 @@ def _cached_step(hh_specs, dense_cfgs, ddos_cfgs, wagg_cfgs):
 
         with jax.named_scope("wagg_groupby"):
             wagg_parts = tuple(fn(cols, valid) for fn in wagg_fns)
-        return (tuple(new_hh), new_dense, tuple(new_ddos)), wagg_parts
+        return ((tuple(new_hh), new_dense, tuple(new_ddos)), wagg_parts,
+                jnp.stack(live) if live else jnp.zeros(0, jnp.int32))
 
     return jax.jit(step, donate_argnums=(0,))
 
@@ -355,6 +359,9 @@ class FusedPipeline(WindowLifecycle):
             (_hh_plan(w.config), w.config) for _, w in self._hh)
         self._cols = self._column_union()
         self._behind = False  # the batch in hand fills a device step
+        # [families] int32 on the device: live_rows of the last step that
+        # fed the tables; read by hh_live alone
+        self._live_rows = None
         # The compiled step is cached on the static spec, NOT per instance:
         # every bench sample / supervisor restart builds a fresh pipeline,
         # and a per-instance jit would recompile the whole fused graph
@@ -434,6 +441,18 @@ class FusedPipeline(WindowLifecycle):
         finally:
             jax.config.update(keyed, before)
 
+    def hh_live(self) -> dict:
+        """Args for the span that reads them (``ckpt_state``): the
+        largest family's live bound in the last step that fed the
+        tables, and the slots a family has there. A device->host read:
+        for a caller that has already waited for that step, as a
+        checkpoint has once its state is built, and for no one else in
+        the dispatch loop."""
+        if self._live_rows is None or not self._hh:
+            return {}
+        return {"hh_live_rows": int(np.max(self._live_rows)),
+                "hh_slots": self._bs}
+
     # ---- host lifecycle ---------------------------------------------------
 
     def _split_parts(self, batch: FlowBatch):
@@ -501,11 +520,13 @@ class FusedPipeline(WindowLifecycle):
             # device steps per batch, rows/padded the step's fill
             with TRACER.span("step_dispatch", rows=len(chunk), padded=bs,
                              do_hh=do_hh, do_dd=do_dd):
-                new_states, wagg_parts = self._step(
+                new_states, wagg_parts, live_rows = self._step(
                     states, cols, valid,
                     valid if do_hh else zeros,
                     valid if do_dd else zeros,
                 )
+            if do_hh:
+                self._live_rows = live_rows
             new_hh, new_dense, new_ddos = new_states
             for (_, w), st in zip(self._hh, new_hh):
                 w.model.state = st

@@ -757,7 +757,7 @@ class StreamWorker:
         """Snapshot open state, then commit covered offsets. Order matters:
         state must be durable before the bus forgets the input."""
         state = None
-        with TRACER.span("ckpt_state", chunk=self._trace_chunk):
+        with TRACER.span("ckpt_state", chunk=self._trace_chunk) as span:
             if self.flusher is not None:
                 # the snapshot no longer contains windows handed to the
                 # flusher; their rows must be IN the sinks before the
@@ -767,6 +767,11 @@ class StreamWorker:
                 self.flusher.drain()
             if self.config.checkpoint_path:
                 state = self._state()
+                # the state's build has waited for the last step: its
+                # live bound costs no wait of its own
+                hh_live = getattr(self.fused, "hh_live", None)
+                if hh_live is not None:
+                    span.update(hh_live())
         if state is not None:
             # ckpt_d2h, ckpt_serialize, ckpt_write: inside save_checkpoint
             save_checkpoint(self.config.checkpoint_path, state)

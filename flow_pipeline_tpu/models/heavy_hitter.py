@@ -177,10 +177,12 @@ def _key_lanes(cols: dict, key_cols) -> jnp.ndarray:
     return jnp.concatenate(lanes, axis=1)
 
 
-def _cms_add(config: HeavyHitterConfig):
+def _cms_add(config: HeavyHitterConfig, n_live):
     """Select the CMS update op for (conservative, cms_impl). All four
     share ops.cms's bucket scheme and state layout, so the selection can
-    change between runs (even mid-stream) without invalidating a sketch."""
+    change between runs (even mid-stream) without invalidating a sketch.
+    ``n_live`` (live_rows of the groups to come) goes to the one op whose
+    cost it bounds: the xla conservative update's estimate."""
     if config.cms_impl == "pallas":
         from ..ops import cms_pallas
 
@@ -199,8 +201,20 @@ def _cms_add(config: HeavyHitterConfig):
         return partial(cms_pallas.cms_add_pallas, tile=tile)
     if config.cms_impl != "xla":
         raise ValueError(f"unknown cms_impl {config.cms_impl!r}")
-    return (cms_ops.cms_add_conservative if config.conservative
-            else cms_ops.cms_add)
+    if config.conservative:
+        return partial(cms_ops.cms_add_conservative, n_live=n_live)
+    return cms_ops.cms_add
+
+
+def live_rows(row_valid):
+    """[] int32: 1 + the index of the last True of ``row_valid`` [N], 0
+    for none: every real group lies below it. Not the count of real
+    groups: a device group-by puts them in a prefix (the sentinel hash
+    sorts last), but under the fused step's shared dst sort
+    (engine.fused consume_b) a family's real groups may have another
+    consumer's between them, and the bound has to hold them all."""
+    n = row_valid.shape[0]
+    return jnp.max(jnp.where(row_valid, jax.lax.iota(jnp.int32, n) + 1, 0))
 
 
 def _resident(th, gh, row_valid):
@@ -227,7 +241,8 @@ def _apply_grouped(state: HHState, uniq, sums, row_valid,
     [N, P+1] float32 per-group value sums with the count plane LAST,
     ``row_valid`` [N] bool. Shared by hh_update and the fused pipeline
     (engine.fused), which computes the groupby once per key family."""
-    new_cms = _cms_add(config)(state.cms, uniq, sums, row_valid)
+    new_cms = _cms_add(config, live_rows(row_valid))(
+        state.cms, uniq, sums, row_valid)
     if config.table_prefilter and uniq.shape[0] > 2 * config.capacity:
         # Table-aware prefilter: boost groups whose key is already in the
         # table so residents are NEVER starved of their increments (see

@@ -19,6 +19,19 @@ space; ref north star: BASELINE.json). Layout is TPU-first:
 
 Bucket choice per depth uses the murmur3 word-lane hash (schema.keys) with
 a distinct seed per row.
+
+The live bound. A device group-by hands over N group SLOTS of which only
+some are real (ops.segment: a batch of N rows yields N slots; ~30 % of
+them hold a group on the benchmark's stream), and on a TPU a gather costs
+by the index (13 ns each on a v5e), real or not. Callers that know where
+their real rows end (models.heavy_hitter._apply_grouped: 1 + the index of
+the last valid row) pass that as ``n_live`` and the conservative update's
+pre-update estimate gathers only the chunks of rows below it. Skipping a
+padding row changes no bit of the state, because such a row is a no-op
+either way: its addends are 0 (``valid`` is False), so its ceiling is
+its own estimate, which is the min of the cells it would raise; and with
+the estimate left at 0 the ceiling is 0, which raises no cell either,
+since cells are sums of non-negative addends and never below 0.
 """
 
 from __future__ import annotations
@@ -27,10 +40,8 @@ from __future__ import annotations
 # (bucket hashing must stay exact unsigned arithmetic — a signed cast
 # here skews every estimate; see docs/STATIC_ANALYSIS.md)
 
-from functools import partial
-
-import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..schema.keys import hash_words
 
@@ -67,29 +78,62 @@ def cms_add(counts, keys, values, valid=None):
     if valid is not None:
         vals = jnp.where(valid[:, None], vals, 0.0)
     for di in range(d):
-        # [P, N] scatter-add into row di; XLA lowers to sorted scatter.
+        # [P, N] scatter-add into row di
         counts = counts.at[:, di, buckets[di]].add(vals.T)
     return counts
 
 
-def cms_query(counts, keys):
+# Rows a trip of cms_query's bounded form gathers. From a sweep on a v5e
+# at N = 32,768 (PERF.md §6, PR 37): a trip costs ~1.4 us beside its
+# gathers, so what a chunk costs is the padding it gathers past the bound
+# (half a chunk a family on average): 512 to 8,192 were read, and a batch
+# whose slots are all real pays 0.5 % of the step for its 32 trips.
+LIVE_CHUNK = 1024
+
+
+def _depth_min(counts, buckets):
+    """[P, n] min over depth rows of the cells at ``buckets`` [D, n]."""
+    ests = [counts[:, di, buckets[di]] for di in range(counts.shape[1])]
+    return jnp.min(jnp.stack(ests, axis=0), axis=0)
+
+
+def cms_query(counts, keys, n_live=None):
     """Point estimate: min over depth rows. Returns [N, P] float32 (upper
-    bound of the true sums for linear updates)."""
+    bound of the true sums for linear updates).
+
+    ``n_live`` (traced int32 scalar, optional): every row a caller reads
+    lies below it. Only the LIVE_CHUNK-row chunks that hold such rows are
+    gathered, in a loop whose trip count is data, and rows at or beyond
+    the bound come back as 0. Where N <= LIVE_CHUNK the plain gather
+    stays, which reads every row."""
     p, d, w = counts.shape
+    n = keys.shape[0]
     buckets = cms_buckets(keys, d, w)  # [D, N]
-    ests = []
-    for di in range(d):
-        ests.append(counts[:, di, buckets[di]])  # [P, N]
-    return jnp.min(jnp.stack(ests, axis=0), axis=0).T  # [N, P]
+    if n_live is None or n <= LIVE_CHUNK:
+        return _depth_min(counts, buckets).T  # [N, P]
+
+    def chunk(i, est):
+        # the last chunk of an N that LIVE_CHUNK does not divide is
+        # clamped to end at N, by the slice and the update alike
+        start = i * LIVE_CHUNK
+        b = lax.dynamic_slice(buckets, (0, start), (d, LIVE_CHUNK))
+        return lax.dynamic_update_slice(est, _depth_min(counts, b),
+                                        (0, start))
+
+    trips = (n_live + (LIVE_CHUNK - 1)) // LIVE_CHUNK
+    est = lax.fori_loop(0, trips, chunk, jnp.zeros((p, n), counts.dtype))
+    return jnp.where(lax.iota(jnp.int32, n) < n_live, est, 0.0).T
 
 
-def cms_add_conservative(counts, keys, values, valid=None):
+def cms_add_conservative(counts, keys, values, valid=None, n_live=None):
     """Conservative update: raise each cell only to (current min estimate +
     addend). Tighter estimates than linear add; still an upper bound. Merge
     by + remains a valid upper bound but loses the CU tightness.
 
     Same shapes as cms_add. Keys must be unique within the call (use
-    sort_groupby first) — duplicate keys would under-count.
+    sort_groupby first) — duplicate keys would under-count. ``n_live``:
+    every ``valid`` row lies below it (the module docstring's live
+    bound); the state that comes back is the same bit for bit.
     """
     p, d, w = counts.shape
     buckets = cms_buckets(keys, d, w)  # [D, N]
@@ -97,7 +141,7 @@ def cms_add_conservative(counts, keys, values, valid=None):
     if valid is not None:
         vals = jnp.where(valid[:, None], vals, 0.0)
     # current estimate before update
-    est = cms_query(counts, keys)  # [N, P]
+    est = cms_query(counts, keys, n_live)  # [N, P]
     target = est + vals  # [N, P] the CU ceiling for this key
     for di in range(d):
         # cell must become at least `target`, but never decrease.

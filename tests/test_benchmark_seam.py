@@ -84,6 +84,9 @@ INSIDE_METRICS = (
     "publish_loop_ms_per_min", "publish_view_ms_p50", "publish_view_mb_p50",
     "publish_swap_ms_p50", "publish_late_ms_p50.live",
     "publish_period_s_p50.live", "flow_age_at_publish_ms_p50.live")
+# ISSUE 37's counter: the live bound's share of a family's group slots,
+# from two args of `ckpt_state`; the fused step's, so the one-chip cells'
+LIVE_SHARE = "cms_live_share"
 ALL_LEDGER_CELLS = ["estate-catchup", "estate-live", "estate-mesh4-catchup",
                     "estate-as64k-catchup", "estate-sliding-catchup"]
 ONE_CHIP = [c for c in ALL_LEDGER_CELLS if c != "estate-mesh4-catchup"]
@@ -107,13 +110,13 @@ def _twin(spec, cell) -> dict:
     man = _manifest(spec.manifest)
     if spec.manifest == TINY:
         # several cells' and no one's twin: it stands as it was written,
-        # but for ISSUE 35's metrics, listed here for each cell whose
-        # ledger cell lists them
+        # but for ISSUE 35's and 37's metrics, listed here for each cell
+        # whose ledger cell lists them
         man["per_layer"] += [
             {**e, "workloads": [c for c, s in CELLS.items()
                                 if s.ledger in e["workloads"]]}
             for e in _manifest("BENCHMARK.json")["per_layer"]
-            if e["name"] in INSIDE_METRICS]
+            if e["name"] in (*INSIDE_METRICS, LIVE_SHARE)]
         return man
     have = set(_listed(man["per_layer"], cell))
     man["per_layer"] += [
@@ -296,6 +299,30 @@ def test_the_inside_tiles_sum_under_their_outside_span(dry_run, cell):
     else:
         assert v["close_extract_ms_per_close"] > 0
     assert ("split_parts_ms_p50" in v) == (cell != MESH_CELL)
+
+
+def test_the_live_share_is_listed_for_the_fused_steps_cells():
+    (entry,) = [e for e in _manifest("BENCHMARK.json")["per_layer"]
+                if e["name"] == LIVE_SHARE]
+    assert entry == {
+        "name": LIVE_SHARE, "unit": "%", "better": "lower",
+        "source": "program_counter", "layer": "fused device step",
+        "moves": "sustained_flows_per_s", "workloads": ONE_CHIP}
+
+
+@pytest.mark.parametrize("cell", TRACED)
+def test_a_traced_line_says_what_share_of_the_slots_is_live(dry_run, cell):
+    """Read from the checkpoints of the window wherever the fused step
+    runs; a per-layer metric, so an untraced line has none, and the
+    sharded programs hand out no bound."""
+    v = _values(dry_run, cell)
+    if cell == MESH_CELL:
+        assert LIVE_SHARE not in v
+    else:
+        # some group is real, and a batch's groups leave slots over
+        assert 0 < v[LIVE_SHARE] < 100
+    if cell in CELLS:
+        assert LIVE_SHARE not in _result(dry_run, cell)["metrics"]
 
 
 def test_the_live_twin_carries_a_flows_age_to_the_snapshot(dry_run):

@@ -245,3 +245,67 @@ def test_worker_fused_vs_serial_sink_rows():
         rows = [canon_rows(r) for r in sink.rows.get("flows_5m", [])]
         out[fused] = sorted(sum(rows, []))
     assert out[True] == out[False]
+
+
+# ---- the live bound (PR 37) -------------------------------------------------
+
+
+def _hh_states_after_every_batch(models, batches):
+    """[(live bound of each family, every hh family's state arrays)], one
+    entry a batch: a window roll resets the tables, so the comparison is
+    made as the stream goes."""
+    pipe = FusedPipeline(models)
+    out = []
+    for b in batches:
+        pipe.update(b)
+        out.append((np.asarray(pipe._live_rows),
+                    {name: [np.asarray(x) for x in w.model.state]
+                     for name, w in pipe._hh}))
+    return out
+
+
+@pytest.mark.parametrize("reference", ["bound_forced_to_n", "plain_gather"])
+def test_live_bound_changes_no_bit_of_any_family(monkeypatch, reference):
+    """Eight batches through the fused step with the conservative
+    update's estimate gathered under the live bound (a chunk an eighth
+    of the batch, so the loop runs at this size; batch 4 is cut in two
+    at a slot roll, batch 6 holds late rows), and through a step that gathers every slot: with the bound forced to N,
+    and in the parent's own form, one plain gather. `cms`, `table_keys`
+    and `table_vals` of the three families agree bit for bit after every
+    batch."""
+    from flow_pipeline_tpu.engine import fused as fused_mod
+    from flow_pipeline_tpu.models import heavy_hitter as hh
+    from flow_pipeline_tpu.ops import cms as cms_ops
+
+    def run(chunk, forced):
+        fused_mod._cached_step.cache_clear()
+        monkeypatch.setattr(cms_ops, "LIVE_CHUNK", chunk)
+        if forced:
+            monkeypatch.setattr(
+                hh, "live_rows",
+                lambda row_valid: jnp.int32(row_valid.shape[0]))
+        batches = make_stream(2000)
+        batches[3].columns["time_received"] += np.uint64(15)
+        try:
+            return _hh_states_after_every_batch(
+                make_models(WINDOW, 2000), batches)
+        finally:
+            monkeypatch.undo()
+            fused_mod._cached_step.cache_clear()
+
+    chunk = BS // 8
+    got = run(chunk, forced=False)
+    want = (run(chunk, forced=True) if reference == "bound_forced_to_n"
+            else run(BS, forced=False))
+    bounds = np.stack([live for live, _ in got])
+    # the bound bites: several trips, and whole chunks skipped
+    assert chunk < bounds.min() and bounds.max() <= BS - chunk
+    if reference == "bound_forced_to_n":
+        assert all((live == BS).all() for live, _ in want)
+    for i, ((_, new), (_, ref)) in enumerate(zip(got, want)):
+        assert sorted(new) == ["top_dst_ips", "top_src_ips", "top_talkers"]
+        for family in new:
+            for field, a, b in zip(hh.HHState._fields, new[family],
+                                   ref[family]):
+                assert a.tobytes() == b.tobytes(), (
+                    f"{family}.{field} after batch {i + 1}")

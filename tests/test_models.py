@@ -641,14 +641,16 @@ class TestResidencyDense:
     @pytest.mark.parametrize("family", sorted(FAMILIES))
     def test_no_loop_left_in_apply_grouped(self, family):
         # the jaxpr, not a platform's HLO: a CPU expands scatters into
-        # loops of its own
+        # loops of its own. One loop is there by design since PR 37: the
+        # conservative update's estimate over the chunks of live rows
+        # (ops.cms.cms_query), whose trips together gather fewer indices
+        # than the one gather it stands for; the residency test has none
         jaxpr = self._apply_grouped_jaxpr(self.FAMILIES[family])
-        assert _loop_primitives(jaxpr) == []
+        assert _loop_primitives(jaxpr) == ["while"]
 
     def test_the_walk_finds_the_sorted_forms_loop(self, monkeypatch):
         # what the test above would say of the parent: searchsorted's
         # while sits inside a nested jit, and the walk reaches it
         monkeypatch.setattr(hh, "_resident", _resident_sorted)
         jaxpr = self._apply_grouped_jaxpr(("src_addr",))
-        assert "while" in _loop_primitives(jaxpr) \
-            or "scan" in _loop_primitives(jaxpr)
+        assert len(_loop_primitives(jaxpr)) > 1

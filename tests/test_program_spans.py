@@ -94,6 +94,7 @@ SPAN_ARGS = {
     "wagg_d2h": ("bytes",),
     "wagg_fold": ("groups", "inserted", "store_groups"),
     "wagg_rows": ("rows",), "wagg_state": ("windows", "groups"),
+    "ckpt_state": ("hh_live_rows", "hh_slots"),
     "ckpt_d2h": ("bytes", "leaves"),
     "ckpt_serialize": ("raw_bytes", "npz_bytes", "members"),
     "decode": ("rows", "partition"), "flush": ("rows", "table"),
@@ -276,6 +277,19 @@ def test_wagg_wait_says_whether_the_drain_lagged(traced_run):
     # folded exactly once
     steps = [s for s in spans if s[0] == "step_dispatch"]
     assert sum(s[5]["folded"] for s in waits) == len(steps)
+
+
+def test_ckpt_state_says_the_live_bound_of_the_last_step(traced_run):
+    """PR 37: the largest family's live bound in the last device step
+    that fed the tables, beside the slots a family has there; read where
+    the checkpoint has already waited for that step."""
+    spans, _ = traced_run
+    states = [s for s in spans if s[0] == "ckpt_state"]
+    assert len(states) >= 3
+    for s in states:
+        assert 0 < s[5]["hh_live_rows"] <= s[5]["hh_slots"] == BS
+    # Zipf over 100 keys: a batch's groups are far fewer than its slots
+    assert max(s[5]["hh_live_rows"] for s in states) < BS // 2
 
 
 def test_checkpoint_bytes_are_what_was_written(traced_run):

@@ -1,10 +1,13 @@
 """Sketch op unit tests against exact numpy counters (SURVEY.md §4:
 "unit-test sketch kernels against exact numpy counters")."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from flow_pipeline_tpu.models.heavy_hitter import live_rows
+from flow_pipeline_tpu.ops import cms as cms_mod
 from flow_pipeline_tpu.ops import (
     QuantileSketchSpec,
     cms_add,
@@ -89,6 +92,103 @@ class TestCMS:
         sk = cms_add(cms_init(1, 4, 1 << 12), jnp.asarray(ukeys),
                      jnp.asarray(uvals), jnp.asarray(valid))
         assert float(jnp.sum(sk)) == 0.0
+
+
+def _conservative_as_the_parent_wrote_it(counts, keys, values, valid):
+    """`cms_add_conservative` before the live bound (PR 37's parent),
+    kept here as the reference: every slot's estimate gathered."""
+    p, d, w = counts.shape
+    buckets = cms_mod.cms_buckets(keys, d, w)
+    vals = jnp.where(valid[:, None], values.astype(jnp.float32), 0.0)
+    est = jnp.min(jnp.stack(
+        [counts[:, di, buckets[di]] for di in range(d)]), axis=0).T
+    target = est + vals
+    for di in range(d):
+        counts = counts.at[:, di, buckets[di]].max(target.T)
+    return counts
+
+
+C = cms_mod.LIVE_CHUNK
+# N at the chunk (the plain gather stays) and past two chunks by a part
+# of one (the last chunk is clamped to end at N)
+LIVE_NS = {"N<=C": C, "N>C": 2 * C + 512}
+
+
+class TestLiveBound:
+    """`cms_query` / `cms_add_conservative` under a live bound (PR 37):
+    the rows below it as the plain call reads them, and the state after
+    the update the parent's, bit for bit."""
+
+    PLANES, DEPTH, WIDTH = 3, 4, 1 << 10  # narrow: most cells collide
+
+    def raised(self, rng, n):
+        """A sketch whose cells an earlier batch of the same keys has
+        raised: no estimate is 0."""
+        keys = jnp.asarray(
+            rng.integers(0, 2**32, size=(n, 2), dtype=np.uint32))
+        before = cms_add_conservative(
+            cms_init(self.PLANES, self.DEPTH, self.WIDTH), keys,
+            jnp.asarray(rng.integers(1, 5000, (n, self.PLANES))),
+            jnp.ones(n, bool))
+        return before, keys
+
+    @staticmethod
+    def holed(rng, n, n_live):
+        """[n] bool: real rows with holes between them (the fused step's
+        shared dst sort can give such), the last of them at n_live - 1."""
+        valid = np.zeros(n, bool)
+        valid[:n_live] = rng.random(n_live) < 0.7
+        if n_live:
+            valid[n_live - 1] = True
+        return valid
+
+    @pytest.mark.parametrize("n_live", [0, 1, C - 1, C, C + 1, "N"])
+    @pytest.mark.parametrize("n", sorted(LIVE_NS))
+    def test_query_reads_the_rows_below_the_bound(self, rng, n, n_live):
+        n = LIVE_NS[n]
+        n_live = n if n_live == "N" else min(n_live, n)
+        counts, keys = self.raised(rng, n)
+        plain = np.asarray(cms_query(counts, keys))
+        assert plain.min() > 0  # a row left out would show
+        got = np.asarray(jax.jit(cms_query)(counts, keys,
+                                            jnp.int32(n_live)))
+        assert got.dtype == plain.dtype and got.shape == plain.shape
+        assert got[:n_live].tobytes() == plain[:n_live].tobytes()
+        if n > C:  # the bounded form: 0 from the bound on
+            assert not got[n_live:].any()
+
+    @pytest.mark.parametrize("n_live", [0, 1, C - 1, C, C + 1, "N"])
+    @pytest.mark.parametrize("n", sorted(LIVE_NS))
+    def test_update_leaves_the_parents_state(self, rng, n, n_live):
+        n = LIVE_NS[n]
+        n_live = n if n_live == "N" else min(n_live, n)
+        counts, keys = self.raised(rng, n)
+        valid = self.holed(rng, n, n_live)
+        assert int(live_rows(jnp.asarray(valid))) == n_live
+        assert n_live < 2 or not valid[:n_live].all()
+        # addends on the padding rows too: the mask is the update's
+        values = jnp.asarray(rng.integers(1, 5000, (n, self.PLANES)))
+        want = np.asarray(_conservative_as_the_parent_wrote_it(
+            counts, keys, values, jnp.asarray(valid)))
+        got = np.asarray(jax.jit(cms_add_conservative)(
+            counts, keys, values, jnp.asarray(valid), jnp.int32(n_live)))
+        assert got.tobytes() == want.tobytes()
+        assert (got != np.asarray(counts)).any() == bool(n_live)
+        # and without a bound it is the function it was
+        assert np.asarray(cms_add_conservative(
+            counts, keys, values, jnp.asarray(valid))).tobytes() \
+            == want.tobytes()
+
+    def test_the_bounded_form_is_a_loop_only_past_one_chunk(self):
+        def loops(n):
+            shape = jax.ShapeDtypeStruct
+            jaxpr = jax.make_jaxpr(cms_query)(
+                shape((self.PLANES, self.DEPTH, self.WIDTH), np.float32),
+                shape((n, 2), np.uint32), shape((), np.int32))
+            return [e.primitive.name for e in jaxpr.eqns
+                    if e.primitive.name in ("while", "scan", "cond")]
+
+        assert loops(C) == [] and loops(C + 1) == ["while"]
 
 
 class TestTopKTable:
