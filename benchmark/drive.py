@@ -16,6 +16,8 @@ import sys
 import threading
 import time
 
+import numpy as np
+
 T_PROCESS = time.monotonic()
 RUN_LIMIT_S = 330.0      # a run must exit within 360 s
 
@@ -29,21 +31,100 @@ class Abort(Exception):
     """The run cannot produce a result; exit non-zero, print none."""
 
 
+class Deal:
+    """Where every flow went: the partition that the stream's kind deals
+    each position to, and its offset there. The stream goes to the bus in
+    position order, so a partition's log holds its positions in ascending
+    order and a flow's offset is its index among them. On one partition
+    a position is its own offset and nothing is kept; on several, one
+    int64 a flow, made once for the whole stream."""
+
+    def __init__(self, spec, partitions: int, total_flows: int):
+        self.partitions = partitions
+        self._positions = None
+        if partitions > 1:
+            part = np.asarray(spec.partition_of(
+                np.arange(total_flows, dtype=np.int64), partitions))
+            if part.min() < 0 or part.max() >= partitions:
+                raise Abort(f"the stream deals to partitions {part.min()}.."
+                            f"{part.max()}; the bus has {partitions}")
+            self._positions = [np.flatnonzero(part == p)
+                               for p in range(partitions)]
+
+    def positions(self, partition: int, first: int, n: int) -> np.ndarray:
+        """Positions of the flows at offsets [first, first + n) of
+        ``partition``, ascending."""
+        if self._positions is None:
+            return np.arange(first, first + n, dtype=np.int64)
+        return self._positions[partition][first:first + n]
+
+    def offsets(self, partition: int, lo: int, hi: int) -> tuple:
+        """[a, b): the offsets of ``partition`` whose flows lie at
+        positions [lo, hi)."""
+        if self._positions is None:
+            return lo, hi
+        a, b = np.searchsorted(self._positions[partition], [lo, hi])
+        return int(a), int(b)
+
+    def split(self, lo: int, hi: int) -> list:
+        """For each partition, where among the flows [lo, hi) its own
+        lie: indices into [0, hi - lo), ascending."""
+        out = []
+        for p in range(self.partitions):
+            a, b = self.offsets(p, lo, hi)
+            out.append(self.positions(p, a, b - a) - lo)
+        return out
+
+    def consumed(self, folded: list) -> np.ndarray:
+        """Positions of the flows below offset ``folded[p]`` of every
+        partition ``p``, ascending: the flows consumed."""
+        if self._positions is None:
+            return np.arange(sum(folded), dtype=np.int64)
+        return np.sort(np.concatenate(
+            [self.positions(p, 0, n) for p, n in enumerate(folded)]))
+
+    def beyond(self, offsets: list, lo: int, hi: int) -> int:
+        """How many flows at positions [lo, hi) lie at or past
+        ``offsets[p]`` in their partition ``p``."""
+        total = 0
+        for p in range(self.partitions):
+            a, b = self.offsets(p, lo, hi)
+            total += max(0, b - max(offsets[p], a))
+        return total
+
+
 def produce(run, lo: int, hi: int) -> None:
-    """Hand flows [lo, hi) to the bus, in one call (one lock, one stamp):
-    frames cut during set-up."""
+    """Hand flows [lo, hi) to the bus, one call a partition (one lock, one
+    stamp): frames cut during set-up, each to the partition its position
+    is dealt to."""
     c = run.spec.chunk_flows
-    parts = []
-    while lo < hi:
-        ci, off = divmod(lo, c)
-        n = min(hi - lo, c - off)
+    parts, at = [], lo
+    while at < hi:
+        ci, off = divmod(at, c)
+        n = min(hi - at, c - off)
         parts.append(run.frames[ci] if n == c
                      else run.frames[ci][off:off + n])
         if off + n == c:
             run.frames[ci] = None  # the bus holds them now
-        lo += n
-    run.sut.bus.produce_many(
-        run.sut.topic, itertools.chain.from_iterable(parts), partition=0)
+        at += n
+    frames = itertools.chain.from_iterable(parts)
+    deal = run.deal
+    if deal.partitions == 1:
+        split = [frames]
+    else:
+        flat = np.empty(hi - lo, object)
+        flat[:] = list(frames)
+        split = [flat[places].tolist() for places in deal.split(lo, hi)]
+    for p, part in enumerate(split):
+        run.sut.bus.produce_many(run.sut.topic, part, partition=p)
+
+
+def folded(run) -> list:
+    """A partition: the offset the worker has folded up to. Its own count
+    of what its state covers, not the fetch position: the feed thread
+    fetches ahead of it."""
+    covered = run.sut.worker._covered
+    return [int(covered.get(p, 0)) for p in range(run.deal.partitions)]
 
 
 def freeze_heap() -> None:
@@ -201,14 +282,21 @@ def drive_profiler(run, times, now: float) -> None:
 
 
 class FetchScan:
-    """Fetches that took flows, each seen once, in order."""
+    """Fetches that took flows, each seen once, in order: (time it
+    returned, partition, first offset, flows, position). The position is
+    the count of flows fetched over all partitions once it had returned:
+    what "the fetch position" means on any number of them."""
 
     def __init__(self, run):
         self.spans, self.i = run.spans.spans, 0
+        self.upto: dict = {}     # partition -> offset fetched up to
 
     def new(self) -> list:
-        n = len(self.spans)
-        out = [(s[2], s[4][0], s[4][1]) for s in self.spans[self.i:n]
-               if s[0] == "bus_fetch" and s[4] is not None]
+        n, out = len(self.spans), []
+        for s in self.spans[self.i:n]:
+            if s[0] == "bus_fetch" and s[4] is not None:
+                p, first, k = s[4]
+                self.upto[p] = max(self.upto.get(p, 0), first + k)
+                out.append((s[2], p, first, k, sum(self.upto.values())))
         self.i = n
         return out

@@ -1,6 +1,6 @@
 """Bytes of the arrays one checkpoint serializes under a mesh (four stacked
-replicas of each sketch), before compression, in MB: median. Source:
-ckpt_serialize's raw_bytes."""
+replicas of each sketch), in MB (what the file holds too: nothing is
+compressed since PR 30): median. Source: ckpt_serialize's raw_bytes."""
 
 from benchmark import program_spans
 

@@ -1,5 +1,6 @@
-"""Bytes of the arrays one checkpoint serializes, before compression, in
-MB: median. Source: ckpt_serialize's raw_bytes."""
+"""Bytes of the arrays one checkpoint serializes, in MB (what the file
+holds too: nothing is compressed since PR 30): median. Source:
+ckpt_serialize's raw_bytes."""
 
 from benchmark import program_spans
 

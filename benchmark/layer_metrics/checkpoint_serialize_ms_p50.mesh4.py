@@ -1,6 +1,7 @@
-"""np.savez_compressed of every array (four stacked replicas of each sketch)
-and json.dumps of the tree, in memory: median. Source: the program's
-ckpt_serialize span, as checkpoint_serialize_ms_p50 reads it on one chip."""
+"""np.savez of every array (four stacked replicas of each sketch; stored,
+not compressed, since PR 30) and json.dumps of the tree, in memory: median.
+Source: the program's ckpt_serialize span, as checkpoint_serialize_ms_p50
+reads it on one chip."""
 
 from benchmark import program_spans
 

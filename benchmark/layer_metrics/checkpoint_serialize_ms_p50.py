@@ -1,5 +1,6 @@
-"""np.savez_compressed of every array and json.dumps of the tree, in memory:
-median. Source: the program's ckpt_serialize span."""
+"""np.savez of every array (stored, not compressed, since PR 30) and
+json.dumps of the tree, in memory: median. Source: the program's
+ckpt_serialize span."""
 
 from benchmark import program_spans
 

@@ -12,7 +12,7 @@ def read(run):
     if not run.publish_log:
         return None
     fetches = run.fetches()
-    ends = [first + n for _t, first, n in fetches]
+    ends = [f[4] for f in fetches]  # flows fetched once it returned
     out = []
     for t_seen, _v, flows in run.publish_log:
         if run.t_a <= t_seen < run.t_b and flows:

@@ -1,9 +1,10 @@
 """Traffic mode ``backlog``: the bus always holds more than the worker
-can take. The window opens when the fetch position reaches
-``window_start_chunks`` and closes at the first fetch ``--seconds`` or
-more later. The first close inside it comes ``first_close_into_flows``
-after it opens (to the next whole event second) and the next a slot
-later, at the same flow indices in every run."""
+can take. The window opens when the fetch position (the count of flows
+fetched over all partitions) reaches ``window_start_chunks`` and closes
+at the first fetch ``--seconds`` or more later. The first close inside
+it comes ``first_close_into_flows`` after it opens (to the next whole
+event second) and the next a slot later, at the same flow indices in
+every run."""
 
 from __future__ import annotations
 
@@ -44,9 +45,9 @@ def control(run, chunks) -> None:
     scan = drive.FetchScan(run)
 
     def opened():
-        for t1, first, n in scan.new():
-            if first >= plan.window_start_flow:
-                run.t_a, run.pos_a = t1, first + n
+        for t1, _p, _first, n, pos in scan.new():
+            if pos - n >= plan.window_start_flow:
+                run.t_a, run.pos_a = t1, pos
                 return True
         return False
 
@@ -57,14 +58,14 @@ def control(run, chunks) -> None:
 
     def closed():
         drive.drive_profiler(run, times, time.monotonic())
-        for t1, first, n in scan.new():
+        for t1, _p, _first, _n, pos in scan.new():
             if t1 >= run.t_a + plan.seconds:
-                run.t_b, run.pos_b = t1, first + n
+                run.t_b, run.pos_b = t1, pos
                 return True
-            if first + n >= plan.total_flows:
+            if pos >= plan.total_flows:
                 raise drive.Abort(
                     f"the backlog ran dry {t1 - run.t_a:.2f} s into the "
-                    f"window, at {(first + n - run.pos_a) / (t1 - run.t_a):.0f}"
+                    f"window, at {(pos - run.pos_a) / (t1 - run.t_a):.0f}"
                     f" flows/s: raise provision_flows_per_s in the traffic "
                     f"file")
         return False
@@ -76,12 +77,15 @@ def control(run, chunks) -> None:
             f"{(run.pos_b - run.pos_a) / (run.t_b - run.t_a):.0f} flows/s: "
             f"raise provision_flows_per_s in the traffic file")
     run.rate_edges = [(run.t_a, run.pos_a), (run.t_b, run.pos_b)]
-    drive.wait(run, lambda: run.sut.worker.flows_seen >= run.pos_b,
+    drive.wait(run, lambda: not run.deal.beyond(drive.folded(run), 0,
+                                                run.pos_b),
                "the flows taken in the window to be folded")
 
 
 def window_flows(run) -> tuple:
-    """The flows the window attempted: those taken between its edges."""
+    """The flows the window attempted: those taken between its edges (on
+    several partitions: as many, the positions between the two counts,
+    every one of them folded before the stream ends)."""
     return run.pos_a, run.pos_b
 
 

@@ -1,7 +1,9 @@
 """Traffic mode ``open_loop``: flows ``[0, backlog)`` are on the bus at
 once (warm-up: they hold the first close and a checkpoint), then flow
 ``i`` is due at ``T0 + (i - backlog) / rate``, offered every ``tick_s``
-whether or not the worker keeps up. The window is the flows
+whether or not the worker keeps up (a flow's due time is its position's:
+one partition only, ``run.py`` refuses more at plan time). The window is
+the flows
 ``[window_start, window_start + rate * seconds)``, i.e. the wall interval
 in which they are due; its first close is due ``first_close_into_s``
 after it opens (the first of the file's candidates that keeps every close
@@ -83,9 +85,8 @@ def control(run, chunks) -> None:
     # the rate is read between the first fetch at or after each edge of
     # the window, as in a backlog cell: right after a fetch the bus holds
     # only what the worker has not kept up with
-    f = run.fetches()
-    run.rate_edges = [next(((t1, first + n) for t1, first, n in f
-                            if t1 >= edge), (f[-1][0], f[-1][1] + f[-1][2]))
+    f = [(t1, pos) for t1, _p, _first, _n, pos in run.fetches()]
+    run.rate_edges = [next((at for at in f if at[0] >= edge), f[-1])
                       for edge in (run.t_a, run.t_b)]
     run.pos_a, run.pos_b = run.rate_edges[0][1], run.rate_edges[1][1]
 
@@ -99,7 +100,7 @@ def window_flows(run) -> tuple:
 def describe(run) -> dict:
     plan = run.plan
     lag = [plan.backlog_flows + (t1 - run.t0_schedule) * plan.rate
-           - (first + n) for t1, first, n in run.fetches()
+           - pos for t1, _p, _first, _n, pos in run.fetches()
            if run.t_a <= t1 <= run.t_b]
     return {"backlog_max_flows": max(lag, default=0.0),
             "backlog_last_flows": lag[-1] if lag else 0.0,

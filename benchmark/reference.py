@@ -1,13 +1,16 @@
 """The plain reference: exact answers over exactly the flows consumed.
 
 Pure numpy on the benchmark's own draws; imports nothing of the program
-and reads nothing the program made. Every attribute of a flow but its
-size is ``table[rank]``, so each slot is first reduced to per-rank sums
-(one bincount over at most ``n_keys`` bins; float64 holds integers
-exactly below 2^53 and the sums are checked against it). A table of a
-configuration is then a grouping of ranks by the key's columns; what a
-table means — exact sums, a ranking by bytes — is its kind, a module
-under ``tables/`` named in the configuration's ``checks.tables``.
+and reads nothing the program made. A flow is (position, rank, bytes,
+packets): every other attribute of it is the stream kind's key table at
+its rank and its event time is the kind's function of its position
+(``manifest.py`` has the contract), so each slot is first reduced to
+per-rank sums (one bincount over ``len(table)`` bins; float64 holds
+integers exactly below 2^53 and the sums are checked against it). A
+table of a configuration is then a grouping of ranks by the key's
+columns; what a table means — exact sums, a ranking by bytes — is its
+kind, a module under ``tables/`` named in the configuration's
+``checks.tables``.
 
 ``precision="bf16"`` is the control, not a mode of the benchmark: every
 addend is rounded to bfloat16 before it is summed, which is what a
@@ -18,8 +21,6 @@ at default precision computes. ``correct`` must come out false for it.
 from __future__ import annotations
 
 import numpy as np
-
-from .flowgen import KeyTable, StreamSpec
 
 _EXACT_F64 = float(2 ** 53)
 
@@ -33,8 +34,8 @@ def _to_bf16(x: np.ndarray) -> np.ndarray:
 
 
 class Reference:
-    def __init__(self, spec: StreamSpec, table: KeyTable,
-                 precision: str = "u64"):
+    def __init__(self, spec, table, precision: str = "u64"):
+        """``spec`` and ``table``: the stream kind's, of this run."""
         if precision not in ("u64", "bf16"):
             raise ValueError(f"precision must be u64|bf16, got {precision!r}")
         self.spec, self.table, self.precision = spec, table, precision
@@ -44,7 +45,7 @@ class Reference:
         """(group id of every rank, one representative rank per group)."""
         if cols not in self._groups:
             # dense ids column by column: one-dimensional integer sorts
-            gid = np.zeros(self.spec.n_keys, np.int64)
+            gid = np.zeros(len(self.table), np.int64)
             for c in cols:
                 _u, col = np.unique(getattr(self.table, c),
                                     return_inverse=True)
@@ -55,29 +56,32 @@ class Reference:
             self._groups[cols] = (gid, first)
         return self._groups[cols]
 
-    def slot_sums(self, rank, nbytes, packets, lo: int, hi: int):
-        """Per-slot per-rank sums over flows [lo, hi): {timeslot:
-        (bytes[n_keys], packets[n_keys], count[n_keys])} as uint64."""
-        spec = self.spec
-        idx = np.arange(lo, hi, dtype=np.int64)
+    def slot_sums(self, idx, rank, nbytes, packets):
+        """Per-slot per-rank sums over the flows at positions ``idx``
+        (ascending), whose draws are ``rank``, ``nbytes``, ``packets``:
+        {timeslot: (bytes[keys], packets[keys], count[keys])} as
+        uint64."""
+        spec, keys = self.spec, len(self.table)
         ts = spec.event_ts(idx).astype(np.int64)
         slot = ts // spec.slot_seconds * spec.slot_seconds
         out = {}
         for s in np.unique(slot):
-            sel = slice(*np.flatnonzero(slot == s)[[0, -1]] + (0, 1))
-            r = rank[lo:hi][sel]
+            sel = np.flatnonzero(slot == s)
+            if not spec.max_disorder_s:
+                # event time never runs backwards: a slot is a run
+                sel = slice(sel[0], sel[-1] + 1)
+            r = rank[sel]
             planes = []
-            for v in (nbytes[lo:hi][sel], packets[lo:hi][sel]):
+            for v in (nbytes[sel], packets[sel]):
                 w = v.astype(np.float64)
                 if self.precision == "bf16":
                     w = _to_bf16(w).astype(np.float64)
-                tot = np.bincount(r, weights=w, minlength=spec.n_keys)
+                tot = np.bincount(r, weights=w, minlength=keys)
                 if tot.max(initial=0.0) >= _EXACT_F64:
                     raise OverflowError("per-rank sum left exact float64")
                 if self.precision == "bf16":
                     tot = tot.astype(np.float32).astype(np.float64)
                 planes.append(tot.astype(np.uint64))
-            planes.append(np.bincount(r, minlength=spec.n_keys)
-                          .astype(np.uint64))
+            planes.append(np.bincount(r, minlength=keys).astype(np.uint64))
             out[int(s)] = tuple(planes)
         return out

@@ -58,10 +58,12 @@ class Run:
         self.spans = SpanLog()
         self.sut = Sut(self.spans, cell.config.get("topic", "flows"),
                        int(cell.config["bus_partitions"]))
+        self.deal = drive.Deal(spec, self.sut.partitions, plan.total_flows)
         self.t_first_flow = None     # set-up ends here
         self.t_a = self.t_b = None   # the measured window, monotonic
         self.rate_edges = None       # [(t, position)] the rate is read between
-        self.pos_a = self.pos_b = None  # flows fetched at its edges
+        self.pos_a = self.pos_b = None  # flows fetched at its edges, over
+        #                                 all partitions
         self.t0_schedule = None      # open loop: flow backlog_flows is due
         self.ticks: list = []        # (scheduled, actual, flows handed)
         self.compiles: list = []     # (t, name, seconds)
@@ -83,9 +85,9 @@ class Run:
         return self.spans.named(name, self.t_a, self.t_b)
 
     def fetches(self) -> list:
-        """(t_returned, first_offset, n) of every fetch that took flows."""
-        return [(s[2], s[4][0], s[4][1]) for s in self.spans.spans
-                if s[0] == "bus_fetch" and s[4] is not None]
+        """(t_returned, partition, first_offset, n, position) of every
+        fetch that took flows (``drive.FetchScan``)."""
+        return drive.FetchScan(self).new()
 
     def due(self, flow: int) -> float:
         return self.t0_schedule + self.plan.due_offset(flow)
@@ -211,13 +213,22 @@ def after_finalize(run: Run, port: int, worker) -> None:
     """On the main thread, after ``worker.finalize()``, the query surface
     still up."""
     f = run.final
+    bus, topic = run.sut.bus, run.sut.topic
+    parts = range(run.sut.partitions)
     f["flows_seen"] = int(worker.flows_seen)
     f["batches_seen"] = int(worker.batches_seen)
-    f["committed"] = int(run.sut.bus.committed(GROUP, run.sut.topic, 0))
-    f["bus_end"] = int(run.sut.bus.end_offset(run.sut.topic, 0))
-    f["late_dropped"] = int(sum(
-        getattr(m, "late_flows_dropped", 0) or 0
-        for m in worker.models.values()))
+    # a partition: the offset the worker had folded up to (the feed thread
+    # fetches ahead of it), the group's commit, the log's end
+    f["folded"] = drive.folded(run)
+    f["committed"] = [int(bus.committed(GROUP, topic, p)) for p in parts]
+    f["bus_end"] = [int(bus.end_offset(topic, p)) for p in parts]
+    if sum(f["folded"]) != f["flows_seen"]:
+        raise Abort(f"the worker folded up to offsets {f['folded']} of its "
+                    f"partitions and counts {f['flows_seen']} flows")
+    f["late_by_model"] = {
+        name: int(getattr(m, "late_flows_dropped", 0) or 0)
+        for name, m in worker.models.items()}
+    f["late_dropped"] = sum(f["late_by_model"].values())
     f["dataplane"] = type(worker.fused).__name__
     f["version"] = _get(port, "/query/version")
     f["queries"] = {q["name"]: _get(port, q["path"])
@@ -276,9 +287,16 @@ def execute(args) -> dict:
 
     manifest_path = os.path.join(ROOT, args.manifest)
     cell = manifest.load_cell(ROOT, manifest_path, args.workload)
-    plan = cell.mode.plan(cell.traffic, cell.config["stream"],
-                          float(args.seconds))
-    spec = schedule.spec_for(args.seed, cell.config["stream"], plan)
+    plan = cell.mode.plan(cell.traffic, cell.stream, float(args.seconds))
+    if plan.rate and int(cell.config["bus_partitions"]) > 1:
+        # Run.due() is a flow's position's; on several partitions the
+        # newest flow of a snapshot is not flow flows_seen - 1
+        raise Abort(
+            f"traffic mode {plan.mode} offers flows at a rate and reads "
+            f"staleness against the due time of flow flows_seen - 1, which "
+            f"holds on one partition only; configuration "
+            f"{cell.config_name} has {cell.config['bus_partitions']}")
+    spec = schedule.spec_for(args.seed, cell.stream, plan)
     run = Run(args, cell, plan, spec)
     ensure_native()
     procs = max(2, min(int(cell.traffic.get("generator_processes", 6)),
@@ -288,8 +306,10 @@ def execute(args) -> dict:
     ring = _ring(procs, spec.chunk_flows)
     pool = chunks = reader = None
     try:
-        pool = ctx.Pool(procs, initializer=_init_worker,
-                        initargs=(spec, ROOT, ring.for_workers()))
+        pool = ctx.Pool(procs, initializer=_init_worker, initargs=(
+            cell.stream.path,
+            (args.seed, dict(cell.stream), plan.first_close_flow,
+             plan.phase_s), ROOT, ring.for_workers()))
         _widen_result_pipe(pool)
         # the stream is made, and cut into frames, while JAX starts
         chunks = drive.Stream(run, pool.imap(
@@ -342,6 +362,7 @@ def execute(args) -> dict:
             run.trace = trace_reduce.reduce_dir(
                 os.path.join(run.rundir, "trace"), run)
         checks = check.run_checks(run)
+        run.final["late_expected"] = check.late_flows(run)
         result = build_result(run, checks)
         if args.control:
             result["controls"] = [check.run_control(run, c)
@@ -370,7 +391,7 @@ def execute(args) -> dict:
 def build_result(run: Run, checks: list) -> dict:
     plan, f = run.plan, run.final
     lo, hi = run.cell.mode.window_flows(run)
-    uncommitted = max(0, hi - max(f["committed"], lo))
+    uncommitted = run.deal.beyond(f["committed"], lo, hi)
     shed = counter_total("guard_shed_total")
     dead = counter_total("sink_deadletter_total")
     failed = int(uncommitted + shed + dead + f["late_dropped"])
@@ -386,9 +407,15 @@ def build_result(run: Run, checks: list) -> dict:
                    "first_flow": lo, "last_flow": hi,
                    "closes_at": run.spec.close_flows(lo, hi),
                    "flows_consumed": f["flows_seen"],
+                   "folded": f["folded"], "committed": f["committed"],
+                   "committed_total": sum(f["committed"]),
+                   "bus_end": f["bus_end"],
+                   "bus_end_total": sum(f["bus_end"]),
                    "dataplane": f["dataplane"],
                    "uncommitted": uncommitted, "shed": shed,
                    "deadlettered": dead, "late_dropped": f["late_dropped"],
+                   "late_by_model": f["late_by_model"],
+                   "late_expected": f["late_expected"],
                    **run.cell.mode.describe(run)},
     }
     if run.trace is not None:
@@ -426,6 +453,9 @@ def main(argv=None) -> int:
     except Abort as e:
         print(f"benchmark: {e}", file=sys.stderr)
         return 3
+    # each number compared beside its limit: the last lines of standard
+    # error and the last key of the result's line
+    result["checks"] = result.pop("checks")
     for c in result["checks"]:
         log(f"check {c['name']}: {c['value']} (limit {c['limit']}) "
             f"{'ok' if c['ok'] else 'FAILED'}")

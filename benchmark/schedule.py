@@ -9,14 +9,14 @@ fixing the indices fixes the closes.
 A traffic file names its ``mode``; the module ``modes/<mode>.py`` (found
 by that name under the manifest's ``paths``) makes the ``Plan`` and drives
 the run. What the modes share is here: the plan's fields, and the choice
-of the stream's phase that puts a close at a wanted flow index.
+of the stream's phase that puts a close at a wanted flow index. ``stream``
+is a ``manifest.Stream`` throughout: the configuration's ``stream`` object
+with its kind beside it, whose spec says where slots roll.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-
-from .flowgen import StreamSpec, stream_spec
 
 
 @dataclass(frozen=True)
@@ -51,11 +51,11 @@ def phase_for(stream: dict, first_close_flow: int, close_flow: int):
     return (-secs) % slot, first_close_flow + secs * rate
 
 
-def closes_between(stream: dict, first_close_flow: int, phase_s: int,
+def closes_between(stream, first_close_flow: int, phase_s: int,
                    lo: int, hi: int) -> tuple:
-    return tuple(stream_spec(0, stream, first_close_flow, phase_s)
+    return tuple(stream.spec(0, first_close_flow, phase_s)
                  .close_flows(lo, hi))
 
 
-def spec_for(seed: int, stream: dict, plan: Plan) -> StreamSpec:
-    return stream_spec(seed, stream, plan.first_close_flow, plan.phase_s)
+def spec_for(seed: int, stream, plan: Plan):
+    return stream.spec(seed, plan.first_close_flow, plan.phase_s)

@@ -28,12 +28,20 @@ import threading
 
 from .spans import SpanLog
 
-# (module, class, method, span name, what to keep with the span)
+
+def _arg(args: tuple, kwargs: dict, at: int, name: str):
+    return args[at] if len(args) > at else kwargs[name]
+
+
+# (module, class, method, span name, what to keep with the span); a fetch
+# keeps (partition, first offset, flows), a commit (partition, next offset)
 HOOKS = [
     ("flow_pipeline_tpu.transport.bus", "InProcessBus", "fetch_span",
-     "bus_fetch", lambda a, k, r: None if r is None else (r[1], r[2] - r[1] + 1)),
+     "bus_fetch", lambda a, k, r: None if r is None else (
+         _arg(a, k, 2, "partition"), r[1], r[2] - r[1] + 1)),
     ("flow_pipeline_tpu.transport.bus", "InProcessBus", "commit",
-     "bus_commit", lambda a, k, r: a[4] if len(a) > 4 else k.get("next_offset")),
+     "bus_commit", lambda a, k, r: (_arg(a, k, 3, "partition"),
+                                    _arg(a, k, 4, "next_offset"))),
     ("flow_pipeline_tpu.schema.batch", "FlowBatch", "from_wire",
      "decode", lambda a, k, r: 0 if r is None else len(r)),
     ("flow_pipeline_tpu.engine.worker", "StreamWorker", "_process",

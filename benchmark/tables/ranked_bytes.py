@@ -17,7 +17,7 @@ import ipaddress
 
 import numpy as np
 
-_PREFIX = 0x20010DB8_00000001_00000000_0000 << 16  # flowgen's prefix
+_PREFIX = 0x20010DB8_00000001_00000000_0000 << 16  # zipf-ranks' prefix
 _SINK_COLS = {"src_host": "src_addr", "dst_host": "dst_addr"}
 KEEP = 4000  # exact keys kept a slot: far past any sink's depth
 

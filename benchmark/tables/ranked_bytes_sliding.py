@@ -13,9 +13,12 @@ one that holds no flow has no rows; the last, which the stream's end cut
 short, is the sum of what was consumed (the worker's forced flush).
 
 The sums come from the benchmark's own draws, a sub-window at a time,
-through ``Reference(spec with slot_seconds = S).slot_sums`` (event time
-does not depend on ``slot_seconds``), and K consecutive ones are added for
-each slide end; then ranked as ``ranked_bytes.want`` ranks. ``want`` is
+through ``Reference(spec with slot_seconds = S).slot_sums`` (the spec is
+a dataclass whose event time does not depend on ``slot_seconds``), and K
+consecutive ones are added for each slide end; then ranked as
+``ranked_bytes.want`` ranks. A sub-window is found as a range of flows, by
+bisection: for a stream whose event time never runs backwards, consumed
+from one partition (anything else is refused). ``want`` is
 handed no draws, so ``read_sink`` and ``control``, which are handed the
 run and are called first, leave the sub-window sums of their precision on
 the run's key table, which every ``Reference`` of the run shares.
@@ -60,7 +63,7 @@ def _first_flow_at(spec, ts: int, lo: int, hi: int) -> int:
 
 
 def _sub_sums(table, spec, precision: str, slide: int, run=None) -> dict:
-    """{sub-window start: (bytes[n_keys], count[n_keys]) uint64} under
+    """{sub-window start: (bytes[keys], count[keys]) uint64} under
     ``precision``, kept on the run's key table once made."""
     kept = table.__dict__.setdefault("_sub_window_sums", {})
     if (precision, slide) not in kept:
@@ -68,7 +71,13 @@ def _sub_sums(table, spec, precision: str, slide: int, run=None) -> dict:
             raise RuntimeError(
                 "ranked_bytes_sliding.want before read_sink or control "
                 "made the sub-window sums")
-        rank, nbytes, packets, n = check._draw_arrays(run)
+        idx, rank, nbytes, packets = check.consumed_draws(run)
+        n = len(idx)
+        if spec.max_disorder_s or idx[-1] != n - 1:
+            raise ValueError(
+                "ranked_bytes_sliding finds a sub-window as a range of "
+                "flows: event time must never run backwards and the flows "
+                "consumed be every flow up to the last")
         ref = Reference(dataclasses.replace(spec, slot_seconds=slide),
                         table, precision)
         # event time is monotone in the flow index: a sub-window is a
@@ -81,8 +90,9 @@ def _sub_sums(table, spec, precision: str, slide: int, run=None) -> dict:
                 edges[-1], n))
         sums: dict = {}
         for lo, hi in zip(edges, edges[1:]):
-            for start, planes in ref.slot_sums(rank, nbytes, packets,
-                                               lo, hi).items():
+            for start, planes in ref.slot_sums(
+                    idx[lo:hi], rank[lo:hi], nbytes[lo:hi],
+                    packets[lo:hi]).items():
                 sums[start] = (planes[0], planes[2])
         kept[precision, slide] = sums
     return kept[precision, slide]

@@ -29,6 +29,15 @@ def test_every_cell_of_the_benchmark_loads(cell):
     assert "setup_s" in {m["name"] for m, _ in c.end_to_end}
     assert len(c.end_to_end) >= 2 and len(c.per_layer) >= 1
     assert all(callable(r.read) for _, r in c.end_to_end + c.per_layer)
+    # no configuration names a stream kind: each gets zipf-ranks, and
+    # drives the one partition it states
+    assert "kind" not in c.config["stream"]
+    assert os.path.basename(c.stream.path) == "zipf-ranks.py"
+    assert all(callable(getattr(c.stream.kind, a))
+               for a in manifest.STREAM_API)
+    spec = c.stream.spec(1, 65536, 0)
+    assert all(hasattr(spec, a) for a in manifest.SPEC_API)
+    assert spec.max_disorder_s == 0 and c.config["bus_partitions"] == 1
 
 
 def test_cells_added_as_files_only():

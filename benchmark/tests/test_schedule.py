@@ -7,7 +7,7 @@ import os
 import numpy as np
 import pytest
 
-from benchmark import schedule
+from benchmark import manifest, schedule
 from benchmark.flowgen import KeyTable, chunk_draws
 from benchmark.modes import backlog, open_loop
 
@@ -20,7 +20,8 @@ def _load(*parts):
         return json.load(f)
 
 
-STREAM = _load("configs", "default-estate.json")["stream"]
+STREAM = manifest.load_stream(
+    ROOT, ["benchmark"], _load("configs", "default-estate.json")["stream"])
 
 
 @pytest.mark.parametrize("seconds", [10, 25, 51])
@@ -98,8 +99,9 @@ BACKLOGS = {
 
 def _backlog(name):
     lay = BACKLOGS[name]
-    return (_load("traffic", name + ".json"),
-            _load("configs", lay["config"] + ".json")["stream"], lay)
+    return (_load("traffic", name + ".json"), manifest.load_stream(
+        ROOT, ["benchmark"],
+        _load("configs", lay["config"] + ".json")["stream"]), lay)
 
 
 def _close_shares(traffic, stream, rate, seconds=51):
@@ -182,7 +184,7 @@ def test_an_edge_close_is_refused():
 
 
 def test_the_flows_are_the_seeds_own_and_repeat():
-    stream = dict(STREAM, n_keys=5000)
+    stream = STREAM.with_params(n_keys=5000)
     plan = backlog.plan(_load("traffic", "backlog-drain.json"), stream, 1)
     s1 = schedule.spec_for(2**31 + 7, stream, plan)
     s2 = schedule.spec_for(8, stream, plan)
@@ -201,13 +203,14 @@ def test_the_flows_are_the_seeds_own_and_repeat():
 
 def test_chunks_cut_blocks_anywhere():
     """A chunk's draws do not depend on how chunks and blocks align."""
-    stream = dict(STREAM, n_keys=500, chunk_flows=2048, block_flows=1000)
+    stream = STREAM.with_params(n_keys=500, chunk_flows=2048,
+                                block_flows=1000)
     plan = backlog.plan(_load("traffic", "backlog-drain.json"), stream, 1)
     spec = schedule.spec_for(5, stream, plan)
     table = KeyTable(spec)
     whole = [np.concatenate([chunk_draws(spec, table, c)[i]
                              for c in range(4)]) for i in range(3)]
-    wide = schedule.spec_for(5, dict(stream, chunk_flows=4096), plan)
+    wide = schedule.spec_for(5, stream.with_params(chunk_flows=4096), plan)
     for i in range(3):
         assert np.array_equal(
             whole[i], np.concatenate([chunk_draws(wide, table, c)[i]

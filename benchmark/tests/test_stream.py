@@ -13,7 +13,7 @@ import types
 
 import pytest
 
-from benchmark import drive, flowgen, schedule
+from benchmark import drive, flowgen, manifest, schedule
 from benchmark.modes import backlog
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -21,15 +21,25 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 CHUNKS = 120
 
 
-def _spec():
+SEED = 2**31 + 29
+
+
+def _stream():
+    """(the stream, what its kind's spec() takes) at a small size."""
     with open(os.path.join(ROOT, "benchmark/configs/default-estate.json")) \
             as f:
-        stream = dict(json.load(f)["stream"], n_keys=500, chunk_flows=256,
-                      block_flows=256)
+        stream = manifest.load_stream(ROOT, ["benchmark"], dict(
+            json.load(f)["stream"], n_keys=500, chunk_flows=256,
+            block_flows=256))
     with open(os.path.join(ROOT, "benchmark/traffic/backlog-drain.json")) \
             as f:
         plan = backlog.plan(json.load(f), stream, 1)
-    return schedule.spec_for(2**31 + 29, stream, plan)
+    return stream, (SEED, dict(stream), plan.first_close_flow, plan.phase_s)
+
+
+def _spec():
+    stream, args = _stream()
+    return stream.kind.spec(*args)
 
 
 @pytest.fixture(scope="module")
@@ -40,7 +50,7 @@ def by_slices():
     table = flowgen.KeyTable(spec)
     out = []
     for c in range(CHUNKS):
-        blob, draws = flowgen.chunk_blob(spec, table, c)
+        blob, draws = flowgen.chunk_blob(flowgen._ZIPF, spec, table, c)
         offs = flowgen.frame_offsets(blob)
         o = offs.tolist()
         out.append(([blob[a:b] for a, b in zip(o[:-1], o[1:])],
@@ -61,11 +71,13 @@ def test_the_ring_hands_over_the_same_frames_in_order(by_slices, procs,
     """More workers than slots: every worker waits for its slot most of
     the time, and a chunk may not take the turn of the chunk ``slots``
     before it (a semaphore a slot let it, and the run hung)."""
-    spec = _spec()
+    stream, spec_args = _stream()
+    spec = stream.kind.spec(*spec_args)
     ctx = multiprocessing.get_context("spawn")
     ring = flowgen.Ring(slots, spec.chunk_flows * 128)
     pool = ctx.Pool(procs, initializer=flowgen._init_worker,
-                    initargs=(spec, ROOT, ring.for_workers()))
+                    initargs=(stream.path, spec_args, ROOT,
+                              ring.for_workers()))
     run = types.SimpleNamespace(
         spec=spec, frames=[], draws=[],
         plan=types.SimpleNamespace(total_flows=CHUNKS * spec.chunk_flows))
@@ -149,6 +161,7 @@ def test_one_large_produce_and_many_small_put_the_same_frames_on_the_bus():
         run = types.SimpleNamespace(
             spec=types.SimpleNamespace(chunk_flows=100),
             frames=[tuple(frames[i:i + 100]) for i in range(0, 5000, 100)],
+            deal=drive.Deal(None, 1, 5000),
             sut=types.SimpleNamespace(bus=bus, topic="t"))
         for lo, hi in zip(cuts[:-1], cuts[1:]):
             drive.produce(run, lo, hi)
@@ -179,6 +192,7 @@ def test_generate_sends_the_early_flows_and_waits_for_the_rest():
     run = types.SimpleNamespace(
         spec=spec, frames=[], draws=[], error=None,
         plan=types.SimpleNamespace(total_flows=24),
+        deal=drive.Deal(None, 1, 24),
         sut=types.SimpleNamespace(bus=bus, topic="t", bus_ready=ready))
     stream = drive.Stream(run, chunks(), ring)
     done = threading.Thread(target=drive.generate, args=(run, stream, 10))
