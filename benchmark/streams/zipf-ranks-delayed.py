@@ -1,0 +1,223 @@
+"""Stream kind ``zipf-ranks-delayed``: ``zipf-ranks`` as upstream's topic
+carries it, on two partitions and out of event-time order.
+
+A copy of ``zipf-ranks.py``: the key universe, every flow's key and sizes
+and the columns are that file's, byte for byte, for a ``--seed`` (so a
+cell on this kind folds ``estate-catchup``'s ranks, bytes and packets);
+only a flow's event time and its partition differ:
+
+- partitions: flow ``i`` goes to partition ``i mod P``: upstream's
+  keyless producer deals round-robin over the topic's partitions
+  (cloudflare/flow-pipeline ``compose/docker-compose-clickhouse-mock.yml``:
+  topic ``flows``, 2 partitions);
+- disorder: NEXmark's generator (``occasionalDelaySec``,
+  ``probDelayedEvent``). The flows whose hash of (seed, position) falls in
+  ``delayed_share`` lie a whole number of seconds behind the clock of
+  their position, drawn evenly from 1..``delay_s_max``; never a flow whose
+  clock is the first second of a slot (``toy-mixed.py``'s rule), so slots
+  open at the same positions whatever the seed and ``close_flows`` is
+  ``zipf-ranks``'. ``max_disorder_s`` = ``delay_s_max``.
+
+The clock itself is ``zipf-ranks``': flows before ``first_close_flow`` lie
+in the slot that ends at ``boundary_ts``, flow ``first_close_flow`` opens
+the next slot ``phase_s`` seconds into it, and the clock then advances one
+second every ``event_rate`` flows. A key the kind does not know is an
+error that names it. This module imports numpy and the standard library
+only.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass, fields
+
+import numpy as np
+
+SFLOW_5 = 1  # schema.message.FlowType.SFLOW_5
+# 2001:db8:0:1::/112, both sides (the original's prefix)
+_PREFIX_WORDS = (0x20010DB8, 0x00000001, 0x00000000, 0x00000000)
+_DST_PORTS = (53, 80, 123, 443, 8080)
+_PROTOS = (6, 17)
+_U64 = np.uint64
+
+
+def _mix(x: np.ndarray, salt: int) -> np.ndarray:
+    """splitmix64's finalizer over ``x + salt``: a hash a position."""
+    x = x.astype(_U64) + _U64(salt % 2**64)
+    x = (x ^ (x >> _U64(30))) * _U64(0xBF58476D1CE4E5B9)
+    x = (x ^ (x >> _U64(27))) * _U64(0x94D049BB133111EB)
+    return x ^ (x >> _U64(31))
+
+
+@dataclass(frozen=True)
+class StreamSpec:
+    seed: int
+    n_keys: int = 1_000_000
+    alpha: float = 1.1
+    as_base: int = 65000
+    as_count: int = 16
+    max_bytes: int = 1500
+    max_packets: int = 100
+    etype: int = 0x86DD
+    sampling_rate: int = 1
+    event_rate: int = 32000         # flows per second of EVENT time
+    slot_seconds: int = 300
+    boundary_ts: int = 1_700_000_100  # a multiple of slot_seconds
+    first_close_flow: int = 65536   # first flow of the slot at boundary_ts
+    phase_s: int = 0                # event seconds into that slot it starts
+    chunk_flows: int = 32768        # flows to a chunk (the program's batch)
+    block_flows: int = 32768        # flows drawn by one RNG
+    delayed_share: float = 0.1      # of the flows, behind their clock
+    delay_s_max: int = 3            # by 1..delay_s_max whole seconds
+
+    @property
+    def max_disorder_s(self) -> int:
+        return self.delay_s_max
+
+    @property
+    def slot_flows(self) -> int:
+        return self.slot_seconds * self.event_rate
+
+    def close_flows(self, lo: int, hi: int) -> list[int]:
+        """Positions in [lo, hi) that are the first flow of a slot: the
+        flows whose arrival closes the slot before."""
+        k, step = self.first_close_flow, self.slot_flows
+        second = k + (self.slot_seconds - self.phase_s) * self.event_rate
+        out = [k] if lo <= k < hi else []
+        first = second + max(0, -(-(lo - second) // step)) * step
+        return out + list(range(first, hi, step))
+
+    def _clock(self, idx: np.ndarray) -> np.ndarray:
+        """``zipf-ranks``' event time: the clock of a position."""
+        i = idx.astype(np.int64) - self.first_close_flow
+        return (self.boundary_ts + np.where(i >= 0, self.phase_s, 0)
+                + i // self.event_rate)
+
+    def event_ts(self, idx: np.ndarray) -> np.ndarray:
+        """Event time (uint64 seconds) of the flows at positions ``idx``:
+        the clock, less the delay of the flows that have one."""
+        clock = self._clock(idx)
+        h = _mix(idx, self.seed * 2 + 1)
+        delayed = (h & _U64(0xFFFFF)) < _U64(int(self.delayed_share
+                                                 * (1 << 20)))
+        by = 1 + ((h >> _U64(32)) % _U64(self.delay_s_max)).astype(np.int64)
+        opens_a_slot = clock % self.slot_seconds == 0
+        return (clock - np.where(delayed & ~opens_a_slot, by, 0)
+                ).astype(np.uint64)
+
+    def partition_of(self, idx: np.ndarray, partitions: int) -> np.ndarray:
+        """The partition each position goes to, of ``partitions``: the
+        keyless producer's round-robin."""
+        return idx.astype(np.int64) % partitions
+
+
+def spec(seed: int, stream: dict, first_close_flow: int,
+         phase_s: int) -> StreamSpec:
+    """StreamSpec from a configuration file's whole ``stream`` object."""
+    known = {f.name for f in fields(StreamSpec)} - {
+        "seed", "first_close_flow", "phase_s"}
+    unknown = sorted(set(stream) - known - {"kind"})
+    if unknown:
+        raise ValueError(
+            f"stream kind zipf-ranks-delayed has no key {unknown}; it has "
+            f"{sorted(known)}")
+    if not 0 <= phase_s < int(stream["slot_seconds"]):
+        raise ValueError(f"phase_s {phase_s} lies outside a slot")
+    if not (0.0 <= float(stream.get("delayed_share", 0.1)) <= 1.0
+            and int(stream.get("delay_s_max", 3)) >= 1):
+        raise ValueError("delayed_share lies in [0, 1] and delay_s_max is "
+                         "at least 1 s")
+    return StreamSpec(seed=int(seed), first_close_flow=first_close_flow,
+                      phase_s=int(phase_s),
+                      **{k: v for k, v in stream.items() if k != "kind"})
+
+
+class KeyTable:
+    """The key universe: one 5-tuple + AS pair per Zipf rank."""
+
+    def __init__(self, spec: StreamSpec):
+        rng = np.random.default_rng([spec.seed, 0])
+        n = spec.n_keys
+        self.src_host = rng.integers(0, 2**16, n, dtype=np.uint32)
+        self.dst_host = rng.integers(0, 2**16, n, dtype=np.uint32)
+        self.src_port = rng.integers(1024, 2**16, n, dtype=np.uint32)
+        self.dst_port = rng.choice(np.array(_DST_PORTS, np.uint32), n)
+        self.proto = rng.choice(np.array(_PROTOS, np.uint32), n)
+        self.src_as = (spec.as_base + rng.integers(
+            0, spec.as_count, n)).astype(np.uint32)
+        self.dst_as = (spec.as_base + rng.integers(
+            0, spec.as_count, n)).astype(np.uint32)
+        w = np.arange(1, n + 1, dtype=np.float64) ** -spec.alpha
+        self.cdf = np.cumsum(w / w.sum())
+        self.cdf[-1] = 1.0
+
+    def __len__(self) -> int:
+        return len(self.cdf)
+
+    def addr_words(self, host: np.ndarray) -> np.ndarray:
+        a = np.empty((len(host), 4), np.uint32)
+        a[:] = _PREFIX_WORDS
+        a[:, 3] = (a[:, 3] & np.uint32(0xFFFF0000)) | host
+        return a
+
+
+key_table = KeyTable
+
+
+def _block_draws(spec: StreamSpec, table: KeyTable, block: int):
+    """(rank int32, bytes uint16, packets uint8) of block ``block``."""
+    n = spec.block_flows
+    rng = np.random.default_rng([spec.seed, 1, block])
+    rank = np.searchsorted(table.cdf, rng.random(n), side="right")
+    rank = np.minimum(rank, spec.n_keys - 1).astype(np.int32)
+    nbytes = rng.integers(0, spec.max_bytes, n).astype(np.uint16)
+    packets = rng.integers(0, spec.max_packets, n).astype(np.uint8)
+    return rank, nbytes, packets
+
+
+def chunk_draws(spec: StreamSpec, table: KeyTable, chunk: int):
+    """(rank, bytes, packets) of the flows at positions [chunk *
+    chunk_flows, (chunk + 1) * chunk_flows): all that is random about
+    them."""
+    lo = chunk * spec.chunk_flows
+    hi = lo + spec.chunk_flows
+    b = spec.block_flows
+    parts = []
+    for block in range(lo // b, -(-hi // b)):
+        d = _block_draws(spec, table, block)
+        a, z = max(lo, block * b) - block * b, min(hi, (block + 1) * b) \
+            - block * b
+        parts.append(tuple(x[a:z] for x in d))
+    return tuple(np.concatenate([p[i] for p in parts]) for i in range(3))
+
+
+def chunk_columns(spec: StreamSpec, table: KeyTable, chunk: int,
+                  draws) -> dict:
+    """The chunk's flows as the program's column layout (names and
+    dtypes of ``schema.batch.COLUMNS``; addresses [n, 4] uint32)."""
+    rank, nbytes, packets = draws
+    n = spec.chunk_flows
+    idx = chunk * n + np.arange(n, dtype=np.int64)
+    ts = spec.event_ts(idx)
+    z32 = np.zeros(n, np.uint32)
+    cols = {
+        "type": np.full(n, SFLOW_5, np.uint32),
+        "time_received": ts,
+        "sampling_rate": np.full(n, spec.sampling_rate, np.uint64),
+        "sequence_num": (idx & 0xFFFFFFFF).astype(np.uint32),
+        "time_flow_start": ts, "time_flow_end": ts,
+        "bytes": nbytes.astype(np.uint64),
+        "packets": packets.astype(np.uint64),
+        "src_as": table.src_as[rank], "dst_as": table.dst_as[rank],
+        "in_if": z32, "out_if": z32,
+        "proto": table.proto[rank],
+        "src_port": table.src_port[rank], "dst_port": table.dst_port[rank],
+        "ip_tos": z32, "forwarding_status": z32, "ip_ttl": z32,
+        "tcp_flags": z32,
+        "etype": np.full(n, spec.etype, np.uint32),
+        "icmp_type": z32, "icmp_code": z32, "ipv6_flow_label": z32,
+        "flow_direction": z32,
+        "src_addr": table.addr_words(table.src_host[rank]),
+        "dst_addr": table.addr_words(table.dst_host[rank]),
+        "sampler_address": np.zeros((n, 4), np.uint32),
+    }
+    return cols

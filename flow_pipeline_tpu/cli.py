@@ -173,10 +173,37 @@ def _build_models(vals):
                     f"the ring of sub-window states is a single chip's "
                     f"device state")
 
+    # -window.lateness reaches every windowed family: flows_5m emits
+    # late partials, a ranked table and the detector hold the unit that
+    # rolled open for that long (models/held.py). Where a family cannot,
+    # say so here in words and run it at 0 (it drops and counts, as
+    # before): no path changes silently. The mesh honours it; the
+    # host-grouped dataplanes are the worker's to name (it picks them).
+    lateness = vals["window.lateness"]
+    if lateness < 0:
+        raise ValueError(f"-window.lateness must be >= 0, got {lateness}")
+    held = lateness
+    for flag, on, why in (
+            ("-window.slide", slide,
+             "a closed sub-window is in the ring, folded and written"),
+            ("-sketch.backend host",
+             vals.get("sketch.backend", "device") == "host",
+             "sketch state resident in the host engine"),
+            ("-mesh.role", vals.get("mesh.role"),
+             "a member ships a window's state at the roll and the "
+             "coordinator merges it once")):
+        if lateness and on:
+            log.warning(
+                "-window.lateness %d: under %s the ranked tables and the "
+                "detector still drop the rows that arrive after their "
+                "unit rolled, and count them in late_flows_dropped (%s); "
+                "flows_5m honours it", lateness, flag, why)
+            held = 0
+
     models = {}
     if vals["model.flows5m"]:
         cfg = WindowAggConfig(batch_size=batch,
-                              allowed_lateness=vals["window.lateness"])
+                              allowed_lateness=lateness)
         if mesh:
             from .parallel import ShardedWindowAggregator
 
@@ -251,9 +278,10 @@ def _build_models(vals):
 
             return WindowedHeavyHitter(cfg, k=vals["sketch.topk"],
                                        model_cls=ShardedHeavyHitter,
-                                       mesh=mesh, name=name)
+                                       lateness=held, mesh=mesh, name=name)
         return WindowedHeavyHitter(cfg, k=vals["sketch.topk"],
-                                   slide_seconds=slide, slide_name=name)
+                                   slide_seconds=slide, slide_name=name,
+                                   lateness=held)
 
     # top_talkers (5-tuple) + top src/dst IP tables (ref: viz.json "Top
     # source/destination IPs"; per-address windowed HH, one per
@@ -276,22 +304,25 @@ def _build_models(vals):
 
                 models[name] = WindowedHeavyHitter(
                     cfg, k=vals["sketch.topk"],
-                    model_cls=ShardedDenseTopK, mesh=mesh, name=name,
+                    model_cls=ShardedDenseTopK, lateness=held,
+                    mesh=mesh, name=name,
                 )
             else:
                 models[name] = WindowedHeavyHitter(
                     cfg, k=vals["sketch.topk"], model_cls=DenseTopKModel,
-                    slide_seconds=slide, slide_name=name,
+                    slide_seconds=slide, slide_name=name, lateness=held,
                 )
     if vals["model.ddos"]:
         if mesh:
             from .parallel import ShardedDDoSDetector
 
             models["ddos_alerts"] = ShardedDDoSDetector(
-                DDoSConfig(batch_size=batch), mesh, name="ddos_alerts"
+                DDoSConfig(batch_size=batch), mesh, name="ddos_alerts",
+                lateness=held,
             )
         else:
-            models["ddos_alerts"] = DDoSDetector(DDoSConfig(batch_size=batch))
+            models["ddos_alerts"] = DDoSDetector(
+                DDoSConfig(batch_size=batch), lateness=held)
     if vals.get("spread.enabled"):
         # flowspread distinct-count detectors (models/superspreader.py,
         # models/scan.py). Spread state is host-resident numpy u8
@@ -314,9 +345,10 @@ def _build_models(vals):
                       registers=vals["spread.regs"],
                       capacity=vals["spread.capacity"], batch_size=batch)
         models[SUPERSPREADER_MODEL] = superspreader_model(
-            superspreader_config(**sizing), k=vals["spread.topk"])
+            superspreader_config(**sizing), k=vals["spread.topk"],
+            lateness=held)
         models[SCAN_MODEL] = scan_model(
-            scan_config(**sizing), k=vals["spread.topk"])
+            scan_config(**sizing), k=vals["spread.topk"], lateness=held)
     return models
 
 

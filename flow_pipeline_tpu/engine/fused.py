@@ -294,6 +294,10 @@ def _cached_step(hh_specs, dense_cfgs, ddos_cfgs, wagg_cfgs):
 class FusedPipeline(WindowLifecycle):
     """Drives a worker's whole model dict through one jitted step/batch."""
 
+    # -window.lateness: a late group is one more dispatch of the step
+    # with the family's held state in the tuple (WindowLifecycle._units)
+    honours_lateness = True
+
     @staticmethod
     def supported(models: dict[str, Any]) -> bool:
         """True iff every model is a plain single-chip kind this pipeline
@@ -306,7 +310,8 @@ class FusedPipeline(WindowLifecycle):
                 batch_sizes.add(m.config.batch_size)
             elif type(m) is WindowedHeavyHitter and type(m.model) in (
                     HeavyHitterModel, DenseTopKModel, SpreadModel):
-                whh_windows.add((m.window_seconds, m.slot_seconds))
+                whh_windows.add((m.window_seconds, m.slot_seconds,
+                                 m.lateness))
                 batch_sizes.add(m.config.batch_size)
             elif type(m) is DDoSDetector:
                 subs.add(m.config.sub_window_seconds)
@@ -483,15 +488,21 @@ class FusedPipeline(WindowLifecycle):
         # (WindowAggregator._min_slot)
         self._behind = len(batch) >= self._bs
         for slot, sub, part in parts:
-            do_hh = self._advance_hh(slot, len(part))
-            do_dd = self._advance_ddos(sub, len(part))
-            self._run_chunks(part, do_hh, do_dd)
+            hh_unit = self._advance_hh(slot, len(part))
+            dd_unit = self._advance_ddos(sub, len(part))
+            # the states of this part's dispatches: a family's open
+            # unit, or the held one its rows belong to
+            with self._units(hh_unit, dd_unit):
+                self._run_chunks(part, hh_unit, dd_unit)
         for _, m in self._waggs:
             if wm > m.watermark:
                 m.watermark = wm
+        self._advance_watermark(wm)
 
-    def _run_chunks(self, part: FlowBatch, do_hh: bool, do_dd: bool) -> None:
+    def _run_chunks(self, part: FlowBatch, hh_unit: str | None,
+                    dd_unit: str | None) -> None:
         bs = self._bs
+        do_hh, do_dd = hh_unit is not None, dd_unit is not None
         for start in range(0, len(part), bs):
             chunk = part.slice(start, start + bs)
             if do_hh and self._spread:
@@ -519,7 +530,9 @@ class FusedPipeline(WindowLifecycle):
             # one span per device step: their count inside one "apply" is
             # device steps per batch, rows/padded the step's fill
             with TRACER.span("step_dispatch", rows=len(chunk), padded=bs,
-                             do_hh=do_hh, do_dd=do_dd):
+                             do_hh=do_hh, do_dd=do_dd,
+                             hh_unit=hh_unit or "dropped",
+                             dd_unit=dd_unit or "dropped"):
                 new_states, wagg_parts, live_rows = self._step(
                     states, cols, valid,
                     valid if do_hh else zeros,

@@ -236,6 +236,10 @@ def _cached_apply(hh_cfgs: tuple, dense_cfgs: tuple, ddos_cfgs: tuple):
 class HostGroupPipeline(FusedPipeline):
     """FusedPipeline with host (numpy) pre-aggregation — CPU backend."""
 
+    # the prepared tables of a late part go nowhere here (apply()); the
+    # worker says so at start-up and runs the families at lateness 0
+    honours_lateness = False
+
     @staticmethod
     def eligible(mode: str = "auto") -> bool:
         """Whether this pipeline should be picked over engine.fused.

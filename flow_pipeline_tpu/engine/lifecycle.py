@@ -2,9 +2,11 @@
 
 A polled batch is cut where the windowed families' slot or the DDoS
 detector's sub-window changes, and each homogeneous group advances the
-wrapped models' own lifecycle (first slot adopts, a newer slot closes
-and rolls, an older slot counts its rows as late) before the device
-work for it is dispatched. ``engine.fused.FusedPipeline`` (one fused
+wrapped models' own lifecycle (``models/held.py``: first slot adopts, a
+newer slot rolls, which closes the open unit or under
+``-window.lateness`` holds it; rows of the held unit go to its state,
+older ones are counted as late) before the device work for it is
+dispatched, and the batch's watermark then closes what it has passed. ``engine.fused.FusedPipeline`` (one fused
 step a chunk) and ``parallel.pipeline.ShardedPipeline`` (a sharded
 program a model) differ in what a group *runs*, not in how a
 batch is cut: both take the cut and the transitions from here, and
@@ -14,8 +16,11 @@ per-model path.
 
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 
+from ..models.held import HELD
 from ..schema.batch import FlowBatch
 
 
@@ -64,44 +69,44 @@ class WindowLifecycle:
         ]
         return groups, int(t.max())
 
-    def _advance_hh(self, slot: int, n_rows: int) -> bool:
-        """Lockstep WindowedHeavyHitter lifecycle (same transitions as its
-        own update(): first slot adopts, newer slot closes + rolls, older
-        slot drops late rows). Returns False when the group is late."""
-        if not self._whh:
-            return False
-        cur = self._whh[0].current_slot
-        if cur is None:
-            for w in self._whh:
-                w.open(slot)
-            return True
-        if slot > cur:
-            for w in self._whh:
-                w.roll(slot)
-            return True
-        if slot < cur:
-            for w in self._whh:
-                w.late_flows_dropped += n_rows
-            return False
-        return True
+    def _advance_hh(self, slot: int, n_rows: int) -> str | None:
+        """Lockstep WindowedHeavyHitter lifecycle (the transitions of its
+        own update(): ``HeldUnits.admit``). Returns where the group's
+        rows go: OPEN, HELD, or None when the group is late."""
+        unit = None
+        for w in self._whh:
+            unit = w.admit(slot, n_rows)
+        return unit
 
-    def _advance_ddos(self, sub: int, n_rows: int) -> bool:
-        """Lockstep DDoSDetector sub-window lifecycle (close scores the
+    def _advance_ddos(self, sub: int, n_rows: int) -> str | None:
+        """Lockstep DDoSDetector sub-window lifecycle (a close scores the
         OLD sub-window before current_sub advances, as in its update())."""
-        if not self._ddos:
-            return False
-        cur = self._ddos[0][1].current_sub
-        if cur is None:
-            for _, d in self._ddos:
-                d.current_sub = sub
-            return True
-        if sub > cur:
-            for _, d in self._ddos:
-                d.close_sub_window()
-                d.current_sub = sub
-            return True
-        if sub < cur:
-            for _, d in self._ddos:
-                d.late_flows_dropped += n_rows
-            return False
-        return True
+        unit = None
+        for _, d in self._ddos:
+            unit = d.admit(sub, n_rows)
+        return unit
+
+    @contextlib.contextmanager
+    def _units(self, hh_unit: str | None, dd_unit: str | None):
+        """Inside, a family whose group goes to its held unit has that
+        unit's state in the open one's place, so what the pipeline runs
+        reads and writes it there; a late group is one more dispatch of
+        the same programs."""
+        swapped = ([*self._whh] if hh_unit == HELD else []) + (
+            [d for _, d in self._ddos] if dd_unit == HELD else [])
+        for m in swapped:
+            m.swap_held()
+        try:
+            yield
+        finally:
+            for m in swapped:
+                m.swap_held()
+
+    def _advance_watermark(self, wm: int) -> None:
+        """The batch whose newest row is ``wm`` is folded: every held
+        unit the watermark has passed by its lateness closes, in the
+        batch that brought it there (as ``flows_5m``'s windows do)."""
+        for w in self._whh:
+            w.advance_watermark(wm)
+        for _, d in self._ddos:
+            d.advance_watermark(wm)

@@ -23,10 +23,14 @@ import jax
 import numpy as np
 
 from ..models.heavy_hitter import HeavyHitterConfig, HeavyHitterModel
+from ..models.held import HELD, HeldUnits
 from ..models.oracle import SECONDS_PER_SLOT
+from ..obs import get_logger
 from ..obs.trace import TRACER
 from ..schema.batch import FlowBatch
 from .checkpoint import Member
+
+log = get_logger("windowed")
 
 
 class LazyWindowTop:
@@ -147,7 +151,7 @@ class SubWindowRing:
         self._closed_fold, self._view = None, (None, None)
 
 
-class WindowedHeavyHitter:
+class WindowedHeavyHitter(HeldUnits):
     """Windowed top-K: update(batch) per batch; flush() yields rows for
     closed windows. Tumbling: one reset sketch per window. Sliding
     (``slide_seconds`` > 0, a divisor of ``window_seconds``): one reset
@@ -157,12 +161,14 @@ class WindowedHeavyHitter:
     window that reaches back before the first flow is the fold of what
     there is, and one that holds no flow emits nothing. With
     ``slide_seconds`` = ``window_seconds`` (K = 1) the rows are a
-    tumbling window's bit for bit."""
+    tumbling window's bit for bit. ``lateness`` (``-window.lateness``)
+    holds a tumbling window that rolled open for its late rows
+    (``models/held.py``); 0 closes it at the roll, as ever."""
 
     def __init__(self, config: HeavyHitterConfig = HeavyHitterConfig(),
                  window_seconds: int = SECONDS_PER_SLOT, k: int = 100,
                  model_cls=HeavyHitterModel, slide_seconds: int = 0,
-                 slide_name: str = "hh", **model_kw):
+                 slide_name: str = "hh", lateness: int = 0, **model_kw):
         self.config = config
         self.window_seconds = window_seconds
         self.k = k
@@ -209,12 +215,64 @@ class WindowedHeavyHitter:
         # its close, and a sliding window's closed sub-windows are in
         # the ring, folded and (under a checkpoint) written. So rows
         # older than the current slot (the window, or under a slide the
-        # sub-window) are DROPPED and counted — unlike the exact
-        # aggregator, which emits late partials. Size the window and
-        # upstream batching so lateness cannot occur, or monitor this
-        # counter. (The ring is where a late row's sub-window still
-        # lives: ROADMAP B-mech 1.)
-        self.late_flows_dropped = 0
+        # sub-window) and, under a lateness, than the held one before
+        # it are DROPPED and counted in late_flows_dropped — unlike the
+        # exact aggregator, which emits late partials. (The ring is
+        # where a late row's sub-window still lives: it does not hold
+        # one open yet, ROADMAP B-mech 1.)
+        why = self._cannot_hold()
+        if lateness and why:
+            log.warning(
+                "-window.lateness %d: table %s still drops the rows that "
+                "arrive after their window rolled, and counts them in "
+                "late_flows_dropped (%s)", lateness, self.name, why)
+            lateness = 0
+        self._init_held(lateness)
+
+    def _cannot_hold(self) -> str | None:
+        """Why this table cannot hold a rolled window open, in words."""
+        if self.ring is not None:
+            return ("under -window.slide a closed sub-window is in the "
+                    "ring, folded and written")
+        if not hasattr(self.model, "load_window_state"):
+            return (f"{type(self.model).__name__} has no window state to "
+                    f"set aside")
+        if getattr(self.config, "hh_sketch", "table") == "invertible":
+            return "hh_sketch=invertible keeps its planes in the host engine"
+        return None
+
+    # ---- models/held.py's hooks -------------------------------------------
+
+    @property
+    def _unit(self) -> int | None:
+        return self.current_slot
+
+    @property
+    def _unit_seconds(self) -> int:
+        return self.slot_seconds
+
+    def _can_hold(self) -> bool:
+        # a flowmesh member ships a window's state at the roll
+        return self.lateness > 0 and self.capture is None
+
+    def _adopt(self, slot: int) -> None:
+        self.open(slot)
+
+    def _close_open(self) -> None:
+        self._close(self.current_slot)
+
+    def _window_state(self):
+        return self.model.window_state()
+
+    def _load_window_state(self, state) -> None:
+        self.model.load_window_state(state)
+
+    def _reset_window(self) -> None:
+        self.model.reset()
+
+    def _close_held_state(self, slot: int, open_state):
+        self._close(slot, reset=False)
+        return open_state
 
     @property
     def window_start(self) -> int | None:
@@ -240,26 +298,23 @@ class WindowedHeavyHitter:
         if len(batch) == 0:
             return
         # split rows by slot so each sketch covers exactly one (sub-)window
-        slots = (
-            batch.columns["time_received"].astype(np.int64)
-            // self.slot_seconds * self.slot_seconds
-        )
+        times = batch.columns["time_received"].astype(np.int64)
+        slots = times // self.slot_seconds * self.slot_seconds
         for slot in np.unique(slots):
             idx = np.flatnonzero(slots == slot)
             part = FlowBatch(
                 {k: v[idx] for k, v in batch.columns.items()}, batch.partition
             )
-            slot = int(slot)
-            if self.current_slot is None:
-                self.open(slot)
-            elif slot > self.current_slot:
-                self.roll(slot)
-            elif slot < self.current_slot:
-                # late rows for a closed (reset) window: drop, never
-                # misattribute them to the current window's timeslot
-                self.late_flows_dropped += len(part)
-                continue
-            self.model.update(part)
+            # late rows for a closed (reset) window: dropped, never
+            # misattributed to the current window's timeslot
+            unit = self.admit(int(slot), len(part))
+            if unit == HELD:
+                self.swap_held()
+                self.model.update(part)
+                self.swap_held()
+            elif unit is not None:
+                self.model.update(part)
+        self.advance_watermark(int(times.max()))
 
     def open(self, slot: int) -> None:
         """Adopt ``slot`` as the open one (the first rows, or the first
@@ -270,27 +325,22 @@ class WindowedHeavyHitter:
             self._slide_over(self.ring.last_sub + self.slot_seconds, slot)
         self.current_slot = slot
 
-    def roll(self, slot: int) -> None:
-        """Rows of a newer ``slot`` have come: close the open one."""
-        self._close()
-        self.open(slot)
-
-    def _close(self) -> None:
+    def _close(self, slot: int, reset: bool = True) -> None:
+        """Close window ``slot`` from the state the model holds."""
         if self.audit_hook is not None:
-            self.audit_hook(self.current_slot, self.model)
+            self.audit_hook(slot, self.model)
         if self.capture is not None:
             # mesh member: ship the window's raw sketch state; no local
             # row extraction (the coordinator extracts from the merge)
-            self.capture(self.current_slot, self.model)
-            self.model.reset()
-            return
-        if self.ring is not None:
+            self.capture(slot, self.model)
+        elif self.ring is not None:
             state = self.model.window_state()
-            self._emit_slide(self.current_slot, state)
-            self.ring.rotate(self.current_slot, state)
+            self._emit_slide(slot, state)
+            self.ring.rotate(slot, state)
         else:
-            self._emit_window(self.current_slot)
-        self.model.reset()
+            self._emit_window(slot)
+        if reset:
+            self.model.reset()
 
     def _emit_window(self, slot: int) -> None:
         """Queue the rows of the tumbling window ``slot``: one extraction
@@ -340,8 +390,11 @@ class WindowedHeavyHitter:
     def flush(self, force: bool = False) -> list:
         """Rows for closed windows (and the open one too, when force) —
         dicts, or unresolved LazyWindowTop handles under lazy_extract."""
-        if force and self.current_slot is not None:
-            self._close()
-            self.current_slot = None
+        if force:
+            if self.held_unit is not None:
+                self.close_held()
+            if self.current_slot is not None:
+                self._close(self.current_slot)
+                self.current_slot = None
         out, self._pending = self._pending, []
         return out

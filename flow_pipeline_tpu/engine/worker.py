@@ -19,7 +19,7 @@ import numpy as np
 
 from ..families import registry
 from ..guard import GuardConfig, GuardController
-from ..models.ddos import DDoSDetector
+from ..models.ddos import DDoSDetector, DDoSState
 from ..models.heavy_hitter import HHState
 from ..models.window_agg import WindowAggregator, WindowStore
 from ..obs import REGISTRY, get_logger
@@ -247,6 +247,18 @@ class StreamWorker:
                     "-processor.hostassist on); falling back to the "
                     "per-model numpy path for this worker")
                 self.fused = None
+        if self.fused is not None and not getattr(
+                self.fused, "honours_lateness", False):
+            for name, m in models.items():
+                if getattr(m, "lateness", 0):
+                    # no path changes silently: -window.lateness reaches
+                    # flows_5m alone on this dataplane
+                    log.warning(
+                        "-window.lateness %d: on the %s dataplane %s still "
+                        "drops the rows that arrive after their unit "
+                        "rolled, and counts them in late_flows_dropped",
+                        m.lateness, type(self.fused).__name__, name)
+                    m.lateness = 0
         if config.ingest_fused == "on":
             # "on" is a hard requirement everywhere, not just inside the
             # pipeline constructor: any selection-level fallback above
@@ -298,6 +310,8 @@ class StreamWorker:
                         m.lazy_extract = True
         self.batches_seen = 0
         self.flows_seen = 0
+        # flowlint: unguarded -- worker thread only (set and read per _process step)
+        self._newest_ts: dict = {}  # partition -> newest time_received
         # offsets covered by state (committable after next snapshot/flush)
         self._covered: dict[int, int] = {}
         self._emitted_since_snapshot = False
@@ -321,6 +335,11 @@ class StreamWorker:
         self.m_late = REGISTRY.gauge(
             "late_flows_dropped",
             "rows dropped because their sketch window had closed",
+        )
+        self.m_folded = REGISTRY.gauge(
+            "late_flows_folded",
+            "rows that came after their unit rolled and went into it, "
+            "held open for -window.lateness",
         )
         self.m_proc = REGISTRY.summary("flow_processing_time_us",
                                        "per-batch processing time")
@@ -520,12 +539,25 @@ class StreamWorker:
             dropped = getattr(model, "late_flows_dropped", None)
             if dropped:
                 self.m_late.set(dropped, model=name)
+            folded = getattr(model, "late_flows_folded", None)
+            if folded:
+                self.m_folded.set(folded, model=name)
         self.batches_seen += 1
         self.flows_seen += len(batch)
         self.m_flows.inc(len(batch))
         self.m_batches.inc()
         self.m_proc.observe((time.perf_counter() - t0) * 1e6)
         span["rows"] = len(batch)
+        if len(batch):
+            # read only (no close waits on a slower partition): the
+            # watermark the units close by, and how far apart the
+            # partitions' newest event times lie at this batch
+            newest = self._newest_ts
+            t = int(batch.columns["time_received"].max())
+            if t > newest.get(batch.partition, -1):
+                newest[batch.partition] = t
+            span["watermark"] = max(newest.values())
+            span["skew_s"] = span["watermark"] - min(newest.values())
         if batch.last_offset >= 0:
             prev = self._covered.get(batch.partition, 0)
             self._covered[batch.partition] = max(prev, batch.last_offset + 1)
@@ -772,6 +804,11 @@ class StreamWorker:
                 hh_live = getattr(self.fused, "hh_live", None)
                 if hh_live is not None:
                     span.update(hh_live())
+                # units held open for late rows, whose states this
+                # checkpoint carries beside the open ones
+                span["held_units"] = sum(
+                    getattr(m, "held_unit", None) is not None
+                    for m in self.models.values())
         if state is not None:
             # ckpt_d2h, ckpt_serialize, ckpt_write: inside save_checkpoint
             save_checkpoint(self.config.checkpoint_path, state)
@@ -803,12 +840,12 @@ class StreamWorker:
                     fam, "checkpoint_save")(model)
             elif isinstance(model, DDoSDetector):
                 # detector, not a mergeable family (NON_FAMILY_KINDS)
-                models_state[name] = {
+                models_state[name] = _with_held(model, {
                     "kind": "ddos",
                     "state": model.state,
                     "current_sub": model.current_sub,
                     "folds": model.folds,
-                }
+                }, DDoSState._asdict)
         return {
             "covered": {str(k): v for k, v in self._covered.items()},
             "models": models_state,
@@ -848,14 +885,14 @@ class StreamWorker:
             if fam is not None:
                 registry.hook(fam, "checkpoint_restore")(model, ms, name)
             elif ms["kind"] == "ddos":
-                st = ms["state"]
-                from ..models.ddos import DDoSState
+                def ddos_state(st: dict) -> DDoSState:
+                    return DDoSState(
+                        **{k: jnp.asarray(v) for k, v in st.items()})
 
-                model.state = DDoSState(
-                    **{k: jnp.asarray(v) for k, v in st.items()}
-                )
+                model.state = ddos_state(ms["state"])
                 model.current_sub = ms["current_sub"]
                 model.folds = ms["folds"]
+                model.restore_held(ms.get("held"), ddos_state)
         # resume reading from the covered offsets, not the poll position
         for p, off in self._covered.items():
             if hasattr(self.consumer, "positions"):
@@ -953,6 +990,21 @@ def restore_wagg_state(model, ms: dict, name: str) -> None:
     model.watermark = ms["watermark"]
 
 
+def _with_held(model, ms: dict, arrays=None) -> dict:
+    """Under -window.lateness a unit that has rolled stays open for its
+    late rows (models/held.py): a checkpoint taken meanwhile carries its
+    state beside the open one, or the offsets committed after it would
+    cover rows that are in no state and in no sink."""
+    held = model.held_checkpoint(arrays or model.model.state_arrays)
+    if held is not None:
+        ms["held"] = held
+    return ms
+
+
+def _restore_held(model, ms: dict) -> None:
+    model.restore_held(ms.get("held"), model.model.state_from_arrays)
+
+
 def _with_ring(model, ms: dict) -> dict:
     """Under a slide the checkpoint also names the ring's closed
     sub-window states, each a member written once (SubWindowRing)."""
@@ -971,11 +1023,11 @@ def _restore_ring(model, ms: dict, name: str) -> None:
 
 
 def save_hh_state(model) -> dict:
-    return _with_ring(model, {
+    return _with_held(model, _with_ring(model, {
         "kind": "windowed_hh",
         "hh": model.model.state,
         "current_slot": model.current_slot,
-    })
+    }))
 
 
 def restore_hh_state(model, ms: dict, name: str) -> None:
@@ -1017,14 +1069,15 @@ def restore_hh_state(model, ms: dict, name: str) -> None:
         )
     model.current_slot = ms["current_slot"]
     _restore_ring(model, ms, name)
+    _restore_held(model, ms)
 
 
 def save_spread_state(model) -> dict:
-    return {
+    return _with_held(model, {
         "kind": "windowed_spread",
         "spread": model.model.state,
         "current_slot": model.current_slot,
-    }
+    })
 
 
 def restore_spread_state(model, ms: dict, name: str) -> None:
@@ -1042,14 +1095,15 @@ def restore_spread_state(model, ms: dict, name: str) -> None:
         table_metric=np.asarray(sp["table_metric"], dtype=np.float32),
     )
     model.current_slot = ms["current_slot"]
+    _restore_held(model, ms)
 
 
 def save_dense_state(model) -> dict:
-    return _with_ring(model, {
+    return _with_held(model, _with_ring(model, {
         "kind": "windowed_dense",
         "totals": model.model.totals,
         "current_slot": model.current_slot,
-    })
+    }))
 
 
 def restore_dense_state(model, ms: dict, name: str) -> None:
@@ -1060,3 +1114,4 @@ def restore_dense_state(model, ms: dict, name: str) -> None:
     model.model.totals = jnp.asarray(ms["totals"])
     model.current_slot = ms["current_slot"]
     _restore_ring(model, ms, name)
+    _restore_held(model, ms)

@@ -33,6 +33,7 @@ import numpy as np
 
 from ..obs.metrics import REGISTRY
 from ..ops import ewma as ewma_ops
+from .held import HELD, HeldUnits
 from ..ops.quantile import QuantileSketchSpec
 from ..schema.batch import FlowBatch
 
@@ -190,10 +191,19 @@ def ddos_close_window(state: DDoSState, *, config: DDoSConfig, spec: QuantileSke
     return new_state, z, state.rates
 
 
-class DDoSDetector:
-    """Host wrapper: feed batches; sub-windows close on time_received."""
+class DDoSDetector(HeldUnits):
+    """Host wrapper: feed batches; sub-windows close on time_received.
+    ``lateness`` (``-window.lateness``) holds a sub-window that rolled
+    open for its late rows (``models/held.py``): its accumulators
+    (rates, witnesses) are set aside in a state of their own, with the
+    baselines as they stood at the roll, and scored against those at the
+    deferred close, whose folded baselines the open state then takes:
+    sub-window n is always scored before n + 1."""
 
-    def __init__(self, config: DDoSConfig = DDoSConfig()):
+    name = "ddos"  # in the held_close span
+
+    def __init__(self, config: DDoSConfig = DDoSConfig(),
+                 lateness: int = 0):
         self.config = config
         self.spec = QuantileSketchSpec(rel_err=config.rel_err)
         self.state = ddos_init(config, self.spec)
@@ -205,7 +215,7 @@ class DDoSDetector:
         # dropped, mirroring WindowedHeavyHitter: folding them into the
         # CURRENT sub-window would inflate its rates and can fire spurious
         # z-score alerts after a burst of late arrivals.
-        self.late_flows_dropped = 0
+        self._init_held(lateness)
         # entropy anomaly signal (rate_entropy): live value and EW
         # baseline for the last closed sub-window; None until the first
         # close with >=2 active buckets folds the baseline
@@ -223,27 +233,62 @@ class DDoSDetector:
         # Split rows by sub-window (a batch may straddle boundaries; rows
         # must not inflate the wrong window's rates). Row order within the
         # batch is irrelevant to the scatter, so boolean selection is fine.
-        subs = (
-            batch.columns["time_received"].astype(np.int64)
-            // self.config.sub_window_seconds
-            * self.config.sub_window_seconds
-        )
+        times = batch.columns["time_received"].astype(np.int64)
+        subs = (times // self.config.sub_window_seconds
+                * self.config.sub_window_seconds)
         for sub in np.unique(subs):
             idx = np.flatnonzero(subs == sub)
             part = FlowBatch(
                 {k: v[idx] for k, v in batch.columns.items()},
                 batch.partition,
             )
-            sub = int(sub)
-            if self.current_sub is None:
-                self.current_sub = sub
-            elif sub > self.current_sub:
-                self.close_sub_window()
-                self.current_sub = sub
-            elif sub < self.current_sub:
-                self.late_flows_dropped += len(part)
-                continue
-            self._accumulate(part)
+            unit = self.admit(int(sub), len(part))
+            if unit == HELD:
+                self.swap_held()
+                self._accumulate(part)
+                self.swap_held()
+            elif unit is not None:
+                self._accumulate(part)
+        self.advance_watermark(int(times.max()))
+
+    # ---- models/held.py's hooks -------------------------------------------
+
+    @property
+    def _unit(self) -> int | None:
+        return self.current_sub
+
+    @property
+    def _unit_seconds(self) -> int:
+        return self.config.sub_window_seconds
+
+    def _adopt(self, sub: int) -> None:
+        self.current_sub = sub
+
+    def _close_open(self) -> None:
+        self._close_state()
+
+    def _window_state(self) -> DDoSState:
+        return self.state
+
+    def _load_window_state(self, state: DDoSState) -> None:
+        self.state = state
+
+    def _fresh_state(self) -> DDoSState:
+        return ddos_init(self.config, self.spec)
+
+    def _reset_window(self) -> None:
+        # buffers of its own: a step donates the state it is handed, so
+        # the open state may share none with the held one. Its baselines
+        # stay unread until the held sub-window's close hands it theirs
+        self.state = self._fresh_state()
+
+    def _close_held_state(self, sub: int, open_state: DDoSState):
+        open_sub, self.current_sub = self.current_sub, sub
+        self._close_state()  # scores what swap_held put in self.state
+        self.current_sub = open_sub
+        closed = self.state
+        return open_state._replace(mean=closed.mean, var=closed.var,
+                                   seen=closed.seen, hist=closed.hist)
 
     def _accumulate(self, batch: FlowBatch) -> None:
         bs = self.config.batch_size
@@ -256,7 +301,17 @@ class DDoSDetector:
             )
 
     def close_sub_window(self) -> list[dict]:
-        """Score + roll the sub-window; returns (and records) new alerts."""
+        """Score + roll: a held sub-window first, then the open one;
+        returns (and records) new alerts."""
+        before = len(self.alerts)
+        if self.held_unit is not None:
+            self.close_held()
+        self._close_state()
+        return self.alerts[before:]
+
+    def _close_state(self) -> list[dict]:
+        """Score the sub-window ``self.state`` accumulated, fold the
+        baselines, reset the accumulators."""
         self.state, z, rates = ddos_close_window(
             self.state, config=self.config, spec=self.spec
         )

@@ -227,6 +227,9 @@ class DenseTopKModel:
     def window_state(self):
         return self.totals
 
+    def load_window_state(self, totals) -> None:
+        self.totals = totals
+
     def empty_state(self):
         return jnp.zeros_like(self.totals)
 

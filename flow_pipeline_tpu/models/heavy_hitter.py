@@ -490,6 +490,11 @@ class HeavyHitterModel:
     def window_state(self) -> HHState:
         return self.state
 
+    def load_window_state(self, state) -> None:
+        """The state to go on with: a held window's, or the open one's
+        back in its place (``models/held.py``)."""
+        self.state = state
+
     def empty_state(self) -> HHState:
         return hh_init(self.config)
 

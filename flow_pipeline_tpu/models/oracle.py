@@ -10,6 +10,13 @@ semantics exactly (ref: compose/clickhouse/create.sh:92-110):
 This is the ground truth every sketch/device path is gated against
 (BASELINE: <=1% top-K Bytes error vs exact flows_5m). Pure numpy with
 uint64 accumulators — slow is fine, wrong is not.
+
+``late_unit_sums`` is the plain reference of ``-window.lateness`` for the
+families whose closed state cannot reopen (the ranked tables' windows,
+the detector's sub-windows): which rows a unit admits when batches come
+out of event-time order, as from two partitions. It imports nothing of
+the engine; tests hold the per-model path, ``FusedPipeline`` and
+``ShardedPipeline`` to it.
 """
 
 from __future__ import annotations
@@ -115,3 +122,83 @@ def topk_exact(
     g = exact_groupby(batch, key_cols, [value_col], timeslot=timeslot)
     order = np.argsort(-g[value_col].astype(np.int64), kind="stable")[:k]
     return {name: arr[order] for name, arr in g.items()}
+
+
+def late_unit_sums(
+    batches: list[FlowBatch],
+    unit_seconds: int,
+    lateness: int,
+    key_cols: list[str],
+    value_cols: list[str] = ("bytes", "packets"),
+) -> dict:
+    """Which rows each unit of event time admits, exactly.
+
+    ``batches`` come in arrival order, each one partition's poll (its
+    ``partition`` is carried, the semantics do not read it: the
+    watermark is the newest ``time_received`` over all of them). A unit
+    is ``time_received // unit_seconds * unit_seconds``. A batch's rows
+    are taken unit by unit, oldest first:
+
+    - the first unit seen is the open one; a newer unit rolls: with
+      ``lateness`` 0 the open unit closes, else it is *held* (after the
+      unit held until then, if any, has closed) and the newer one opens;
+    - rows of the open unit and of the held one are admitted; any other
+      row is older than both and is dropped;
+    - once a batch is folded the watermark takes its newest row, and the
+      held unit closes if the watermark has reached its end + lateness;
+    - when the stream ends the held unit closes, then the open one.
+
+    Returns ``{"units": {unit: exact_groupby of the rows it admitted},
+    "dropped": rows dropped, "folded": rows admitted into a held unit,
+    "closed_at": {unit: index of the batch that closed it, len(batches)
+    for the end of the stream}, "order": units in closing order}``."""
+    admitted: dict[int, list] = {}
+    closed_at: dict[int, int] = {}
+    order: list[int] = []
+    open_unit = held = watermark = None
+    dropped = folded = 0
+
+    def close(unit, at):
+        closed_at[unit] = at
+        order.append(unit)
+
+    for i, batch in enumerate(batches):
+        if len(batch) == 0:
+            continue
+        t = batch.columns["time_received"].astype(np.int64)
+        units = t // unit_seconds * unit_seconds
+        for unit in np.unique(units).tolist():
+            rows = np.flatnonzero(units == unit)
+            if open_unit is None:
+                open_unit = unit
+            elif unit > open_unit:
+                if held is not None:
+                    close(held, i)
+                    held = None
+                if lateness > 0:
+                    held = open_unit
+                else:
+                    close(open_unit, i)
+                open_unit = unit
+            if unit == held:
+                folded += len(rows)
+            elif unit != open_unit:
+                dropped += len(rows)
+                continue
+            admitted.setdefault(unit, []).append(
+                {k: v[rows] for k, v in batch.columns.items()})
+        watermark = max(int(t.max()), watermark or 0)
+        if held is not None and watermark >= held + unit_seconds + lateness:
+            close(held, i)
+            held = None
+    for unit in (held, open_unit):
+        if unit is not None:
+            close(unit, len(batches))
+    units_out = {}
+    for unit, parts in admitted.items():
+        cols = {k: np.concatenate([p[k] for p in parts]) for k in parts[0]}
+        units_out[unit] = exact_groupby(
+            FlowBatch(cols), list(key_cols), list(value_cols),
+            timeslot=False)
+    return {"units": units_out, "dropped": dropped, "folded": folded,
+            "closed_at": closed_at, "order": order}

@@ -34,13 +34,13 @@ def scan_config(depth: int = 2, width: int = 1 << 12,
 
 def scan_model(config: SpreadConfig | None = None,
                window_seconds: int = SECONDS_PER_SLOT,
-               k: int = 64):
+               k: int = 64, lateness: int = 0):
     """The windowed detector: a WindowedHeavyHitter wrapper over
     SpreadModel with the alert gauge labeled for this detector."""
     from ..engine.windowed import WindowedHeavyHitter
 
     whh = WindowedHeavyHitter(config or scan_config(),
                               window_seconds=window_seconds, k=k,
-                              model_cls=SpreadModel)
+                              model_cls=SpreadModel, lateness=lateness)
     whh.model.metric_label = SCAN_MODEL
     return whh

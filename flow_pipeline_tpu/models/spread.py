@@ -228,3 +228,24 @@ class SpreadModel:
 
     def reset(self) -> None:
         self.state = spread_init(self.config)
+
+    # ---- a window held for its late rows (models/held.py) -------------
+
+    def window_state(self) -> SpreadState:
+        return self.state
+
+    def load_window_state(self, state: SpreadState) -> None:
+        self.state = state
+
+    @staticmethod
+    def state_arrays(state: SpreadState) -> dict:
+        return state._asdict()
+
+    @staticmethod
+    def state_from_arrays(arrays: dict) -> SpreadState:
+        # numpy, NOT jnp: spread state is host-resident by design
+        return SpreadState(
+            regs=np.asarray(arrays["regs"], dtype=np.uint8),
+            table_keys=np.asarray(arrays["table_keys"], dtype=np.uint32),
+            table_metric=np.asarray(arrays["table_metric"],
+                                    dtype=np.float32))

@@ -35,13 +35,13 @@ def superspreader_config(depth: int = 2, width: int = 1 << 12,
 
 def superspreader_model(config: SpreadConfig | None = None,
                         window_seconds: int = SECONDS_PER_SLOT,
-                        k: int = 64):
+                        k: int = 64, lateness: int = 0):
     """The windowed detector: a WindowedHeavyHitter wrapper over
     SpreadModel with the alert gauge labeled for this detector."""
     from ..engine.windowed import WindowedHeavyHitter
 
     whh = WindowedHeavyHitter(config or superspreader_config(),
                               window_seconds=window_seconds, k=k,
-                              model_cls=SpreadModel)
+                              model_cls=SpreadModel, lateness=lateness)
     whh.model.metric_label = SUPERSPREADER_MODEL
     return whh

@@ -113,6 +113,10 @@ class ShardedPipeline(WindowLifecycle):
     """Drives a worker's sharded models through one cut a polled
     batch."""
 
+    # -window.lateness: a held unit is a second set of stacked replicas,
+    # merged over the chips at the deferred close
+    honours_lateness = True
+
     @staticmethod
     def supported(models: dict[str, Any]) -> bool:
         """True iff every model is a sharded kind this pipeline knows,
@@ -125,7 +129,7 @@ class ShardedPipeline(WindowLifecycle):
             elif type(m) is WindowedHeavyHitter and type(m.model) in (
                     ShardedHeavyHitter, ShardedDenseTopK):
                 inner = m.model
-                windows.add(m.window_seconds)
+                windows.add((m.window_seconds, m.lateness))
             elif type(m) is ShardedDDoSDetector:
                 inner = m
                 subs.append(m.config.sub_window_seconds)
@@ -173,17 +177,24 @@ class ShardedPipeline(WindowLifecycle):
         for i in range(len(groups)):
             # what this part runs: (models, rows, watermark)
             run = []
+            hh_unit = dd_unit = None
             if i == 0:
                 run.append((self._waggs, None, wm))
             if i in slot_runs:
                 slot, rows = slot_runs[i]
-                if self._advance_hh(slot, _count(rows, batch)):
+                hh_unit = self._advance_hh(slot, _count(rows, batch))
+                if hh_unit:
                     run.append((self._families, rows, None))
             if i in sub_runs:
                 sub, rows = sub_runs[i]
-                if self._advance_ddos(sub, _count(rows, batch)):
+                dd_unit = self._advance_ddos(sub, _count(rows, batch))
+                if dd_unit:
                     run.append((self._ddos, rows, None))
-            self._dispatch(placed, [r for r in run if r[0]])
+            # a family's programs run on its open replicas, or on the
+            # held ones its rows belong to
+            with self._units(hh_unit, dd_unit):
+                self._dispatch(placed, [r for r in run if r[0]])
+        self._advance_watermark(wm)
 
     def _dispatch(self, placed: _Placed, run: list) -> None:
         """One part's programs over the batch's placements: a

@@ -240,6 +240,18 @@ class ShardedHeavyHitter:
             stack_state(hh.hh_init(self.config), self.n_dev),
         )
 
+    # ---- a window held for its late rows (models/held.py): the stacked
+    # replicas are set aside whole and merged at the deferred close
+
+    def window_state(self) -> hh.HHState:
+        return self.state
+
+    def load_window_state(self, state: hh.HHState) -> None:
+        self.state = state
+
+    state_arrays = staticmethod(hh.HeavyHitterModel.state_arrays)
+    state_from_arrays = staticmethod(hh.HeavyHitterModel.state_from_arrays)
+
 
 # ---------------------------------------------------------------------------
 # Exact window aggregation, sharded
@@ -383,8 +395,9 @@ class ShardedDDoSDetector(ddos_mod.DDoSDetector):
     """
 
     def __init__(self, config: ddos_mod.DDoSConfig = ddos_mod.DDoSConfig(),
-                 mesh: Mesh | None = None, name: str = "ddos"):
-        super().__init__(config)
+                 mesh: Mesh | None = None, name: str = "ddos",
+                 lateness: int = 0):
+        super().__init__(config, lateness)
         self.name = name
         self.mesh = mesh if mesh is not None else make_mesh()
         self.n_dev = self.mesh.devices.size
@@ -443,13 +456,16 @@ class ShardedDDoSDetector(ddos_mod.DDoSDetector):
                 check_vma=False,
             )
         )
-        # re-stack the single-chip init state onto the device axis
+        self.state = self._fresh_state()
+
+    def _fresh_state(self) -> ddos_mod.DDoSState:
+        # the single-chip init state re-stacked onto the device axis
         sharding = NamedSharding(self.mesh, P(DATA_AXIS))
-        self.state = jax.tree.map(
+        return jax.tree.map(
             lambda x: jax.device_put(
                 jnp.broadcast_to(x[None], (self.n_dev,) + x.shape), sharding
             ),
-            self.state,
+            super()._fresh_state(),
         )
 
     @property
@@ -465,7 +481,7 @@ class ShardedDDoSDetector(ddos_mod.DDoSDetector):
         sub-window lifecycle and ``valid`` masks the rows of this one."""
         self.state = self._acc(self.state, cols, valid)
 
-    def close_sub_window(self) -> list[dict]:
+    def _close_state(self) -> list[dict]:
         s = self.state
         with TRACER.span("mesh_merge", model=self.name,
                          bytes=s.rates.nbytes + s.wmax.nbytes

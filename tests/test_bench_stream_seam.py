@@ -1,0 +1,478 @@
+"""The seam the two-partition cell stands on, in tier-1 (PR 38's cases that
+need no subprocess, copied from ``benchmark/tests/test_stream_seam.py``,
+``test_manifest.py`` and ``test_reference.py``, which tier-1 does not
+collect: PERF.md 7), and ISSUE 39's own: the stream kind
+``zipf-ranks-delayed`` is ``zipf-ranks`` byte for byte but for a flow's
+event time and partition, and ``estate-2part`` is ``default-estate`` but
+for the keys its file lists."""
+
+import hashlib
+import json
+import os
+import types
+
+import numpy as np
+import pytest
+
+from benchmark import check, drive, flowgen, manifest, schedule
+from benchmark.modes import backlog
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FIXTURES = os.path.join(ROOT, "benchmark", "tests", "fixtures")
+REAL = os.path.join(ROOT, "BENCHMARK.json")
+TINY_STREAM = "benchmark/tests/fixtures/BENCHMARK.tiny-stream.json"
+PATHS = ["benchmark", "benchmark/tests/fixtures"]
+with open(os.path.join(FIXTURES, "zipf_ranks_digests.json")) as f:
+    # chunk_blob of chunks 0, 1 and the last of every cell at 51 s, two
+    # seeds, by the parent of PR 38 (2a8a86d) before the first edit
+    DIGESTS = json.load(f)
+
+
+# ---- a kind is a file, found by name ---------------------------------------
+
+
+def _stream(**changed):
+    with open(os.path.join(ROOT, "benchmark/configs/default-estate.json")) \
+            as f:
+        return manifest.load_stream(ROOT, PATHS,
+                                    {**json.load(f)["stream"], **changed})
+
+
+def test_a_configuration_without_the_key_gets_zipf_ranks():
+    stream = _stream()
+    assert "kind" not in stream
+    assert stream.path == os.path.join(ROOT, "benchmark", "streams",
+                                       "zipf-ranks.py")
+    assert stream.kind.StreamSpec is flowgen.StreamSpec  # loaded once
+
+
+def test_an_unknown_stream_key_is_an_error_that_names_it():
+    with pytest.raises(ValueError, match=r"no key \['attack_share'\]"):
+        _stream(attack_share=0.1).spec(1, 4096, 0)
+    toy = manifest.load_cell(ROOT, os.path.join(ROOT, TINY_STREAM),
+                             "tiny-stream-2part-catchup").stream
+    with pytest.raises(ValueError, match=r"unknown keys \['alpha_2'\]"):
+        toy.with_params(alpha_2=1.0).spec(1, 4096, 0)
+
+
+def test_a_missing_kind_file_names_the_paths_searched():
+    with pytest.raises(FileNotFoundError) as e:
+        _stream(kind="no-such-kind")
+    assert "no-such-kind.py" in str(e.value)
+    assert all(p in str(e.value) for p in PATHS)
+
+
+def test_a_kind_that_lacks_part_of_the_api_is_refused(tmp_path):
+    os.makedirs(tmp_path / "streams")
+    with open(tmp_path / "streams" / "half.py", "w") as f:
+        f.write("def spec(*a): return None\n"
+                "def key_table(s): return None\n")
+    with pytest.raises(TypeError, match="defines no chunk_draws"):
+        manifest.load_stream(str(tmp_path), ["."], {"kind": "half"})
+    with open(tmp_path / "streams" / "thin.py", "w") as f:
+        f.write("import types\n"
+                "def spec(*a): return types.SimpleNamespace(seed=1)\n"
+                "key_table = chunk_draws = chunk_columns = spec\n")
+    thin = manifest.load_stream(str(tmp_path), ["."], {"kind": "thin"})
+    with pytest.raises(TypeError, match="max_disorder_s"):
+        thin.spec(1, 0, 0)
+
+
+def test_a_kind_under_a_fixture_path_is_found_after_the_benchmarks_own():
+    cell = manifest.load_cell(ROOT, os.path.join(ROOT, TINY_STREAM),
+                              "tiny-stream-2part-catchup")
+    assert cell.stream.path.endswith(os.path.join(
+        "tests", "fixtures", "streams", "toy-mixed.py"))
+    assert cell.stream["kind"] == "toy-mixed"
+    spec = cell.stream.spec(7, 4096, 0)
+    assert spec.max_disorder_s == 2 == cell.config["close_lateness_s"]
+    table = cell.stream.kind.key_table(spec)
+    assert set(np.unique(table.etype)) == {0x0800, 0x86DD}
+    assert len(table) == 2000 == len(table.src_addr)
+
+
+# ---- zipf-ranks makes what flowgen.py made ---------------------------------
+
+
+@pytest.mark.parametrize("at", sorted(DIGESTS))
+def test_zipf_ranks_makes_the_parents_bytes(at):
+    name, seed = at.split(":")
+    cell = manifest.load_cell(ROOT, REAL, name)
+    plan = cell.mode.plan(cell.traffic, cell.stream, 51.0)
+    spec = schedule.spec_for(int(seed), cell.stream, plan)
+    table = cell.stream.kind.key_table(spec)
+    want = DIGESTS[at]
+    assert want["chunks"][-1] == plan.total_flows // spec.chunk_flows - 1
+    frames, draws = hashlib.sha256(), hashlib.sha256()
+    for c in want["chunks"]:
+        blob, drawn = flowgen.chunk_blob(cell.stream.kind, spec, table, c)
+        frames.update(blob)
+        for d in drawn:
+            draws.update(d.dtype.str.encode())
+            draws.update(d.tobytes())
+    assert frames.hexdigest() == want["frames"]
+    assert draws.hexdigest() == want["draws"]
+
+
+def test_zipf_ranks_in_order_on_one_partition_keeps_nothing():
+    deal = drive.Deal(_stream().spec(3, 4096, 0), 1, 10**9)
+    assert deal._positions is None  # a position is its own offset
+    assert deal.offsets(0, 17, 99) == (17, 99)
+    assert deal.consumed([12]).tolist() == list(range(12))
+    assert deal.beyond([40], 30, 50) == 10
+
+
+# ---- what was consumed, on three partitions ---------------------------------
+
+
+class _ByThree:
+    """A spec that deals position i to partition (i * i) mod 3: uneven
+    (partition 2 gets nothing)."""
+    chunk_flows, slot_seconds, max_disorder_s = 8, 300, 0
+
+    def partition_of(self, idx, partitions):
+        return (idx * idx) % partitions
+
+    def event_ts(self, idx):
+        return (1_700_000_100 + idx // 4).astype(np.uint64)
+
+    def close_flows(self, lo, hi):
+        return []
+
+
+def test_consumed_set_arithmetic_on_three_partitions_with_uneven_offsets():
+    deal = drive.Deal(_ByThree(), 3, 30)
+    mine = [[i for i in range(30) if (i * i) % 3 == p] for p in range(3)]
+    assert [deal.positions(p, 0, 30).tolist() for p in range(3)] == mine
+    assert mine[2] == [] and len(mine[0]) == 10 and len(mine[1]) == 20
+    # offsets of the flows at positions [7, 19): by search, not by count
+    assert [deal.offsets(p, 7, 19) for p in range(3)] == [(3, 7), (4, 12),
+                                                          (0, 0)]
+    assert [deal.offsets(p, 7, 19) for p in range(3)] == [
+        tuple(sum(i < edge for i in mine[p]) for edge in (7, 19))
+        for p in range(3)]
+    assert [x.tolist() for x in deal.split(7, 19)] == [
+        [2, 5, 8, 11], [0, 1, 3, 4, 6, 7, 9, 10], []]
+    # folded up to offsets 4, 9 and 0: the union of three prefixes
+    got = deal.consumed([4, 9, 0])
+    assert got.tolist() == sorted(mine[0][:4] + mine[1][:9])
+    # of the flows [7, 19): those at or past each partition's offset
+    assert deal.beyond([4, 9, 0], 7, 19) == len(
+        [i for i in range(7, 19) if i not in set(got.tolist())])
+    run = types.SimpleNamespace(
+        deal=deal, spec=_ByThree(), final={"folded": [4, 9, 0]},
+        draws=[tuple(np.arange(8 * c, 8 * c + 8) * k for k in (1, 2, 3))
+               for c in range(4)])
+    idx, rank, nbytes, packets = check.consumed_draws(run)
+    assert idx.tolist() == got.tolist()
+    assert (rank == idx).all() and (nbytes == 2 * idx).all() \
+        and (packets == 3 * idx).all()
+
+
+def test_a_stream_that_deals_past_the_bus_is_refused():
+    class Wide(_ByThree):
+        def partition_of(self, idx, partitions):
+            return idx % (partitions + 1)
+
+    with pytest.raises(drive.Abort, match="the bus has 3"):
+        drive.Deal(Wide(), 3, 30)
+
+
+def test_produce_deals_each_frame_to_its_partition_in_offset_order():
+    class Bus:
+        def __init__(self):
+            self.logs = {p: [] for p in range(3)}
+
+        def produce_many(self, topic, values, partition=None):
+            self.logs[partition].extend(values)
+
+    bus = Bus()
+    run = types.SimpleNamespace(
+        spec=_ByThree(), deal=drive.Deal(_ByThree(), 3, 32),
+        frames=[tuple(b"%d" % i for i in range(8 * c, 8 * c + 8))
+                for c in range(4)],
+        sut=types.SimpleNamespace(bus=bus, topic="t"))
+    for lo, hi in ((0, 5), (5, 6), (6, 21), (21, 32)):
+        drive.produce(run, lo, hi)
+    assert run.frames == [None] * 4
+    for p in range(3):
+        assert bus.logs[p] == [b"%d" % i for i in
+                               run.deal.positions(p, 0, 32).tolist()]
+
+
+def _spans(*fetches):
+    return types.SimpleNamespace(spans=types.SimpleNamespace(spans=[
+        ("bus_fetch", t - 0.001, t, 0, meta) for t, meta in fetches]))
+
+
+def test_the_fetch_position_is_the_count_over_all_partitions():
+    scan = drive.FetchScan(_spans(
+        (1.0, (0, 0, 10)), (2.0, None), (3.0, (1, 0, 4)), (4.0, (0, 10, 5)),
+        (5.0, (1, 4, 6))))
+    assert scan.new() == [(1.0, 0, 0, 10, 10), (3.0, 1, 0, 4, 14),
+                          (4.0, 0, 10, 5, 19), (5.0, 1, 4, 6, 25)]
+    assert scan.new() == []
+
+
+def test_backlog_aborts_at_the_summed_position(monkeypatch):
+    """Two partitions of 50 flows each: the run is dry when the fetches
+    of both have taken 100 between them, not when one has reached its
+    own end."""
+    plan = types.SimpleNamespace(window_start_flow=20, total_flows=100,
+                                 seconds=60.0)
+    run = _spans()
+    coming = _spans((1.0, (0, 0, 20)), (2.0, (1, 0, 30)), (3.0, (0, 20, 30)),
+                    (4.0, (1, 30, 20))).spans.spans
+    run.__dict__.update(
+        plan=plan, spec=types.SimpleNamespace(chunk_flows=10), error=None,
+        traced=False, cell=types.SimpleNamespace(traffic={"run_in_chunks": 1}),
+        sut=types.SimpleNamespace(worker=types.SimpleNamespace(
+            flows_seen=10**9)))
+
+    def wait(run, cond, what, poll=0.0):
+        """A fetch returns between two looks of the mode."""
+        while not cond():
+            if not coming:
+                raise AssertionError(f"still waiting for {what}")
+            run.spans.spans.append(coming.pop(0))
+
+    monkeypatch.setattr(drive, "wait", wait)
+    monkeypatch.setattr(drive, "generate", lambda *a: None)
+    monkeypatch.setattr(drive, "produce", lambda *a: None)
+    with pytest.raises(drive.Abort, match=r"ran dry 2\.00 s into the "
+                                          r"window, at 25 flows/s"):
+        backlog.control(run, None)
+    # the window opened at the fetch that began at or past 20 flows taken
+    assert (run.t_a, run.pos_a) == (2.0, 50)
+    assert not coming  # partition 0 reached its own end at 3.0: not dry
+
+
+# ---- every cell's stream is a kind with the whole API (test_manifest.py) ----
+
+
+def _cells() -> list:
+    with open(REAL) as f:
+        return [w["name"] for w in json.load(f)["workloads"]]
+
+
+@pytest.mark.parametrize("name", _cells())
+def test_every_cell_loads_a_stream_kind_with_the_whole_api(name):
+    c = manifest.load_cell(ROOT, REAL, name)
+    assert all(callable(getattr(c.stream.kind, a))
+               for a in manifest.STREAM_API)
+    spec = c.stream.spec(1, 65536, 0)
+    assert all(hasattr(spec, a) for a in manifest.SPEC_API)
+    if name == CELL_2PART:
+        assert os.path.basename(c.stream.path) == "zipf-ranks-delayed.py"
+        assert spec.max_disorder_s == 3 and c.config["bus_partitions"] == 2
+    else:
+        # no other configuration names a stream kind: each gets
+        # zipf-ranks, and drives the one partition it states
+        assert "kind" not in c.config["stream"]
+        assert os.path.basename(c.stream.path) == "zipf-ranks.py"
+        assert spec.max_disorder_s == 0 and c.config["bus_partitions"] == 1
+
+
+# ---- the reference groups by slot under disorder (test_reference.py) ---------
+
+
+def test_slot_sums_group_by_slot_where_event_time_runs_backwards():
+    """A kind that declares disorder: a slot's flows are no run of
+    positions, and the sums are those of a flow-by-flow count over a
+    scattered set of positions."""
+    import dataclasses
+
+    from benchmark.reference import Reference
+
+    kind = _stream().kind
+    base = kind.StreamSpec(seed=11, n_keys=300, event_rate=20,
+                           chunk_flows=2048, first_close_flow=4096)
+
+    class Jittered(type(base)):
+        max_disorder_s = 40
+
+        def event_ts(self, idx):
+            back = (idx.astype(np.int64) * 7919) % (self.max_disorder_s + 1)
+            return (type(base).event_ts(self, idx).astype(np.int64)
+                    - back).astype(np.uint64)
+
+    spec = Jittered(**dataclasses.asdict(base))
+    table = kind.KeyTable(spec)
+    rank, nbytes, packets = kind.chunk_draws(spec, table, 2)
+    lo = 2 * spec.chunk_flows  # the first flow of the slot at boundary_ts
+    idx = lo + np.flatnonzero(np.arange(2048) % 3 != 1)  # two of three
+    rank, nbytes, packets = rank[idx - lo], nbytes[idx - lo], \
+        packets[idx - lo]
+    slot = spec.event_ts(idx).astype(np.int64) // 300 * 300
+    assert len(np.unique(slot)) == 2
+    assert (np.diff(slot) < 0).any()  # no runs
+    got = Reference(spec, table).slot_sums(idx, rank, nbytes, packets)
+    want: dict = {}
+    for s, r, b, p in zip(slot.tolist(), rank.tolist(), nbytes.tolist(),
+                          packets.tolist()):
+        tot = want.setdefault(s, np.zeros((3, len(table)), np.uint64))
+        tot[:, r] += np.array([b, p, 1], np.uint64)
+    assert set(got) == set(want)
+    for s in want:
+        assert all((g == w).all() for g, w in zip(got[s], want[s]))
+
+
+# ---- ISSUE 39: zipf-ranks-delayed and estate-2part ---------------------------
+
+CELL_2PART = "estate-2part-catchup"
+SEEDS = [2**31 + 11, 3700001001]
+
+
+def _both(seed: int):
+    """(zipf-ranks' spec, the delayed kind's spec, each with its kind) for
+    the two cells' plans at 51 s."""
+    out = []
+    for name in ("estate-catchup", CELL_2PART):
+        cell = manifest.load_cell(ROOT, REAL, name)
+        plan = cell.mode.plan(cell.traffic, cell.stream, 51.0)
+        out.append((cell.stream.kind,
+                    schedule.spec_for(seed, cell.stream, plan), plan))
+    return out
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_the_delayed_kind_is_zipf_ranks_but_for_time_and_partition(seed):
+    (plain, spec_a, plan_a), (delayed, spec_b, plan_b) = _both(seed)
+    assert plan_a == plan_b  # the same traffic file lays the same closes
+    ta, tb = plain.key_table(spec_a), delayed.key_table(spec_b)
+    for col in ("src_host", "dst_host", "src_port", "dst_port", "proto",
+                "src_as", "dst_as", "cdf"):
+        assert (getattr(ta, col) == getattr(tb, col)).all(), col
+    last = plan_a.total_flows // spec_a.chunk_flows - 1
+    for chunk in (0, 1, 107, last):
+        da = plain.chunk_draws(spec_a, ta, chunk)
+        db = delayed.chunk_draws(spec_b, tb, chunk)
+        for x, y in zip(da, db):
+            assert x.dtype == y.dtype and (x == y).all()
+        ca = plain.chunk_columns(spec_a, ta, chunk, da)
+        cb = delayed.chunk_columns(spec_b, tb, chunk, db)
+        assert list(ca) == list(cb)
+        timed = {"time_received", "time_flow_start", "time_flow_end"}
+        for name in ca:
+            assert ca[name].dtype == cb[name].dtype
+            if name not in timed:
+                assert (ca[name] == cb[name]).all(), name
+        behind = ca["time_received"].astype(np.int64) \
+            - cb["time_received"].astype(np.int64)
+        assert set(np.unique(behind)) <= {0, 1, 2, 3}
+        assert (cb["time_flow_start"] == cb["time_received"]).all()
+    assert spec_a.close_flows(0, plan_a.total_flows) \
+        == spec_b.close_flows(0, plan_b.total_flows)
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_a_tenth_of_the_flows_lie_one_to_three_seconds_behind(seed):
+    _, (kind, spec, plan) = _both(seed)
+    idx = np.arange(262144, 262144 + 2_000_000)
+    clock = spec._clock(idx)
+    behind = clock - spec.event_ts(idx).astype(np.int64)
+    share = (behind > 0).mean()
+    assert 0.097 < share < 0.103
+    counts = np.bincount(behind, minlength=4)[1:]
+    assert len(counts) == 3 and counts.min() > 0.3 * counts.sum()
+    # never a flow whose clock is the first second of a slot: the slot
+    # opens at the same position whatever the seed
+    closes = spec.close_flows(0, plan.total_flows)
+    assert closes[0] == spec.first_close_flow and len(closes) >= 4
+    for close in closes[1:]:
+        second = np.arange(close, close + spec.event_rate)
+        assert (spec.event_ts(second) == spec._clock(second)).all()
+        assert spec._clock(second[:1])[0] % spec.slot_seconds == 0
+    # the warm-up's close opens its slot phase_s seconds in: a delay of
+    # 3 s stays inside the slot
+    assert spec.phase_s >= spec.delay_s_max
+    for close in closes:
+        slot = spec.event_ts(np.arange(close - 200_000, close + 200_000)
+                             ).astype(np.int64) // spec.slot_seconds
+        assert (slot[:200_000] < slot[200_000]).all()
+        # and some that follow it lie back in the slot before: what the
+        # lateness is for
+        late = np.flatnonzero(slot[200_000:] < slot[200_000])
+        if close == closes[0]:
+            assert not len(late)
+        else:
+            assert 0 < len(late) < 0.1 * 200_000
+            assert late.max() < 3 * spec.event_rate
+    # dealt round-robin, as upstream's keyless producer deals
+    assert (spec.partition_of(idx, 2) == idx % 2).all()
+    # another seed delays other flows
+    other = kind.spec(seed + 1, {k: v for k, v in _stream_2part().items()},
+                      spec.first_close_flow, spec.phase_s)
+    assert (other.event_ts(idx) != spec.event_ts(idx)).mean() > 0.15
+
+
+def _stream_2part() -> dict:
+    with open(os.path.join(ROOT, "benchmark/configs/estate-2part.json")) as f:
+        return json.load(f)["stream"]
+
+
+def test_the_delayed_kind_names_a_key_it_does_not_know():
+    stream = manifest.load_stream(ROOT, PATHS, _stream_2part())
+    with pytest.raises(ValueError, match=r"zipf-ranks-delayed has no key "
+                                         r"\['delay_ms'\]"):
+        stream.with_params(delay_ms=5).spec(1, 4096, 0)
+    with pytest.raises(ValueError, match="delayed_share"):
+        stream.with_params(delayed_share=1.5).spec(1, 4096, 0)
+
+
+def test_estate_2part_is_default_estate_but_for_what_its_file_lists():
+    with open(os.path.join(ROOT, "benchmark/configs/default-estate.json")) \
+            as f:
+        base = json.load(f)
+    with open(os.path.join(ROOT, "benchmark/configs/estate-2part.json")) as f:
+        cfg = json.load(f)
+    with open(REAL) as f:
+        (entry,) = [c for c in json.load(f)["configs"]
+                    if c["name"] == "estate-2part"]
+    assert entry["reduced"] == ["scale"] == list(cfg["reduced"])
+    assert entry["source"] == cfg["source"] and len(entry["source"]) <= 200
+    assert cfg["bus_partitions"] == 2 and cfg["close_lateness_s"] == 7
+    flags = dict(zip(cfg["processor_flags"][::2], cfg["processor_flags"][1::2]))
+    was = dict(zip(base["processor_flags"][::2], base["processor_flags"][1::2]))
+    assert flags == {**was, "-window.lateness": "7"}
+    assert cfg["stream"] == {"kind": "zipf-ranks-delayed", **base["stream"],
+                             "delayed_share": 0.1, "delay_s_max": 3}
+    # the shapes, the checks and their limits are default-estate's
+    for same in ("chips", "topic", "checks", "sink_rows_per_window",
+                 "close_table", "flags_added_by_the_harness"):
+        assert cfg[same] == base[same], same
+    assert cfg["reduced"]["scale"] == base["reduced"]["scale"]
+    assert {k: v for k, v in cfg["guarantees"].items()
+            if k != "late_rows"} == base["guarantees"]
+    assert "late_flows_dropped 0" in cfg["guarantees"]["late_rows"]
+    changed = set(cfg["changed_from_default_estate"])
+    assert {"bus_partitions", "stream.kind", "close_lateness_s"} <= changed
+
+
+def test_the_benchmark_stays_inside_its_limits():
+    """128 per-layer metrics at most, names of 64 characters, a `why` of
+    200: a file outside them is refused before a run."""
+    with open(REAL) as f:
+        man = json.load(f)
+    assert len(man["per_layer"]) <= 128 and len(man["workloads"]) <= 24
+    assert os.path.getsize(REAL) <= 64 * 1024
+    for group in ("configs", "workloads", "per_layer", "end_to_end"):
+        names = [e["name"] for e in man[group]]
+        assert len(names) == len(set(names))
+        assert all(len(n) <= 64 for n in names)
+    for e in man["configs"] + man["workloads"]:
+        assert 1 <= len(e["why"]) <= 200 and "\n" not in e["why"]
+    (cell,) = [w for w in man["workloads"] if w["name"] == CELL_2PART]
+    assert cell == {"name": CELL_2PART, "config": "estate-2part",
+                    "traffic": "backlog-drain", "chips": 1,
+                    "why": cell["why"]}
+    listed = [e["name"] for e in man["per_layer"]
+              if CELL_2PART in e.get("workloads", [])]
+    assert listed == [
+        "backlog_left_share", "split_parts_ms_p50",
+        "device_steps_per_batch.2part", "batch_fill_share.2part",
+        "step_device_ms_p50.2part", "fused_step_roofline.2part",
+        "batch_period_ms_p50.2part", "checkpoint_raw_mb_p50.2part",
+        "late_rows_folded_share", "late_rows_dropped",
+        "held_close_delay_ms_p50", "held_units_at_checkpoint_p50",
+        "partition_skew_s_p50"]

@@ -28,6 +28,8 @@ TINY = "benchmark/tests/fixtures/BENCHMARK.tiny.json"
 TINY_AS = "benchmark/tests/fixtures/BENCHMARK.tiny-as.json"
 # and estate-sliding-catchup's (ISSUE 33)
 TINY_SLIDING = "benchmark/tests/fixtures/BENCHMARK.tiny-sliding.json"
+# and estate-2part-catchup's (ISSUE 39)
+TINY_2PART = "benchmark/tests/fixtures/BENCHMARK.tiny-2part.json"
 
 
 class Cell(NamedTuple):
@@ -54,6 +56,9 @@ AS_CELL = "tiny-as-catchup"
 # -window.slide 30 at the tiny size: run once, traced, with both controls
 SLIDING_CELL = "tiny-sliding-catchup"
 SLIDING_KIND = "ranked_bytes_sliding"
+# two partitions, flows out of order, -window.lateness: run once, traced,
+# with both controls
+TWOPART_CELL = "tiny-2part-catchup"
 # the live and the four-chip twin, traced too (ISSUE 35: the spans of the
 # serve path and of a publish of four stacked replicas)
 LIVE_CELL, MESH_CELL = "tiny-live", "tiny-mesh4-catchup"
@@ -64,13 +69,17 @@ TRACED = {TRACED_CELL: CELLS[TRACED_CELL],
                         0, TINY_AS),
           SLIDING_CELL: Cell("estate-sliding-catchup", "FusedPipeline",
                              2**31 + 11, 0, TINY_SLIDING,
-                             f"bf16,bf16:{SLIDING_KIND}")}
+                             f"bf16,bf16:{SLIDING_KIND}"),
+          TWOPART_CELL: Cell("estate-2part-catchup", "FusedPipeline",
+                             2**31 + 11, 0, TINY_2PART,
+                             "bf16,bf16:ranked_bytes")}
 # they read the `XLA Modules` line of a /device:TPU plane: a CPU trace has
 # none, and a CPU number never goes under a device metric's name
 TPU_PLANE_ONLY = ("step_device_ms_p50", "fused_step_roofline",
                   "step_device_ms_p50.as64k", "fused_step_roofline.as64k",
                   "step_device_ms_p50.sliding", "fused_step_roofline.sliding",
-                  "slide_fold_device_ms_per_slide", "slide_fold_roofline")
+                  "slide_fold_device_ms_per_slide", "slide_fold_roofline",
+                  "step_device_ms_p50.2part", "fused_step_roofline.2part")
 
 
 # ISSUE 35's readers of the program's spans inside the layers that only
@@ -213,7 +222,7 @@ def test_traced_dry_run_reads_every_layer_metric(dry_run, cell, metric):
         assert math.isfinite(line["metrics"][metric]["value"])
 
 
-@pytest.mark.parametrize("cell", [AS_CELL, SLIDING_CELL])
+@pytest.mark.parametrize("cell", [AS_CELL, SLIDING_CELL, TWOPART_CELL])
 def test_a_twin_is_the_ledgers_cell_at_the_tiny_size(cell):
     """Every per-layer metric the ledger's cell reports, and no other:
     the fixture's own in the ledger's order, then those added since."""
@@ -269,7 +278,7 @@ def test_an_inside_metric_lists_the_cells_that_have_its_span(metric):
         return
     assert entry["moves"] == "sustained_flows_per_s"
     if metric == "split_parts_ms_p50":  # the fused pipeline's cut
-        assert entry["workloads"] == ONE_CHIP
+        assert entry["workloads"] == [*ONE_CHIP, TRACED[TWOPART_CELL].ledger]
     elif metric == "close_extract_ms_per_close":  # a tumbling close
         assert sorted(entry["workloads"]) == sorted(
             c for c in ALL_LEDGER_CELLS if c != "estate-sliding-catchup")
@@ -277,7 +286,12 @@ def test_an_inside_metric_lists_the_cells_that_have_its_span(metric):
         assert entry["workloads"] == ALL_LEDGER_CELLS
 
 
-@pytest.mark.parametrize("cell", TRACED)
+# the two-partition twin lists its ledger cell's metrics and no other:
+# of ISSUE 35's and 37's it has the cut's alone
+TILED = [c for c in TRACED if c != TWOPART_CELL]
+
+
+@pytest.mark.parametrize("cell", TILED)
 def test_the_inside_tiles_sum_under_their_outside_span(dry_run, cell):
     """Counts and order, not rates: a tile is never longer than what it
     tiles, and the hook round ``_write_rows`` holds every sink's part."""
@@ -310,7 +324,7 @@ def test_the_live_share_is_listed_for_the_fused_steps_cells():
         "moves": "sustained_flows_per_s", "workloads": ONE_CHIP}
 
 
-@pytest.mark.parametrize("cell", TRACED)
+@pytest.mark.parametrize("cell", TILED)
 def test_a_traced_line_says_what_share_of_the_slots_is_live(dry_run, cell):
     """Read from the checkpoints of the window wherever the fused step
     runs; a per-layer metric, so an untraced line has none, and the
@@ -336,7 +350,8 @@ def test_the_live_twin_carries_a_flows_age_to_the_snapshot(dry_run):
     assert v["publish_period_s_p50.live"] > 0
     assert v["publish_view_ms_p50"] + v["publish_swap_ms_p50"] \
         <= v["publish_ms_p50"] * 1.05 + 1.0
-    for cell in (TRACED_CELL, MESH_CELL, AS_CELL, SLIDING_CELL):
+    for cell in (TRACED_CELL, MESH_CELL, AS_CELL, SLIDING_CELL,
+                 TWOPART_CELL):
         assert not [m for m in _values(dry_run, cell) if m in INSIDE_METRICS
                     and m.endswith(".live")]
 
@@ -380,5 +395,45 @@ def test_the_sliding_twins_controls_come_out_not_correct(dry_run, control):
     failed = {c["name"] for c in found["checks"] if not c["ok"]}
     assert "topk_bytes_max_rel_err" in failed
     assert "slide_windows_missing" not in failed
+    if ":" in control:
+        assert failed == {"topk_bytes_max_rel_err"}
+
+
+def test_the_2part_twin_folds_its_late_rows_on_the_fused_path(dry_run):
+    """Two partitions dealt round-robin, a tenth of the flows 1-3 s
+    behind, `-window.lateness 9` through cli.processor_main: the harness
+    counts thousands of flows that arrive after their slot rolled, no
+    family drops one, every table is the reference's over exactly the
+    flows consumed on both partitions, and the spans say the held units
+    did the work."""
+    line = _result(dry_run, TWOPART_CELL, trace=1)
+    assert line["correct"] is True and line["failed"] == 0
+    window = line["window"]
+    assert window["dataplane"] == TRACED[TWOPART_CELL].dataplane
+    assert window["late_expected"] > 0
+    assert set(window["late_by_model"].values()) == {0}
+    assert len(window["folded"]) == 2 and min(window["folded"]) > 0
+    assert window["folded"] == window["committed"]
+    checks = {c["name"]: c["value"] for c in line["checks"]}
+    assert checks["topk_bytes_max_rel_err"] < 1e-5
+    assert checks["commits_ahead_of_flush"] == 0
+    value = {name: m["value"] for name, m in line["metrics"].items()}
+    assert value["compiles_in_window"] == 0
+    assert value["late_rows_dropped"] == 0
+    assert 0 < value["late_rows_folded_share"] < 50
+    assert value["device_steps_per_batch.2part"] > 1
+    assert value["batch_fill_share.2part"] < 100
+    assert value["held_close_delay_ms_p50"] > 0
+    assert 0 <= value["held_units_at_checkpoint_p50"] <= 6
+    assert 0 <= value["partition_skew_s_p50"] <= 9
+
+
+@pytest.mark.parametrize("control", TRACED[TWOPART_CELL].control.split(","))
+def test_the_2part_twins_controls_come_out_not_correct(dry_run, control):
+    line = _result(dry_run, TWOPART_CELL, trace=1)
+    found = next(c for c in line["controls"] if c["control"] == control)
+    assert found["correct"] is False
+    failed = {c["name"] for c in found["checks"] if not c["ok"]}
+    assert "topk_bytes_max_rel_err" in failed
     if ":" in control:
         assert failed == {"topk_bytes_max_rel_err"}

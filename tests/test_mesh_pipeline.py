@@ -204,7 +204,10 @@ def test_the_pipeline_gives_what_the_per_model_loop_gives(case):
     models = _models()
     pipeline = ShardedPipeline(models)
     got = _drive(models, batches, pipeline.update)
+    _assert_same(got, want, batches, models)
 
+
+def _assert_same(got, want, batches, models):
     assert got["polls"] == want["polls"]
     assert got["alerts"] == want["alerts"]
     assert got["entropy"] == pytest.approx(want["entropy"], rel=1e-6)
@@ -239,6 +242,61 @@ def test_the_pipeline_gives_what_the_per_model_loop_gives(case):
         assert len(got["closed"][name]) == len(want["closed"][name])
         for g, w in zip(got["closed"][name], want["closed"][name]):
             _same_top(g, w)
+
+
+def _two_partition_polls(n_polls: int, rate: int = 512) -> list:
+    """Polls of one global step each, alternately from two partitions
+    that share the positions round-robin; the clock advances a second
+    every ``rate`` positions (a poll spans 4 s of it), starts 14 s before
+    a slot's end, and a tenth of the flows lie 1-3 s behind it."""
+    n = n_polls * GB
+    pos = np.arange(n)
+    h = (pos * 2654435761 + 12345) % 1000
+    times = (T0 + SLOT - 14 + pos // rate
+             - np.where(h < 100, 1 + h % 3, 0))
+    whole = _rows(n, 5, times)
+    parts = [np.flatnonzero(pos % 2 == p) for p in (0, 1)]
+    return [FlowBatch({k: v[parts[p][at:at + GB]]
+                       for k, v in whole.columns.items()}, p)
+            for at in range(0, n // 2, GB) for p in (0, 1)]
+
+
+@pytest.mark.parametrize("lateness, drops", [(8, False), (2, True)])
+def test_late_rows_fold_into_held_replicas_under_the_mesh(lateness, drops):
+    """`-window.lateness` under `-processor.mesh`: the held unit is a
+    second set of stacked replicas, a late group runs the family's own
+    sharded programs on it, and the deferred close merges it over the
+    chips. Held to the per-model loop, poll by poll, and to the plain
+    reference's counts."""
+    from flow_pipeline_tpu.models.oracle import late_unit_sums
+
+    batches = _two_partition_polls(14)
+    extra = ["-window.lateness", str(lateness)]
+    want = _per_model(_models(extra=extra), batches)
+    models = _models(extra=extra)
+    assert {models[n].lateness for n in MODELS[1:]} == {lateness}
+    pipeline = ShardedPipeline(models)
+    got = _drive(models, batches, pipeline.update)
+    _assert_same(got, want, batches, models)
+    slots = late_unit_sums(batches, SLOT, lateness, ["src_port"])
+    subs = late_unit_sums(batches, SUB, lateness, ["dst_addr"],
+                          ["packets"])
+    ports, ddos = models["top_src_ports"], models["ddos_alerts"]
+    assert (ports.late_flows_dropped, ports.late_flows_folded) == (
+        slots["dropped"], slots["folded"])
+    assert (ddos.late_flows_dropped, ddos.late_flows_folded) == (
+        subs["dropped"], subs["folded"])
+    assert (slots["dropped"] + subs["dropped"] > 0) == drops
+    assert slots["folded"] > 0 and subs["folded"] > 0
+    # the slot before the roll closed on the way, late rows and all
+    (closed,) = got["closed"]["top_src_ports"]
+    exact = slots["units"][T0]
+    live = np.asarray(closed["valid"], bool)
+    assert int(closed["timeslot"][0]) == T0
+    counts = dict(zip(exact["src_port"].tolist(), exact["count"].tolist()))
+    top = dict(zip(np.asarray(closed["src_port"])[live].tolist(),
+                   np.asarray(closed["count"])[live].tolist()))
+    assert len(top) == 100 and all(counts[k] == n for k, n in top.items())
 
 
 def test_the_cases_hold_what_their_names_say():

@@ -689,11 +689,13 @@ def test_benchmark_kind_finds_sub_windows_by_bisection():
     """Event time is monotone in the flow index, with the warm-up's jump
     in it: the edges found by bisection are where the sub-window of
     consecutive flows changes."""
-    from benchmark.flowgen import StreamSpec
+    from benchmark import manifest
     from benchmark.tables import ranked_bytes_sliding as kind
 
-    spec = StreamSpec(seed=1, event_rate=7, first_close_flow=100,
-                      phase_s=95, slot_seconds=300)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    stream = manifest.load_stream(root, ["benchmark"], {
+        "event_rate": 7, "slot_seconds": 300})  # no kind: zipf-ranks
+    spec = stream.spec(1, 100, 95)
     n = 5000
     sub = spec.event_ts(np.arange(n)).astype(np.int64) // S
     want = [0, *(np.flatnonzero(sub[1:] != sub[:-1]) + 1).tolist(), n]
