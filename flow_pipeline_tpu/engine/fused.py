@@ -24,10 +24,25 @@ create.sh:92-110). This module is the TPU-first equivalent:
 
 Window lifecycle (closing sketches at slot roll, DDoS sub-windows, late
 -row drops) stays host-side and byte-identical to the unfused models':
-the batch is split at (slot, sub-window) boundaries and each homogeneous
-group advances the wrapped models' own lifecycle hooks before the fused
-device call. tests/test_fused.py proves output equivalence against the
-unfused path, late rows included.
+the batch is cut at (slot, sub-window) boundaries and the wrapped
+models' own lifecycle hooks advance before the device call for their
+rows.
+
+What a cut poll runs (engine/lifecycle.py: runs). A family is cut at
+its own unit only, as on the per-model path and under the mesh: the
+groups of one window slot are a *slot run* and take ONE fused step, in
+which flows_5m (which keys by timeslot itself), the tables and the ports
+take the run's rows and the detector the rows of the run's newest
+sub-window (the step has a mask for each). The run's older sub-windows
+run the detector's own small program alone (models.ddos.ddos_accumulate,
+the per-model path's), first and in order, over the columns already on
+the device. So a poll that crosses only a sub-window (one in twenty in
+order, two in five on two partitions with a few seconds of disorder)
+costs one step and a few ms, not two padded steps; only a slot roll runs
+the step twice. Rows are never compacted: lanes are built and placed
+once a poll and a run is a boolean mask. tests/test_fused.py proves
+output equivalence against the unfused path, late rows and count-min
+estimates included.
 """
 
 from __future__ import annotations
@@ -41,7 +56,12 @@ import numpy as np
 from jax import lax
 
 from ..models import heavy_hitter as hh
-from ..models.ddos import DDoSDetector, _accumulate_grouped
+from ..models.ddos import (
+    DDoSDetector,
+    _accumulate_grouped,
+    ddos_accumulate,
+    ddos_input_cols,
+)
 from ..models.dense_top import DenseTopKModel, dense_update
 from ..models.heavy_hitter import HeavyHitterModel
 from ..models.spread import SpreadModel
@@ -57,7 +77,7 @@ from ..ops.segment import (
     hash_sort,
     presorted_segments,
 )
-from .lifecycle import WindowLifecycle
+from .lifecycle import WindowLifecycle, _count, _runs
 from .windowed import WindowedHeavyHitter
 
 log = get_logger("fused")
@@ -291,11 +311,55 @@ def _cached_step(hh_specs, dense_cfgs, ddos_cfgs, wagg_cfgs):
     return jax.jit(step, donate_argnums=(0,))
 
 
+class _Lanes:
+    """One polled batch on the device: each device step's padded
+    columns, built and placed at their first use and kept until the
+    batch is done (every run of a cut poll reads the same placement
+    under a mask of its own)."""
+
+    def __init__(self, batch: FlowBatch, bs: int, names: tuple):
+        self._batch, self._bs, self._names = batch, bs, names
+        self._placed: dict = {}
+
+    def starts(self) -> range:
+        return range(0, len(self._batch), self._bs)
+
+    def place(self, start: int) -> tuple:
+        """(chunk, host columns, device columns, device pad mask) of
+        the device step whose first row is ``start``."""
+        if start not in self._placed:
+            bs = self._bs
+            chunk = self._batch.slice(start, start + bs)
+            with TRACER.span("lane_build", rows=len(chunk), padded=bs):
+                padded, mask = chunk.pad_to(bs)
+                host_cols = padded.device_columns(self._names)
+            with TRACER.span("h2d", cols=len(host_cols) + 1) as span:
+                cols = {k: jnp.asarray(v) for k, v in host_cols.items()}
+                valid = jnp.asarray(mask)
+                span["bytes"] = mask.nbytes + sum(
+                    v.nbytes for v in host_cols.values())
+            self._placed[start] = (chunk, host_cols, cols, valid)
+        return self._placed[start]
+
+    def mask(self, start: int, rows) -> tuple:
+        """(host mask, its count) of the run ``rows`` (a mask over the
+        whole batch; None: every row) in the device step at ``start``."""
+        bs = self._bs
+        mask = np.zeros(bs, dtype=bool)
+        n = min(bs, len(self._batch) - start)
+        if rows is None:
+            mask[:n] = True
+        else:
+            mask[:n] = rows[start:start + n]
+        return mask, int(mask.sum())
+
+
 class FusedPipeline(WindowLifecycle):
     """Drives a worker's whole model dict through one jitted step/batch."""
 
-    # -window.lateness: a late group is one more dispatch of the step
-    # with the family's held state in the tuple (WindowLifecycle._units)
+    # -window.lateness: a late run is a dispatch (of the step, or of the
+    # detector's program) with the family's held state in the open
+    # one's place (WindowLifecycle._units)
     honours_lateness = True
 
     @staticmethod
@@ -364,6 +428,7 @@ class FusedPipeline(WindowLifecycle):
             (_hh_plan(w.config), w.config) for _, w in self._hh)
         self._cols = self._column_union()
         self._behind = False  # the batch in hand fills a device step
+        self._detector_warm = not self._ddos  # see _warm_detector
         # [families] int32 on the device: live_rows of the last step that
         # fed the tables; read by hh_live alone
         self._live_rows = None
@@ -460,83 +525,130 @@ class FusedPipeline(WindowLifecycle):
 
     # ---- host lifecycle ---------------------------------------------------
 
-    def _split_parts(self, batch: FlowBatch):
-        """Split a batch at (window slot, DDoS sub-window) boundaries into
-        homogeneous parts, in (slot, sub) order. Returns (parts, wm) with
-        parts = [(slot, sub, FlowBatch)] and wm the batch watermark —
-        pure host work, shared by update() and the ingest runtime's
-        prepare stage (which runs it off the worker thread). The cut is
-        WindowLifecycle._split_groups'; a part that is not the whole
-        batch is compacted into a copy, which _run_chunks then cuts
-        into device steps of contiguous rows."""
-        groups, wm = self._split_groups(batch)
-        parts = [
-            (slot, sub, batch if rows is None else FlowBatch(
-                {k: v[rows] for k, v in batch.columns.items()},
-                batch.partition))
-            for slot, sub, rows in groups
-        ]
-        return parts, wm
-
     def update(self, batch: FlowBatch) -> None:
         if len(batch) == 0:
             return
         with TRACER.span("split_parts") as span:
-            parts, wm = self._split_parts(batch)
-            span["parts"] = len(parts)
+            groups, wm = self._split_groups(batch)
+            slot_runs = _runs(groups, 0)
+            span["parts"] = len(groups)
         # a batch that fills a device step: the source holds a backlog
         # (WindowAggregator._min_slot)
         self._behind = len(batch) >= self._bs
-        for slot, sub, part in parts:
-            hh_unit = self._advance_hh(slot, len(part))
-            dd_unit = self._advance_ddos(sub, len(part))
-            # the states of this part's dispatches: a family's open
-            # unit, or the held one its rows belong to
-            with self._units(hh_unit, dd_unit):
-                self._run_chunks(part, hh_unit, dd_unit)
+        self._run_chunks(batch, groups, slot_runs)
         for _, m in self._waggs:
             if wm > m.watermark:
                 m.watermark = wm
         self._advance_watermark(wm)
 
-    def _run_chunks(self, part: FlowBatch, hh_unit: str | None,
-                    dd_unit: str | None) -> None:
+    def _run_chunks(self, batch: FlowBatch, groups: list,
+                    slot_runs: dict) -> None:
+        """What each run of a cut poll runs. A slot run (the groups of
+        one window slot: the whole poll, but at a slot roll) is ONE
+        fused step a chunk: flows_5m, the tables and the ports take the
+        run's rows, the detector those of the run's newest sub-window.
+        The run's older sub-windows take the detector's own program
+        alone, first and in order, each against the state its unit has
+        then (sub-window n is scored before n + 1). Rows stay where
+        they are: a run is a mask over lanes placed once a poll."""
+        lanes = _Lanes(batch, self._bs, self._cols)
+        if not self._detector_warm:
+            self._warm_detector(lanes)
+        firsts = [*slot_runs, len(groups)]
+        for first, end in zip(firsts, firsts[1:]):
+            slot, rows = slot_runs[first]
+            hh_unit = self._advance_hh(slot, _count(rows, batch))
+            *older, (_, sub, newest_rows) = groups[first:end]
+            for _, old_sub, old_rows in older:
+                dd_unit = self._advance_ddos(old_sub,
+                                             _count(old_rows, batch))
+                if dd_unit is not None:
+                    with self._units(None, dd_unit):
+                        self._run_detector(lanes, old_rows, dd_unit)
+            # a run of one sub-window: the detector takes the run's rows
+            dd_rows = newest_rows if older else rows
+            dd_unit = self._advance_ddos(sub, _count(dd_rows, batch))
+            # the states of this run's steps: a family's open unit, or
+            # the held one its rows belong to
+            with self._units(hh_unit, dd_unit):
+                self._run_step(lanes, rows, dd_rows, hh_unit, dd_unit)
+
+    def _run_detector(self, lanes: _Lanes, rows: np.ndarray,
+                      dd_unit: str) -> None:
+        """The detector's accumulate alone (the per-model path's
+        program, models.ddos.ddos_accumulate) for the rows of one
+        sub-window, over the columns the poll already has on the
+        device."""
+        for start in lanes.starts():
+            mask, n = lanes.mask(start, rows)
+            if not n:
+                continue
+            cols = lanes.place(start)[2]
+            for _, d in self._ddos:
+                # one span per dispatch: their count inside one "apply"
+                # is how often a poll crossed only a sub-window
+                with TRACER.span("detector_dispatch", rows=n,
+                                 padded=self._bs, dd_unit=dd_unit):
+                    d.state = ddos_accumulate(
+                        d.state,
+                        {k: cols[k] for k in ddos_input_cols(d.config)},
+                        jnp.asarray(mask), config=d.config)
+
+    def _warm_detector(self, lanes: _Lanes) -> None:
+        """Compile the detector's own program in the pipeline's first
+        batch, on a scratch state under an all-false mask: the first
+        poll that crosses only a sub-window may come much later, and
+        nothing may compile then (a measured window counts compiles)."""
+        _, _, cols, valid = lanes.place(0)
+        for _, d in self._ddos:
+            ddos_accumulate(
+                d._fresh_state(),
+                {k: cols[k] for k in ddos_input_cols(d.config)},
+                jnp.zeros_like(valid), config=d.config)
+        self._detector_warm = True
+
+    def _run_step(self, lanes: _Lanes, rows, dd_rows,
+                  hh_unit: str | None, dd_unit: str | None) -> None:
         bs = self._bs
         do_hh, do_dd = hh_unit is not None, dd_unit is not None
-        for start in range(0, len(part), bs):
-            chunk = part.slice(start, start + bs)
+        for start in lanes.starts():
+            mask, n = lanes.mask(start, rows)
+            if not n:
+                continue
+            chunk, host_cols, cols, pad_valid = lanes.place(start)
             if do_hh and self._spread:
                 # host-side spread fold per chunk (see __init__): the
                 # chunk is <= one model batch, so model.update makes
                 # exactly one grouped pass over it
                 with TRACER.span("spread_fold"):
+                    part = (chunk if rows is None
+                            else chunk.take(mask[:len(chunk)]))
                     for _, w in self._spread:
-                        w.model.update(chunk)
-            with TRACER.span("lane_build", rows=len(chunk), padded=bs):
-                padded, mask = chunk.pad_to(bs)
-                host_cols = padded.device_columns(self._cols)
-            with TRACER.span("h2d", cols=len(host_cols) + 1) as span:
-                cols = {k: jnp.asarray(v) for k, v in host_cols.items()}
-                valid = jnp.asarray(mask)
-                span["bytes"] = mask.nbytes + sum(
-                    v.nbytes for v in host_cols.values())
-            zeros = (jnp.zeros_like(valid)
-                     if not (do_hh and do_dd) else None)
+                        w.model.update(part)
+            dd_mask, dd_n = ((mask, n) if dd_rows is rows
+                             else lanes.mask(start, dd_rows))
             states = (
                 tuple(w.model.state for _, w in self._hh),
                 tuple(w.model.totals for _, w in self._dense),
                 tuple(d.state for _, d in self._ddos),
             )
             # one span per device step: their count inside one "apply" is
-            # device steps per batch, rows/padded the step's fill
-            with TRACER.span("step_dispatch", rows=len(chunk), padded=bs,
+            # device steps per batch, rows/padded the step's fill. The
+            # masks of a run that is not the whole chunk cross here
+            with TRACER.span("step_dispatch", rows=n, padded=bs,
                              do_hh=do_hh, do_dd=do_dd,
                              hh_unit=hh_unit or "dropped",
-                             dd_unit=dd_unit or "dropped"):
+                             dd_unit=dd_unit or "dropped",
+                             dd_rows=dd_n if do_dd else 0):
+                valid = pad_valid if rows is None else jnp.asarray(mask)
+                valid_dd = (valid if dd_mask is mask
+                            else jnp.asarray(dd_mask))
+                zeros = (jnp.zeros_like(valid)
+                         if not (do_hh and do_dd) else None)
                 new_states, wagg_parts, live_rows = self._step(
                     states, cols, valid,
                     valid if do_hh else zeros,
-                    valid if do_dd else zeros,
+                    valid_dd if do_dd else zeros,
                 )
             if do_hh:
                 self._live_rows = live_rows

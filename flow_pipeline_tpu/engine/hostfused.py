@@ -1,8 +1,10 @@
 """Host-grouped fused step: the CPU-backend twin of engine.fused.
 
 Same model surface, same window lifecycle (it IS a FusedPipeline
-subclass — update()'s slot/sub splitting and lifecycle advancement are
-inherited untouched), different pre-aggregation substrate: batches are
+subclass — the cut at slot/sub boundaries and the lifecycle advancement
+are WindowLifecycle's; each (slot, sub) group is compacted into a part
+of its own here, where FusedPipeline runs masks over rows in place),
+different pre-aggregation substrate: batches are
 grouped on the HOST with numpy (ops.hostgroup — ~20x cheaper than
 XLA:CPU's single-threaded lax.sort on one core) and only the compact
 group tables cross into the XLA step, which keeps what XLA is still
@@ -369,6 +371,22 @@ class HostGroupPipeline(FusedPipeline):
     # its group thread while the worker thread applies the previous
     # batch. update() = apply(prepare()) keeps the serial path the same
     # code — pipelined and serial modes cannot drift apart.
+
+    def _split_parts(self, batch: FlowBatch):
+        """Split a batch at (window slot, DDoS sub-window) boundaries into
+        homogeneous parts, in (slot, sub) order. Returns (parts, wm) with
+        parts = [(slot, sub, FlowBatch)] and wm the batch watermark. The
+        cut is WindowLifecycle._split_groups'; a part that is not the
+        whole batch is compacted into a copy (the host groupby wants
+        contiguous rows; FusedPipeline masks rows where they are)."""
+        groups, wm = self._split_groups(batch)
+        parts = [
+            (slot, sub, batch if rows is None else FlowBatch(
+                {k: v[rows] for k, v in batch.columns.items()},
+                batch.partition))
+            for slot, sub, rows in groups
+        ]
+        return parts, wm
 
     def prepare(self, batch: FlowBatch) -> Optional[PreparedBatch]:
         if len(batch) == 0:

@@ -6,10 +6,18 @@ wrapped models' own lifecycle (``models/held.py``: first slot adopts, a
 newer slot rolls, which closes the open unit or under
 ``-window.lateness`` holds it; rows of the held unit go to its state,
 older ones are counted as late) before the device work for it is
-dispatched, and the batch's watermark then closes what it has passed. ``engine.fused.FusedPipeline`` (one fused
-step a chunk) and ``parallel.pipeline.ShardedPipeline`` (a sharded
-program a model) differ in what a group *runs*, not in how a
-batch is cut: both take the cut and the transitions from here, and
+dispatched, and the batch's watermark then closes what it has passed.
+
+A family is cut at its own unit only, as on the per-model path: the
+groups of a cut are gathered into *runs* (``_runs``), the neighbours
+that share a slot for the windowed families and those that share a
+sub-window for the detector, and a run is a mask over rows that stay
+where they are. So a poll that crosses only a sub-window is one run of
+the tables and two of the detector. ``engine.fused.FusedPipeline`` (one
+fused step a slot run, the detector's own program for the run's other
+sub-windows) and ``parallel.pipeline.ShardedPipeline`` (a sharded
+program a model and run) differ in what a run *runs*, not in how a batch
+is cut: both take the cut, the runs and the transitions from here, and
 tests/test_fused.py and tests/test_mesh_pipeline.py hold each to the
 per-model path.
 """
@@ -22,6 +30,28 @@ import numpy as np
 
 from ..models.held import HELD
 from ..schema.batch import FlowBatch
+
+
+def _runs(groups: list, key: int) -> dict:
+    """The maximal runs of consecutive ``groups`` that share their
+    ``key`` (0: slot, 1: sub-window), as {index of the run's first
+    group: (value, rows)}; ``rows`` is the union of the run's row masks,
+    None for the whole batch. Slot and sub-window are both monotone in a
+    row's time, so in (slot, sub) order equal values are neighbours."""
+    runs: list = []
+    for i, group in enumerate(groups):
+        if runs and runs[-1][1] == group[key]:
+            runs[-1][2] = runs[-1][2] | group[2]
+        else:
+            runs.append([i, group[key], group[2]])
+    if len(runs) == 1:
+        runs[0][2] = None
+    return {first: (value, rows) for first, value, rows in runs}
+
+
+def _count(rows, batch: FlowBatch) -> int:
+    """Rows of ``batch`` under the mask ``rows`` (None: all of them)."""
+    return len(batch) if rows is None else int(rows.sum())
 
 
 class WindowLifecycle:

@@ -10,8 +10,10 @@ model set made of the sharded kinds and, for each polled batch:
 
 1. cuts it once at (window slot, detector sub-window) boundaries
    (``WindowLifecycle._split_groups``, the cut ``FusedPipeline`` uses:
-   a scalar fast path that touches no row when the poll holds one pair)
-   and advances the windowed families and the detector in lockstep;
+   a scalar fast path that touches no row when the poll holds one pair),
+   gathers the groups into runs (``engine.lifecycle._runs``, shared with
+   ``FusedPipeline`` since PR 40) and advances the windowed families
+   and the detector in lockstep;
 2. pads, builds and places each model's columns a global step of
    ``n_dev x batch_size`` rows (``sharded.place_global_step``), once a
    poll whatever the cut;
@@ -25,6 +27,9 @@ where they were placed and each family group gets the mask of its rows
 detector, all valid rows for ``flows_5m``, which groups by timeslot
 itself). So a poll that crosses a sub-window runs only the detector's
 program twice, and the windowed families run twice only at a slot roll.
+``FusedPipeline`` runs by the same rule on one chip: there a slot run
+is one fused step that carries the detector's newest sub-window, and
+the other sub-windows run the detector's own program alone.
 
 A placement is still a model's own (``mesh_shard`` [models] 1). One
 placement of the union of the columns for all seven programs was built
@@ -46,7 +51,7 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..engine.lifecycle import WindowLifecycle
+from ..engine.lifecycle import WindowLifecycle, _count, _runs
 from ..engine.windowed import WindowedHeavyHitter
 from ..obs.trace import TRACER
 from ..schema.batch import FlowBatch
@@ -58,23 +63,6 @@ from .sharded import (
     ShardedWindowAggregator,
     place_global_step,
 )
-
-
-def _runs(groups: list, key: int) -> dict:
-    """The maximal runs of consecutive ``groups`` that share their
-    ``key`` (0: slot, 1: sub-window), as {index of the run's first
-    group: (value, rows)}; ``rows`` is the union of the run's row masks,
-    None for the whole batch. Slot and sub-window are both monotone in a
-    row's time, so in (slot, sub) order equal values are neighbours."""
-    runs: list = []
-    for i, group in enumerate(groups):
-        if runs and runs[-1][1] == group[key]:
-            runs[-1][2] = runs[-1][2] | group[2]
-        else:
-            runs.append([i, group[key], group[2]])
-    if len(runs) == 1:
-        runs[0][2] = None
-    return {first: (value, rows) for first, value, rows in runs}
 
 
 class _Placed:
@@ -217,7 +205,3 @@ class ShardedPipeline(WindowLifecycle):
                             cols, valid if part is None else part, *args)
                     steps.add(g)
             update["steps"] = len(steps)
-
-
-def _count(rows, batch: FlowBatch) -> int:
-    return len(batch) if rows is None else int(rows.sum())

@@ -42,11 +42,11 @@ WINDOW = 300
 BS = 512
 
 
-def make_models(sub_seconds: int, n_keys: int):
+def make_models(sub_seconds: int, n_keys: int, width: int = 1 << 10):
     """The cli's default model family at test scale (cli._build_models)."""
     def hh_cfg(key_cols):
         return HeavyHitterConfig(key_cols=key_cols, batch_size=BS,
-                                 width=1 << 10, capacity=128)
+                                 width=width, capacity=128)
 
     return {
         "flows_5m": WindowAggregator(WindowAggConfig(batch_size=BS)),
@@ -153,30 +153,41 @@ class TestFusedEquivalence:
                 np.testing.assert_array_equal(np.asarray(x[k]),
                                               np.asarray(y[k]))
 
-    def test_finer_ddos_cadence(self):
-        """DDoS sub-windows finer than the sketch window: the fused path
-        chunks hh updates at sub boundaries, so CMS *estimates* may take a
-        different (equally valid) path — but exact outputs (flows_5m,
-        dense ports, ddos, table sums with no eviction) must still match."""
+    @pytest.mark.parametrize("sub, width", [
+        (30, 1 << 10), (10, 1 << 10), (10, 1 << 7)],
+        ids=["sub30", "sub10", "sub10-narrow-cms"])
+    def test_finer_ddos_cadence(self, sub, width):
+        """DDoS sub-windows finer than the sketch window. A family is
+        cut at its own unit only (engine/lifecycle.py: runs), so the
+        tables take a batch that crosses sub-windows in ONE conservative
+        update, as the per-model path does, and every output matches on
+        every column, CMS estimates included, as in the aligned case.
+        A batch of make_stream spans 30 s: one sub-window of 30, three of
+        10. At width 128 the count-min rows collide, so tables cut at
+        the detector's boundary (two updates where the per-model path
+        makes one) would estimate otherwise."""
         batches = make_stream(n_keys=100)  # 100 < capacity 128: no eviction
-        fused = drive_fused(make_models(30, 100), batches)
-        serial = drive_serial(make_models(30, 100), batches)
+        fused = drive_fused(make_models(sub, 100, width), batches)
+        serial = drive_serial(make_models(sub, 100, width), batches)
 
         assert canon_rows(fused["flows_5m"].flush(True)) == \
             canon_rows(serial["flows_5m"].flush(True))
-        assert_same_windows(fused["top_src_ports"].flush(True),
-                            serial["top_src_ports"].flush(True))
-        for name in ("top_talkers", "top_src_ips", "top_dst_ips"):
-            exact = ["timeslot", "bytes", "packets", "count", "valid",
-                     *fused[name].config.key_cols]
+        for name in ("top_talkers", "top_src_ips", "top_dst_ips",
+                     "top_src_ports"):
             assert_same_windows(fused[name].flush(True),
-                                serial[name].flush(True), keys=exact)
+                                serial[name].flush(True))
+            assert fused[name].late_flows_dropped == \
+                serial[name].late_flows_dropped
         fa, sa = fused["ddos_alerts"], serial["ddos_alerts"]
+        assert fa.late_flows_dropped == sa.late_flows_dropped
+        assert fa.folds == sa.folds
         assert len(fa.alerts) == len(sa.alerts)
         for x, y in zip(fa.alerts, sa.alerts):
             for k in x:
                 np.testing.assert_array_equal(np.asarray(x[k]),
                                               np.asarray(y[k]))
+        for xa, xb in zip(fa.state, sa.state):
+            np.testing.assert_array_equal(np.asarray(xa), np.asarray(xb))
 
     def test_mixed_scale_col_dst_families_demoted(self):
         """Two dst-keyed sketch families with DIFFERENT scale_col: the
@@ -212,6 +223,115 @@ class TestFusedEquivalence:
         worker = StreamWorker(None, {"x": Opaque()},
                               config=WorkerConfig(fused=True))
         assert worker.fused is None
+
+
+def _dispatches(pipe, batch) -> dict:
+    """{span name: [args]} of what one poll dispatched."""
+    from flow_pipeline_tpu.obs.trace import TRACER
+
+    TRACER.configure("always")
+    try:
+        pipe.update(batch)
+        spans = TRACER.snapshot()
+    finally:
+        TRACER.configure("off")
+    out = {"step_dispatch": [], "detector_dispatch": [], "split_parts": [],
+           "lane_build": [], "h2d": []}
+    for name, *_rest, args in spans:
+        if name in out:
+            out[name].append(args)
+    return out
+
+
+def _poll_at(gen, times):
+    b = gen.batch(len(times))
+    b.columns["time_received"] = np.asarray(times, np.uint64)
+    return b
+
+
+@pytest.mark.parametrize("crossing, steps, detectors", [
+    ("none", 1, 0), ("sub", 1, 1), ("two-subs", 1, 2), ("slot", 2, 0),
+    ("slot-and-sub", 2, 1)])
+def test_a_run_is_a_dispatch(crossing, steps, detectors):
+    """What a cut poll runs (ISSUE 40): one fused step a slot run, which
+    carries the run's rows for flows_5m, the tables and the ports and
+    the rows of the run's newest sub-window for the detector, and the
+    detector's own program alone for each older sub-window of the run.
+    Rows stay where they are: the lanes are built and placed once."""
+    gen = FlowGenerator(ZipfProfile(n_keys=100, alpha=1.2), seed=3)
+    t0 = 6000  # slot- and sub-aligned
+    pipe = FusedPipeline(make_models(10, 100))
+    pipe.update(_poll_at(gen, np.full(BS, t0 + 275)))
+    n = np.arange(BS)
+    times = {
+        "none": np.full(BS, t0 + 276),
+        # 100 rows of the sub-window that is open, the rest of the next
+        "sub": np.where(n < 100, t0 + 279, t0 + 281),
+        "two-subs": np.where(n < 100, t0 + 279,
+                             np.where(n < 300, t0 + 285, t0 + 291)),
+        # the next slot begins a sub-window too
+        "slot": np.where(n < 100, t0 + 279, t0 + 301),
+        "slot-and-sub": np.where(n < 100, t0 + 279,
+                                 np.where(n < 300, t0 + 301, t0 + 311)),
+    }[crossing]
+    # rows in no order: a run is a mask, not a range
+    times = np.random.default_rng(5).permutation(times)
+    got = _dispatches(pipe, _poll_at(gen, times))
+    assert len(got["step_dispatch"]) == steps
+    assert len(got["detector_dispatch"]) == detectors
+    assert len(got["lane_build"]) == len(got["h2d"]) == 1
+    (cut,) = got["split_parts"]
+    assert cut["parts"] == {"none": 1, "sub": 2, "two-subs": 3, "slot": 2,
+                            "slot-and-sub": 3}[crossing]
+    # every row rides exactly one step and exactly one detector program
+    assert sum(s["rows"] for s in got["step_dispatch"]) == BS
+    assert (sum(s["dd_rows"] for s in got["step_dispatch"])
+            + sum(d["rows"] for d in got["detector_dispatch"])) == BS
+    for s in got["step_dispatch"]:
+        assert s["padded"] == BS and s["do_hh"] and s["do_dd"]
+        assert (s["hh_unit"], s["dd_unit"]) == ("open", "open")
+    for d in got["detector_dispatch"]:
+        assert d["padded"] == BS and d["dd_unit"] == "open"
+    if crossing == "sub":
+        assert got["step_dispatch"][0]["dd_rows"] == BS - 100
+        assert got["detector_dispatch"][0]["rows"] == 100
+
+
+def test_the_detectors_program_is_compiled_in_the_first_batch():
+    """Nothing compiles at the first crossing, which may come inside a
+    measured window (compiles_in_window): the pipeline's first batch has
+    already run ddos_accumulate once, on a scratch state."""
+    import jax
+
+    from flow_pipeline_tpu.models.ddos import ddos_accumulate
+
+    gen = FlowGenerator(ZipfProfile(n_keys=100, alpha=1.2), seed=4)
+    models = make_models(10, 100)
+    # a batch size no other test compiles the detector's program at
+    for m in models.values():
+        m.config = type(m.config)(**{**m.config.__dict__,
+                                     "batch_size": BS - 128})
+    bs = BS - 128
+    pipe = FusedPipeline(models)
+    before = ddos_accumulate._cache_size()
+    pipe.update(_poll_at(gen, np.full(bs, 6000)))
+    assert ddos_accumulate._cache_size() == before + 1
+    # a roll in a poll of its own: the close's program is the warm-up's
+    pipe.update(_poll_at(gen, np.full(bs, 6011)))
+    state = [np.asarray(x) for x in models["ddos_alerts"].state]
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, _d, **_kw: compiles.append(name)
+        if "compil" in name else None)
+    try:
+        pipe.update(_poll_at(gen, np.where(np.arange(bs) < 50, 6019, 6021)))
+    finally:
+        jax.monitoring.clear_event_listeners()
+    assert ddos_accumulate._cache_size() == before + 1
+    assert not [c for c in compiles if "backend_compile" in c], compiles
+    # the warm-up touched no state of the detector's
+    assert any((np.asarray(a) != b).any()
+               for a, b in zip(models["ddos_alerts"].state, state))
 
 
 def test_worker_fused_vs_serial_sink_rows():

@@ -275,12 +275,21 @@ def test_two_partitions_against_the_reference(seed, lateness, drops,
         assert all(v > 0 for v in got["folded"].values())
 
 
-def test_fused_equals_the_per_model_path_bit_for_bit():
-    """Beyond the sums: est columns, ranks and the detector's state."""
+@pytest.mark.parametrize("lateness", [8, 2, 0],
+                         ids=["within", "exceeded", "L0"])
+def test_fused_equals_the_per_model_path_bit_for_bit(lateness):
+    """Beyond the sums: est columns, ranks, the detector's state and
+    every model's counters. Two polls in five hold rows of two of the
+    detector's sub-windows: the fused path cuts a family at its own
+    unit only, as the per-model path does (the tables take such a poll
+    in one step, the other sub-window's rows the detector's own program
+    alone), so nothing may differ, whatever the lateness holds or
+    drops."""
     polls = two_partition_polls(5, 2 * WINDOW * RATE)
-    a, b = make_models(8), make_models(8)
+    a, b = make_models(lateness), make_models(lateness)
     fa, fb = drive(a, polls, True), drive(b, polls, False)
     for name in TABLES:
+        assert len(fa["windows"][name]) == len(fb["windows"][name])
         for (ia, wa), (ib, wb) in zip(fa["windows"][name],
                                       fb["windows"][name]):
             assert ia == ib
@@ -288,6 +297,10 @@ def test_fused_equals_the_per_model_path_bit_for_bit():
                 np.testing.assert_array_equal(np.asarray(wa[col]),
                                               np.asarray(wb[col]), col)
     assert fa["subs"] == fb["subs"]
+    assert fa["dropped"] == fb["dropped"]
+    assert fa["folded"] == fb["folded"]
+    if lateness == 8:
+        assert all(v > 0 for v in fa["folded"].values())
     for xa, xb in zip(jax.tree.leaves(a["ddos_alerts"].state),
                       jax.tree.leaves(b["ddos_alerts"].state)):
         np.testing.assert_array_equal(np.asarray(xa), np.asarray(xb))
@@ -667,8 +680,19 @@ def test_spans_and_gauges_of_a_late_group():
     finally:
         TRACER.configure("off")
     steps = [s[5] for s in spans if s[0] == "step_dispatch"]
+    alone = [s[5] for s in spans if s[0] == "detector_dispatch"]
     assert {"open", "held"} <= {s["hh_unit"] for s in steps}
-    assert {"open", "held"} <= {s["dd_unit"] for s in steps}
+    # a poll's older sub-window, held more often than not, takes the
+    # detector's own program; the newest rides the step
+    assert {"open", "held"} <= {s["dd_unit"] for s in alone}
+    assert "open" in {s["dd_unit"] for s in steps}
+    assert all(0 < s["rows"] <= s["padded"] == BS for s in alone)
+    # every row the worker applied rode one step, and one of the two
+    # kinds of dispatch for the detector
+    applied = sum(s[5]["rows"] for s in spans if s[0] == "apply")
+    assert sum(s["rows"] for s in steps) == applied
+    assert (sum(s["dd_rows"] for s in steps)
+            + sum(s["rows"] for s in alone)) == applied
     closes = [s[5] for s in spans if s[0] == "held_close"]
     tables = [c for c in closes if c["model"] == "hh" and c["unit"] == T0]
     assert tables and all(c["late_rows"] > 0 and c["held_ms"] > 0

@@ -48,6 +48,7 @@ WORKER_SPANS = {
     "lane_build": "apply",
     "h2d": "apply",
     "step_dispatch": "apply",
+    "detector_dispatch": "apply",
     "wagg_wait": "apply",
     "wagg_d2h": "apply",
     "wagg_fold": "apply",
@@ -89,7 +90,9 @@ SPAN_ARGS = {
     "publish_view": ("model", "rows", "bytes"),
     "publish_swap": ("ranges",),
     "lane_build": ("rows", "padded"), "h2d": ("bytes", "cols"),
-    "step_dispatch": ("rows", "padded", "do_hh", "do_dd"),
+    "step_dispatch": ("rows", "padded", "do_hh", "do_dd", "hh_unit",
+                      "dd_unit", "dd_rows"),
+    "detector_dispatch": ("rows", "padded", "dd_unit"),
     "wagg_wait": ("folded", "left"),
     "wagg_d2h": ("bytes",),
     "wagg_fold": ("groups", "inserted", "store_groups"),
@@ -103,8 +106,8 @@ SCOPES = ("hh_chain_sort", "dst_sort", "hh_table_merge", "dense_scatter",
           "ddos_accumulate", "wagg_groupby", "hh_group_own")
 
 
-def _models(spread=True):
-    models = make_models(WINDOW, 100)
+def _models(spread=True, sub=WINDOW):
+    models = make_models(sub, 100)
     if spread:
         models["portscan"] = scan_model(
             scan_config(depth=2, width=256, registers=16, capacity=32,
@@ -157,7 +160,9 @@ def _worker(tmp_path, snapshot_calls, consumer=None, models=None):
 
     worker = StreamWorker(
         consumer or Consumer(_stream_to_bus(make_stream()), fixedlen=True),
-        models or _models(),
+        # the detector at 10 s: every batch of the stream spans three of
+        # its sub-windows, two of which run its own program alone
+        models or _models(sub=10),
         # sqlite as cli._make_sinks hands it over: behind the retry wrapper
         [CollectSink(), ResilientSink(SQLiteSink())],
         WorkerConfig(poll_max=BS, snapshot_every=2, host_assist="off",
@@ -257,9 +262,10 @@ def test_step_dispatch_counts_steps_and_fill(traced_run):
     applies = [s for s in spans if s[0] == "apply"]
     per_apply = [sum(_inside(s, a) for s in steps) for a in applies]
     assert all(n >= 1 for n in per_apply)
-    # batch 5 of the stream holds late rows: its slot group is split off
-    # and runs a padded step of its own
-    assert max(per_apply) >= 2
+    # batch 5 of the stream holds late rows: its slot run is a mask of
+    # its own and runs a padded step of its own; no other batch crosses
+    # a slot, and a sub-window crossing runs no second step
+    assert sorted(per_apply)[-2:] == [1, 2]
     assert sum(s[5]["rows"] for s in steps) == sum(
         a[5]["rows"] for a in applies)
 
@@ -539,13 +545,29 @@ def test_split_parts_counts_the_parts_of_a_poll(traced_run):
     cuts = [s for s in spans if s[0] == "split_parts"]
     applies = [s for s in spans if s[0] == "apply"]
     assert len(cuts) == len(applies)  # one a polled batch
-    # batch 5 holds late rows: a part of their own; the steps of an
-    # apply are at least its parts
-    assert max(s[5]["parts"] for s in cuts) >= 2
     steps = [s for s in spans if s[0] == "step_dispatch"]
+    alone = [s for s in spans if s[0] == "detector_dispatch"]
+    builds = [s for s in spans if s[0] == "lane_build"]
     for cut, a in zip(cuts, applies):
         assert _inside(cut, a)
-        assert sum(_inside(s, a) for s in steps) >= cut[5]["parts"] >= 1
+        mine = [s for s in steps if _inside(s, a)]
+        # a batch of the stream spans three of the detector's
+        # sub-windows; batch 5 holds late rows, two slots back, beside
+        # them. A slot run is one step, which carries the run's newest
+        # sub-window; each other part is the detector's program alone
+        assert cut[5]["parts"] >= 3
+        assert len(mine) + sum(_inside(s, a) for s in alone) \
+            == cut[5]["parts"]
+        assert len(mine) == (2 if cut[5]["parts"] == 4 else 1)
+        # the lanes are built once a batch whatever the cut
+        assert sum(_inside(s, a) for s in builds) == 1
+        # every row rides one of the two for the detector, unless it is
+        # late (batch 5's 25 rows: the step that carries them says so)
+        late = sum(s[5]["rows"] for s in mine if not s[5]["do_dd"])
+        assert (sum(s[5]["dd_rows"] for s in mine)
+                + sum(s[5]["rows"] for s in alone if _inside(s, a))
+                + late) == a[5]["rows"]
+    assert sorted(s[5]["parts"] for s in cuts)[-2:] == [3, 4]
 
 
 def test_a_tumbling_close_is_one_window_close_a_ranked_table(traced_run):

@@ -399,7 +399,12 @@ def test_fold_and_close_spans_count_groups_and_rows(whole, reference):
     # row of a key is a group of its own, so at least the rows emitted)
     assert all(0 <= f["inserted"] <= f["groups"] for f in folds)
     assert sum(f["inserted"] for f in folds) >= sum(per_slot.values())
-    assert min(f["inserted"] for f in folds) < AS_COUNT
+    # a store that fills leaves a drain fewer groups to insert: the rest
+    # are added into rows that are there (a poll is one partial since a
+    # sub-window crossing runs no second step: no drain is a late
+    # group's handful any more)
+    assert min(f["inserted"] for f in folds) \
+        < min(f["groups"] for f in folds) - AS_COUNT
     assert sorted(s["rows"] for s in _args(spans, "wagg_rows")) \
         == sorted(per_slot.values())
     assert sorted(s["rows"] for s in _args(spans, "flush")
