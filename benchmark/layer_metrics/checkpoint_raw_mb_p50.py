@@ -1,6 +1,7 @@
 """Bytes of the arrays one checkpoint serializes, in MB (what the file
-holds too: nothing is compressed since PR 30): median. Source:
-ckpt_serialize's raw_bytes."""
+holds too: nothing is compressed since PR 30): median. The as64k cell's
+holds the window store among them, the mesh cell's four stacked replicas
+of each sketch. Source: ckpt_serialize's raw_bytes."""
 
 from benchmark import program_spans
 

@@ -1,6 +1,9 @@
 """The fused step's least possible time over its measured device time, in
 per cent. Bytes and operations come from shapes (roofline.py), peaks from
-the table there keyed by device_kind. Source: profiler trace."""
+the table there keyed by device_kind. Source: profiler trace. In the
+as64k and the sliding cell no kernel is new and roofline.py's bytes serve
+unchanged: the AS labels change no shape, and the step's bytes are one
+sub-window's state, not ten (the ring never enters the step)."""
 
 from benchmark import reduce, roofline
 

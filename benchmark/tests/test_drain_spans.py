@@ -41,7 +41,8 @@ def test_the_manifest_lists_both_under_the_drains_layer():
         assert m["layer"] == LAYER
         assert m["moves"] == "sustained_flows_per_s"
         assert m["source"] == "program_span"
-        assert m["workloads"] == ["estate-catchup", "estate-live"]
+        # a later cell appends itself to a metric's list
+        assert m["workloads"][:2] == ["estate-catchup", "estate-live"]
     assert LAYER in {m["layer"] for m in per_layer
                      if m["name"] not in METRICS}
 
@@ -112,8 +113,10 @@ def test_dry_run_reports_both(dry_runs, cell):
     m = {k: v["value"] for k, v in line["metrics"].items()}
     assert m["batch_period_ms_p50"] > 0
     if cell == "tiny-catchup":
-        # full batches: every probe lags but a close's; a checkpoint's
-        # drain is the other kind
-        assert 50 < m["drain_lagged_share"] <= 100
+        # full batches: every probe lags but a close's; a close's and a
+        # checkpoint's drain are the other kind, and at this size they are
+        # most of the drains: ~70 batches, ~25 closes and ~35 checkpoints
+        # in the window, whatever the machine (all three count flows)
+        assert 20 < m["drain_lagged_share"] <= 100
     else:  # part-full batches while the loop keeps up: drained at once
         assert 0 <= m["drain_lagged_share"] < 100

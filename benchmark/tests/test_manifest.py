@@ -16,6 +16,12 @@ TINY = os.path.join(HERE, "fixtures", "BENCHMARK.tiny.json")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 
 
+# a configuration that names no stream kind gets zipf-ranks, in order, on
+# the one partition it states; one names its own (PR 39): the kind, the
+# most seconds a flow lies behind an earlier one, the partitions
+STREAMS = {"estate-2part": ("zipf-ranks-delayed", 3, 2)}
+
+
 def _man(path):
     with open(path) as f:
         return json.load(f)
@@ -29,15 +35,16 @@ def test_every_cell_of_the_benchmark_loads(cell):
     assert "setup_s" in {m["name"] for m, _ in c.end_to_end}
     assert len(c.end_to_end) >= 2 and len(c.per_layer) >= 1
     assert all(callable(r.read) for _, r in c.end_to_end + c.per_layer)
-    # no configuration names a stream kind: each gets zipf-ranks, and
-    # drives the one partition it states
-    assert "kind" not in c.config["stream"]
-    assert os.path.basename(c.stream.path) == "zipf-ranks.py"
+    kind, disorder_s, partitions = STREAMS.get(
+        c.config_name, (manifest.DEFAULT_STREAM, 0, 1))
+    assert c.config["stream"].get("kind", manifest.DEFAULT_STREAM) == kind
+    assert os.path.basename(c.stream.path) == kind + ".py"
     assert all(callable(getattr(c.stream.kind, a))
                for a in manifest.STREAM_API)
     spec = c.stream.spec(1, 65536, 0)
     assert all(hasattr(spec, a) for a in manifest.SPEC_API)
-    assert spec.max_disorder_s == 0 and c.config["bus_partitions"] == 1
+    assert spec.max_disorder_s == disorder_s
+    assert c.config["bus_partitions"] == partitions
 
 
 def test_cells_added_as_files_only():
@@ -141,3 +148,35 @@ def test_contract_shape():
         assert os.path.isfile(os.path.join(ROOT, c["file"]))
         assert set(c["reduced"]) <= set(_man(os.path.join(
             ROOT, c["file"]))["reduced"])
+
+
+# the twins that tests/ names, each with the file and line that names it
+# (at PR 41): a PR that may edit tests/ retires them into their bases'
+# lists, as the other seventeen were
+KEPT_TWINS = {
+    "device_steps_per_batch.2part": "tests/test_benchmark_seam.py:424",
+    "batch_fill_share.2part": "tests/test_benchmark_seam.py:425",
+    "step_device_ms_p50.2part": "tests/test_bench_stream_seam.py:474",
+    "fused_step_roofline.2part": "tests/test_bench_stream_seam.py:474",
+    "batch_period_ms_p50.2part": "tests/test_bench_stream_seam.py:475",
+    "checkpoint_raw_mb_p50.2part": "tests/test_bench_stream_seam.py:475",
+    "checkpoint_raw_mb_p50.sliding": "tests/test_benchmark_seam.py:382",
+}
+
+
+def test_a_new_cell_joins_a_metrics_list_and_adds_no_twin_of_it():
+    """An entry ``<base>.<suffix>`` that differs from the entry ``<base>``
+    in its name and cells alone is the same reader under a second name:
+    the cell belongs in the base's ``workloads``, and an entry of its own
+    is for a span, a counter or a kernel that is new."""
+    entries = {m["name"]: m for m in _man(REAL)["per_layer"]}
+
+    def rest(m):
+        return {k: v for k, v in m.items() if k not in ("name", "workloads")}
+
+    twins = [name for name, m in entries.items()
+             if rest(entries.get(name.rpartition(".")[0], {})) == rest(m)]
+    assert sorted(twins) == sorted(KEPT_TWINS)
+    for name, where in KEPT_TWINS.items():
+        with open(os.path.join(ROOT, where.split(":")[0])) as f:
+            assert f'"{name}"' in f.read(), where

@@ -22,10 +22,10 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 CELL = "estate-mesh4-catchup"
 TINY = os.path.join(HERE, "fixtures", "BENCHMARK.tiny.json")
+# the checkpoint's two are every cell's readers, which list this cell too
+SHARED_READERS = ["checkpoint_serialize_ms_p50", "checkpoint_raw_mb_p50"]
 SPAN_READERS = ["mesh_shard_ms_per_batch", "mesh_dispatch_ms_per_batch",
-                "mesh_drain_ms_p50", "chip_rows_min_share",
-                "checkpoint_serialize_ms_p50.mesh4",
-                "checkpoint_raw_mb_p50.mesh4"]
+                "mesh_drain_ms_p50", "chip_rows_min_share", *SHARED_READERS]
 TRACE_READERS = ["mesh_hh_update_ms", "mesh_dense_update_ms",
                  "mesh_ddos_update_ms", "mesh_wagg_update_ms",
                  "mesh_rest_device_ms", "mesh_merge_device_ms_per_close",
@@ -73,14 +73,18 @@ def test_the_entries_are_there_and_name_only_this_cell():
                         traffic="backlog-drain-mesh4", chips=4)
     assert [w["chips"] for w in man["workloads"]].count(4) == 1
     entries = {m["name"]: m for m in man["per_layer"]}
+    own = [name for name in NEW if name not in SHARED_READERS]
     for name in NEW:
-        assert entries[name]["workloads"] == [CELL]
+        assert CELL in entries[name]["workloads"]
         assert entries[name]["moves"] == "sustained_flows_per_s"
-    # no entry from before the cell names it
+    for name in own:
+        assert entries[name]["workloads"] == [CELL]
+    # no entry from before the cell names it, but the shared readers
     first = min(i for i, m in enumerate(man["per_layer"])
-                if m["name"] in NEW)
+                if m["name"] in own)
     assert all(CELL not in m["workloads"]
-               for m in man["per_layer"][:first] if "workloads" in m)
+               for m in man["per_layer"][:first]
+               if "workloads" in m and m["name"] not in SHARED_READERS)
     for name in ROOFLINES:
         assert entries[name]["unit"] == "%"
         assert entries[name]["source"] == "device_trace"
@@ -174,9 +178,9 @@ def test_drain_and_checkpoint_read_their_spans():
               {"raw_bytes": 53e6, "npz_bytes": 1e6})]
     run = _span_run(spans)
     assert _reader("mesh_drain_ms_p50").read(run) == pytest.approx(40.0)
-    assert _reader("checkpoint_serialize_ms_p50.mesh4").read(run) \
+    assert _reader("checkpoint_serialize_ms_p50").read(run) \
         == pytest.approx(4000.0)
-    assert _reader("checkpoint_raw_mb_p50.mesh4").read(run) \
+    assert _reader("checkpoint_raw_mb_p50").read(run) \
         == pytest.approx(53.0)
 
 
@@ -335,7 +339,7 @@ def test_the_traced_dry_run_reports(traced, name):
     assert value > 0
     if name.endswith("_share"):
         assert value <= 100.0
-    if name == "checkpoint_raw_mb_p50.mesh4":
+    if name == "checkpoint_raw_mb_p50":
         # four stacked replicas: the two 65536x3x2 int32 port planes
         # alone are 3.1 MB a chip, the three 4x3x4096 sketches 0.6 MB
         assert 4 * 3.7 < value < 4 * 5.0
