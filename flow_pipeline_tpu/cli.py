@@ -229,6 +229,12 @@ def _build_models(vals):
         hh_families.append(("top_talkers",
                             ("src_addr", "dst_addr", "src_port",
                              "dst_port", "proto")))
+    if vals["model.pairs"]:
+        # BASELINE config 2: (SrcAddr, DstAddr) heavy hitters, the key
+        # HeavyHitterConfig defaults to. A prefix of the 5-tuple and an
+        # extension of src_addr, so in the fused step it rides their
+        # sort as a third member (engine/fused.py: chains)
+        hh_families.append(("top_pairs", ("src_addr", "dst_addr")))
     if vals["model.ips"]:
         hh_families.append(("top_src_ips", ("src_addr",)))
         hh_families.append(("top_dst_ips", ("dst_addr",)))
@@ -366,6 +372,9 @@ def _processor_flags(fs: FlagSet) -> FlagSet:
               "| on | off")
     fs.boolean("model.flows5m", True, "Exact 5m rollup model")
     fs.boolean("model.talkers", True, "5-tuple top-K talkers model")
+    fs.boolean("model.pairs", False,
+               "Host-pair (src_addr, dst_addr) top-K model: table "
+               "top_pairs")
     fs.boolean("model.ips", True, "Top src/dst IP models")
     fs.boolean("model.ports", True, "Top src/dst port models")
     fs.boolean("model.ddos", True, "DDoS spike detector")

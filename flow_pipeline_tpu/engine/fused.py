@@ -85,6 +85,7 @@ log = get_logger("fused")
 # numpy (not jnp): a module-level jnp constant would initialize the JAX
 # backend at import time — importing the engine must never claim a chip
 _SENTINEL = np.uint32(0xFFFFFFFF)
+ETYPE_IPV4 = 0x0800
 
 
 def _hh_plan(cfg) -> tuple:
@@ -330,7 +331,14 @@ class _Lanes:
         if start not in self._placed:
             bs = self._bs
             chunk = self._batch.slice(start, start + bs)
-            with TRACER.span("lane_build", rows=len(chunk), padded=bs):
+            # rows whose address lanes hold a v4 address, left-padded:
+            # counted before the span that times the build, and only for
+            # a recorder that keeps it
+            v4 = ({"v4_rows": int(np.count_nonzero(
+                chunk.columns["etype"] == ETYPE_IPV4))}
+                if TRACER.recording else {})
+            with TRACER.span("lane_build", rows=len(chunk), padded=bs,
+                             **v4):
                 padded, mask = chunk.pad_to(bs)
                 host_cols = padded.device_columns(self._names)
             with TRACER.span("h2d", cols=len(host_cols) + 1) as span:
@@ -432,6 +440,10 @@ class FusedPipeline(WindowLifecycle):
         # [families] int32 on the device: live_rows of the last step that
         # fed the tables; read by hh_live alone
         self._live_rows = None
+        # {family: its table's keys as byte strings} at hh_live's last
+        # read under a recorder, and the steps that fed the tables since
+        self._table_rows: dict = {}
+        self._hh_steps = 0
         # The compiled step is cached on the static spec, NOT per instance:
         # every bench sample / supervisor restart builds a fresh pipeline,
         # and a per-instance jit would recompile the whole fused graph
@@ -520,8 +532,36 @@ class FusedPipeline(WindowLifecycle):
         the dispatch loop."""
         if self._live_rows is None or not self._hh:
             return {}
-        return {"hh_live_rows": int(np.max(self._live_rows)),
-                "hh_slots": self._bs}
+        out = {"hh_live_rows": int(np.max(self._live_rows)),
+               "hh_slots": self._bs}
+        if TRACER.recording:
+            out["hh_admitted"] = self._admitted()
+            out["hh_steps"], self._hh_steps = self._hh_steps, 0
+        return out
+
+    def _admitted(self) -> dict:
+        """{family: keys its table holds now and did not hold at the
+        last call}, counted on the host (a key that came and went in
+        between is not seen; a table a close emptied reads as all new).
+        The tables' keys cross to the host in one read, ~40 KB a
+        family: for hh_live's caller alone."""
+        out = {}
+        tables = jax.device_get([w.model.state.table_keys
+                                 for _, w in self._hh])
+        for (name, _), keys in zip(self._hh, tables):
+            rows = keys[(keys != _SENTINEL).any(axis=1)]
+            # a key row as one byte string
+            held = set(rows.view(f"V{rows.strides[0]}").ravel().tolist())
+            out[name] = len(held - self._table_rows.get(name, set()))
+            self._table_rows[name] = held
+        return out
+
+    @property
+    def hh_families(self) -> tuple:
+        """The sketch families' names in the order of the step's
+        ``hh_table_merge_<i>`` scopes: the index a trace reader needs to
+        tell one family's merge from another's."""
+        return tuple(name for name, _ in self._hh)
 
     # ---- host lifecycle ---------------------------------------------------
 
@@ -652,6 +692,7 @@ class FusedPipeline(WindowLifecycle):
                 )
             if do_hh:
                 self._live_rows = live_rows
+                self._hh_steps += 1
             new_hh, new_dense, new_ddos = new_states
             for (_, w), st in zip(self._hh, new_hh):
                 w.model.state = st

@@ -356,6 +356,11 @@ class WindowedHeavyHitter(HeldUnits):
             top["timeslot"] = np.full(
                 len(top["valid"]), slot, dtype=np.uint64)
             span["rows"] = int(top["valid"].sum())
+            # the largest sum the table emits: past 2^24 a float32 plane
+            # no longer holds every integer (ops/cms.py)
+            ranked = top.get("bytes")
+            if ranked is not None and span["rows"]:
+                span["bytes_max"] = float(ranked[top["valid"]].max())
             self._pending.append(top)
 
     def _emit_slide(self, sub: int, open_state) -> None:

@@ -116,11 +116,20 @@ def topk_exact(
     k: int,
     value_col: str = "bytes",
     timeslot: bool = False,
+    scale_col: str | None = None,
 ) -> dict[str, np.ndarray]:
     """Exact top-K keys by summed value — heavy-hitter ground truth.
-    Ties broken by key order (stable) so results are deterministic."""
-    g = exact_groupby(batch, key_cols, [value_col], timeslot=timeslot)
-    order = np.argsort(-g[value_col].astype(np.int64), kind="stable")[:k]
+    Ties broken by key order (stable) so results are deterministic.
+
+    With ``scale_col`` the ranking is by the exact ``<value>_scaled`` sum
+    (value * max(rate, 1), uint64): what a ranked family with
+    ``HeavyHitterConfig.scale_col`` estimates, and the dashboards'
+    ``sum(bytes*sampling_rate)``. The raw sums ride along."""
+    g = exact_groupby(batch, key_cols, [value_col], timeslot=timeslot,
+                      scale_col=scale_col)
+    ranked = g[f"{value_col}_scaled" if scale_col else value_col]
+    # descending on uint64 without a signed cast: sums may pass 2^63
+    order = np.argsort(ranked.max(initial=0) - ranked, kind="stable")[:k]
     return {name: arr[order] for name, arr in g.items()}
 
 

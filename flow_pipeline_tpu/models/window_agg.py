@@ -159,6 +159,20 @@ def _first_read(partial) -> np.ndarray:
                     partial[0].ndim == 3)
 
 
+def _distinct_rates(parts: list) -> int:
+    """How many sampling rates the rows of one drain came under: the
+    store is keyed by (key lanes, rate), the rate last. One compare where
+    every row has the first row's rate (an unsampled stream), a sort
+    only where they differ."""
+    lanes = [p[:, -1] for p in parts if len(p)]
+    if not lanes:
+        return 0
+    first = lanes[0][0]
+    if all((lane == first).all() for lane in lanes):
+        return 1
+    return len(np.unique(np.concatenate(lanes)))
+
+
 def _packed(keys: np.ndarray) -> np.ndarray:
     """Each key row as one big-endian byte string: numpy orders and
     searches those bytewise, which is the rows' lexicographic order."""
@@ -471,9 +485,16 @@ class WindowAggregator:
                     all_sums.append(sums_np[d, :g])
                     all_counts.append(counts_np[d, :g])
             span["bytes"] = nbytes
+        # counted outside the span that times the fold, and only for a
+        # recorder that keeps it
+        rates = (_distinct_rates(all_keys)
+                 if self.config.scale_col is not None and TRACER.recording
+                 else 0)
         with TRACER.span("wagg_fold") as span:
             keys = np.concatenate(all_keys)
             span["groups"] = len(keys)
+            if rates:
+                span["rates"] = rates
             span["inserted"] = self._merge_partials(
                 keys, np.concatenate(all_sums), np.concatenate(all_counts))
             span["store_groups"] = sum(map(len, self.windows.values()))

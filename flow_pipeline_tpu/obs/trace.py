@@ -162,6 +162,12 @@ class TraceRecorder:
     def mode(self) -> str:
         return self._mode
 
+    @property
+    def recording(self) -> bool:
+        """True iff a span entered now would be kept: what a caller asks
+        before it computes a span arg that costs more than a ``len``."""
+        return self._mode != "off" and not self.paused
+
     # ---- recording --------------------------------------------------------
 
     def record(self, name: str, t0: float, t1: float,

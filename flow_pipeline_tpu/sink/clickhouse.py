@@ -76,6 +76,7 @@ class ClickHouseSink:
             # flush 400s and the processor crash-loops
             for stmt in (ddl.CLICKHOUSE_FLOWS_RAW, ddl.CLICKHOUSE_FLOWS_5M,
                          ddl.CLICKHOUSE_TOP_TALKERS,
+                         ddl.CLICKHOUSE_TOP_PAIRS,
                          ddl.CLICKHOUSE_TOP_SRC_IPS,
                          ddl.CLICKHOUSE_TOP_DST_IPS,
                          ddl.CLICKHOUSE_TOP_SRC_PORTS,

@@ -62,6 +62,18 @@ CREATE TABLE IF NOT EXISTS top_talkers (
 );
 """
 
+POSTGRES_TOP_PAIRS = """
+CREATE TABLE IF NOT EXISTS top_pairs (
+    timeslot  BIGINT,
+    rank      INT,
+    src_addr  TEXT,
+    dst_addr  TEXT,
+    bytes     BIGINT,
+    packets   BIGINT,
+    count     BIGINT
+);
+"""
+
 POSTGRES_TOP_SRC_IPS = """
 CREATE TABLE IF NOT EXISTS top_src_ips (
     timeslot  BIGINT,
@@ -157,6 +169,19 @@ CREATE TABLE IF NOT EXISTS top_talkers (
     src_port UInt32,
     dst_port UInt32,
     proto UInt32,
+    bytes UInt64,
+    packets UInt64,
+    count UInt64
+) ENGINE = MergeTree()
+ORDER BY (timeslot, rank);
+"""
+
+CLICKHOUSE_TOP_PAIRS = """
+CREATE TABLE IF NOT EXISTS top_pairs (
+    timeslot UInt64,
+    rank UInt32,
+    src_addr String,
+    dst_addr String,
     bytes UInt64,
     packets UInt64,
     count UInt64
@@ -264,6 +289,8 @@ TABLE_COLUMNS = {
                  "count", "bytes_scaled", "packets_scaled"],
     "top_talkers": ["timeslot", "rank", "src_addr", "dst_addr", "src_port",
                     "dst_port", "proto", "bytes", "packets", "count"],
+    "top_pairs": ["timeslot", "rank", "src_addr", "dst_addr", "bytes",
+                  "packets", "count"],
     "top_src_ips": ["timeslot", "rank", "src_addr", "bytes", "packets",
                     "count"],
     "top_dst_ips": ["timeslot", "rank", "dst_addr", "bytes", "packets",
@@ -280,7 +307,7 @@ TABLE_COLUMNS = {
 }
 
 
-RANKED_TABLES = {"top_talkers", "top_src_ips", "top_dst_ips",
+RANKED_TABLES = {"top_talkers", "top_pairs", "top_src_ips", "top_dst_ips",
                  "top_src_ports", "top_dst_ports"}
 
 
@@ -323,6 +350,12 @@ CREATE TABLE IF NOT EXISTS flows_5m (
 CREATE TABLE IF NOT EXISTS top_talkers (
     timeslot INTEGER, rank INTEGER, src_addr TEXT, dst_addr TEXT,
     src_port INTEGER, dst_port INTEGER, proto INTEGER,
+    bytes INTEGER, packets INTEGER, count INTEGER
+);
+""",
+    "top_pairs": """
+CREATE TABLE IF NOT EXISTS top_pairs (
+    timeslot INTEGER, rank INTEGER, src_addr TEXT, dst_addr TEXT,
     bytes INTEGER, packets INTEGER, count INTEGER
 );
 """,

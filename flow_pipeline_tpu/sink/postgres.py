@@ -32,6 +32,7 @@ DDL = {
     "flows": ddl.POSTGRES_FLOWS,
     "flows_5m": ddl.POSTGRES_FLOWS_5M,
     "top_talkers": ddl.POSTGRES_TOP_TALKERS,
+    "top_pairs": ddl.POSTGRES_TOP_PAIRS,
     "top_src_ips": ddl.POSTGRES_TOP_SRC_IPS,
     "top_dst_ips": ddl.POSTGRES_TOP_DST_IPS,
     "top_src_ports": ddl.POSTGRES_TOP_SRC_PORTS,

@@ -33,8 +33,8 @@ KNOWN_FLAGS = frozenset({
     # processor
     "processor.backend", "processor.batch", "processor.mesh",
     "processor.fused", "processor.hostassist",
-    "model.flows5m", "model.talkers", "model.ips", "model.ports",
-    "model.ddos",
+    "model.flows5m", "model.talkers", "model.pairs", "model.ips",
+    "model.ports", "model.ddos",
     "sketch.width", "sketch.cms", "sketch.prefilter", "sketch.admission",
     "sketch.capacity", "sketch.topk", "sketch.backend", "hh.sketch",
     # flowspread (models/spread.py) — distinct-count detectors

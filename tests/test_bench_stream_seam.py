@@ -26,6 +26,12 @@ with open(os.path.join(FIXTURES, "zipf_ranks_digests.json")) as f:
     # chunk_blob of chunks 0, 1 and the last of every cell at 51 s, two
     # seeds, by the parent of PR 38 (2a8a86d) before the first edit
     DIGESTS = json.load(f)
+with open(os.path.join(ROOT, "tests", "data",
+                       "backbone_ranks_digests.json")) as f:
+    # the same of hh-backbone-catchup, as PR 42 left the kind
+    # backbone-ranks: a later PR that changes a stream's bytes changes
+    # what every ledger line of its cells measured
+    DIGESTS.update(json.load(f))
 
 
 # ---- a kind is a file, found by name ---------------------------------------
@@ -95,7 +101,7 @@ def test_a_kind_under_a_fixture_path_is_found_after_the_benchmarks_own():
 
 
 @pytest.mark.parametrize("at", sorted(DIGESTS))
-def test_zipf_ranks_makes_the_parents_bytes(at):
+def test_a_stream_kind_makes_the_bytes_it_was_recorded_making(at):
     name, seed = at.split(":")
     cell = manifest.load_cell(ROOT, REAL, name)
     plan = cell.mode.plan(cell.traffic, cell.stream, 51.0)
@@ -265,6 +271,9 @@ def test_every_cell_loads_a_stream_kind_with_the_whole_api(name):
     if name == CELL_2PART:
         assert os.path.basename(c.stream.path) == "zipf-ranks-delayed.py"
         assert spec.max_disorder_s == 3 and c.config["bus_partitions"] == 2
+    elif name == CELL_BACKBONE:
+        assert os.path.basename(c.stream.path) == "backbone-ranks.py"
+        assert spec.max_disorder_s == 0 and c.config["bus_partitions"] == 1
     else:
         # no other configuration names a stream kind: each gets
         # zipf-ranks, and drives the one partition it states
@@ -320,6 +329,7 @@ def test_slot_sums_group_by_slot_where_event_time_runs_backwards():
 # ---- ISSUE 39: zipf-ranks-delayed and estate-2part ---------------------------
 
 CELL_2PART = "estate-2part-catchup"
+CELL_BACKBONE = "hh-backbone-catchup"
 SEEDS = [2**31 + 11, 3700001001]
 
 
@@ -475,4 +485,185 @@ def test_the_benchmark_stays_inside_its_limits():
         "batch_period_ms_p50.2part", "checkpoint_raw_mb_p50.2part",
         "late_rows_folded_share", "late_rows_dropped",
         "held_close_delay_ms_p50", "held_units_at_checkpoint_p50",
-        "partition_skew_s_p50"]
+        "partition_skew_s_p50", "detector_dispatch_per_batch"]
+
+
+# ---- the stream kind backbone-ranks (ISSUE 42) --------------------------------
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_backbone_ranks_has_the_spec_api_and_the_table_its_file_states(seed):
+    at = str(seed)
+    cell = manifest.load_cell(ROOT, REAL, CELL_BACKBONE)
+    plan = cell.mode.plan(cell.traffic, cell.stream, 51.0)
+    spec = schedule.spec_for(seed, cell.stream, plan)
+    table = cell.stream.kind.key_table(spec)  # the real size: 4x10^6 ranks
+    stream = cell.config["stream"]
+    assert all(hasattr(spec, a) for a in manifest.SPEC_API)
+    assert spec.seed == int(at) and spec.max_disorder_s == 0
+    assert len(table) == stream["n_keys"] == 4_000_000
+    # both families, the v4 address in the trailing four bytes
+    v4 = table.etype == 0x0800
+    assert set(np.unique(table.etype)) == {0x0800, 0x86DD}
+    assert abs(v4.mean() - stream["v4_share"]) < 0.002
+    for words in (table.src_addr, table.dst_addr):
+        assert not words[v4, :3].any()
+        assert (words[v4, 3] >> 20 == 0x0A000000 >> 20).all()
+        assert (words[~v4, 0] == 0x20010DB8).all()
+    assert (table.src_ip >> stream["host_bits"] == v4).all()
+    # a rate a rank, from the file's list in the file's shares
+    rates, counts = np.unique(table.sampling_rate, return_counts=True)
+    assert rates.tolist() == stream["rates"]
+    assert np.allclose(counts / len(table), stream["rate_shares"],
+                       atol=0.002)
+    # the event clock, the closes and the dealing are zipf-ranks' own
+    other = _stream().spec(int(at), plan.first_close_flow, plan.phase_s)
+    idx = np.array([0, 65535, 65536, 10**6, 5 * 10**7])
+    assert np.array_equal(spec.event_ts(idx), other.event_ts(idx))
+    assert spec.close_flows(0, 10**8) == other.close_flows(0, 10**8)
+    assert np.array_equal(spec.partition_of(idx, 3), idx % 3)
+    # the columns carry the table's family and rate at each flow's rank
+    draws = cell.stream.kind.chunk_draws(spec, table, 5)
+    cols = cell.stream.kind.chunk_columns(spec, table, 5, draws)
+    assert np.array_equal(cols["etype"], table.etype[draws[0]])
+    assert np.array_equal(cols["sampling_rate"],
+                          table.sampling_rate[draws[0]].astype(np.uint64))
+    assert np.array_equal(cols["src_addr"], table.src_addr[draws[0]])
+
+
+def test_backbone_ranks_names_the_keys_it_does_not_know_or_lacks():
+    with open(os.path.join(ROOT, "benchmark/configs/hh-backbone.json")) as f:
+        params = json.load(f)["stream"]
+    stream = manifest.load_stream(ROOT, PATHS, {**params, "etype": 34525})
+    with pytest.raises(ValueError, match="etype"):
+        stream.spec(1, 65536, 0)
+    lacking = {k: v for k, v in params.items() if k != "host_bits"}
+    with pytest.raises(ValueError, match="host_bits"):
+        manifest.load_stream(ROOT, PATHS, lacking).spec(1, 65536, 0)
+    with pytest.raises(ValueError, match="rate_shares"):
+        manifest.load_stream(ROOT, PATHS, {
+            **params, "rate_shares": [0.5, 0.3]}).spec(1, 65536, 0)
+
+
+def test_the_sampled_table_kinds_sum_in_integers_flow_by_flow():
+    """``exact_sums_sampled`` and ``ranked_bytes_sampled`` against a sum
+    made one flow at a time in Python integers: each flow's bytes times
+    its own rank's rate."""
+    from benchmark.reference import Reference
+
+    cell = manifest.load_cell(
+        ROOT, os.path.join(FIXTURES, "BENCHMARK.tiny-backbone.json"),
+        "tiny-backbone-catchup")
+    spec = cell.stream.spec(2**31 + 11, 4096, 0)
+    kind = cell.stream.kind
+    table = kind.key_table(spec)
+    draws = [kind.chunk_draws(spec, table, c) for c in range(4)]
+    rank, nbytes, packets = (np.concatenate([d[i] for d in draws])
+                             for i in range(3))
+    idx = np.arange(len(rank))
+    ref = Reference(spec, table)
+    sums = ref.slot_sums(idx, rank, nbytes, packets)
+    slot = (spec.event_ts(idx).astype(np.int64) // spec.slot_seconds
+            * spec.slot_seconds).tolist()
+    exact, pairs = {}, {}
+    for s, r, b, p in zip(slot, rank.tolist(), nbytes.tolist(),
+                          packets.tolist()):
+        rate = int(table.sampling_rate[r])
+        k = (s, int(table.src_as[r]), int(table.dst_as[r]),
+             int(table.etype[r]))
+        e = exact.setdefault(k, [0, 0, 0, 0, 0])
+        for i, v in enumerate((b, p, 1, b * rate, p * rate)):
+            e[i] += v
+        k = (int(table.src_ip[r]), int(table.dst_ip[r]))
+        pairs.setdefault(s, {}).setdefault(k, 0)
+        pairs[s][k] += b * rate
+    entries = {e["name"]: e for e in cell.config["checks"]["tables"]}
+    sampled = cell.table_kinds["exact_sums_sampled"]
+    want = sampled.want(ref, entries["flows_5m"], sums)
+    assert want == {k: tuple(v) for k, v in exact.items()}
+    assert len({k[3] for k in want}) == 2  # both families
+    ranked = cell.table_kinds["ranked_bytes_sampled"]
+    got = ranked.want(ref, entries["top_pairs"], sums)
+    for s, keys in got.items():
+        best = sorted(pairs[s].items(), key=lambda kv: -kv[1])
+        assert list(keys.values())[:50] == [v for _k, v in best[:50]]
+        assert all(pairs[s][k] == v for k, v in keys.items())
+    # a wrong scaled sum and a missing group are each seen
+    good = dict(want)
+    numbers = sampled.compare(entries["flows_5m"], want, good, len(rank))
+    assert [v for v, _lim in numbers.values()] == [0, 0, 0]
+    k = next(iter(good))
+    off = {**good, k: (*good[k][:3], good[k][3] + 1, good[k][4])}
+    assert sampled.compare(entries["flows_5m"], want, off, len(rank))[
+        "flows5m_scaled_mismatches"] == (1, 0)
+    less = {q: v for q, v in good.items() if q != k}
+    found = sampled.compare(entries["flows_5m"], want, less, len(rank))
+    assert found["flows5m_mismatched_groups"][0] == 1
+    assert found["unaccounted_flows"][0] == good[k][2]
+    # addresses of both families come back to the kind's own numbering
+    bits = cell.config["stream"]["host_bits"]
+    assert ranked._ip("10.0.0.5", bits) == 5 | 1 << bits
+    assert ranked._ip("2001:db8:0:1::9", bits) == 9
+    assert ranked._ip("10.16.0.5", bits) == -1  # outside 10.0.0.0/12
+    assert ranked._ip("2001:db8:0:2::9", bits) == -1
+
+
+def test_the_backbone_roofline_counts_four_families_at_the_files_width():
+    from benchmark import backbone_roofline as br
+
+    with open(os.path.join(ROOT, "benchmark/configs/hh-backbone.json")) as f:
+        cfg = json.load(f)
+    fams = br.families(cfg)
+    assert fams == [br.FIVE, ("src_addr", "dst_addr"), ("src_addr",),
+                    ("dst_addr",)]
+    assert br._chains(fams) == (3, 0)  # one sort for three, none alone
+    whole = br.hh_step_bytes(cfg)
+
+    def with_flags(*changed):
+        return br.hh_step_bytes({"processor_flags": [
+            f for f in cfg["processor_flags"]
+            if f.split("=")[0] not in {c.split("=")[0] for c in changed}]
+            + list(changed)})
+
+    rows, cap = 32768, 1024
+    pair = (2 * rows * 4 * 3 * 4            # its count-min cells
+            + 2 * cap * (8 + 3) * 4         # its table
+            + 2 * rows * 2 * 4)             # its two hash lanes on the sort
+    assert whole - with_flags("-model.pairs=false") == pair
+    # a batch touches at most as many cells of a row as it has rows
+    flags = cfg["processor_flags"]
+    at = flags.index("-sketch.width")
+    narrow = dict(cfg, processor_flags=[*flags[:at + 1], "1024",
+                                        *flags[at + 2:]])
+    assert whole - br.hh_step_bytes(narrow) == 4 * 2 * (
+        rows - 1024) * 4 * 3 * 4
+    least, bound = br.hh_step_least_seconds(cfg, "TPU v5 lite")
+    assert bound == "hbm_bytes" and least == whole / 819e9
+    with pytest.raises(KeyError):
+        br.hh_step_least_seconds(cfg, "cpu")
+
+
+def test_a_familys_merge_scope_is_told_from_the_others_by_its_index():
+    from benchmark import family_scopes
+
+    text = """
+%fused_computation.7 (p.1: f32[8], p.2: f32[8]) -> f32[8] {
+  %p.1 = f32[8]{0} parameter(0)
+  %t.8 = f32[8]{0} transpose(%p.1), metadata={op_name="jit(step)/hh_table_merge_2/transpose"}
+  ROOT %s.9 = f32[8]{0} scatter(%p.1, %t.8)
+}
+
+ENTRY %main (a.1: f32[8]) -> f32[8] {
+  %a.1 = f32[8]{0} parameter(0), metadata={op_name="jit(step)/hh_chain_sort/sort"}
+  %f.7 = f32[8]{0} fusion(%c.3, %a.1), kind=kLoop, calls=%fused_computation.7
+  %f.2 = f32[8]{0} fusion(%a.1), kind=kLoop, metadata={op_name="jit(step)/hh_table_merge_1/scatter"}
+  %c.3 = f32[8]{0} copy(%f.2)
+  %f.4 = f32[8]{0} fusion(%a.1), kind=kLoop, metadata={op_name="jit(step)/hh_table_merge_10/while/body/gather"}
+  %f.5 = f32[8]{0} fusion(%a.1), kind=kLoop, metadata={op_name="jit(step)/hh_table_merge_0/top_k"}
+  %c.6 = f32[8]{0} copy(%a.1)
+}
+"""
+    # f.7 has no metadata of its own: what is fused into it says whose it
+    # is, before its first operand (another family's) does
+    assert family_scopes.merge_index_map(text) == {
+        "t.8": 2, "s.9": 2, "f.7": 2,
+        "f.2": 1, "c.3": 1, "f.4": 10, "f.5": 0}

@@ -30,6 +30,8 @@ TINY_AS = "benchmark/tests/fixtures/BENCHMARK.tiny-as.json"
 TINY_SLIDING = "benchmark/tests/fixtures/BENCHMARK.tiny-sliding.json"
 # and estate-2part-catchup's (ISSUE 39)
 TINY_2PART = "benchmark/tests/fixtures/BENCHMARK.tiny-2part.json"
+# and hh-backbone-catchup's (ISSUE 42)
+TINY_BACKBONE = "benchmark/tests/fixtures/BENCHMARK.tiny-backbone.json"
 
 
 class Cell(NamedTuple):
@@ -39,6 +41,7 @@ class Cell(NamedTuple):
     devices: int     # virtual CPU devices; 0: the backend's one
     manifest: str = TINY
     control: str = ""  # controls compared beside the program's result
+    seconds: int = 3   # of measured window
 
 
 # 2^31+26 loses one flow of the mesh cell at the tiny capacity (PERF.md
@@ -59,6 +62,11 @@ SLIDING_KIND = "ranked_bytes_sliding"
 # two partitions, flows out of order, -window.lateness: run once, traced,
 # with both controls
 TWOPART_CELL = "tiny-2part-catchup"
+# the host-pair family, three sampling rates and both address families:
+# run once, traced, with both controls
+BACKBONE_CELL = "tiny-backbone-catchup"
+BACKBONE = "hh-backbone-catchup"
+BACKBONE_KIND = "ranked_bytes_sampled"
 # the live and the four-chip twin, traced too (ISSUE 35: the spans of the
 # serve path and of a publish of four stacked replicas)
 LIVE_CELL, MESH_CELL = "tiny-live", "tiny-mesh4-catchup"
@@ -69,17 +77,19 @@ TRACED = {TRACED_CELL: CELLS[TRACED_CELL],
                         0, TINY_AS),
           SLIDING_CELL: Cell("estate-sliding-catchup", "FusedPipeline",
                              2**31 + 11, 0, TINY_SLIDING,
-                             f"bf16,bf16:{SLIDING_KIND}"),
+                             f"bf16,bf16:{SLIDING_KIND}", seconds=5),
           TWOPART_CELL: Cell("estate-2part-catchup", "FusedPipeline",
                              2**31 + 11, 0, TINY_2PART,
-                             "bf16,bf16:ranked_bytes")}
+                             "bf16,bf16:ranked_bytes"),
+          BACKBONE_CELL: Cell(BACKBONE, "FusedPipeline", 2**31 + 11, 0,
+                              TINY_BACKBONE,
+                              f"bf16,bf16:{BACKBONE_KIND}")}
 # they read the `XLA Modules` line of a /device:TPU plane: a CPU trace has
 # none, and a CPU number never goes under a device metric's name
 TPU_PLANE_ONLY = ("step_device_ms_p50", "fused_step_roofline",
-                  "step_device_ms_p50.as64k", "fused_step_roofline.as64k",
-                  "step_device_ms_p50.sliding", "fused_step_roofline.sliding",
                   "slide_fold_device_ms_per_slide", "slide_fold_roofline",
-                  "step_device_ms_p50.2part", "fused_step_roofline.2part")
+                  "step_device_ms_p50.2part", "fused_step_roofline.2part",
+                  "hh_step_roofline")
 
 
 # ISSUE 35's readers of the program's spans inside the layers that only
@@ -157,7 +167,7 @@ def dry_run(tmp_path_factory):
                                     f"{spec.devices}")
             p = subprocess.run(
                 [*command, "--manifest", manifest, "--workload", cell,
-                 "--seed", str(spec.seed), "--seconds", "3",
+                 "--seed", str(spec.seed), "--seconds", str(spec.seconds),
                  "--trace", str(trace),
                  *(["--control", spec.control] if spec.control else [])],
                 cwd=ROOT, env=env, capture_output=True, text=True,
@@ -222,7 +232,8 @@ def test_traced_dry_run_reads_every_layer_metric(dry_run, cell, metric):
         assert math.isfinite(line["metrics"][metric]["value"])
 
 
-@pytest.mark.parametrize("cell", [AS_CELL, SLIDING_CELL, TWOPART_CELL])
+@pytest.mark.parametrize("cell", [AS_CELL, SLIDING_CELL, TWOPART_CELL,
+                                  BACKBONE_CELL])
 def test_a_twin_is_the_ledgers_cell_at_the_tiny_size(cell):
     """Every per-layer metric the ledger's cell reports, and no other:
     the fixture's own in the ledger's order, then those added since."""
@@ -277,13 +288,16 @@ def test_an_inside_metric_lists_the_cells_that_have_its_span(metric):
         assert entry["moves"] == "query_staleness_p50_s"
         return
     assert entry["moves"] == "sustained_flows_per_s"
+    # each list ends with the cell ISSUE 42 added: it has every span
     if metric == "split_parts_ms_p50":  # the fused pipeline's cut
-        assert entry["workloads"] == [*ONE_CHIP, TRACED[TWOPART_CELL].ledger]
+        assert entry["workloads"] == [*ONE_CHIP, TRACED[TWOPART_CELL].ledger,
+                                      BACKBONE]
     elif metric == "close_extract_ms_per_close":  # a tumbling close
         assert sorted(entry["workloads"]) == sorted(
-            c for c in ALL_LEDGER_CELLS if c != "estate-sliding-catchup")
+            [*(c for c in ALL_LEDGER_CELLS if c != "estate-sliding-catchup"),
+             BACKBONE])
     else:
-        assert entry["workloads"] == ALL_LEDGER_CELLS
+        assert entry["workloads"] == [*ALL_LEDGER_CELLS, BACKBONE]
 
 
 # the two-partition twin lists its ledger cell's metrics and no other:
@@ -321,7 +335,8 @@ def test_the_live_share_is_listed_for_the_fused_steps_cells():
     assert entry == {
         "name": LIVE_SHARE, "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "fused device step",
-        "moves": "sustained_flows_per_s", "workloads": ONE_CHIP}
+        "moves": "sustained_flows_per_s",
+        "workloads": [*ONE_CHIP, BACKBONE]}
 
 
 @pytest.mark.parametrize("cell", TILED)
@@ -351,7 +366,7 @@ def test_the_live_twin_carries_a_flows_age_to_the_snapshot(dry_run):
     assert v["publish_view_ms_p50"] + v["publish_swap_ms_p50"] \
         <= v["publish_ms_p50"] * 1.05 + 1.0
     for cell in (TRACED_CELL, MESH_CELL, AS_CELL, SLIDING_CELL,
-                 TWOPART_CELL):
+                 TWOPART_CELL, BACKBONE_CELL):
         assert not [m for m in _values(dry_run, cell) if m in INSIDE_METRICS
                     and m.endswith(".live")]
 
@@ -377,7 +392,10 @@ def test_the_sliding_twin_slides_on_the_fused_path(dry_run):
     assert value["slides_in_window"] >= 5 > value["window_closes_in_window"]
     assert value["slide_rows_per_close"] > 100
     assert value["compiles_in_window"] == 0
-    # ten sub-window states on the device, one in a checkpoint
+    # ten sub-window states on the device, one in a checkpoint (8.7
+    # checkpoints' worth once the ring is full: the twin's 5 s hold the
+    # slides that fill it under six workers too, where 3 s held the
+    # median at a ring half full, 4.4 in PR 42's whole run)
     assert value["ring_mb_on_device"] > 5 * value[
         "checkpoint_raw_mb_p50.sliding"]
     assert 0 < value["checkpoint_member_mb_p50"] < value[
@@ -437,3 +455,59 @@ def test_the_2part_twins_controls_come_out_not_correct(dry_run, control):
     assert "topk_bytes_max_rel_err" in failed
     if ":" in control:
         assert failed == {"topk_bytes_max_rel_err"}
+
+
+def test_the_backbone_twin_ranks_sampled_dual_stack_keys_on_the_fused_path(
+        dry_run):
+    """`-model.pairs` through cli.processor_main on a stream of both
+    address families whose ranks carry one of three sampling rates: the
+    four ranked tables (`top_pairs` among them) and `flows_5m`'s scaled
+    columns are the reference's over exactly the flows consumed, and the
+    new spans' readers say what the stream is."""
+    line = _result(dry_run, BACKBONE_CELL, trace=1)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["window"]["dataplane"] == TRACED[BACKBONE_CELL].dataplane
+    checks = {c["name"]: c["value"] for c in line["checks"]}
+    assert {"flows5m_mismatched_groups", "flows5m_scaled_mismatches",
+            "unaccounted_flows", "topk_bytes_max_rel_err",
+            "commit_offset_gap", "commits_ahead_of_flush",
+            "query_mismatches"} == set(checks)
+    assert checks["topk_bytes_max_rel_err"] < 1e-5
+    assert all(v == 0 for n, v in checks.items()
+               if n != "topk_bytes_max_rel_err")
+    value = {name: m["value"] for name, m in line["metrics"].items()}
+    assert value["compiles_in_window"] == 0
+    assert 50 < value["v4_rows_share"] < 70     # v4_share 0.6 of the ranks
+    assert value["fold_rates_per_batch"] == 3   # every drain mixes them
+    assert value["ranked_sum_log2_max"] > 24    # past float32's integers
+    assert value["table_admissions_per_batch"] >= 0
+    assert 0 < value["step_pairs_merge_ms"] < value["step_table_merge_ms"]
+    assert "hh_step_roofline" not in value and "step_ddos_ms" not in value
+
+
+@pytest.mark.parametrize("control", TRACED[BACKBONE_CELL].control.split(","))
+def test_the_backbone_twins_controls_come_out_not_correct(dry_run, control):
+    line = _result(dry_run, BACKBONE_CELL, trace=1)
+    found = next(c for c in line["controls"] if c["control"] == control)
+    assert found["correct"] is False
+    failed = {c["name"] for c in found["checks"] if not c["ok"]}
+    assert "topk_bytes_max_rel_err" in failed
+    if ":" in control:
+        assert failed == {"topk_bytes_max_rel_err"}
+    else:
+        assert {"flows5m_mismatched_groups",
+                "flows5m_scaled_mismatches"} <= failed
+
+
+def test_the_detectors_own_runs_are_counted_where_polls_cross_sub_windows(
+        dry_run):
+    """PR 40's mechanism has its reader (ISSUE 42): listed for the
+    two-partition cell alone, where two polls in five hold rows of two
+    detector sub-windows."""
+    (entry,) = [e for e in _manifest("BENCHMARK.json")["per_layer"]
+                if e["name"] == "detector_dispatch_per_batch"]
+    assert entry["workloads"] == [TRACED[TWOPART_CELL].ledger]
+    assert entry["source"] == "program_counter"
+    assert _values(dry_run, TWOPART_CELL)["detector_dispatch_per_batch"] > 0
+    assert "detector_dispatch_per_batch" not in _values(dry_run,
+                                                        BACKBONE_CELL)

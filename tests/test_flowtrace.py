@@ -58,6 +58,18 @@ class TestTraceRecorder:
         assert tracer.snapshot() == []
         assert tracer.chrome_trace()["traceEvents"] == []
 
+    @pytest.mark.parametrize("mode, paused, keeps", [
+        ("off", False, False), ("ring", False, True), ("always", False, True),
+        ("ring", True, False)])
+    def test_recording_says_whether_a_span_would_be_kept(self, tracer, mode,
+                                                         paused, keeps):
+        tracer.configure(mode)
+        tracer.paused = paused
+        assert tracer.recording is keeps
+        with tracer.span("y"):
+            pass
+        assert bool(tracer.snapshot()) is keeps
+
     def test_ring_bounds_and_overwrites_oldest(self, tracer):
         for i in range(20):
             tracer.record("s", float(i), float(i) + 0.5, chunk=i)
