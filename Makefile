@@ -41,7 +41,7 @@ lint:
 
 # Seeded-mutation smoke for the lint gate itself: three mutations into
 # a scratch copy of the tree (a deleted family registration surface, a
-# deleted fsync barrier inside write_bytes_durable, an RLock downgraded
+# deleted fsync barrier inside staged_durable, an RLock downgraded
 # to a self-deadlocking Lock), each of which the owning rule must fail
 # naming the defect — a lint that cannot fail is indistinguishable from
 # no lint. The durability leg is the static prong of the two-prong

@@ -29,21 +29,28 @@ staging directory, the staging directory is atomically renamed over
 the target, and the containing directory is fsynced — so a crash at
 ANY point leaves the complete old checkpoint (possibly under ``.old``)
 or the complete new one, never a torn or silently-empty mix. The
-crash-point model checker (``make crash-parity``) enumerates every
-window of the save and pins exactly that. Only numpy/json are used —
-no pickle, so a checkpoint directory is safe to share between trust
-domains.
+arrays go through that idiom streamed, not whole: ``_write_npz`` writes
+the archive member by member into the staging file that
+``fsutil.staged_durable`` holds open, each array's bytes in one write
+from the array's own buffer, and the fsync, the replace and the
+directory fsync follow when that block ends; a 50 MB checkpoint is
+never a second time in memory. The crash-point model checker
+(``make crash-parity``) enumerates every window of the save and pins
+exactly that. Only numpy/json are used — no pickle, so a checkpoint
+directory is safe to share between trust domains.
 """
 
 from __future__ import annotations
 
 # flowlint: durable-checked
 
+import contextlib
 import io
 import json
 import os
 import shutil
 import tempfile
+import zipfile
 from typing import Any
 
 import numpy as np
@@ -76,14 +83,33 @@ def _write_member(path: str, member: Member) -> None:
         os.makedirs(parent, exist_ok=True)
         fsutil.fsync_dir(os.path.dirname(parent))
     with TRACER.span("ckpt_member", sub=member.sub) as span:
-        buf = io.BytesIO()
-        np.savez(buf, **{k: np.asarray(v)
-                         for k, v in member.arrays.items()})
-        data = buf.getvalue()
-        span["bytes"] = len(data)
-        fsutil.write_bytes_durable(
-            os.path.join(parent, member.file + ".npz"), data)
+        with fsutil.staged_durable(
+                os.path.join(parent, member.file + ".npz")) as f:
+            _write_npz(f, member.arrays)
+            span["bytes"] = f.tell()
     member.arrays, member.written = None, True
+
+
+def _write_npz(f, arrays: dict) -> None:
+    """Stream ``arrays`` into ``f`` as the archive ``np.savez`` makes
+    and ``np.load`` opens: a zip of stored ``<name>.npy`` members with
+    their CRCs, zip64 where it takes one. Each array goes from its own
+    buffer to the file in one write (one copy only where a leaf is not
+    C-contiguous). A :class:`fsutil.DurableFile` has no ``seek``, so
+    ``zipfile`` writes its streaming form: a member's CRC and size
+    follow its bytes and stand in the central directory, which is what
+    a reader goes by."""
+    with zipfile.ZipFile(f, "w", zipfile.ZIP_STORED) as archive:
+        for name, arr in arrays.items():
+            arr = np.asarray(arr, order="C")  # copies a strided leaf
+            if arr.dtype.hasobject:
+                raise ValueError(f"{name}: a checkpoint holds no "
+                                 f"object arrays (no pickle)")
+            with archive.open(name + ".npy", "w",
+                              force_zip64=True) as member:
+                np.lib.format.write_array_header_1_0(
+                    member, np.lib.format.header_data_from_array_1_0(arr))
+                member.write(memoryview(arr.reshape(-1).view(np.uint8)))
 
 
 def _prune_members(path: str, named: list) -> None:
@@ -187,64 +213,81 @@ def _freeze(key):
     return tuple(key) if isinstance(key, list) else key
 
 
-def save_checkpoint(path: str, state: Any) -> None:
+def save_checkpoint(path: str, state: Any, *, whole: bool = False) -> None:
     """Atomically and DURABLY write ``state`` (nested dicts/lists/
     NamedTuples/arrays). The payloads are staged (and individually
     fsynced) in a sibling temp directory, the directory is renamed over
     the target, and the parent directory entry is fsynced — only then
     is the superseded ``.old`` tree deleted, so every crash window
-    leaves a complete old or complete new checkpoint on disk."""
+    leaves a complete old or complete new checkpoint on disk.
+
+    ``whole`` builds ``arrays.npz`` in memory and writes it in one
+    piece, as every checkpoint was written before the arrays were
+    streamed: the same file to a reader behind the same barriers, with
+    three more passes over the bytes. Only the mesh processor asks for
+    it, and only until its benchmark cell has room for the faster form
+    (``parallel/pipeline.py::ShardedPipeline.checkpoint_whole``)."""
     parent = os.path.dirname(os.path.abspath(path)) or "."
     os.makedirs(parent, exist_ok=True)
     with TRACER.span("ckpt_d2h", bytes=0, leaves=0) as span:
         state = _to_host(state, span)
     tmp = tempfile.mkdtemp(prefix=".ckpt-", dir=parent)
     try:
-        # serialize in memory, publish through the one durable-write
-        # idiom (write tmp -> fsync -> replace -> dir fsync): numpy's
-        # own savez path never fsyncs
-        with TRACER.span("ckpt_serialize") as span:
-            arrays: dict[str, np.ndarray] = {}
-            members: list[Member] = []
-            meta = _encode(state, arrays, "r", members)
-            buf = io.BytesIO()
-            np.savez(buf, **arrays)
-            npz = buf.getvalue()
-            meta_json = json.dumps(meta).encode("utf-8")
-            span["raw_bytes"] = sum(a.nbytes for a in arrays.values())
-            span["members"] = len(arrays)
-            span["npz_bytes"] = len(npz)
-        for member in members:  # durable before the checkpoint names it
-            if not member.written:
-                _write_member(path, member)
-        with TRACER.span("ckpt_write"):
-            fsutil.write_bytes_durable(os.path.join(tmp, "arrays.npz"), npz)
-            fsutil.write_bytes_durable(os.path.join(tmp, "meta.json"),
-                                       meta_json)
-            if os.path.isdir(path):
-                old = path + ".old"
-                # a crash between the renames below can leave a stale .old;
-                # clear it or every future snapshot fails with ENOTEMPTY
-                if os.path.isdir(old):
+        # publish through the one durable-write idiom (write tmp ->
+        # fsync -> replace -> dir fsync; numpy's own savez path never
+        # fsyncs): the arrays are streamed into their staging file
+        # under ckpt_serialize, and ``staging`` says the rest of the
+        # sentence under ckpt_write
+        with contextlib.ExitStack() as staging:
+            with TRACER.span("ckpt_serialize") as span:
+                arrays: dict[str, np.ndarray] = {}
+                members: list[Member] = []
+                meta = _encode(state, arrays, "r", members)
+                meta_json = json.dumps(meta).encode("utf-8")
+                npz = os.path.join(tmp, "arrays.npz")
+                if whole:
+                    buf = io.BytesIO()
+                    np.savez(buf, **arrays)
+                    data = buf.getvalue()
+                else:
+                    f = staging.enter_context(fsutil.staged_durable(npz))
+                    _write_npz(f, arrays)
+                span["raw_bytes"] = sum(a.nbytes for a in arrays.values())
+                span["members"] = len(arrays)
+                span["npz_bytes"] = len(data) if whole else f.tell()
+            for member in members:  # durable before the checkpoint names it
+                if not member.written:
+                    _write_member(path, member)
+            with TRACER.span("ckpt_write"):
+                if whole:
+                    fsutil.write_bytes_durable(npz, data)
+                staging.close()  # the arrays' fsync, replace and dir fsync
+                fsutil.write_bytes_durable(os.path.join(tmp, "meta.json"),
+                                           meta_json)
+                if os.path.isdir(path):
+                    old = path + ".old"
+                    # a crash between the renames below can leave a stale .old;
+                    # clear it or every future snapshot fails with ENOTEMPTY
+                    if os.path.isdir(old):
+                        fsutil.rmtree(old)
+                    fsutil.rename(path, old)
+                    fsutil.rename(tmp, path)
                     fsutil.rmtree(old)
-                fsutil.rename(path, old)
-                fsutil.rename(tmp, path)
-                fsutil.rmtree(old)
-            else:
-                fsutil.rename(tmp, path)
-                # a crash between the two renames of a PREVIOUS save leaves
-                # the predecessor under .old with no primary; now that a
-                # complete new checkpoint is published (rename above), the
-                # stale .old is superseded — clear it AFTER publishing so
-                # no crash window is ever left with neither tree
-                if os.path.isdir(path + ".old"):
-                    fsutil.rmtree(path + ".old")
-            # directory-entry barrier: the renames above (and the .old
-            # cleanup) are durable only once the parent directory is —
-            # without this a power loss after the ack could silently revert
-            # an acked checkpoint to its predecessor
-            fsutil.fsync_dir(parent)
-            _prune_members(path, members)
+                else:
+                    fsutil.rename(tmp, path)
+                    # a crash between the two renames of a PREVIOUS save leaves
+                    # the predecessor under .old with no primary; now that a
+                    # complete new checkpoint is published (rename above), the
+                    # stale .old is superseded — clear it AFTER publishing so
+                    # no crash window is ever left with neither tree
+                    if os.path.isdir(path + ".old"):
+                        fsutil.rmtree(path + ".old")
+                # directory-entry barrier: the renames above (and the .old
+                # cleanup) are durable only once the parent directory is —
+                # without this a power loss after the ack could silently revert
+                # an acked checkpoint to its predecessor
+                fsutil.fsync_dir(parent)
+                _prune_members(path, members)
     except BaseException:
         # flowlint: disable=durability-protocol -- best-effort cleanup of the unpublished staging dir on a failed save; no ack references it, resurrection after a crash is harmless garbage
         shutil.rmtree(tmp, ignore_errors=True)

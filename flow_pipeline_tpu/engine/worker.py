@@ -811,7 +811,9 @@ class StreamWorker:
                     for m in self.models.values())
         if state is not None:
             # ckpt_d2h, ckpt_serialize, ckpt_write: inside save_checkpoint
-            save_checkpoint(self.config.checkpoint_path, state)
+            save_checkpoint(
+                self.config.checkpoint_path, state,
+                whole=getattr(self.fused, "checkpoint_whole", False))
         self._emitted_since_snapshot = False
         with TRACER.span("ckpt_commit", chunk=self._trace_chunk):
             for partition, next_off in sorted(self._covered.items()):

@@ -24,7 +24,8 @@ rule; docs/FAULT_TOLERANCE.md states what each surface promises):
   containing directory — fsyncing contents alone does not persist the
   directory entry, power loss can drop a fully-synced file;
 - an atomic publish is ``write tmp → fsync tmp → replace → fsync_dir``;
-  :func:`write_bytes_durable` is that whole sentence as one call.
+  :func:`staged_durable` is that whole sentence round a writer, and
+  :func:`write_bytes_durable` the same for bytes that are whole.
 
 ``suppressed(...)`` exists for the mutation smoke only: it deletes one
 barrier kind (``fsync`` / ``fsync_dir`` / ``replace``) from the
@@ -43,8 +44,8 @@ from typing import Optional
 
 __all__ = [
     "OpRecorder", "observed", "suppressed", "open_durable",
-    "fsync_file", "fsync_dir", "write_bytes_durable", "replace",
-    "rename", "remove", "rmtree",
+    "fsync_file", "fsync_dir", "staged_durable", "write_bytes_durable",
+    "replace", "rename", "remove", "rmtree",
 ]
 
 
@@ -133,16 +134,21 @@ def _rec(op: tuple) -> None:
 class DurableFile:
     """Thin binary-file proxy that reports writes to the observer.
     Supports the surface the durable writers use: ``write``, ``flush``,
-    ``fileno``, ``tell``, ``close``, context manager."""
+    ``fileno``, ``tell``, ``close``, context manager. No ``seek``: the
+    op log is every byte at the offset it landed on, offsets rising, and
+    a writer that would patch what it wrote (``zipfile``) streams."""
 
     def __init__(self, path: str, raw):
         self.path = path
         self._raw = raw
 
     def write(self, data) -> int:
+        obs = _observer
+        if obs is None:  # no copy of a 12 MB plane for nobody to read
+            return self._raw.write(data)
         off = self._raw.tell()
         n = self._raw.write(data)
-        _rec(("write", self.path, off, bytes(data)))
+        obs.record(("write", self.path, off, bytes(data)))
         return n
 
     def flush(self) -> None:
@@ -257,15 +263,24 @@ def rmtree(path: str) -> None:
     _rec(("rmtree", path))
 
 
-def write_bytes_durable(path: str, data: bytes) -> None:
-    """The whole atomic-publish sentence as one call: write a sibling
-    temp file, fsync it, atomically replace ``path``, fsync the
-    containing directory. After this returns, ``path`` holds exactly
-    ``data`` across any crash — or the previous contents of ``path``
-    if the crash beat the replace."""
+@contextlib.contextmanager
+def staged_durable(path: str):
+    """The whole atomic-publish sentence round a writer: yields a
+    sibling temp file of ``path``, open; when the block ends, fsyncs it,
+    atomically replaces ``path`` and fsyncs the containing directory.
+    After the block, ``path`` holds exactly what was written across any
+    crash — or its previous contents if the crash beat the replace. A
+    block that raises publishes nothing."""
     tmp = path + ".tmp"
     with open_durable(tmp, "wb") as f:
-        f.write(data)
+        yield f
         fsync_file(f)
     replace(tmp, path)
     fsync_dir(os.path.dirname(os.path.abspath(path)) or ".")
+
+
+def write_bytes_durable(path: str, data: bytes) -> None:
+    """:func:`staged_durable` for bytes that are whole already: after
+    this returns, ``path`` holds exactly ``data`` across any crash."""
+    with staged_durable(path) as f:
+        f.write(data)

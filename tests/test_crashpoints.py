@@ -252,6 +252,55 @@ def _check_checkpoint(croot: str, acked: list) -> None:
             "acked checkpoint restored a torn state"
 
 
+# ---- scenario: a checkpoint of several arrays --------------------------------
+#
+# arrays.npz is streamed into its staging file one write an array
+# (engine/checkpoint.py::_write_npz), so the crash points of a save fall
+# between two arrays' writes and, by the torn tails and the dropped
+# writes, inside one. Whatever is left, a reader sees one whole state.
+
+
+def _multi_state(step: int) -> dict:
+    return {"step": step,
+            "cms": np.full((3, 512), step, np.float32),
+            "keys": np.arange(96, dtype=np.uint64) * step,
+            "valid": np.arange(40) % (step + 1) == 0,
+            "sums": np.arange(60, dtype=np.int64).reshape(12, 5)[:, ::2]
+            - step}
+
+
+_MULTI_ARRAYS = ("cms", "keys", "valid", "sums")
+
+
+def _run_checkpoint_multi(root: str, rec: fsutil.OpRecorder,
+                          whole: bool = False) -> None:
+    path = os.path.join(root, "ckpt", "snap")
+    with fsutil.observed(rec):
+        for step in (1, 2):
+            save_checkpoint(path, _multi_state(step), whole=whole)
+            rec.mark(f"m{step}")
+
+
+def _run_checkpoint_whole(root: str, rec: fsutil.OpRecorder) -> None:
+    """The mesh processor's form (ShardedPipeline.checkpoint_whole): the
+    archive built in memory and written in one piece."""
+    _run_checkpoint_multi(root, rec, whole=True)
+
+
+def _check_checkpoint_multi(croot: str, acked: list) -> None:
+    path = os.path.join(croot, "ckpt", "snap")
+    if not acked and not checkpoint_exists(path):
+        return  # crashed before anything was published: fine
+    got = load_checkpoint(path)  # loads completely or raises
+    want = _multi_state(got["step"])
+    for name in _MULTI_ARRAYS:  # every array of ONE step: no torn tree
+        assert got[name].dtype == want[name].dtype
+        assert np.array_equal(got[name], want[name]), \
+            f"{name} is not step {got['step']}'s"
+    if "m2" in acked:
+        assert got["step"] == 2, "acked checkpoint m2 did not restore"
+
+
 # ---- scenario: the window store's array form in a checkpoint ----------------
 
 
@@ -369,6 +418,8 @@ _SCENARIOS = {
     "deadletter": (_run_dlq, _check_dlq),
     "archive": (_run_archive, _check_archive),
     "checkpoint": (_run_checkpoint, _check_checkpoint),
+    "checkpoint_multi": (_run_checkpoint_multi, _check_checkpoint_multi),
+    "checkpoint_whole": (_run_checkpoint_whole, _check_checkpoint_multi),
     "checkpoint_wagg": (_run_checkpoint_wagg, _check_checkpoint_wagg),
 }
 
@@ -420,6 +471,10 @@ class TestBarrierMutations:
         ("deadletter", "replace"),
         ("checkpoint", "fsync"), ("checkpoint", "fsync_dir"),
         ("checkpoint", "replace"),
+        ("checkpoint_multi", "fsync"), ("checkpoint_multi", "fsync_dir"),
+        ("checkpoint_multi", "replace"),
+        ("checkpoint_whole", "fsync"), ("checkpoint_whole", "fsync_dir"),
+        ("checkpoint_whole", "replace"),
         ("checkpoint_wagg", "fsync"), ("checkpoint_wagg", "fsync_dir"),
         ("checkpoint_wagg", "replace"),
         ("checkpoint_ring", "fsync"), ("checkpoint_ring", "fsync_dir"),
@@ -466,6 +521,33 @@ class TestCheckpointMidSave:
         save_checkpoint(path, _CKPT_2)
         assert not os.path.isdir(path + ".old")
         assert _ckpt_equal(load_checkpoint(path), _CKPT_2)
+
+    def test_one_write_an_array_in_the_op_log(self, tmp_path):
+        """The recorder sees every byte of the streamed arrays.npz at
+        the offset it landed on, offsets rising, and each array's bytes
+        as ONE write from the array's buffer: the crash points between
+        two of them and the torn tails inside one are what
+        test_every_crash_state_recovers[checkpoint_multi] replays."""
+        path = str(tmp_path / "snap")
+        state = _multi_state(1)
+        rec = fsutil.OpRecorder()
+        with fsutil.observed(rec):
+            save_checkpoint(path, state)
+        writes = [op for op in rec.ops if op[0] == "write"
+                  and op[1].endswith("arrays.npz.tmp")]
+        offsets = [op[2] for op in writes]
+        assert offsets == sorted(offsets)
+        assert [off + len(data) for _, _, off, data in writes][:-1] == \
+            offsets[1:]  # no hole, no byte written twice
+        for name in _MULTI_ARRAYS:
+            raw = np.ascontiguousarray(state[name]).tobytes()
+            assert sum(data == raw for *_, data in writes) == 1, name
+        with open(os.path.join(path, "arrays.npz"), "rb") as f:
+            assert f.read() == b"".join(op[3] for op in writes)
+        # the fsync of what was staged comes before the name it gets
+        kinds = [(op[0], os.path.basename(op[1])) for op in rec.ops]
+        assert kinds.index(("fsync", "arrays.npz.tmp")) < \
+            kinds.index(("replace", "arrays.npz.tmp"))
 
     def test_torn_payload_rejects_loudly(self, tmp_path):
         """A damaged arrays.npz must raise, never silently decode."""

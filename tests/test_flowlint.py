@@ -9,6 +9,8 @@ import os
 import sys
 import textwrap
 
+import pytest
+
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, REPO)
 
@@ -1910,6 +1912,41 @@ class TestDurabilityProtocol:
         assert len(out) == 1
         assert "no later fsutil.fsync_file(fh)" in out[0].message
 
+    @pytest.mark.parametrize("synced", [True, False])
+    def test_a_handle_yielded_is_written_through(self, tmp_path, synced):
+        """`yield f` hands f to a block that writes it: the handle owes
+        the fsync that a literal f.write would
+        (fsutil.staged_durable, which the checkpoint's streamed
+        arrays.npz goes through)."""
+        out = _lint(tmp_path, _DUR + f"""
+            import contextlib
+
+            @contextlib.contextmanager
+            def staged(path):
+                tmp = path + ".tmp"
+                with fsutil.open_durable(tmp, "wb") as f:
+                    yield f
+                    {"fsutil.fsync_file(f)" if synced else "pass"}
+                fsutil.replace(tmp, path)
+                fsutil.fsync_dir(".")
+        """, rules=("durability-protocol",))
+        msgs = " ".join(f.message for f in out)
+        if synced:
+            assert out == []
+        else:
+            assert "no later fsutil.fsync_file(f)" in msgs
+            assert "never fsynced" in msgs
+
+    def test_staged_durable_is_the_whole_sentence(self, tmp_path):
+        """A block under fsutil.staged_durable owes nothing more: the
+        helper fsyncs, replaces and fsyncs the directory when it ends."""
+        out = _lint(tmp_path, _DUR + """
+            def f(path, fill):
+                with fsutil.staged_durable(path) as f:
+                    fill(f)
+        """, rules=("durability-protocol",))
+        assert out == []
+
     def test_replace_of_unsynced_temp_flagged(self, tmp_path):
         out = _lint(tmp_path, _DUR + """
             def f(path):
@@ -2100,8 +2137,9 @@ class TestDurabilityMutationGate:
          r"self\._journal\.sync\(\)", 3),   # fence()'s ack barrier
         ("flow_pipeline_tpu/mesh/coordinator.py",
          r"self\._journal\.sync\(\)", 5),   # submit()'s ack barrier
-        # the dead-letter spill is one write_bytes_durable call; its
-        # three barriers live in fsutil's own protocol sentence
+        # the dead-letter spill is one write_bytes_durable call and the
+        # checkpoint's streamed arrays.npz one staged_durable block;
+        # their three barriers live in fsutil's own protocol sentence
         ("flow_pipeline_tpu/utils/fsutil.py",
          r"^        fsync_file\(f\)", 0),
         ("flow_pipeline_tpu/utils/fsutil.py",

@@ -22,6 +22,7 @@ operator's interrupt does, or the way a kill does.
 import os
 import sqlite3
 import tempfile
+import zipfile
 
 import numpy as np
 import pytest
@@ -346,6 +347,10 @@ def test_checkpoint_kill_and_restore_give_the_uninterrupted_rows(
     assert committed == 6 * CHIPS * BATCH
     state = np.load(tmp_path / "ckpt" / "arrays.npz")
     assert any(a.shape[:1] == (CHIPS,) for a in state.values())
+    with zipfile.ZipFile(tmp_path / "ckpt" / "arrays.npz") as archive:
+        # written whole (ShardedPipeline.checkpoint_whole): no member is
+        # in the streamed form, whose CRC and sizes follow its bytes
+        assert not any(i.flag_bits & 0x08 for i in archive.infolist())
     second = Harness(bus, monkeypatch)
     restored = second.run(_argv(tmp_path))
     TRACER.configure("off")

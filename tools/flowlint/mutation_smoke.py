@@ -8,7 +8,7 @@ owning rule fails the mutant while naming the defect:
 - **family**: the spread family's ``merge=`` registration line is
   deleted — family-citizenship must name the missing surface;
 - **durability**: the ``fsync_file(f)`` barrier inside
-  ``fsutil.write_bytes_durable`` is deleted (the way a bad refactor
+  ``fsutil.staged_durable`` is deleted (the way a bad refactor
   would) — durability-protocol must flag the now-torn publish. This is
   the static prong of the durability mutation gate; the dynamic prong
   (``make crash-parity``) proves the same deletion produces a

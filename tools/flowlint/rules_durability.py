@@ -9,7 +9,8 @@ durable-write protocol through ``utils/fsutil``:
 - a created/renamed NAME becomes durable at ``fsync_dir`` on its
   containing directory;
 - an atomic publish is ``write tmp -> fsync tmp -> replace ->
-  fsync_dir`` (``write_bytes_durable`` is the whole sentence).
+  fsync_dir`` (``staged_durable`` is the whole sentence round a writer,
+  ``write_bytes_durable`` the same for whole bytes).
 
 This rule models that protocol over the AST of every module marked
 ``# flowlint: durable-checked``. Within a marked module it reports:
@@ -25,7 +26,9 @@ This rule models that protocol over the AST of every module marked
   implementation);
 - **unsynced-write**: a write to a tracked durable handle with no
   lexically-later ``fsync_file`` on that handle in the same function
-  and no group-commit annotation (see below);
+  and no group-commit annotation (see below). A ``yield`` of the
+  handle is a write to it: the block that takes it writes through it
+  (``fsutil.staged_durable``);
 - **replace-before-fsync**: ``fsutil.replace``/``rename`` whose source
   is a temp file that was written but never fsynced first — the
   published file could be empty or torn after a crash;
@@ -79,7 +82,7 @@ CORE_REL = "flow_pipeline_tpu/utils/fsutil.py"
 _H_OPEN = "open_durable"
 _H_FSYNC = "fsync_file"
 _H_FSYNC_DIR = "fsync_dir"
-_H_WBD = "write_bytes_durable"
+_H_WHOLE = {"write_bytes_durable", "staged_durable"}
 _H_NAME_OPS = {"replace": "replace", "rename": "rename",
                "remove": "remove", "rmtree": "rmtree"}
 
@@ -160,6 +163,7 @@ class _Fn:
     def __init__(self, node: ast.FunctionDef):
         self.node = node
         self.calls: list[ast.Call] = []
+        self.yields: list[ast.Yield] = []
         self.handles: dict[str, ast.Call] = {}  # handle key -> open call
         self.temp_paths: set[str] = set()  # staging path variable names
         self._scan(node)
@@ -182,6 +186,8 @@ class _Fn:
                             self.handles[key] = item.context_expr
             if isinstance(child, ast.Call):
                 self.calls.append(child)
+            if isinstance(child, ast.Yield) and child.value is not None:
+                self.yields.append(child)
             self._scan(child)
 
     def _scan_assign(self, node: ast.Assign) -> None:
@@ -396,7 +402,7 @@ def _check_function(sf: SourceFile, cls: str | None, fn: _Fn,
         if name == _H_FSYNC_DIR:
             dirsyncs.append(line)
             continue
-        if name == _H_WBD:
+        if name in _H_WHOLE:
             continue  # the whole protocol in one self-contained call
         if name in _H_NAME_OPS:
             src = _arg_name(call, 0)
@@ -404,6 +410,12 @@ def _check_function(sf: SourceFile, cls: str | None, fn: _Fn,
             if name in ("replace", "rename") and src:
                 published.add(src)
             continue
+
+    # ---- a handle yielded: the block that takes it writes through it -------
+    for node in fn.yields:
+        key = _handle_expr(node.value)
+        if key in handles:
+            writes.append((node.lineno, key))
 
     # ---- unsynced handle writes --------------------------------------------
     for line, handle in writes:
