@@ -29,7 +29,11 @@ owner at a time (the parent imports neither jax nor flow_pipeline_tpu):
     cache        a second, short pipeline process on the same shapes: the
                  fused step must come out of the persistent compile cache
     cms_kernels  ops/cms_pallas.py compiled (not interpreted) at the
-                 processor's default shapes, bit for bit against ops/cms
+                 processor's default shapes, bit for bit against ops/cms;
+                 ops/cms's conservative update (padding slots out of the
+                 scatter) bit for bit against the one that scatters every
+                 slot, at widths 2^16 and 2^18 on a Zipf, a part-full and
+                 an all-distinct batch, with each one's ms a call
     mesh4        the same stream with -processor.mesh 4 (skipped, with
                  the device count it saw, on fewer than four devices)
     oracle4      (CPU) the oracle checks on mesh4's output
@@ -544,11 +548,128 @@ def stage_cms_kernels(args) -> dict:
                     f"cells at round {r}")
         if float(jnp.max(want)) >= 2**24:
             raise AssertionError("inputs left the exact f32 envelope")
+    live = _padding_leaves_the_scatter(
+        rng, n, lanes, planes, depth,
+        widths=(512, 1024) if args.tiny else (1 << 16, 1 << 18),
+        n_keys=2000 if args.tiny else 1_000_000,
+        rounds=rounds, reps=3 if args.tiny else 100)
     return {"device": device, "interpret": bool(args.tiny),
             "shape": {"groups": n, "key_lanes": lanes, "planes": planes,
                       "depth": depth, "width": width},
             "kernels": sorted(pairs), "rounds": rounds, "bit_exact": True,
-            **clog.record()}
+            "padding_leaves_the_scatter": live, **clog.record()}
+
+
+def _conservative_with_every_slot_scattered(counts, keys, values, valid,
+                                            n_live):
+    """ops.cms.cms_add_conservative as it ran until PR 45: the estimate
+    under the live bound, then every one of the N slots in every row's
+    scatter-max. The reference the update is held to, and timed beside."""
+    import jax.numpy as jnp
+
+    from flow_pipeline_tpu.ops import cms
+
+    _, depth, width = counts.shape
+    buckets = cms.cms_buckets(keys, depth, width)
+    vals = jnp.where(valid[:, None], values.astype(jnp.float32), 0.0)
+    target = cms.cms_query(counts, keys, n_live) + vals
+    for di in range(depth):
+        counts = counts.at[:, di, buckets[di]].max(target.T)
+    return counts
+
+
+def _group_slots(rng, n, lanes, planes, n_keys) -> dict:
+    """{batch: (uniq, sums, valid)}: the N group slots that the device
+    group-by (ops.segment.hash_groupby_float, the one hh_update runs)
+    hands the update for three batches of N rows: ``zipf`` (Zipf 1.1 over
+    ``n_keys`` keys, the catch-up cells' stream: ~30 % of the slots hold
+    a group), ``part_full`` (the same with an eighth of the rows there, a
+    live poll) and ``all_distinct`` (N keys, no slot is padding). The
+    padding slots hold what the group-by leaves in them."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flow_pipeline_tpu.ops.segment import hash_groupby_float
+
+    table = rng.integers(0, 2**32, size=(n_keys, lanes), dtype=np.uint32)
+    weights = 1.0 / np.arange(1, n_keys + 1) ** 1.1
+    ranks = rng.choice(n_keys, size=n, p=weights / weights.sum())
+    values = rng.integers(1, 1500, size=(n, planes)).astype(np.float32)
+    every = np.ones(n, bool)
+    rows = {
+        "zipf": (table[ranks], every),
+        "part_full": (table[ranks], np.arange(n) < n // 8),
+        "all_distinct": (rng.integers(0, 2**32, size=(n, lanes),
+                                      dtype=np.uint32), every),
+    }
+    out = {}
+    for name, (keys, valid) in rows.items():
+        uniq, sums, counts = hash_groupby_float(
+            jnp.asarray(keys), jnp.asarray(values), jnp.asarray(valid))
+        out[name] = (uniq, sums, counts > 0)
+    return out
+
+
+def _padding_leaves_the_scatter(rng, n, lanes, planes, depth, *, widths,
+                                n_keys, rounds, reps) -> dict:
+    """PR 45: at the cells' shapes and on the three batches of
+    ``_group_slots``, the conservative update with its padding slots out
+    of the scatter leaves the state of the update that scatters every
+    slot, bit for bit over ``rounds`` updates of one sketch (so the later
+    ones raise cells that hold mass), and what a call of each takes: the
+    median of three timings of ``reps`` calls with the state donated, as
+    the fused step has it. A time, not a metric: the step's own is the
+    benchmark's ``step_device_ms_p50``."""
+    import statistics
+
+    import jax
+    import numpy as np
+
+    from flow_pipeline_tpu.models.heavy_hitter import live_rows
+    from flow_pipeline_tpu.ops import cms
+
+    forms = {
+        "every_slot": jax.jit(_conservative_with_every_slot_scattered,
+                              donate_argnums=0),
+        "ops_cms": jax.jit(cms.cms_add_conservative, donate_argnums=0),
+    }
+
+    def ms_a_call(fn, width, batch):
+        state = fn(cms.cms_init(planes, depth, width), *batch)
+        timings = []
+        for _ in range(3):
+            jax.block_until_ready(state)
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                state = fn(state, *batch)
+            jax.block_until_ready(state)
+            timings.append((time.perf_counter() - t0) * 1e3 / reps)
+        return round(statistics.median(timings), 4)
+
+    out = {}
+    for name, (uniq, sums, valid) in _group_slots(
+            rng, n, lanes, planes, n_keys).items():
+        batch = (uniq, sums[:, :planes], valid, live_rows(valid))
+        rec = out[name] = {"slots": n, "real": int(valid.sum()),
+                           "live_rows": int(batch[3]), "ms_a_call": {}}
+        for width in widths:
+            # each form donates its state: two buffers from the start
+            want = cms.cms_init(planes, depth, width)
+            got = cms.cms_init(planes, depth, width)
+            for r in range(rounds):
+                want = forms["every_slot"](want, *batch)
+                got = forms["ops_cms"](got, *batch)
+                if np.asarray(want).tobytes() != np.asarray(got).tobytes():
+                    raise AssertionError(
+                        f"ops.cms.cms_add_conservative differs from the "
+                        f"update that scatters every slot: batch {name}, "
+                        f"width {width}, round {r}")
+            if rec["real"] and not np.asarray(got).any():
+                raise AssertionError(f"batch {name} raised no cell")
+            rec["ms_a_call"][str(width)] = {
+                form: ms_a_call(fn, width, batch)
+                for form, fn in forms.items()}
+    return out
 
 
 def stage_mesh4(args) -> dict:

@@ -182,7 +182,9 @@ def _cms_add(config: HeavyHitterConfig, n_live):
     share ops.cms's bucket scheme and state layout, so the selection can
     change between runs (even mid-stream) without invalidating a sketch.
     ``n_live`` (live_rows of the groups to come) goes to the one op whose
-    cost it bounds: the xla conservative update's estimate."""
+    cost it bounds: the xla conservative update, whose estimate gathers
+    the rows below it and whose scatters drop the rows that are not
+    valid (all at or beyond it, and the holes below)."""
     if config.cms_impl == "pallas":
         from ..ops import cms_pallas
 

@@ -22,16 +22,20 @@ a distinct seed per row.
 
 The live bound. A device group-by hands over N group SLOTS of which only
 some are real (ops.segment: a batch of N rows yields N slots; ~30 % of
-them hold a group on the benchmark's stream), and on a TPU a gather costs
-by the index (13 ns each on a v5e), real or not. Callers that know where
-their real rows end (models.heavy_hitter._apply_grouped: 1 + the index of
-the last valid row) pass that as ``n_live`` and the conservative update's
-pre-update estimate gathers only the chunks of rows below it. Skipping a
-padding row changes no bit of the state, because such a row is a no-op
-either way: its addends are 0 (``valid`` is False), so its ceiling is
-its own estimate, which is the min of the cells it would raise; and with
-the estimate left at 0 the ceiling is 0, which raises no cell either,
-since cells are sums of non-negative addends and never below 0.
+them hold a group on the benchmark's stream), and on a TPU a gather or a
+scatter costs by the index (a gather 13 ns each on a v5e, a scatter-max
+into a row of 2^16 cells ~50 ns for a real slot), real or not. Callers
+that know where their real rows end (models.heavy_hitter._apply_grouped:
+1 + the index of the last valid row) pass that as ``n_live``, and the
+bound serves both halves of the conservative update: its pre-update
+estimate gathers only the chunks of rows below it, and its scatters drop
+every slot that holds no group: those at or beyond the bound and the
+holes below it, which is what ``valid`` False says. Skipping a padding
+slot changes no bit of the state, because such a slot is a no-op either
+way: its addends are 0 (``valid`` is False), so its ceiling is its own
+estimate, which is the min of the cells it would raise; and with the
+estimate left at 0 the ceiling is 0, which raises no cell either, since
+cells are sums of non-negative addends and never below 0.
 """
 
 from __future__ import annotations
@@ -133,7 +137,10 @@ def cms_add_conservative(counts, keys, values, valid=None, n_live=None):
     Same shapes as cms_add. Keys must be unique within the call (use
     sort_groupby first) — duplicate keys would under-count. ``n_live``:
     every ``valid`` row lies below it (the module docstring's live
-    bound); the state that comes back is the same bit for bit.
+    bound): the estimate is gathered below it alone. A row whose
+    ``valid`` is False is in no row's scatter-max; without ``valid``
+    every row is. Either way the state that comes back is the same bit
+    for bit as from the plain update over every row.
     """
     p, d, w = counts.shape
     buckets = cms_buckets(keys, d, w)  # [D, N]
@@ -143,9 +150,14 @@ def cms_add_conservative(counts, keys, values, valid=None, n_live=None):
     # current estimate before update
     est = cms_query(counts, keys, n_live)  # [N, P]
     target = est + vals  # [N, P] the CU ceiling for this key
+    if valid is not None:
+        # A slot that holds no group leaves the scatter: its index goes to
+        # `w`, out of range HIGH, which mode="drop" discards (a negative
+        # index would wrap before the check).
+        buckets = jnp.where(valid[None, :], buckets, w)
     for di in range(d):
         # cell must become at least `target`, but never decrease.
-        counts = counts.at[:, di, buckets[di]].max(target.T)
+        counts = counts.at[:, di, buckets[di]].max(target.T, mode="drop")
     return counts
 
 

@@ -429,3 +429,72 @@ def test_live_bound_changes_no_bit_of_any_family(monkeypatch, reference):
                                    ref[family]):
                 assert a.tobytes() == b.tobytes(), (
                     f"{family}.{field} after batch {i + 1}")
+
+
+# ---- padding out of the scatter (PR 45) -------------------------------------
+
+
+def _steps_of(kind: str, steps: int = 6):
+    """``steps`` polls inside one window slot: ``zipf`` (full batches,
+    about a third of the group slots real), ``part_full`` (an eighth of
+    a batch, the rest padding rows) and ``all_distinct`` (every row its
+    own 5-tuple, source and destination: no padding slot in any
+    family)."""
+    gen = FlowGenerator(ZipfProfile(n_keys=2000, alpha=1.1), seed=45)
+    batches = []
+    for i in range(steps):
+        b = gen.batch(BS // 8 if kind == "part_full" else BS)
+        n = len(b.columns["bytes"])
+        if kind == "all_distinct":
+            row = np.arange(i * BS, i * BS + n, dtype=np.uint32)
+            b.columns["src_addr"][:, 3] = row
+            b.columns["dst_addr"][:, 3] = row + np.uint32(1 << 20)
+        b.columns["time_received"] = (
+            6000 + i * 20 + np.arange(n) % 20).astype(np.uint64)
+        batches.append(b)
+    return batches
+
+
+@pytest.mark.parametrize("width", [1 << 10, 1 << 14])
+@pytest.mark.parametrize("kind", ["zipf", "part_full", "all_distinct"])
+def test_padding_out_of_the_scatter_changes_no_bit_of_any_family(
+        monkeypatch, kind, width):
+    """Six steps of the fused pipeline with the padding slots dropped
+    from the conservative update's scatters, and six of a pipeline built
+    on the update that gathers and scatters every slot (PR 37's
+    parent): every family's `cms`, `table_keys` and
+    `table_vals` agree bit for bit after every step, and the live bounds
+    the step hands out are the same."""
+    from test_sketches import _conservative_as_the_parent_wrote_it
+
+    from flow_pipeline_tpu.engine import fused as fused_mod
+    from flow_pipeline_tpu.models import heavy_hitter as hh
+    from flow_pipeline_tpu.ops import cms as cms_ops
+
+    def run(parents_update):
+        fused_mod._cached_step.cache_clear()
+        if parents_update:
+            monkeypatch.setattr(cms_ops, "cms_add_conservative",
+                                _conservative_as_the_parent_wrote_it)
+        try:
+            return _hh_states_after_every_batch(
+                make_models(WINDOW, 2000, width=width), _steps_of(kind))
+        finally:
+            monkeypatch.undo()
+            fused_mod._cached_step.cache_clear()
+
+    got, want = run(False), run(True)
+    bounds = np.stack([live for live, _ in got])
+    if kind == "all_distinct":
+        assert (bounds == BS).all()  # nothing to drop
+    else:
+        assert 0 < bounds.min() and bounds.max() < BS // 2
+    for i, ((live, new), (ref_live, ref)) in enumerate(zip(got, want)):
+        assert live.tobytes() == ref_live.tobytes(), f"step {i + 1}"
+        assert sorted(new) == ["top_dst_ips", "top_src_ips", "top_talkers"]
+        for family in new:
+            assert np.asarray(new[family][0]).any()
+            for field, a, b in zip(hh.HHState._fields, new[family],
+                                   ref[family]):
+                assert a.tobytes() == b.tobytes(), (
+                    f"{family}.{field} after step {i + 1}")

@@ -7,7 +7,10 @@ streams dealt to two partitions with NEXmark-style delay, polled
 alternately: the per-model path and ``FusedPipeline`` (the mesh:
 tests/test_mesh_pipeline.py). With lateness 0 every row, counter and the
 lowered text of the fused step equal the parent commit's, recorded from
-it in ``tests/data/lateness0_parent.json`` by this file run as a script.
+it in ``tests/data/lateness0_parent.json`` by this file run as a script
+(the step's text re-recorded at PR 45, which took the padding slots out
+of the count-min scatters on purpose: the four digests of rows, counters
+and detector state stayed what PR 39's parent left).
 """
 
 from __future__ import annotations

@@ -12,7 +12,8 @@ three-member chain of the fused step (``src_addr`` < pair < 5-tuple on
 one sort) gives each member the rows its own group-by gives; (c) with
 the flag off every existing configuration's step lowers to the parent's
 text, byte for byte (``tests/data/pairs_off_parent.json``, recorded from
-the parent commit by this file run as a script); (d) the flag builds the
+the parent commit by this file run as a script; re-recorded at PR 45,
+which changed every configuration's step on purpose); (d) the flag builds the
 family under ``-window.slide``, ``-window.lateness`` and
 ``-processor.mesh`` like the others; (e) the spans say what they
 counted. Counts only: nothing here is timed.

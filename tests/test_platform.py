@@ -147,6 +147,13 @@ class TestChipSmoke:
         assert stages["oracle"]["flows_5m"]["bit_exact"]
         assert len(stages["oracle"]["windows"]) == 4
         assert stages["cms_kernels"]["interpret"]
+        live = stages["cms_kernels"]["padding_leaves_the_scatter"]
+        assert sorted(live) == ["all_distinct", "part_full", "zipf"]
+        assert live["all_distinct"]["real"] == live["all_distinct"]["slots"]
+        assert 0 < live["part_full"]["real"] < live["zipf"]["real"]
+        assert all(set(forms) == {"every_slot", "ops_cms"}
+                   for rec in live.values()
+                   for forms in rec["ms_a_call"].values())
         # one device: mesh4 says so instead of passing silently
         assert stages["mesh4"]["skipped"]
         assert "saw 1" in stages["mesh4"]["reason"]

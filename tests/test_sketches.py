@@ -94,12 +94,18 @@ class TestCMS:
         assert float(jnp.sum(sk)) == 0.0
 
 
-def _conservative_as_the_parent_wrote_it(counts, keys, values, valid):
+def _conservative_as_the_parent_wrote_it(counts, keys, values, valid=None,
+                                         n_live=None):
     """`cms_add_conservative` before the live bound (PR 37's parent),
-    kept here as the reference: every slot's estimate gathered."""
+    kept here as the reference: every slot's estimate gathered and every
+    slot in every row's scatter (PR 45 took the padding out of it).
+    `n_live` is taken and not used, so that a test can put this in the
+    update's place."""
     p, d, w = counts.shape
     buckets = cms_mod.cms_buckets(keys, d, w)
-    vals = jnp.where(valid[:, None], values.astype(jnp.float32), 0.0)
+    vals = values.astype(jnp.float32)
+    if valid is not None:
+        vals = jnp.where(valid[:, None], vals, 0.0)
     est = jnp.min(jnp.stack(
         [counts[:, di, buckets[di]] for di in range(d)]), axis=0).T
     target = est + vals
@@ -178,6 +184,159 @@ class TestLiveBound:
         assert np.asarray(cms_add_conservative(
             counts, keys, values, jnp.asarray(valid))).tobytes() \
             == want.tobytes()
+
+    # what a batch's slots hold -> [n] bool, or None for `valid=None`
+    MASKS = {
+        "all_real": lambda rng, n: np.ones(n, bool),
+        "all_padding": lambda rng, n: np.zeros(n, bool),
+        "prefix": lambda rng, n: np.arange(n) < n // 3,
+        "holed": lambda rng, n: TestLiveBound.holed(rng, n, n // 2),
+        "padding_shares_a_bucket": lambda rng, n: np.arange(n) % 2 == 0,
+        "valid_none": lambda rng, n: None,
+    }
+
+    @pytest.mark.parametrize("sketch", ["empty", "holds_mass"])
+    @pytest.mark.parametrize("bound", ["bound", "no_bound"])
+    @pytest.mark.parametrize("n", sorted(LIVE_NS))
+    @pytest.mark.parametrize("mask", sorted(MASKS))
+    def test_padding_leaves_the_scatter_and_no_bit_moves(
+            self, rng, mask, n, bound, sketch):
+        """PR 45: a slot that holds no group is dropped from every row's
+        scatter-max, and the state is the one the plain scatter over
+        every slot leaves, byte for byte."""
+        self.no_bit_moves(rng, mask, n, bound, sketch, self.WIDTH)
+
+    @pytest.mark.parametrize("mask", sorted(MASKS))
+    def test_no_bit_moves_at_the_backbones_width(self, rng, mask):
+        """The same at 2^18 cells a row (`hh-backbone`): few cells
+        collide, so a slot dropped by mistake would leave its own cell
+        low."""
+        self.no_bit_moves(rng, mask, "N>C", "bound", "holds_mass", 1 << 18)
+
+    def no_bit_moves(self, rng, mask, n, bound, sketch, width):
+        n = LIVE_NS[n]
+        valid = self.MASKS[mask](rng, n)
+        keys = rng.integers(0, 2**32, size=(n, 2), dtype=np.uint32)
+        if mask == "padding_shares_a_bucket":
+            # each padding slot holds its real neighbour's key: the two
+            # share a cell in every row, and the padding one has addends
+            keys[1::2] = keys[:-1:2]
+        keys = jnp.asarray(keys)
+        counts = cms_init(self.PLANES, self.DEPTH, width)
+        if sketch == "holds_mass":
+            # a window's worth: twenty batches over keys that collide
+            # with this one's (the narrow width), so most cells are > 0
+            for _ in range(20):
+                counts = cms_add_conservative(
+                    counts,
+                    jnp.asarray(rng.integers(0, 2**32, size=(n, 2),
+                                             dtype=np.uint32)),
+                    jnp.asarray(rng.integers(1, 5000, (n, self.PLANES))),
+                    jnp.ones(n, bool))
+            if width == self.WIDTH:
+                assert (np.asarray(counts) > 0).mean() > 0.9
+        values = jnp.asarray(rng.integers(1, 5000, (n, self.PLANES)))
+        if valid is None:
+            args = (None, None)
+        else:
+            valid = jnp.asarray(valid)
+            args = (valid, live_rows(valid) if bound == "bound" else None)
+        want = np.asarray(_conservative_as_the_parent_wrote_it(
+            counts, keys, values, args[0]))
+        got = np.asarray(jax.jit(cms_add_conservative)(
+            counts, keys, values, *args))
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert got.tobytes() == want.tobytes()
+        real = n if valid is None else int(valid.sum())
+        assert (got != np.asarray(counts)).any() == bool(real)
+        # a real slot's cells hold at least what it brought
+        if real:
+            rows = slice(None) if valid is None else np.asarray(valid)
+            est = np.asarray(cms_query(jnp.asarray(got), keys))[rows]
+            assert (est >= np.asarray(values, np.float32)[rows]).all()
+
+    @pytest.mark.parametrize("mask", ["all_padding", "prefix", "holed"])
+    def test_a_dropped_slot_is_in_no_scatter(self, rng, mask):
+        """What the equalities above cannot see (a padding slot is a
+        no-op in either form): on a sketch below 0 everywhere, which no
+        update makes, a padding slot beyond the bound has the ceiling 0
+        and the plain scatter raises its cells to it. Here they stay."""
+        n = LIVE_NS["N>C"]
+        valid = self.MASKS[mask](rng, n)
+        keys = jnp.asarray(
+            rng.integers(0, 2**32, size=(n, 2), dtype=np.uint32))
+        values = jnp.asarray(rng.integers(1, 5000, (n, self.PLANES)))
+        counts = jnp.full((self.PLANES, self.DEPTH, 1 << 16), -1.0)
+        got = np.asarray(jax.jit(cms_add_conservative)(
+            counts, keys, values, jnp.asarray(valid),
+            live_rows(jnp.asarray(valid))))
+        plain = np.asarray(_conservative_as_the_parent_wrote_it(
+            counts, keys, values, jnp.asarray(valid)))
+        buckets = np.asarray(cms_mod.cms_buckets(keys, self.DEPTH, 1 << 16))
+        for d in range(self.DEPTH):
+            padding_only = np.setdiff1d(buckets[d][~valid],
+                                        buckets[d][valid])
+            assert len(padding_only) > n // 4
+            assert (got[:, d, padding_only] == -1.0).all()
+            real = buckets[d][valid]
+            assert got[:, d, real].tobytes() == plain[:, d, real].tobytes()
+
+    @staticmethod
+    def scatters(jaxpr, inside=()):
+        """[(the control flow round it, its mode)] for every scatter-max
+        of ``jaxpr``, sub-jaxprs (jit, while, cond) included."""
+        out = []
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            if name == "scatter-max":
+                out.append((inside, eqn.params["mode"]))
+            for param in eqn.params.values():
+                for sub in (param if isinstance(param, (list, tuple))
+                            else [param]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        out += TestLiveBound.scatters(
+                            sub, inside if name == "jit" else
+                            inside + (name,))
+        return out
+
+    @pytest.mark.parametrize("masked", ["mask_and_bound", "mask", "plain"])
+    @pytest.mark.parametrize("n", sorted(LIVE_NS))
+    @pytest.mark.parametrize("depth", [1, 2, 4, 5])
+    def test_the_update_is_depth_scatters_and_none_in_a_loop(
+            self, depth, n, masked):
+        """The form the records refused (a scatter-max inside a `while`
+        costs ~105 ns an index on a v5e; a `lax.switch` ladder is gated:
+        PERF.md §6, PRs 37 and 45) must not come back unseen: exactly
+        `depth` scatter-max, each at the top level, each dropping what
+        is out of range."""
+        n = LIVE_NS[n]
+        shape = jax.ShapeDtypeStruct
+        args = [shape((self.PLANES, depth, self.WIDTH), np.float32),
+                shape((n, 2), np.uint32), shape((n, self.PLANES), np.int32)]
+        if masked != "plain":
+            args.append(shape((n,), bool))
+        if masked == "mask_and_bound":
+            args.append(shape((), np.int32))
+        found = self.scatters(
+            jax.make_jaxpr(cms_add_conservative)(*args).jaxpr)
+        assert len(found) == depth
+        assert {inside for inside, _ in found} == {()}
+        assert {mode for _, mode in found} \
+            == {jax.lax.GatherScatterMode.FILL_OR_DROP}
+
+    def test_a_scatter_in_a_loop_would_be_seen(self):
+        """`scatters` names the control flow round a scatter-max."""
+        def chunked(counts, idx, target):
+            return jax.lax.fori_loop(
+                0, 2, lambda i, c: c.at[idx].max(target), counts)
+
+        shape = jax.ShapeDtypeStruct
+        found = self.scatters(jax.make_jaxpr(chunked)(
+            shape((8,), np.float32), shape((4,), np.int32),
+            shape((4,), np.float32)).jaxpr)
+        assert [inside for inside, _ in found] in (
+            [("while",)], [("scan",)])
 
     def test_the_bounded_form_is_a_loop_only_past_one_chunk(self):
         def loops(n):
@@ -321,3 +480,66 @@ class TestQuantile:
         hist = spec.add(spec.init(), jnp.asarray([0.0, 0.0, 5.0]))
         assert float(hist[0]) == 2.0
         assert spec.quantile(np.asarray(hist), 0.5) == 0.0
+
+
+class TestPerModelPath:
+    """`hh_update` (the path a model takes outside the fused step) on
+    the conservative update that drops its padding slots (PR 45), against
+    the same update with every slot scattered."""
+
+    N = 2 * C + 512
+
+    def cols(self, rng, kind):
+        """A batch's columns and row mask: ``zipf`` (few keys, so most
+        group slots are padding), ``part_full`` (an eighth of the rows
+        there) and ``all_distinct`` (no padding slot)."""
+        n = self.N
+        table = rng.integers(0, 2**32, size=(400, 2, 4), dtype=np.uint32)
+        ranks = (np.arange(n) if kind == "all_distinct"
+                 else rng.zipf(1.3, n) % 400)
+        addrs = (rng.integers(0, 2**32, size=(n, 2, 4), dtype=np.uint32)
+                 if kind == "all_distinct" else table[ranks])
+        cols = {
+            "src_addr": addrs[:, 0], "dst_addr": addrs[:, 1],
+            "bytes": rng.integers(1, 1500, n).astype(np.int32),
+            "packets": rng.integers(1, 10, n).astype(np.int32),
+            "sampling_rate": np.ones(n, np.int32),
+        }
+        valid = (np.arange(n) < n // 8 if kind == "part_full"
+                 else np.ones(n, bool))
+        return ({k: jnp.asarray(v) for k, v in cols.items()},
+                jnp.asarray(valid))
+
+    @pytest.mark.parametrize("admission", ["est", "plain"])
+    @pytest.mark.parametrize("capacity", [64, 1024])  # prefilter on / off
+    @pytest.mark.parametrize("kind", ["zipf", "part_full", "all_distinct"])
+    def test_every_state_array_is_the_parents(
+            self, monkeypatch, rng, kind, capacity, admission):
+        from flow_pipeline_tpu.models import heavy_hitter as hh
+
+        config = hh.HeavyHitterConfig(
+            batch_size=self.N, width=1 << 12, capacity=capacity,
+            table_admission=admission)
+        batches = [self.cols(rng, kind) for _ in range(3)]
+
+        def run(parents_update):
+            if parents_update:
+                monkeypatch.setattr(cms_mod, "cms_add_conservative",
+                                    _conservative_as_the_parent_wrote_it)
+            # a fresh jit: hh_update's own would keep the first trace
+            step = jax.jit(hh.hh_update.__wrapped__,
+                           static_argnames=("config",))
+            state, out = hh.hh_init(config), []
+            try:
+                for cols, valid in batches:
+                    state = step(state, cols, valid, config=config)
+                    out.append([np.asarray(x) for x in state])
+            finally:
+                monkeypatch.undo()
+            return out
+
+        got, want = run(False), run(True)
+        assert got[-1][0].any()
+        for i, (new, ref) in enumerate(zip(got, want)):
+            for field, a, b in zip(hh.HHState._fields, new, ref):
+                assert a.tobytes() == b.tobytes(), f"{field}, batch {i + 1}"
