@@ -9,7 +9,7 @@ It drives the system's main path once, through the entry point a user
 calls (``flow_pipeline_tpu.cli.pipeline_main``, i.e. ``python -m
 flow_pipeline_tpu.cli pipeline``), with every processor default left
 alone — batch 32768, CMS width 65536 x depth 4, table capacity 1024, all
-five model families, ``-sketch.backend device``, ``-sketch.cms xla``,
+five model families, ``-sketch.backend device``,
 ``-processor.hostassist auto`` — and checks what comes out against the
 repo's exact oracle (``models/oracle.py``).
 
@@ -28,12 +28,11 @@ owner at a time (the parent imports neither jax nor flow_pipeline_tpu):
                  top-20 top_talkers within 1% of the exact byte totals
     cache        a second, short pipeline process on the same shapes: the
                  fused step must come out of the persistent compile cache
-    cms_kernels  ops/cms_pallas.py compiled (not interpreted) at the
-                 processor's default shapes, bit for bit against ops/cms;
-                 ops/cms's conservative update (padding slots out of the
+    cms_kernels  ops/cms's conservative update (padding slots out of the
                  scatter) bit for bit against the one that scatters every
-                 slot, at widths 2^16 and 2^18 on a Zipf, a part-full and
-                 an all-distinct batch, with each one's ms a call
+                 slot, at the processor's default shapes and widths 2^16
+                 and 2^18 on a Zipf, a part-full and an all-distinct
+                 batch, with each one's ms a call
     mesh4        the same stream with -processor.mesh 4 (skipped, with
                  the device count it saw, on fewer than four devices)
     oracle4      (CPU) the oracle checks on mesh4's output
@@ -98,7 +97,7 @@ REDUCED = [
 ]
 REDUCED_TINY = [
     "tiny: 2.0e4 flows at 20 flows/s, 2,000 keys, batch 2048, CMS width "
-    "4096, capacity 256, hostassist off, CPU, Pallas interpreted",
+    "4096, capacity 256, hostassist off, CPU",
 ]
 
 
@@ -510,53 +509,21 @@ def stage_cache(args) -> dict:
 
 def stage_cms_kernels(args) -> dict:
     device = _device_setup(args.tiny)
-    import functools
-
-    import jax
-    import jax.numpy as jnp
     import numpy as np
 
-    from flow_pipeline_tpu.ops import cms, cms_pallas
-
     # the processor's defaults: N groups = batch, 5-tuple v6 key lanes
-    n, lanes, planes, depth, width = ((256, 11, 3, 4, 512) if args.tiny
-                                      else (32768, 11, 3, 4, 65536))
-    rng = np.random.default_rng(args.seed)
-    keys = jnp.asarray(rng.integers(0, 2**32, size=(n, lanes),
-                                    dtype=np.uint32))
-    vals = jnp.asarray(rng.integers(1, 1500, size=(n, planes))
-                       .astype(np.float32))  # integer-valued, sums < 2^24
-    valid = jnp.asarray(rng.random(n) > 0.1)
-    clog = CompileLog()
-    # --tiny is the only place Pallas is interpreted outside tests/
-    pairs = {
-        "add": (jax.jit(cms.cms_add), functools.partial(
-            cms_pallas.cms_add_pallas, interpret=args.tiny)),
-        "conservative": (jax.jit(cms.cms_add_conservative), functools.partial(
-            cms_pallas.cms_add_conservative_pallas, interpret=args.tiny)),
-    }
+    n, lanes, planes, depth = 256 if args.tiny else 32768, 11, 3, 4
+    widths = (512, 1024) if args.tiny else (1 << 16, 1 << 18)
     rounds = 2 if args.tiny else 3  # >1: estimates feed CU ceilings
-    for name, (xla_fn, pallas_fn) in pairs.items():
-        want = got = cms.cms_init(planes, depth, width)
-        for r in range(rounds):
-            want = xla_fn(want, keys, vals, valid)
-            got = pallas_fn(got, keys, vals, valid)
-            if not np.array_equal(np.asarray(want), np.asarray(got)):
-                diff = int(np.sum(np.asarray(want) != np.asarray(got)))
-                raise AssertionError(
-                    f"cms_pallas {name} differs from ops.cms in {diff} "
-                    f"cells at round {r}")
-        if float(jnp.max(want)) >= 2**24:
-            raise AssertionError("inputs left the exact f32 envelope")
+    clog = CompileLog()
     live = _padding_leaves_the_scatter(
-        rng, n, lanes, planes, depth,
-        widths=(512, 1024) if args.tiny else (1 << 16, 1 << 18),
-        n_keys=2000 if args.tiny else 1_000_000,
+        np.random.default_rng(args.seed), n, lanes, planes, depth,
+        widths=widths, n_keys=2000 if args.tiny else 1_000_000,
         rounds=rounds, reps=3 if args.tiny else 100)
-    return {"device": device, "interpret": bool(args.tiny),
+    return {"device": device,
             "shape": {"groups": n, "key_lanes": lanes, "planes": planes,
-                      "depth": depth, "width": width},
-            "kernels": sorted(pairs), "rounds": rounds, "bit_exact": True,
+                      "depth": depth, "widths": list(widths)},
+            "rounds": rounds, "bit_exact": True,
             "padding_leaves_the_scatter": live, **clog.record()}
 
 

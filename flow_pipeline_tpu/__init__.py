@@ -4,7 +4,7 @@ A brand-new framework with the capabilities of cloudflare/flow-pipeline
 (flow generation/collection -> Kafka transport -> ingest -> windowed
 aggregation -> dashboards), re-designed TPU-first: the aggregation tier is a
 device-resident streaming-sketch engine (count-min, space-saving top-K,
-EWMA/quantile anomaly detection) written in JAX/Pallas, sharded over a
+EWMA/quantile anomaly detection) written in JAX, sharded over a
 `jax.sharding.Mesh` with ICI collectives merging per-chip sketch state.
 
 Module map (mirrors the reference's layer map, SURVEY.md §1):

@@ -243,18 +243,16 @@ def _build_models(vals):
         mode = vals.get("hh.sketch", "auto")
         if mode != "auto":
             return mode
-        if mesh or vals.get("sketch.backend", "device") != "host":
-            return "table"
-        if not vals.get("processor.fused", True):
-            # -processor.fused=false skips pipeline construction
-            # entirely: an invertible family would land on the slow
-            # per-model numpy path, exactly what auto must never choose
-            return "table"
-        from .engine.hostfused import HostGroupPipeline
+        from .engine.dataplane import host_sketch_serves
 
-        if not HostGroupPipeline.eligible(
+        # only the host sketch pipeline folds an invertible family, and
+        # a mesh's models never get it: elsewhere it would land on the
+        # slow per-model numpy path, exactly what auto must never choose
+        if mesh or not host_sketch_serves(
+                vals.get("processor.fused", True),
+                vals.get("sketch.backend", "device"),
                 vals.get("processor.hostassist", "auto")):
-            return "table"  # no host pipeline -> nothing to serve it
+            return "table"
         cascade = any(set(key_cols) < set(other)
                       for _, other in hh_families)
         return "invertible" if cascade else "table"
@@ -265,7 +263,6 @@ def _build_models(vals):
             batch_size=batch,
             width=vals["sketch.width"],
             capacity=vals["sketch.capacity"],
-            cms_impl=vals["sketch.cms"],
             table_prefilter=vals["sketch.prefilter"],
             table_admission=vals["sketch.admission"],
             hh_sketch=resolve_hh_sketch(key_cols),
@@ -379,7 +376,6 @@ def _processor_flags(fs: FlagSet) -> FlagSet:
     fs.boolean("model.ports", True, "Top src/dst port models")
     fs.boolean("model.ddos", True, "DDoS spike detector")
     fs.integer("sketch.width", 1 << 16, "Count-min width")
-    fs.string("sketch.cms", "xla", "CMS update impl: xla | pallas")
     fs.string("sketch.backend", "device",
               "Sketch step executor: device (jitted CMS/top-K apply) | "
               "host (native threaded uint64 engine; needs the "
@@ -430,22 +426,11 @@ def _processor_flags(fs: FlagSet) -> FlagSet:
                                      "flows_raw on sinks that support it")
     fs.integer("feed.prefetch", 2, "Decoded batches fetched ahead of the "
                                    "device step (0 disables)")
-    fs.string("ingest.mode", "pipelined",
-              "Host dataplane: pipelined (grouping overlaps the device "
-              "step, async window flush) | serial (pre-r6 path, A/B)")
-    fs.integer("ingest.shards", 0, "Grouping shards on the ingest pool "
-                                   "(0 auto, 1 disables sharding)")
-    fs.integer("ingest.depth", 2, "Prepared batches held ahead of the "
-                                  "device step")
-    fs.integer("ingest.flush_queue", 8, "Max queued background flush jobs")
     fs.integer("ingest.threads", 0,
                "Worker threads inside the native dataplane kernels "
                "(fused pass, sketch engine, lane building, wagg fold); "
                "deterministic at any count — 0 keeps the conservative "
                "auto count (half the cores, capped at 4)")
-    fs.boolean("ingest.native_group", True,
-               "Group with the native radix kernel (libflowdecode); "
-               "falls back to numpy when unbuilt")
     fs.string("ingest.fused", "auto",
               "Single-pass fused native dataplane (group->cascade->"
               "sketch in one C pass): auto (on when sketch.backend=host "
@@ -673,12 +658,9 @@ def _worker_config(vals) -> "WorkerConfig":
         fused=vals["processor.fused"],
         host_assist=vals["processor.hostassist"],
         sketch_backend=vals["sketch.backend"],
-        ingest_mode=vals["ingest.mode"],
-        ingest_shards=vals["ingest.shards"],
-        ingest_depth=vals["ingest.depth"],
-        ingest_flush_queue=vals["ingest.flush_queue"],
         ingest_threads=vals["ingest.threads"],
-        ingest_native_group=vals["ingest.native_group"],
+        # the native radix grouping falls back to numpy when unbuilt
+        ingest_native_group=True,
         ingest_fused=vals["ingest.fused"],
         obs_audit=vals["obs.audit"],
         guard_lag=vals["guard.lag"],

@@ -103,8 +103,8 @@ def _hh_plan(cfg) -> tuple:
 def _cached_step(hh_specs, dense_cfgs, ddos_cfgs, wagg_cfgs):
     """Build + jit the fused device step for one static model spec.
 
-    Module-level cache: pipelines are rebuilt freely (bench samples,
-    supervisor restarts), and the fused graph is the most expensive
+    Module-level cache: pipelines are rebuilt freely (a benchmark run's
+    worker, supervisor restarts), and the fused graph is the most expensive
     compile in the framework — it must be shared the same way the
     unfused models' module-level jits are. All spec elements are frozen
     config dataclasses / string tuples, so the key is hashable.
@@ -445,7 +445,7 @@ class FusedPipeline(WindowLifecycle):
         self._table_rows: dict = {}
         self._hh_steps = 0
         # The compiled step is cached on the static spec, NOT per instance:
-        # every bench sample / supervisor restart builds a fresh pipeline,
+        # every benchmark run / supervisor restart builds a fresh pipeline,
         # and a per-instance jit would recompile the whole fused graph
         # each time (the unfused models' jits are module-cached too).
         self._step = _cached_step(

@@ -241,6 +241,8 @@ class HostGroupPipeline(FusedPipeline):
     # the prepared tables of a late part go nowhere here (apply()); the
     # worker says so at start-up and runs the families at lateness 0
     honours_lateness = False
+    has_prepare_split = True
+    feeds_audit = True
 
     @staticmethod
     def eligible(mode: str = "auto") -> bool:

@@ -60,6 +60,18 @@ class WindowLifecycle:
     rolls at, which under ``-window.slide`` is the slide) and ``_ddos``
     ((name, detector) pairs, all of one ``_sub_seconds``)."""
 
+    # What a pipeline can do, read by engine/dataplane.py::choose (the
+    # worker asks no class by name): rows that come after their unit
+    # rolled are folded into it, held open for -window.lateness;
+    # prepare(batch) / apply(prepared) besides update(batch), so a
+    # group thread can run ahead of the step; invertible hh families
+    # (-hh.sketch) are folded; the sampled shadow audit (-obs.audit)
+    # is fed.
+    honours_lateness = False
+    has_prepare_split = False
+    serves_invertible = False
+    feeds_audit = False
+
     _whh: list
     _ddos: list
     _window_seconds: int | None

@@ -33,6 +33,7 @@ COMMIT_LATENCY_BUCKETS = (
     3_600.0,
 )
 from .checkpoint import load_checkpoint, save_checkpoint
+from .dataplane import choose
 from .prefetch import PrefetchConsumer
 from .windowed import WindowedHeavyHitter
 
@@ -90,8 +91,6 @@ class WorkerConfig:
     # the single-threaded path (the pre-r6 behavior, the A/B baseline).
     ingest_mode: str = "pipelined"
     ingest_shards: int = 0       # grouping shards: 0 auto, 1 disables
-    ingest_depth: int = 2        # prepared batches held ready
-    ingest_flush_queue: int = 8  # queued background flush jobs (bound)
     # Worker threads inside the native dataplane kernels (the fused
     # pass, the staged sketch engine, lane building, the wagg fold) —
     # every kernel is deterministic at ANY count, so this is purely a
@@ -118,8 +117,8 @@ class WorkerConfig:
     # uint64 counts for a deterministic ~1/256 key cohort and publishes
     # relative-error/recall/saturation metrics at every window close;
     # "full" audits every key (tests, the error-vs-fill sweep); "off"
-    # disables. Needs the host-grouped pipeline (CPU backend or
-    # -processor.hostassist on) — elsewhere it quietly stays off.
+    # disables. Needs a pipeline that feeds it (the host-grouped ones);
+    # dataplane.choose reports the effective mode (Choice.audit).
     obs_audit: str = "sample"
     # flowguard (-guard.lag, guard/): watermark-lag budget in seconds
     # before the degradation ladder engages. 0 (the default) disarms
@@ -155,159 +154,41 @@ class StreamWorker:
         self.models = models
         self.sinks = list(sinks)
         self.config = config
-        if config.ingest_mode not in ("pipelined", "serial"):
-            raise ValueError(
-                f"ingest_mode must be pipelined|serial, "
-                f"got {config.ingest_mode!r}")
-        if config.sketch_backend not in ("device", "host"):
-            raise ValueError(
-                f"sketch_backend must be device|host, "
-                f"got {config.sketch_backend!r}")
-        if config.ingest_fused not in ("auto", "on", "off"):
-            raise ValueError(
-                f"ingest_fused must be auto|on|off, "
-                f"got {config.ingest_fused!r}")
-        if config.ingest_threads < 0:
-            raise ValueError(
-                f"ingest_threads must be >= 0 (0 = auto), "
-                f"got {config.ingest_threads}")
-        if config.ingest_fused == "on" and config.sketch_backend != "host":
-            raise ValueError(
-                "ingest_fused='on' requires sketch_backend='host' — the "
-                "fused pass updates the host sketch engine in place")
-        if config.obs_audit not in ("off", "sample", "full"):
-            raise ValueError(
-                f"obs_audit must be off|sample|full, "
-                f"got {config.obs_audit!r}")
-        if config.guard_lag < 0:
-            raise ValueError(
-                f"guard_lag must be >= 0 (0 = disarmed), "
-                f"got {config.guard_lag}")
+        # what runs is chosen in one place (engine/dataplane.py): build
+        # what it names, say what it says, apply what follows from it
+        choice = self.choice = choose(
+            models, config,
+            None if consumer is None
+            else isinstance(consumer, PrefetchConsumer))
         # flowguard: constructed unconditionally (its metric families
         # must exist — as zeros — on every worker for the honesty
         # tests), armed only when a lag budget is declared
         self.guard = GuardController(GuardConfig(
             lag_budget=config.guard_lag,
             max_level=config.guard_max_level))
-        # invertible hh families (-hh.sketch=invertible) have no jitted
-        # table step: they are served by the host sketch pipeline
-        # (staged or fused) or, failing that, the per-model numpy path
-        hh_sketch = self._hh_sketch_mode(models)
-        self.fused = None
-        if config.fused and models:
-            from .fused import FusedPipeline
-            from .hostfused import HostGroupPipeline
-
-            if FusedPipeline.supported(models):
-                host_grouped = HostGroupPipeline.eligible(config.host_assist)
-                if config.sketch_backend == "host" and host_grouped:
-                    from ..hostsketch import HostSketchPipeline
-
-                    self.fused = HostSketchPipeline(
-                        models, shards=config.ingest_shards,
-                        native_group=config.ingest_native_group,
-                        fused=config.ingest_fused,
-                        audit=config.obs_audit,
-                        threads=config.ingest_threads)
-                elif config.sketch_backend == "host":
-                    # the host engine consumes the host-grouped prepare
-                    # tables; without them there is nothing to feed it
-                    log.warning(
-                        "sketch.backend=host needs the host-grouped "
-                        "pipeline (CPU backend or -processor.hostassist "
-                        "on); keeping the device sketch step")
-                    self.fused = FusedPipeline(models)
-                elif host_grouped:
-                    self.fused = HostGroupPipeline(
-                        models, shards=config.ingest_shards,
-                        native_group=config.ingest_native_group,
-                        audit=config.obs_audit)
-                else:
-                    self.fused = FusedPipeline(models)
-            else:
-                # a set of the mesh-sharded kinds (-processor.mesh) has
-                # programs of its own; the poll is still cut once for all
-                from ..parallel.pipeline import ShardedPipeline
-
-                if ShardedPipeline.supported(models):
-                    self.fused = ShardedPipeline(models)
-                else:
-                    log.info("model set not fusable; using per-model "
-                             "updates")
-        if hh_sketch in ("invertible", "mixed") and self.fused is not None:
-            from ..hostsketch import HostSketchPipeline
-
-            if not isinstance(self.fused, HostSketchPipeline):
-                # the jitted table step cannot fold invertible state;
-                # only the host sketch engine (and the per-model numpy
-                # fallback) can — degrade loudly rather than corrupt
-                log.warning(
-                    "hh.sketch=invertible needs the host sketch "
-                    "pipeline (-sketch.backend=host + CPU backend or "
-                    "-processor.hostassist on); falling back to the "
-                    "per-model numpy path for this worker")
-                self.fused = None
-        if self.fused is not None and not getattr(
-                self.fused, "honours_lateness", False):
-            for name, m in models.items():
-                if getattr(m, "lateness", 0):
-                    # no path changes silently: -window.lateness reaches
-                    # flows_5m alone on this dataplane
-                    log.warning(
-                        "-window.lateness %d: on the %s dataplane %s still "
-                        "drops the rows that arrive after their unit "
-                        "rolled, and counts them in late_flows_dropped",
-                        m.lateness, type(self.fused).__name__, name)
-                    m.lateness = 0
-        if config.ingest_fused == "on":
-            # "on" is a hard requirement everywhere, not just inside the
-            # pipeline constructor: any selection-level fallback above
-            # (non-fusable models, host grouping ineligible, fused=False)
-            # would otherwise silently run the staged/device path under a
-            # flag that documents "errors when it cannot serve"
-            from ..hostsketch import HostSketchPipeline
-
-            if not isinstance(self.fused, HostSketchPipeline):
-                raise RuntimeError(
-                    "ingest_fused='on' but the host sketch pipeline was "
-                    "not selected — it needs a fusable model set and "
-                    "host-grouped pre-aggregation (CPU backend or "
-                    "-processor.hostassist on)")
-        # Pipelined ingest runtime: a group thread prepares batch N+1
-        # while this thread applies batch N, and a background flusher
-        # takes window extraction + sink writes off the hot path. Only
-        # the host-grouped pipeline has the prepare/apply split; other
-        # paths (device-sorted fused, per-model, mesh-sharded) keep the
-        # serial loop — their overlap comes from jax async dispatch.
+        self.fused = (None if choice.pipeline is None
+                      else choice.pipeline(models, **choice.kwargs))
+        for level, words, args in choice.words:
+            log.log(level, words, *args)
+        if choice.error is not None:
+            raise choice.error
+        for name in choice.lateness_dropped:
+            models[name].lateness = 0
         self.executor = None
         self.flusher = None
-        if config.ingest_mode == "pipelined" and consumer is not None:
-            from .hostfused import HostGroupPipeline
+        if choice.pipelined:
             from ..ingest import AsyncFlusher, PipelinedExecutor
 
-            if isinstance(self.fused, HostGroupPipeline) and not isinstance(
-                    consumer, PrefetchConsumer):
-                # prefetch=0 leaves the raw consumer unwrapped; moving its
-                # poll() onto the group thread while commit() stays here
-                # would hit a non-thread-safe Kafka client from two
-                # threads. The PrefetchConsumer wrap is what serializes
-                # all client access on its feed thread — without it, keep
-                # the serial loop.
-                log.info("ingest pipelined mode needs the prefetch wrap "
-                         "(feed.prefetch > 0); using the serial path")
-            elif isinstance(self.fused, HostGroupPipeline):
-                # the guard admission runs INSIDE the prepare wrapper on
-                # the group thread: shed rows never reach grouping, so
-                # degradation sheds the pre-aggregation cost too
-                self.executor = PipelinedExecutor(
-                    consumer, self._prepare_admitted,
-                    poll_max=config.poll_max, depth=config.ingest_depth)
-                self.flusher = AsyncFlusher(
-                    max_queue=config.ingest_flush_queue)
-                for m in models.values():
-                    if isinstance(m, WindowedHeavyHitter) and \
-                            hasattr(m.model, "top_lazy"):
-                        m.lazy_extract = True
+            # the guard admission runs INSIDE the prepare wrapper on
+            # the group thread: shed rows never reach grouping, so
+            # degradation sheds the pre-aggregation cost too
+            self.executor = PipelinedExecutor(
+                consumer, self._prepare_admitted, poll_max=config.poll_max)
+            self.flusher = AsyncFlusher()
+            for m in models.values():
+                if isinstance(m, WindowedHeavyHitter) and \
+                        hasattr(m.model, "top_lazy"):
+                    m.lazy_extract = True
         self.batches_seen = 0
         self.flows_seen = 0
         # flowlint: unguarded -- worker thread only (set and read per _process step)
@@ -379,26 +260,6 @@ class StreamWorker:
         from ..obs.audit import register_audit_metrics
 
         register_audit_metrics()
-        if config.obs_audit != "off" and \
-                getattr(self.fused, "audit", None) is None and models:
-            has_hh = any(
-                isinstance(m, WindowedHeavyHitter)
-                and getattr(m.model, "snapshot_kind", None)
-                == "windowed_hh" for m in models.values())
-            if not has_hh:
-                # nothing sketch-backed to audit (dense/exact models
-                # only) — flipping pipeline knobs would not change that
-                log.info("obs.audit=%s: no sketch-backed families in "
-                         "the model set; nothing to audit",
-                         config.obs_audit)
-            else:
-                # the audit consumes the host-grouped pipelines'
-                # tables; the device-sorted/per-model paths have
-                # nothing to feed it
-                log.info("obs.audit=%s needs the host-grouped pipeline "
-                         "(CPU backend or -processor.hostassist on); "
-                         "sketch accuracy audit is off for this worker",
-                         config.obs_audit)
         # runtime identity: what this worker ACTUALLY runs (native
         # capability set, trace mode, sketch backend) — dashboards and
         # bench artifacts join against it instead of trusting flags
@@ -406,7 +267,7 @@ class StreamWorker:
 
         publish_build_info(config.build_role,
                            sketch_backend=config.sketch_backend,
-                           hh_sketch=hh_sketch)
+                           hh_sketch=choice.hh_sketch)
         # flowlint: unguarded -- written by whichever single thread runs _write_rows (worker inline, or the one flusher thread)
         self._commit_watermark = 0.0
         # flowlint: unguarded -- worker thread only (set per _process step, read when queueing flush jobs)
@@ -426,25 +287,6 @@ class StreamWorker:
                 check = getattr(sink, "check_raw_schema", None)
                 if check is not None:
                     check()
-
-    @staticmethod
-    def _hh_sketch_mode(models: dict) -> str:
-        """The heavy-hitter sketch family this worker actually runs —
-        the flow_build_info ``hh_sketch`` label ("none" when the model
-        set has no sketch-backed hh family)."""
-        modes = {
-            getattr(m.model.config, "hh_sketch", "table")
-            for m in models.values()
-            if isinstance(m, WindowedHeavyHitter)
-            and getattr(m.model, "snapshot_kind", None) == "windowed_hh"}
-        if not modes:
-            return "none"
-        if modes == {"table"}:
-            return "table"
-        # any invertible family needs the host sketch pipeline (the
-        # fallback check below keys off this); a table+invertible mix
-        # (-hh.sketch=auto's cascade flip) is labeled honestly
-        return "invertible" if modes == {"invertible"} else "mixed"
 
     # ---- main loop --------------------------------------------------------
 

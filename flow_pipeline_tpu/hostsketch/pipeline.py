@@ -122,6 +122,10 @@ class HostSketchPipeline(HostGroupPipeline):
     exports) keeps the staged prepare/apply split, which doubles as the
     bit-exact parity reference (tests/test_fusedplane.py)."""
 
+    # the engine's u64 planes are where an invertible family's state
+    # lives; the jitted table step cannot fold it
+    serves_invertible = True
+
     def __init__(self, models: dict, shards: int = 0,
                  native_group: bool = False,
                  pool: Optional[ShardPool] = None,

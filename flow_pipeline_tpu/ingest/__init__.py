@@ -19,8 +19,9 @@ hot path):
                    for closed windows run off the hot path, with errors
                    propagated back to the worker.
 
-engine.worker wires these in behind --ingest.mode (serial keeps the old
-single-threaded path for A/B); per-stage queue depths export through
+engine.worker wires these in where engine.dataplane chose a pipeline
+with the prepare/apply split (WorkerConfig.ingest_mode="serial" keeps
+the single-threaded path, the parity reference); per-stage queue depths export through
 obs.metrics as ingest_queue_depth / ingest_queue_highwater.
 """
 

@@ -49,11 +49,6 @@ class HeavyHitterConfig:
     capacity: int = 1024  # candidate table rows
     batch_size: int = 8192
     conservative: bool = True
-    # CMS update implementation: "xla" (scatter) or "pallas" (dense tile
-    # kernels, ops.cms_pallas — same bucket scheme/state, so the choice is
-    # purely a per-hardware performance call; no cell of the benchmark
-    # runs "pallas": ROADMAP A7).
-    cms_impl: str = "xla"
     # Feed the table merge only 2*capacity candidates — the batch's top
     # groups by plane-0 sum PLUS every group whose key is already
     # RESIDENT in the table — shrinking its sort from (capacity + batch)
@@ -178,31 +173,13 @@ def _key_lanes(cols: dict, key_cols) -> jnp.ndarray:
 
 
 def _cms_add(config: HeavyHitterConfig, n_live):
-    """Select the CMS update op for (conservative, cms_impl). All four
-    share ops.cms's bucket scheme and state layout, so the selection can
-    change between runs (even mid-stream) without invalidating a sketch.
-    ``n_live`` (live_rows of the groups to come) goes to the one op whose
-    cost it bounds: the xla conservative update, whose estimate gathers
-    the rows below it and whose scatters drop the rows that are not
-    valid (all at or beyond it, and the holes below)."""
-    if config.cms_impl == "pallas":
-        from ..ops import cms_pallas
-
-        # Derive the width tile from the config so any width the xla impl
-        # accepts works here too (the kernels pad the key dimension
-        # themselves, so batch size is unconstrained). The kernels are
-        # compiled; a test that wants them interpreted on the CPU says so
-        # itself (pltpu.force_tpu_interpret_mode).
-        if config.width % 128:
-            raise ValueError(
-                f"cms_impl='pallas' needs width % 128 == 0, got {config.width}"
-            )
-        tile = 256 if config.width % 256 == 0 else 128
-        if config.conservative:
-            return partial(cms_pallas.cms_add_conservative_pallas, tile=tile)
-        return partial(cms_pallas.cms_add_pallas, tile=tile)
-    if config.cms_impl != "xla":
-        raise ValueError(f"unknown cms_impl {config.cms_impl!r}")
+    """The CMS update op for ``conservative``. Both share ops.cms's
+    bucket scheme and state layout, so the selection can change between
+    runs (even mid-stream) without invalidating a sketch. ``n_live``
+    (live_rows of the groups to come) goes to the one op whose cost it
+    bounds: the conservative update, whose estimate gathers the rows
+    below it and whose scatters drop the rows that are not valid (all
+    at or beyond it, and the holes below)."""
     if config.conservative:
         return partial(cms_ops.cms_add_conservative, n_live=n_live)
     return cms_ops.cms_add

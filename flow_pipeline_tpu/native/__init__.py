@@ -368,14 +368,6 @@ def missing_features() -> list[str]:
     return [feat for feat, ok in capabilities().items() if not ok]
 
 
-def reload() -> bool:
-    """Re-attempt loading (e.g. after a caller built the library); returns
-    availability."""
-    global _TRIED
-    _TRIED = False
-    return available()
-
-
 # Column order shared with native/flowdecode.cc — scalar uint32 columns in
 # schema order, then the three [N,4] address columns.
 def _column_order():

@@ -2,7 +2,7 @@
 
 Everything here is jit-safe, static-shape, 32-bit-lane code. The design
 replaces ClickHouse's C++ aggregation engine (the reference's only "native
-kernel", ref: compose/clickhouse/create.sh:70-110) with XLA/Pallas:
+kernel", ref: compose/clickhouse/create.sh:70-110) with XLA:
 
 - ``segment``   sort-based exact groupby (lexicographic multi-key lax.sort
                 + segment reductions) — the workhorse behind exact windowed

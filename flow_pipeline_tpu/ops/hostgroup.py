@@ -58,7 +58,8 @@ def native_group_available() -> bool:
     """Whether the native hash-group kernel (native.hash_group: same
     64-bit hash, radix sort + collision verify in one C pass) can serve
     as grouping backend. Callers opt in per call via ``native=True``
-    (--ingest.native_group); the pure-numpy path stays the reference
+    (WorkerConfig.ingest_native_group, which the CLI sets); the
+    pure-numpy path stays the reference
     implementation the oracle tests pin down."""
     from .. import native
 

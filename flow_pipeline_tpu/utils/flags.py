@@ -35,14 +35,13 @@ KNOWN_FLAGS = frozenset({
     "processor.fused", "processor.hostassist",
     "model.flows5m", "model.talkers", "model.pairs", "model.ips",
     "model.ports", "model.ddos",
-    "sketch.width", "sketch.cms", "sketch.prefilter", "sketch.admission",
+    "sketch.width", "sketch.prefilter", "sketch.admission",
     "sketch.capacity", "sketch.topk", "sketch.backend", "hh.sketch",
     # flowspread (models/spread.py) — distinct-count detectors
     "spread.enabled", "spread.depth", "spread.width", "spread.regs",
     "spread.capacity", "spread.topk",
     "window.lateness", "window.slide", "archive.raw", "feed.prefetch",
-    "ingest.mode", "ingest.shards", "ingest.depth", "ingest.flush_queue",
-    "ingest.native_group", "ingest.fused", "ingest.threads",
+    "ingest.fused", "ingest.threads",
     "checkpoint.path", "flush.count", "metrics.addr", "sink", "in",
     "listen.feed", "query.addr", "obs.trace", "obs.audit",
     # flowchaos (utils/faults.py, sink/resilient.py, mesh/journal.py)

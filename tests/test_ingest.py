@@ -272,5 +272,5 @@ class TestPipelinedWorker:
 
     def test_queue_depth_bounded_end_to_end(self):
         sink = CollectSink()
-        w = _run_worker("pipelined", sink, ingest_depth=2)
-        assert w.executor.high_water <= 2
+        w = _run_worker("pipelined", sink)
+        assert w.executor.high_water <= w.executor.depth == 2
