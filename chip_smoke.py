@@ -33,6 +33,12 @@ owner at a time (the parent imports neither jax nor flow_pipeline_tpu):
                  slot, at the processor's default shapes and widths 2^16
                  and 2^18 on a Zipf, a part-full and an all-distinct
                  batch, with each one's ms a call
+    spread_kernels  ops/spread's register update as the fused step runs it
+                 under -spread.enabled (spread_scatter on the flat device
+                 plane, rows that are not valid dropped) bit for bit
+                 against the numpy twin, at
+                 estate-spread's shapes (2 x 2^15 x 256 registers), for
+                 both detectors, with ms a call for int32 and uint8
     mesh4        the same stream with -processor.mesh 4 (skipped, with
                  the device count it saw, on fewer than four devices)
     oracle4      (CPU) the oracle checks on mesh4's output
@@ -72,8 +78,8 @@ import urllib.error
 import urllib.request
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
-STAGES = ("native", "pipeline", "oracle", "cache", "cms_kernels", "mesh4",
-          "oracle4")
+STAGES = ("native", "pipeline", "oracle", "cache", "cms_kernels",
+          "spread_kernels", "mesh4", "oracle4")
 CPU_STAGES = ("oracle", "oracle4")  # run under JAX_PLATFORMS=cpu
 SERVED_STAGES = ("pipeline", "mesh4")  # parent polls -serve.addr
 DEADLINE_S = 1150  # the whole smoke, compilation included (limit: 1200)
@@ -637,6 +643,116 @@ def _padding_leaves_the_scatter(rng, n, lanes, planes, depth, *, widths,
                 form: ms_a_call(fn, width, batch)
                 for form, fn in forms.items()}
     return out
+
+
+def stage_spread_kernels(args) -> dict:
+    """PR 47: the spread detectors' register update as the fused step
+    runs it (ops.spread.spread_scatter on the flat device plane) against
+    the numpy twin (hostsketch.engine.np_spread_update) bit for bit, at
+    estate-spread's shapes, over three batches into one plane, for both
+    detectors' element widths and for two row sets: every row of the
+    batch (what the step hands it) and the batch's unique groups with
+    their holes dropped from the scatter (what it would save to group
+    first: nothing, PERF.md 6, PR 47). Beside it what a call takes for
+    each register dtype
+    (ops.spread.DEVICE_REG_DTYPE is the one the step uses): the median
+    of three timings of ``reps`` calls with the plane donated. A time,
+    not a metric: the step's own is the benchmark's
+    ``step_spread_regs_ms``. Then what the candidate table's admission
+    reads, ops.spread.spread_decode_device (float32, from the flat
+    plane), against the host's decode of the same registers
+    (hostsketch.engine.np_spread_query, float64) for the last batch's
+    sources, within 1e-4, with its ms a call."""
+    device = _device_setup(args.tiny)
+    import statistics
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flow_pipeline_tpu.hostsketch.engine import (
+        np_spread_query,
+        np_spread_update,
+    )
+    from flow_pipeline_tpu.ops import spread
+    from flow_pipeline_tpu.ops.segment import hash_groupby_float
+
+    n = 256 if args.tiny else 32768
+    shape = (2, 64, 16) if args.tiny else (2, 32768, 256)
+    n_keys, reps = (2000, 3) if args.tiny else (1_000_000, 100)
+    rng = np.random.default_rng(args.seed)
+    clog = CompileLog()
+    scatter = jax.jit(spread.spread_scatter, static_argnums=1,
+                      donate_argnums=0)
+    decode = jax.jit(spread.spread_decode_device, static_argnums=1)
+    table = rng.integers(0, 2**32, size=(n_keys, 8), dtype=np.uint32)
+    weights = 1.0 / np.arange(1, n_keys + 1) ** 1.1
+    out = {}
+    for detector, ew in (("superspreaders", 4), ("portscan", 1)):
+        want = np.zeros(shape, np.uint8)
+        planes = {name: jnp.zeros(int(np.prod(shape)), spread.DEVICE_REG_DTYPE)
+                  for name in ("groups", "every_row")}
+        for _ in range(3):
+            ranks = rng.choice(n_keys, size=n, p=weights / weights.sum())
+            rows = table[ranks][:, :4 + ew]
+            np_spread_update(want, rows[:, :4], rows[:, 4:])
+            uniq, _, counts = hash_groupby_float(
+                jnp.asarray(rows), jnp.zeros((n, 0), jnp.float32),
+                jnp.ones(n, bool))
+            real = counts > 0
+            handed = {"groups": (uniq[:, :4], uniq[:, 4:], real),
+                      "every_row": (jnp.asarray(rows[:, :4]),
+                                    jnp.asarray(rows[:, 4:]),
+                                    jnp.ones(n, bool))}
+            for name, batch in handed.items():
+                planes[name] = scatter(planes[name], shape, *batch)
+        for name, plane in planes.items():
+            got = np.asarray(spread.host_regs(plane, shape=shape))
+            if got.dtype != np.uint8 or got.tobytes() != want.tobytes():
+                raise AssertionError(
+                    f"{detector}: spread_scatter over {name} differs from "
+                    f"np_spread_update")
+        if not want.any():
+            raise AssertionError(f"{detector}: no register was raised")
+        keys = uniq[:, :4]
+        got = np.asarray(decode(planes["every_row"], shape, keys))
+        ok = np.asarray(real)
+        host = np_spread_query(want, np.asarray(keys)[ok])
+        if got.dtype != np.float32 or not np.allclose(
+                got[ok], host, rtol=1e-4) or not host.max() > 1:
+            raise AssertionError(
+                f"{detector}: spread_decode_device differs from "
+                f"np_spread_query by {np.abs(got[ok] / host - 1).max()}")
+        timings = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                est = decode(planes["every_row"], shape, keys)
+            jax.block_until_ready(est)
+            timings.append((time.perf_counter() - t0) * 1e3 / reps)
+        rec = out[detector] = {
+            "rows": n, "groups": int(real.sum()),
+            "registers_raised": int(np.count_nonzero(want)),
+            "decode_max_rel_diff": float(np.abs(got[ok] / host - 1).max()),
+            "decode_ms_a_call": round(statistics.median(timings), 4),
+            "ms_a_call": {}}
+        for dtype in (jnp.int32, jnp.uint8):
+            for name, batch in handed.items():
+                plane = scatter(jnp.zeros(int(np.prod(shape)), dtype),
+                                shape, *batch)
+                timings = []
+                for _ in range(3):
+                    jax.block_until_ready(plane)
+                    t0 = time.perf_counter()
+                    for _ in range(reps):
+                        plane = scatter(plane, shape, *batch)
+                    jax.block_until_ready(plane)
+                    timings.append((time.perf_counter() - t0) * 1e3 / reps)
+                rec["ms_a_call"][f"{jnp.dtype(dtype).name}.{name}"] = round(
+                    statistics.median(timings), 4)
+    return {"device": device, "shape": list(shape), "bit_exact": True,
+            "device_dtype": jnp.dtype(spread.DEVICE_REG_DTYPE).name,
+            "detectors": out, **clog.record()}
 
 
 def stage_mesh4(args) -> dict:

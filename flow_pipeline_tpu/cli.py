@@ -164,7 +164,8 @@ def _build_models(vals):
                  vals.get("sketch.backend", "device") == "host",
                  "sketch state resident in the host engine"),
                 ("-spread.enabled", vals.get("spread.enabled"),
-                 "host-resident register planes"),
+                 "the register planes have no fold program for the "
+                 "ring to run"),
                 ("-mesh.role", vals.get("mesh.role"),
                  "windows merged and extracted at the coordinator")):
             if on:
@@ -328,15 +329,19 @@ def _build_models(vals):
                 DDoSConfig(batch_size=batch), lateness=held)
     if vals.get("spread.enabled"):
         # flowspread distinct-count detectors (models/superspreader.py,
-        # models/scan.py). Spread state is host-resident numpy u8
-        # registers by design — like the invertible hh family it has no
-        # device layout to shard, so refuse -processor.mesh instead of
-        # silently running an unsharded model beside sharded ones.
+        # models/scan.py). Where their state lives is the dataplane's
+        # choice (engine/dataplane.py): on one chip the fused step
+        # updates the register planes on the device, the host-grouped
+        # pipelines keep them in host numpy. Neither form has a sharded
+        # layout yet (parallel/sharded.py knows no spread kind), so
+        # refuse -processor.mesh instead of silently running an
+        # unsharded model beside sharded ones.
         if mesh:
             raise ValueError(
                 "-spread.enabled does not support -processor.mesh device "
-                "sharding (host-resident u8 register planes); use "
-                "flowmesh workers instead")
+                "sharding (the register planes have one-chip and host "
+                "layouts only, no sharded one); use flowmesh workers "
+                "instead")
         from .models.scan import SCAN_MODEL, scan_config, scan_model
         from .models.superspreader import (
             SUPERSPREADER_MODEL,
@@ -396,8 +401,9 @@ def _processor_flags(fs: FlagSet) -> FlagSet:
     fs.boolean("spread.enabled", False,
                "flowspread distinct-count detectors: superspreaders "
                "(src -> distinct dst addrs) + portscan (src -> distinct "
-               "dst ports); host-resident register planes, incompatible "
-               "with -processor.mesh")
+               "dst ports); register planes on the device inside the "
+               "fused step, in host memory on the host-grouped "
+               "dataplanes; incompatible with -processor.mesh")
     fs.integer("spread.depth", 2, "Spread sketch rows (min over rows at "
                                   "decode)")
     fs.integer("spread.width", 1 << 12, "Spread sketch buckets per row")

@@ -9,13 +9,17 @@ it) and the per-model loop, from what it can observe: the models' types
 (each pipeline's ``supported``), their ``hh_sketch`` and ``lateness``,
 the ``WorkerConfig`` fields, the default backend (through
 ``HostGroupPipeline.eligible``) and whether the consumer is
-prefetch-wrapped. It builds nothing and logs nothing: the ``Choice`` it
+prefetch-wrapped. The pipeline chosen also says where the spread
+detectors' planes live: on the device under one whose step updates them
+(``spread_in_step``), host numpy under every other; the words say
+which. It builds nothing and logs nothing: the ``Choice`` it
 returns names the class and its arguments, what follows from the class
 (executor + flusher, the audit's mode, which models run at lateness 0)
 and the words to log, and ``StreamWorker.__init__`` carries it out.
 
 What a pipeline can do is a class attribute (``honours_lateness``,
-``has_prepare_split``, ``serves_invertible``, ``feeds_audit``; the
+``has_prepare_split``, ``serves_invertible``, ``feeds_audit``,
+``spread_in_step``; the
 ``False`` defaults are ``WindowLifecycle``'s), never a class name
 tested here or in the worker.
 """
@@ -173,6 +177,17 @@ def choose(models: dict[str, Any], config,
             "-processor.hostassist on); falling back to the "
             "per-model numpy path for this worker", ()))
         pipeline, kwargs = None, {}
+    spread = [name for name, m in models.items()
+              if isinstance(m, WindowedHeavyHitter)
+              and getattr(m.model, "snapshot_kind", None)
+              == "windowed_spread"]
+    if spread:
+        on_device = pipeline is not None and pipeline.spread_in_step
+        words.append((
+            INFO, "spread detectors %s: register planes %s",
+            (", ".join(spread),
+             "on the device, updated inside the fused step" if on_device
+             else "in host memory, folded between device steps")))
     dropped = []
     if pipeline is not None and not pipeline.honours_lateness:
         for name, m in models.items():

@@ -64,12 +64,13 @@ from ..models.ddos import (
 )
 from ..models.dense_top import DenseTopKModel, dense_update
 from ..models.heavy_hitter import HeavyHitterModel
-from ..models.spread import SpreadModel
+from ..models.spread import SpreadModel, SpreadState
 from ..models.window_agg import WindowAggregator
 from ..models.window_agg import _cached_update as _cached_wagg_update
 from ..obs import get_logger
 from ..obs.trace import TRACER
 from ..schema.batch import FlowBatch, lane_width
+from ..ops import spread as spread_ops
 from ..ops.segment import (
     _hash_grouped,
     hash_groupby_float,
@@ -100,7 +101,8 @@ def _hh_plan(cfg) -> tuple:
 
 
 @functools.lru_cache(maxsize=None)
-def _cached_step(hh_specs, dense_cfgs, ddos_cfgs, wagg_cfgs):
+def _cached_step(hh_specs, dense_cfgs, ddos_cfgs, wagg_cfgs,
+                 spread_specs=()):
     """Build + jit the fused device step for one static model spec.
 
     Module-level cache: pipelines are rebuilt freely (a benchmark run's
@@ -173,7 +175,10 @@ def _cached_step(hh_specs, dense_cfgs, ddos_cfgs, wagg_cfgs):
     # followed per scope from one compilation to the next, where XLA's own
     # instruction numbering is not stable. They change HLO metadata only.
     def step(states, cols, valid, valid_hh, valid_dd):
-        hh_states, dense_tots, ddos_states = states
+        if spread_specs:
+            hh_states, dense_tots, ddos_states, spread_states = states
+        else:
+            hh_states, dense_tots, ddos_states = states
 
         chain_results: dict[int, tuple] = {}
         for members in chains:
@@ -264,6 +269,7 @@ def _cached_step(hh_specs, dense_cfgs, ddos_cfgs, wagg_cfgs):
                     return u, s, counts
 
         new_hh, live = [], []
+        key_groups: dict[tuple, tuple] = {}  # key_cols -> (uniq, counts)
         for i, ((plan, cfg), st) in enumerate(zip(hh_specs, hh_states)):
             if plan[0] == "B":
                 uniq, sums, counts = consume_b(0, 0, 2)
@@ -279,6 +285,7 @@ def _cached_step(hh_specs, dense_cfgs, ddos_cfgs, wagg_cfgs):
                         vals = vals * r[:, None]
                     uniq, sums, counts = hash_groupby_float(
                         lanes, vals, valid_hh)
+            key_groups.setdefault(tuple(cfg.key_cols), (uniq, counts))
             # one scope per family, by its index among the hh families
             with jax.named_scope(f"hh_table_merge_{i}"):
                 sums3 = jnp.concatenate(
@@ -304,9 +311,39 @@ def _cached_step(hh_specs, dense_cfgs, ddos_cfgs, wagg_cfgs):
                 new_ddos.append(_accumulate_grouped(
                     dst_state, u, s[:, 0], counts > 0, dcfg))
 
+        # one pair of scopes a detector, by its name (a trace reader's
+        # contract like the others': benchmark/spread_scopes.py)
+        new_spread = []
+        for (name, cfg), st in zip(spread_specs,
+                                   spread_states if spread_specs else ()):
+            shape = (cfg.depth, cfg.width, cfg.registers)
+            lanes = hh._key_lanes(cols, cfg.key_cols)
+            with jax.named_scope(f"spread_regs_{name}"):
+                regs = spread_ops.spread_scatter(
+                    st.regs, shape, lanes,
+                    hh._key_lanes(cols, (cfg.elem_col,)), valid_hh)
+            with jax.named_scope(f"spread_table_{name}"):
+                # the batch's sources, once each: an hh family keyed as
+                # the detector has grouped them already (top_src_ips in
+                # the default estate), else a sort of the detector's own
+                if tuple(cfg.key_cols) in key_groups:
+                    uniq, counts = key_groups[tuple(cfg.key_cols)]
+                else:
+                    uniq, _, counts = hash_groupby_float(
+                        lanes, jnp.zeros((lanes.shape[0], 0), jnp.float32),
+                        valid_hh)
+                tk, tm = spread_ops.spread_table_admit(
+                    st.table_keys, st.table_metric, uniq,
+                    spread_ops.spread_decode_device(regs, shape, uniq),
+                    counts > 0)
+            new_spread.append(SpreadState(regs, tk, tm))
+
         with jax.named_scope("wagg_groupby"):
             wagg_parts = tuple(fn(cols, valid) for fn in wagg_fns)
-        return ((tuple(new_hh), new_dense, tuple(new_ddos)), wagg_parts,
+        new_states = (tuple(new_hh), new_dense, tuple(new_ddos))
+        if spread_specs:
+            new_states += (tuple(new_spread),)
+        return (new_states, wagg_parts,
                 jnp.stack(live) if live else jnp.zeros(0, jnp.int32))
 
     return jax.jit(step, donate_argnums=(0,))
@@ -369,6 +406,11 @@ class FusedPipeline(WindowLifecycle):
     # detector's program) with the family's held state in the open
     # one's place (WindowLifecycle._units)
     honours_lateness = True
+    # the spread detectors' state lives on the device and the jitted
+    # step updates it; the host-grouped subclasses keep it in host numpy
+    # and fold it themselves (engine/hostfused.py: _fold_spread)
+    spread_in_step = True
+    _compiled_step_text = None  # compiled_step_text()'s, once had
 
     @staticmethod
     def supported(models: dict[str, Any]) -> bool:
@@ -402,11 +444,12 @@ class FusedPipeline(WindowLifecycle):
         self._dense: list[tuple[str, WindowedHeavyHitter]] = []
         self._ddos: list[tuple[str, DDoSDetector]] = []
         # spread wrappers ride the SAME window lifecycle (_advance_hh
-        # closes every _whh member in lockstep) but not the jitted step:
-        # their state is host numpy by design and their grouping key
-        # (key + counted element) cannot share the hh pre-agg, so each
-        # chunk updates them host-side — the max monoid makes that
-        # bit-identical to any other chunking/ordering.
+        # closes every _whh member in lockstep) and, where
+        # spread_in_step, the jitted step: the registers take a
+        # scatter-max of the run's rows (the max monoid makes that
+        # bit-identical to any other chunking/ordering), the candidate
+        # table the batch's sources at what the registers then decode
+        # to (ops.spread.spread_table_admit).
         self._spread: list[tuple[str, WindowedHeavyHitter]] = []
         self._whh: list[WindowedHeavyHitter] = []  # hh/dense/spread wrappers
         for name, m in models.items():
@@ -434,6 +477,11 @@ class FusedPipeline(WindowLifecycle):
                              if self._ddos else None)
         self._hh_specs = tuple(
             (_hh_plan(w.config), w.config) for _, w in self._hh)
+        # the detectors this pipeline's step updates: their state moves
+        # to the device (none where a subclass folds them on the host)
+        self._stepped_spread = self._spread if self.spread_in_step else []
+        for _, w in self._stepped_spread:
+            w.model.to_device()
         self._cols = self._column_union()
         self._behind = False  # the batch in hand fills a device step
         self._detector_warm = not self._ddos  # see _warm_detector
@@ -453,6 +501,7 @@ class FusedPipeline(WindowLifecycle):
             tuple(w.config for _, w in self._dense),
             tuple(d.config for _, d in self._ddos),
             tuple(m.config for _, m in self._waggs),
+            tuple((name, w.config) for name, w in self._stepped_spread),
         )
 
     # ---- device step ------------------------------------------------------
@@ -496,7 +545,12 @@ class FusedPipeline(WindowLifecycle):
         instruction numbering). So this compiles the step again, as a jit
         of its own, under a cache key that includes the metadata: a full
         compile the first time for a source, a cache hit after. Not for
-        the dispatch loop."""
+        the dispatch loop. Kept once had: the step, its batch size, its
+        columns and its states' shapes are fixed when the pipeline is
+        built, and a traced run's readers ask several times."""
+        if self._compiled_step_text is not None:
+            return self._compiled_step_text
+
         def shape(a):
             return jax.ShapeDtypeStruct(a.shape, a.dtype)
 
@@ -504,10 +558,7 @@ class FusedPipeline(WindowLifecycle):
         cols = {k: shape(v)
                 for k, v in padded.device_columns(self._cols).items()}
         valid = shape(mask)
-        states = jax.tree_util.tree_map(shape, (
-            tuple(w.model.state for _, w in self._hh),
-            tuple(w.model.totals for _, w in self._dense),
-            tuple(d.state for _, d in self._ddos)))
+        states = jax.tree_util.tree_map(shape, self._states())
         inner = self._step.__wrapped__
 
         def step(*args):  # a new function: JAX memoizes lowerings by it
@@ -519,9 +570,10 @@ class FusedPipeline(WindowLifecycle):
         before = getattr(jax.config, keyed)
         jax.config.update(keyed, True)
         try:
-            return lowered.compile().as_text()
+            self._compiled_step_text = lowered.compile().as_text()
         finally:
             jax.config.update(keyed, before)
+        return self._compiled_step_text
 
     def hh_live(self) -> dict:
         """Args for the span that reads them (``ckpt_state``): the
@@ -555,6 +607,23 @@ class FusedPipeline(WindowLifecycle):
             out[name] = len(held - self._table_rows.get(name, set()))
             self._table_rows[name] = held
         return out
+
+    def _states(self) -> tuple:
+        """The step's donated argument: every family's open state (or
+        the held one a late run has put in its place)."""
+        states = (tuple(w.model.state for _, w in self._hh),
+                  tuple(w.model.totals for _, w in self._dense),
+                  tuple(d.state for _, d in self._ddos))
+        if self._stepped_spread:
+            states += (tuple(w.model.state
+                             for _, w in self._stepped_spread),)
+        return states
+
+    @property
+    def spread_families(self) -> tuple:
+        """The spread detectors the step updates, by the names in its
+        ``spread_regs_<name>`` / ``spread_table_<name>`` scopes."""
+        return tuple(name for name, _ in self._stepped_spread)
 
     @property
     def hh_families(self) -> tuple:
@@ -655,23 +724,10 @@ class FusedPipeline(WindowLifecycle):
             mask, n = lanes.mask(start, rows)
             if not n:
                 continue
-            chunk, host_cols, cols, pad_valid = lanes.place(start)
-            if do_hh and self._spread:
-                # host-side spread fold per chunk (see __init__): the
-                # chunk is <= one model batch, so model.update makes
-                # exactly one grouped pass over it
-                with TRACER.span("spread_fold"):
-                    part = (chunk if rows is None
-                            else chunk.take(mask[:len(chunk)]))
-                    for _, w in self._spread:
-                        w.model.update(part)
+            _chunk, host_cols, cols, pad_valid = lanes.place(start)
             dd_mask, dd_n = ((mask, n) if dd_rows is rows
                              else lanes.mask(start, dd_rows))
-            states = (
-                tuple(w.model.state for _, w in self._hh),
-                tuple(w.model.totals for _, w in self._dense),
-                tuple(d.state for _, d in self._ddos),
-            )
+            states = self._states()
             # one span per device step: their count inside one "apply" is
             # device steps per batch, rows/padded the step's fill. The
             # masks of a run that is not the whole chunk cross here
@@ -693,7 +749,10 @@ class FusedPipeline(WindowLifecycle):
             if do_hh:
                 self._live_rows = live_rows
                 self._hh_steps += 1
-            new_hh, new_dense, new_ddos = new_states
+            new_hh, new_dense, new_ddos = new_states[:3]
+            if self._stepped_spread:
+                for (_, w), st in zip(self._stepped_spread, new_states[3]):
+                    w.model.state = st
             for (_, w), st in zip(self._hh, new_hh):
                 w.model.state = st
             for (_, w), tot in zip(self._dense, new_dense):

@@ -51,6 +51,7 @@ from ..models.ddos import _accumulate_grouped
 from ..models.dense_top import dense_update
 from ..models.spread import SpreadState, spread_key_width
 from ..obs import REGISTRY, get_logger
+from ..obs.trace import TRACER
 from ..obs.tracing import StageTimer
 from ..ops.hostgroup import native_group_available, select_lanes
 from ..schema.batch import FlowBatch, lane_width
@@ -243,6 +244,9 @@ class HostGroupPipeline(FusedPipeline):
     honours_lateness = False
     has_prepare_split = True
     feeds_audit = True
+    # the register planes stay host numpy, the native kernel's (or its
+    # numpy twin's) to mutate in place: _fold_spread
+    spread_in_step = False
 
     @staticmethod
     def eligible(mode: str = "auto") -> bool:
@@ -653,13 +657,15 @@ class HostGroupPipeline(FusedPipeline):
         native hs_spread_update kernel when the library exports it, the
         numpy twin otherwise — either way bit-identical to
         SpreadModel.update over the same chunk, which is the parity
-        anchor tests/test_spread.py pins."""
+        anchor tests/test_spread.py pins. One ``spread_fold`` span a
+        chunk: the host fold between two dispatches that FusedPipeline
+        no longer has (its step updates the planes on the device)."""
         from ..hostsketch.engine import (
             np_spread_table_merge,
             spread_apply_update,
         )
 
-        with self.stages.stage("host_spread"):
+        with self.stages.stage("host_spread"), TRACER.span("spread_fold"):
             for (name, w), (pairs, cand_keys, cand_counts, aud) in zip(
                     self._spread, ch.spread_in):
                 m = w.model

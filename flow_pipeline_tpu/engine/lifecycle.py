@@ -66,11 +66,13 @@ class WindowLifecycle:
     # prepare(batch) / apply(prepared) besides update(batch), so a
     # group thread can run ahead of the step; invertible hh families
     # (-hh.sketch) are folded; the sampled shadow audit (-obs.audit)
-    # is fed.
+    # is fed; the spread detectors' planes live on the device and its
+    # step updates them (False: in host numpy, folded between steps).
     honours_lateness = False
     has_prepare_split = False
     serves_invertible = False
     feeds_audit = False
+    spread_in_step = False
 
     _whh: list
     _ddos: list

@@ -651,6 +651,15 @@ class StreamWorker:
                 span["held_units"] = sum(
                     getattr(m, "held_unit", None) is not None
                     for m in self.models.values())
+                # the spread detectors' register planes in it (a byte a
+                # register whichever home they have), held ones too
+                planes = [p["regs"].nbytes for ms in state["models"].values()
+                          if ms.get("kind") == "windowed_spread"
+                          for p in (ms["spread"]._asdict(),
+                                    ms.get("held", {}).get("state"))
+                          if p is not None]
+                if planes:
+                    span["spread_plane_bytes"] = sum(planes)
         if state is not None:
             # ckpt_d2h, ckpt_serialize, ckpt_write: inside save_checkpoint
             save_checkpoint(
@@ -917,9 +926,13 @@ def restore_hh_state(model, ms: dict, name: str) -> None:
 
 
 def save_spread_state(model) -> dict:
+    # the form that leaves the model: [depth, width, m] uint8 wherever
+    # the state lives. A device state's leaves are device arrays still,
+    # the registers narrowed there, so that save_checkpoint's ckpt_d2h
+    # copies them once, a byte a register, as it copies every family's
     return _with_held(model, {
         "kind": "windowed_spread",
-        "spread": model.model.state,
+        "spread": model.model.leaving(),
         "current_slot": model.current_slot,
     })
 
@@ -927,17 +940,10 @@ def save_spread_state(model) -> dict:
 def restore_spread_state(model, ms: dict, name: str) -> None:
     if not _kind_matches(model, ms, name):
         return
-    from ..models.spread import SpreadState
-
-    # numpy, NOT jnp: spread state is host-resident by
-    # design (u8 registers + u32 table keys — the exact
-    # max monoid IS the canonical form)
-    sp = ms["spread"]  # NamedTuple decoded as field dict
-    model.model.state = SpreadState(
-        regs=np.asarray(sp["regs"], dtype=np.uint8),
-        table_keys=np.asarray(sp["table_keys"], dtype=np.uint32),
-        table_metric=np.asarray(sp["table_metric"], dtype=np.float32),
-    )
+    # one form on disk whatever build wrote it (u8 registers + u32
+    # table keys: the exact max monoid IS the canonical form); the
+    # model places it where its dataplane keeps state
+    model.model.state = model.model.state_from_arrays(ms["spread"])
     model.current_slot = ms["current_slot"]
     _restore_held(model, ms)
 

@@ -283,7 +283,7 @@ register(SketchFamily(
     payload_kinds=("spread",),
     merge_monoid="max",
     ranked=True,
-    state_attr="state",
+    state_attr="canonical",
     payload="flow_pipeline_tpu.mesh.codec:spread_payload",
     merge="flow_pipeline_tpu.mesh.merge:merge_spread",
     top_rows="flow_pipeline_tpu.mesh.merge:spread_top_rows",

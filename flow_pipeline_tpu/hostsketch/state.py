@@ -94,11 +94,12 @@ def is_inv_state(state) -> bool:
 def is_spread_state(state) -> bool:
     """Whether any sketch-state form (the model-facing SpreadState or a
     checkpoint/mesh field dict) is a flowspread distinct-count state —
-    the dispatch rule checkpoint restore and the mesh codec share. The
-    spread state is host-resident numpy BY DESIGN (u8 registers + u32
-    candidate keys; the exact max monoid IS the canonical form, like
-    the invertible family's u64 planes), so unlike the hh table family
-    there is no device-layout conversion to make."""
+    the dispatch rule checkpoint restore and the mesh codec share. Every
+    form that leaves a spread model is the canonical one (u8 registers +
+    u32 candidate keys: the exact max monoid, like the invertible
+    family's u64 planes), whether its open state lives on the device or
+    in host numpy (models/spread.py), so there is no layout conversion
+    to make here."""
     if isinstance(state, dict):
         return "regs" in state
     return hasattr(state, "regs")

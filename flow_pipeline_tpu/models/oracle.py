@@ -17,6 +17,10 @@ the detector's sub-windows): which rows a unit admits when batches come
 out of event-time order, as from two partitions. It imports nothing of
 the engine; tests hold the per-model path, ``FusedPipeline`` and
 ``ShardedPipeline`` to it.
+
+``distinct_exact`` is the plain reference of the distinct-count (spread)
+family: per window and key, how many distinct elements the key touched.
+A sort and a unique; no hash of ``ops/spread.py``'s.
 """
 
 from __future__ import annotations
@@ -131,6 +135,43 @@ def topk_exact(
     # descending on uint64 without a signed cast: sums may pass 2^63
     order = np.argsort(ranked.max(initial=0) - ranked, kind="stable")[:k]
     return {name: arr[order] for name, arr in g.items()}
+
+
+def distinct_exact(
+    batch: FlowBatch,
+    key_cols: list[str],
+    elem_col: str,
+    timeslot: bool = True,
+) -> dict[str, np.ndarray]:
+    """Exact distinct count of ``elem_col`` per key (and per 5-minute
+    timeslot of ``time_received``) over the rows of ``batch``: the
+    ground truth of ``models/spread.py``'s register-decoded estimates
+    (superspreaders: src_addr -> dst_addr; portscan: src_addr ->
+    dst_port).
+
+    Returns one array per key column (addresses as [G, 4]), a leading
+    ``timeslot`` when asked, ``distinct`` (uint64: the distinct elements)
+    and ``count`` (uint64: the rows, what a sum in the place of the max
+    would report). Rows are in lexicographic key order."""
+    keys = _key_matrix(batch, list(key_cols), timeslot)
+    rows = np.concatenate(
+        [keys, _key_matrix(batch, [elem_col], False)], axis=1)
+    kw = keys.shape[1]
+    pairs, per_pair = np.unique(rows, axis=0, return_counts=True)
+    uniq, start, distinct = np.unique(
+        pairs[:, :kw], axis=0, return_index=True, return_counts=True)
+    out: dict[str, np.ndarray] = {}
+    col = 0
+    if timeslot:
+        out["timeslot"], col = uniq[:, 0], 1
+    for name in key_cols:
+        w = 4 if batch.columns[name].ndim == 2 else 1
+        out[name] = uniq[:, col:col + w] if w == 4 else uniq[:, col]
+        col += w
+    out["distinct"] = distinct.astype(np.uint64)
+    out["count"] = np.add.reduceat(per_pair, start).astype(np.uint64) \
+        if len(start) else np.zeros(0, np.uint64)
+    return out
 
 
 def late_unit_sums(

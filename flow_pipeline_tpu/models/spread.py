@@ -10,7 +10,7 @@ per-key u8 registers from a hash of the COUNTED DIMENSION
 (``elem_col``), so duplicate (key, element) pairs are free
 (idempotent max) and the mesh merge is an exact element-wise max.
 
-Two halves per update chunk:
+Two halves per update chunk, on the host homes:
 
 - registers: group the chunk to unique (key, element) pairs (the max
   monoid makes this bit-identical to raw row updates), then scatter-max
@@ -22,6 +22,19 @@ Two halves per update chunk:
   are always decoded from the registers at extraction
   (hostsketch.engine.np_spread_query, the one decode every serve path
   shares), so identical registers give identical answers everywhere.
+  (The device home's table keeps another metric, what a key's registers
+  decoded to when it was last seen: ops.spread.spread_table_admit says
+  why. The rows of a close are decoded from the registers either way.)
+
+Where the state lives is the dataplane's to say (engine/dataplane.py
+picks it, families/registry.py names the two homes): the host-grouped
+pipelines and the per-model loop keep it in host numpy and fold it as
+above; ``engine.fused.FusedPipeline`` moves it to the device
+(``SpreadModel.to_device``) and updates it inside its jitted step, the
+registers as one flat plane (ops/spread.py: ``device_regs``). Every
+form that leaves the model (checkpoint, snapshot, mesh payload, the
+rows of a close) is the host one, [depth, width, m] uint8, whichever
+home the state has.
 
 Windowing rides the same wrapper as every other family:
 ``WindowedHeavyHitter(config, model_cls=SpreadModel)``. Concrete
@@ -36,6 +49,7 @@ from typing import NamedTuple
 import numpy as np
 
 from ..obs.metrics import REGISTRY
+from ..obs.trace import TRACER
 from ..schema.batch import FlowBatch, lane_width
 
 _SENTINEL = np.uint32(0xFFFFFFFF)
@@ -60,11 +74,15 @@ class SpreadConfig:
 
 
 class SpreadState(NamedTuple):
-    """Spread sketch state — HOST-resident numpy by design (u8
-    registers + u32 candidate keys; the exact max monoid IS the
-    canonical form, like the invertible family's u64 planes). The
-    update path mutates ``regs`` in place; readers that capture state
-    (top_lazy, snapshot publishers) copy."""
+    """Spread sketch state: u8 registers + u32 candidate keys, the
+    exact max monoid's canonical form. On the host (the host-grouped
+    dataplanes, the per-model loop, and every form that leaves a model)
+    the fields are numpy as annotated, the update path mutates ``regs``
+    in place, and readers that capture state (top_lazy, snapshot
+    publishers) copy. On the device (``SpreadModel.to_device``) the
+    same tuple holds jax arrays, ``regs`` flat in
+    ops.spread.DEVICE_REG_DTYPE; the fused step donates and replaces it
+    whole, and ``SpreadModel.host_state`` is the one way back."""
 
     regs: np.ndarray          # [depth, width, m] uint8
     table_keys: np.ndarray    # [capacity, key_width] uint32
@@ -93,6 +111,11 @@ def spread_init(config: SpreadConfig) -> SpreadState:
         raise ValueError(
             f"spread elem_col {config.elem_col!r} cannot be a key "
             f"column — a key always touches exactly one of itself")
+    if config.depth * config.width * config.registers >= 2 ** 31:
+        raise ValueError(
+            f"spread planes of {config.depth} x {config.width} x "
+            f"{config.registers} registers pass 2^31 cells, which a "
+            f"device step's int32 cell index cannot name")
     return SpreadState(
         regs=np.zeros((config.depth, config.width, config.registers),
                       np.uint8),
@@ -160,6 +183,13 @@ class SpreadModel:
     def __init__(self, config: SpreadConfig = SpreadConfig()):
         self.config = config
         self.state = spread_init(config)
+        # True once a device pipeline owns the state (to_device): every
+        # state this model then makes or adopts is the device form
+        self.on_device = False
+        # (the device state a host copy was made of, the copy): a
+        # publish's top() and view parts, and a checkpoint that finds
+        # the state unchanged, share ONE device->host copy of the planes
+        self._host_of: tuple = (None, None)
         # detector name for the alerting gauge (cli sets it; None keeps
         # extraction metric-silent, e.g. in parity tests)
         self.metric_label: str | None = None
@@ -167,6 +197,71 @@ class SpreadModel:
         # /metrics from the first scrape (labeled series appear when a
         # named detector publishes), not only after the first extract
         REGISTRY.gauge(*SPREAD_TOP_GAUGE)
+
+    # ---- where the state lives ---------------------------------------------
+
+    @property
+    def _shape(self) -> tuple:
+        cfg = self.config
+        return cfg.depth, cfg.width, cfg.registers
+
+    def to_device(self) -> None:
+        """Hand the state to a device step (engine.fused): from here on
+        ``state`` is jax arrays and ``update`` is the pipeline's."""
+        if not self.on_device:
+            self.on_device = True
+            self.state = self._placed(self.state)
+
+    def _placed(self, host: SpreadState) -> SpreadState:
+        """``host`` (numpy, the canonical form) as this model holds
+        state: itself, or its device form."""
+        if not self.on_device:
+            return host
+        import jax.numpy as jnp
+
+        from ..ops.spread import device_regs
+
+        return SpreadState(device_regs(host.regs),
+                           jnp.asarray(host.table_keys),
+                           jnp.asarray(host.table_metric))
+
+    def leaving(self, state: SpreadState | None = None) -> SpreadState:
+        """``state`` (default: the open one) in the form that leaves the
+        model, [depth, width, m] uint8: numpy as it is; a device state
+        as device arrays still, the registers narrowed there, so that
+        whoever copies it to the host (a checkpoint's ckpt_d2h) moves a
+        byte a register, once. A host copy already made of this very
+        state is handed out instead."""
+        state = self.state if state is None else state
+        if isinstance(state.regs, np.ndarray):
+            return state
+        if self._host_of[0] is state.regs:
+            return self._host_of[1]
+        from ..ops.spread import host_regs
+
+        return state._replace(regs=host_regs(state.regs, shape=self._shape))
+
+    def host_state(self, state: SpreadState | None = None) -> SpreadState:
+        """``state`` (default: the open one) as numpy. A device state is
+        copied once for as long as it stays the model's (a step replaces
+        it whole, so identity tells): the planes cross a publish once,
+        not once a reader."""
+        state = self.state if state is None else state
+        if isinstance(state.regs, np.ndarray):
+            return state
+        if self._host_of[0] is not state.regs:
+            host = SpreadState(*(np.asarray(x)
+                                 for x in self.leaving(state)))
+            self._host_of = (state.regs, host)
+        return self._host_of[1]
+
+    @property
+    def canonical(self) -> SpreadState:
+        """The open state in the canonical (host) form: what a mesh
+        member ships (families/registry.py: ``state_attr``)."""
+        return self.host_state()
+
+    # ---- update (host homes; a device pipeline updates in its step) -------
 
     def update(self, batch: FlowBatch) -> None:
         """Per-model update path (the host pipeline folds prepared pair
@@ -179,6 +274,10 @@ class SpreadModel:
         )
         from ..ops.hostgroup import group_by_key
 
+        if self.on_device:
+            raise RuntimeError(
+                "this spread model's state is on the device: the fused "
+                "step updates it (engine.fused), not update()")
         cfg = self.config
         kw = spread_key_width(cfg)
         bs = cfg.batch_size
@@ -200,7 +299,16 @@ class SpreadModel:
                 key_uniq, pair_counts.astype(np.float32))
             self.state = SpreadState(self.state.regs, tk, tm)
 
-    def _publish(self, top: dict[str, np.ndarray]) -> dict[str, np.ndarray]:
+    # ---- extraction ---------------------------------------------------------
+
+    def _top_of(self, state: SpreadState, k: int) -> dict[str, np.ndarray]:
+        """The ranked rows of ``state``: the planes to the host (where
+        they are not), the register decode and the ranking, one
+        ``spread_decode`` span."""
+        with TRACER.span("spread_decode",
+                         model=self.metric_label or "spread") as span:
+            top = spread_top_from(self.host_state(state), self.config, k)
+            span["rows"] = int(top["valid"].sum())
         if self.metric_label is not None:
             peak = float(top["spread"][0]) if top["valid"].any() else 0.0
             REGISTRY.gauge(*SPREAD_TOP_GAUGE).set(
@@ -210,24 +318,40 @@ class SpreadModel:
     def top(self, k: int | None = None) -> dict[str, np.ndarray]:
         """Top-k rows ranked by register-decoded spread. ``spread`` is
         the HLL estimate (min over depth rows); ``pairs`` is the
-        accumulated admission metric (a union-bound upper bound on the
-        true distinct count, useful as a sanity cross-check)."""
-        k = k or self.config.capacity
-        return self._publish(spread_top_from(self.state, self.config, k))
+        admission metric: on the host homes the accumulated count of
+        pairs (a union-bound upper bound on the true distinct count,
+        useful as a sanity cross-check), on the device what the key
+        decoded to when a batch last held it (never above ``spread``)."""
+        return self._top_of(self.state, k or self.config.capacity)
 
     def top_lazy(self, k: int | None = None):
         """Zero-arg closure producing top(k) from the state captured
-        NOW. The update path mutates registers in place, so the capture
-        copies — once per window close, same cost class as extraction."""
-        config = self.config
-        k = k or config.capacity
-        state = SpreadState(self.state.regs.copy(),
-                            self.state.table_keys.copy(),
-                            self.state.table_metric.copy())
-        return lambda: self._publish(spread_top_from(state, config, k))
+        NOW. The host update path mutates registers in place, so the
+        capture copies — once per window close, same cost class as
+        extraction; a device state is immutable and a close replaces it
+        rather than donating it, so it is captured as it is."""
+        k = k or self.config.capacity
+        state = self.state
+        if not self.on_device:
+            state = SpreadState(*(x.copy() for x in state))
+        return lambda: self._top_of(state, k)
 
     def reset(self) -> None:
-        self.state = spread_init(self.config)
+        if not self.on_device:
+            self.state = spread_init(self.config)
+            return
+        # made on the device: no plane crosses for a fresh window
+        import jax.numpy as jnp
+
+        from ..ops.spread import DEVICE_REG_DTYPE
+
+        cfg = self.config
+        self.state = SpreadState(
+            jnp.zeros(cfg.depth * cfg.width * cfg.registers,
+                      DEVICE_REG_DTYPE),
+            jnp.full((cfg.capacity, spread_key_width(cfg)), _SENTINEL,
+                     jnp.uint32),
+            jnp.zeros(cfg.capacity, jnp.float32))
 
     # ---- a window held for its late rows (models/held.py) -------------
 
@@ -237,15 +361,14 @@ class SpreadModel:
     def load_window_state(self, state: SpreadState) -> None:
         self.state = state
 
-    @staticmethod
-    def state_arrays(state: SpreadState) -> dict:
-        return state._asdict()
+    def state_arrays(self, state: SpreadState) -> dict:
+        return self.leaving(state)._asdict()
 
-    @staticmethod
-    def state_from_arrays(arrays: dict) -> SpreadState:
-        # numpy, NOT jnp: spread state is host-resident by design
-        return SpreadState(
+    def state_from_arrays(self, arrays: dict) -> SpreadState:
+        """A checkpoint's field dict (any build's: the host form is the
+        only one ever written) as this model holds state."""
+        return self._placed(SpreadState(
             regs=np.asarray(arrays["regs"], dtype=np.uint8),
             table_keys=np.asarray(arrays["table_keys"], dtype=np.uint32),
             table_metric=np.asarray(arrays["table_metric"],
-                                    dtype=np.float32))
+                                    dtype=np.float32)))

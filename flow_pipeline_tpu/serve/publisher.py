@@ -81,9 +81,15 @@ def hh_view_parts(m: WindowedHeavyHitter):
 def spread_view_parts(m: WindowedHeavyHitter):
     from ..models.spread import spread_key_width
 
-    # the update path mutates registers in place — the snapshot
-    # must freeze its own copy (the immutability contract)
-    return None, spread_key_width(m.config), m.model.state.regs.copy()
+    regs = m.model.host_state().regs
+    if not m.model.on_device:
+        # the host update path mutates registers in place — the
+        # snapshot must freeze its own copy (the immutability contract)
+        regs = regs.copy()
+    # a device state's host copy is the one this publish's top() made
+    # (SpreadModel.host_state): the planes cross once, and nothing
+    # mutates the copy
+    return None, spread_key_width(m.config), regs
 
 
 def dense_view_parts(m: WindowedHeavyHitter):

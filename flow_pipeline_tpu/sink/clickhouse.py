@@ -81,6 +81,8 @@ class ClickHouseSink:
                          ddl.CLICKHOUSE_TOP_DST_IPS,
                          ddl.CLICKHOUSE_TOP_SRC_PORTS,
                          ddl.CLICKHOUSE_TOP_DST_PORTS,
+                         ddl.CLICKHOUSE_SUPERSPREADERS,
+                         ddl.CLICKHOUSE_PORTSCAN,
                          ddl.CLICKHOUSE_DDOS_ALERTS):
                 self._post(stmt)
             for stmt in ddl.CLICKHOUSE_MIGRATIONS:

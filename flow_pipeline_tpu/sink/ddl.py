@@ -118,6 +118,28 @@ CREATE TABLE IF NOT EXISTS top_dst_ports (
 );
 """
 
+# The spread detectors' rows (-spread.enabled; models/spread.py): a source,
+# its register-decoded distinct count and the admission metric beside it.
+POSTGRES_SUPERSPREADERS = """
+CREATE TABLE IF NOT EXISTS superspreaders (
+    timeslot  BIGINT,
+    rank      INT,
+    src_addr  TEXT,
+    spread    DOUBLE PRECISION,
+    pairs     DOUBLE PRECISION
+);
+"""
+
+POSTGRES_PORTSCAN = """
+CREATE TABLE IF NOT EXISTS portscan (
+    timeslot  BIGINT,
+    rank      INT,
+    src_addr  TEXT,
+    spread    DOUBLE PRECISION,
+    pairs     DOUBLE PRECISION
+);
+"""
+
 POSTGRES_DDOS_ALERTS = """
 CREATE TABLE IF NOT EXISTS ddos_alerts (
     sub_window         BIGINT,
@@ -237,6 +259,28 @@ CREATE TABLE IF NOT EXISTS top_dst_ports (
 ORDER BY (timeslot, rank);
 """
 
+CLICKHOUSE_SUPERSPREADERS = """
+CREATE TABLE IF NOT EXISTS superspreaders (
+    timeslot UInt64,
+    rank UInt32,
+    src_addr String,
+    spread Float64,
+    pairs Float64
+) ENGINE = MergeTree()
+ORDER BY (timeslot, rank);
+"""
+
+CLICKHOUSE_PORTSCAN = """
+CREATE TABLE IF NOT EXISTS portscan (
+    timeslot UInt64,
+    rank UInt32,
+    src_addr String,
+    spread Float64,
+    pairs Float64
+) ENGINE = MergeTree()
+ORDER BY (timeslot, rank);
+"""
+
 CLICKHOUSE_DDOS_ALERTS = """
 CREATE TABLE IF NOT EXISTS ddos_alerts (
     sub_window UInt64,
@@ -299,6 +343,8 @@ TABLE_COLUMNS = {
                       "count"],
     "top_dst_ports": ["timeslot", "rank", "dst_port", "bytes", "packets",
                       "count"],
+    "superspreaders": ["timeslot", "rank", "src_addr", "spread", "pairs"],
+    "portscan": ["timeslot", "rank", "src_addr", "spread", "pairs"],
     "ddos_alerts": ["sub_window", "bucket", "dst_addr", "rate", "zscore",
                     "baseline_quantile"],
     "flows": ["time_flow", "type", "sampling_rate", "src_as", "dst_as",
@@ -308,7 +354,8 @@ TABLE_COLUMNS = {
 
 
 RANKED_TABLES = {"top_talkers", "top_pairs", "top_src_ips", "top_dst_ips",
-                 "top_src_ports", "top_dst_ports"}
+                 "top_src_ports", "top_dst_ports", "superspreaders",
+                 "portscan"}
 
 
 def assign_ranks(table: str, records: list[dict]) -> list[dict]:
@@ -381,6 +428,18 @@ CREATE TABLE IF NOT EXISTS top_src_ports (
 CREATE TABLE IF NOT EXISTS top_dst_ports (
     timeslot INTEGER, rank INTEGER, dst_port INTEGER,
     bytes INTEGER, packets INTEGER, count INTEGER
+);
+""",
+    "superspreaders": """
+CREATE TABLE IF NOT EXISTS superspreaders (
+    timeslot INTEGER, rank INTEGER, src_addr TEXT,
+    spread REAL, pairs REAL
+);
+""",
+    "portscan": """
+CREATE TABLE IF NOT EXISTS portscan (
+    timeslot INTEGER, rank INTEGER, src_addr TEXT,
+    spread REAL, pairs REAL
 );
 """,
     "ddos_alerts": """

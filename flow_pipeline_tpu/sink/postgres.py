@@ -37,6 +37,8 @@ DDL = {
     "top_dst_ips": ddl.POSTGRES_TOP_DST_IPS,
     "top_src_ports": ddl.POSTGRES_TOP_SRC_PORTS,
     "top_dst_ports": ddl.POSTGRES_TOP_DST_PORTS,
+    "superspreaders": ddl.POSTGRES_SUPERSPREADERS,
+    "portscan": ddl.POSTGRES_PORTSCAN,
     "ddos_alerts": ddl.POSTGRES_DDOS_ALERTS,
 }
 

@@ -32,6 +32,10 @@ with open(os.path.join(ROOT, "tests", "data",
     # backbone-ranks: a later PR that changes a stream's bytes changes
     # what every ledger line of its cells measured
     DIGESTS.update(json.load(f))
+with open(os.path.join(ROOT, "tests", "data",
+                       "spreaders_ranks_digests.json")) as f:
+    # and of estate-spread-catchup, as PR 47 left zipf-ranks-spreaders
+    DIGESTS.update(json.load(f))
 
 
 # ---- a kind is a file, found by name ---------------------------------------
@@ -274,6 +278,9 @@ def test_every_cell_loads_a_stream_kind_with_the_whole_api(name):
     elif name == CELL_BACKBONE:
         assert os.path.basename(c.stream.path) == "backbone-ranks.py"
         assert spec.max_disorder_s == 0 and c.config["bus_partitions"] == 1
+    elif name == CELL_SPREAD:
+        assert os.path.basename(c.stream.path) == "zipf-ranks-spreaders.py"
+        assert spec.max_disorder_s == 0 and c.config["bus_partitions"] == 1
     else:
         # no other configuration names a stream kind: each gets
         # zipf-ranks, and drives the one partition it states
@@ -330,6 +337,7 @@ def test_slot_sums_group_by_slot_where_event_time_runs_backwards():
 
 CELL_2PART = "estate-2part-catchup"
 CELL_BACKBONE = "hh-backbone-catchup"
+CELL_SPREAD = "estate-spread-catchup"
 SEEDS = [2**31 + 11, 3700001001]
 
 
@@ -667,3 +675,248 @@ ENTRY %main (a.1: f32[8]) -> f32[8] {
     assert family_scopes.merge_index_map(text) == {
         "t.8": 2, "s.9": 2, "f.7": 2,
         "f.2": 1, "c.3": 1, "f.4": 10, "f.5": 0}
+
+
+# ---- ISSUE 47: zipf-ranks-spreaders, estate-spread and its checks ------------
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_the_spreaders_kind_is_zipf_ranks_but_for_the_re_homed_ranks(seed):
+    """Ranks, bytes and packets of a seed are ``zipf-ranks``' byte for
+    byte; of the key table only the re-homed ranks' source, destination
+    host and destination port differ, so ``flows_5m`` (the AS pairs) is
+    ``estate-catchup``'s too."""
+    plain = manifest.load_cell(ROOT, REAL, "estate-catchup").stream
+    cell = manifest.load_cell(ROOT, REAL, CELL_SPREAD)
+    spread = cell.stream
+    assert cell.config["stream"]["kind"] == "zipf-ranks-spreaders"
+    sa, sb = plain.spec(seed, 65536, 17), spread.spec(seed, 65536, 17)
+    ta, tb = plain.kind.key_table(sa), spread.kind.key_table(sb)
+    for chunk in (0, 1, 107):
+        da = plain.kind.chunk_draws(sa, ta, chunk)
+        db = spread.kind.chunk_draws(sb, tb, chunk)
+        for x, y in zip(da, db):
+            assert x.dtype == y.dtype and (x == y).all()
+        ca = plain.kind.chunk_columns(sa, ta, chunk, da)
+        cb = spread.kind.chunk_columns(sb, tb, chunk, db)
+        assert list(ca) == list(cb)
+        for name in ca:
+            assert ca[name].dtype == cb[name].dtype
+            if name not in ("src_addr", "dst_addr", "dst_port"):
+                assert (ca[name] == cb[name]).all(), name
+    assert sa.close_flows(0, 10**8) == sb.close_flows(0, 10**8)
+    for col in ("src_port", "proto", "src_as", "dst_as", "cdf"):
+        assert (getattr(ta, col) == getattr(tb, col)).all(), col
+    ranks, source, _rng = spread.kind.spreader_ranks(sb)
+    assert len(ranks) == len(set(ranks.tolist())) == 50_000
+    moved = np.zeros(len(tb), bool)
+    moved[ranks] = True
+    for col in ("src_host", "dst_host", "dst_port"):
+        assert (getattr(ta, col)[~moved] == getattr(tb, col)[~moved]).all()
+    # 64 sources whose shares fall as 1 / (s + 1): ~10,500 the first,
+    # ~165 the last; even ones fan out to hosts, odd ones scan ports
+    held = np.bincount(source, minlength=64)
+    assert len(held) == 64 and 10_000 < held[0] < 11_000
+    assert 150 < held[63] < 180 and (np.diff(held) <= 0).all()
+    assert (tb.src_host[ranks] == source).all()
+    for s in (0, 1, 62, 63):
+        mine = ranks[source == s]
+        hosts = len(np.unique(tb.dst_host[mine]))
+        ports = len(np.unique(tb.dst_port[mine]))
+        assert (hosts, ports) == ((len(mine), 1) if s % 2 == 0
+                                  else (1, len(mine)))
+    assert (tb.dst_port[ranks[source % 2 == 0]]
+            == spread.kind.FAN_PORT).all()
+    assert tb.src_host.max() < 2**16 and tb.dst_host.max() < 2**16
+
+
+def test_the_spreaders_kind_names_the_keys_it_does_not_know():
+    stream = manifest.load_cell(ROOT, REAL, CELL_SPREAD).stream
+    with pytest.raises(ValueError,
+                       match=r"zipf-ranks-spreaders has no key \['attack"):
+        stream.with_params(attack=1).spec(1, 4096, 0)
+    with pytest.raises(ValueError, match="spread_rank_share"):
+        stream.with_params(spread_rank_share=1.5).spec(1, 4096, 0)
+
+
+def test_estate_spread_is_default_estate_but_for_what_its_file_lists():
+    with open(os.path.join(ROOT, "benchmark/configs/default-estate.json")) \
+            as f:
+        base = json.load(f)
+    with open(os.path.join(ROOT, "benchmark/configs/estate-spread.json")) \
+            as f:
+        cfg = json.load(f)
+    with open(REAL) as f:
+        man = json.load(f)
+    (entry,) = [c for c in man["configs"] if c["name"] == "estate-spread"]
+    assert entry["reduced"] == ["scale", "bus_partitions"] == list(
+        cfg["reduced"])
+    assert entry["source"] == cfg["source"] and len(entry["source"]) <= 200
+    assert cfg["processor_flags"] == [
+        *base["processor_flags"], "-spread.enabled=true",
+        "-spread.width", "32768", "-spread.regs", "256"]
+    assert cfg["stream"] == {"kind": "zipf-ranks-spreaders",
+                             **base["stream"], "spread_rank_share": 0.05,
+                             "spread_sources": 64}
+    for same in ("chips", "topic", "bus_partitions", "reduced",
+                 "sink_rows_per_window", "close_table",
+                 "flags_added_by_the_harness"):
+        assert cfg[same] == base[same], same
+    # the estate's own tables, checks and limits stay; two tables and
+    # the query checks of the detectors come after them
+    n = len(base["checks"]["tables"])
+    assert cfg["checks"]["tables"][:n] == base["checks"]["tables"]
+    assert [(t["name"], t["kind"], t["key"], t["element"], t["top_n"])
+            for t in cfg["checks"]["tables"][n:]] == [
+        ("superspreaders", "ranked_spread", ["src_host"], "dst_host", 32),
+        ("portscan", "ranked_spread", ["src_host"], "dst_port", 32)]
+    # every one of the 32 has to be there (the floor is top_n: the
+    # warm-up slot's ties), the worst error under 1 (a lost source), the
+    # sources of 1,000 targets held to the guarantee itself
+    assert all(0.25 < t["limit"] < 1.0 and t["floor"] == t["top_n"]
+               and t["heavy"] == 1000 and t["heavy_limit"]
+               == cfg["guarantees"]["spread_rel_err_max"]
+               for t in cfg["checks"]["tables"][n:])
+    q = len(base["checks"]["queries"])
+    assert cfg["checks"]["queries"][:q] == base["checks"]["queries"]
+    # one reset check a detector (benchmark/queries/spread_keys.py)
+    assert [(x["kind"], x["model"], "key=" in x["path"])
+            for x in cfg["checks"]["queries"][q:]] == [
+        ("spread_keys", "superspreaders", False),
+        ("spread_keys", "portscan", False)]
+    assert {k: v for k, v in cfg["guarantees"].items()
+            if not k.startswith("spread_")} == base["guarantees"]
+    assert cfg["guarantees"]["spread_rel_err_max"] == 0.25
+    assert {k: v for k, v in cfg["assumed"].items()
+            if not k.startswith("spread_")} == base["assumed"]
+    assert {"spread_rank_share", "spread_sources", "spread_width",
+            "spread_regs", "spread_floor"} <= set(cfg["assumed"])
+    (cell,) = [w for w in man["workloads"] if w["name"] == CELL_SPREAD]
+    assert cell == {"name": CELL_SPREAD, "config": "estate-spread",
+                    "traffic": "backlog-drain-spread", "chips": 1,
+                    "why": cell["why"]}
+    with open(os.path.join(
+            ROOT, "benchmark/traffic/backlog-drain-spread.json")) as f:
+        traffic = json.load(f)
+    assert traffic["mode"] == "backlog"
+    assert traffic["provision_flows_per_s"] == 2_240_000
+    assert traffic["reader"] == {"poll_interval_s": 0.02}
+
+
+def _toy_reference():
+    """Six ranks of three sources on a hand-made key table, counts a
+    slot: source 7 touches hosts {1, 2, 3} (rank 3 unseen: host 4 does
+    not count) with ports {80}; source 8 one host, ports {1, 2}."""
+    table = types.SimpleNamespace(
+        src_host=np.array([7, 7, 7, 7, 8, 8], np.uint32),
+        dst_host=np.array([1, 2, 3, 4, 9, 9], np.uint32),
+        dst_port=np.array([80, 80, 80, 80, 1, 2], np.uint32))
+    counts = np.array([5, 1, 2, 0, 40, 2], np.uint64)
+    ref = types.SimpleNamespace(table=table)
+    return ref, {300: (counts * 100, counts, counts)}
+
+
+def test_the_spread_tables_reference_counts_distinct_elements_of_seen_ranks():
+    kind = manifest._load_module(os.path.join(
+        ROOT, "benchmark", "tables", "ranked_spread.py"), manifest.TABLE_API)
+    ref, sums = _toy_reference()
+    hosts = {"name": "superspreaders", "key": ["src_host"],
+             "element": "dst_host", "top_n": 2, "floor": 2, "limit": 0.25,
+             "heavy": 3, "heavy_limit": 0.1}
+    ports = dict(hosts, name="portscan", element="dst_port")
+    assert kind.want(ref, hosts, sums) == {300: {7: 3, 8: 1}}
+    assert kind.want(ref, ports, sums) == {300: {8: 2, 7: 1}}
+    run = types.SimpleNamespace(cell=types.SimpleNamespace(
+        config={"sink_rows_per_window": 100}))
+    # the control ranks by flows and reports them: 42 where 1 belongs
+    assert kind.control(ref, hosts, sums, run) == {
+        300: [(8, 42.0), (7, 8.0)]}
+    exact = kind.want(ref, hosts, sums)
+    ok = kind.compare(hosts, exact, {300: [(7, 3.2), (8, 1.0)]}, 50)
+    assert ok["spread_max_rel_err"][0] == pytest.approx(0.2 / 3)
+    assert ok["spread_missing_keys"] == (0, 0)
+    # the heavy sources (3 elements or more: source 7 alone), by the
+    # root mean square over the slots; a lost one counts as 1
+    assert ok["spread_heavy_rms_rel_err"] == (pytest.approx(0.2 / 3), 0.1)
+    two = kind.compare(hosts, {300: exact[300], 600: exact[300]},
+                       {300: [(7, 3.3), (8, 1.0)], 600: [(8, 1.0)]}, 50)
+    assert two["spread_heavy_rms_rel_err"][0] == pytest.approx(
+        ((0.1 ** 2 + 1.0) / 2) ** 0.5)
+    assert kind.compare(dict(hosts, heavy=4), exact, {300: [(7, 9.0)]},
+                        50)["spread_heavy_rms_rel_err"][0] == 0.0
+    # a missing source counts only at or above the floor; an error is
+    # relative to max(exact, floor)
+    lost = kind.compare(hosts, exact, {300: [(8, 1.5)]}, 50)
+    assert lost["spread_missing_keys"] == (1, 0)
+    assert lost["spread_max_rel_err"][0] == pytest.approx(0.25)
+    summed = kind.compare(hosts, exact,
+                          kind.control(ref, hosts, sums, run), 50)
+    assert summed["spread_max_rel_err"][0] == pytest.approx(41 / 2)
+    # of the program the file imports the sink's list of columns alone:
+    # no sketch, no hash, no model
+    with open(kind.__file__) as f:
+        imported = [ln.split()[1] for ln in f.read().splitlines()
+                    if ln.split()[:1] in (["from"], ["import"])
+                    and "flow_pipeline_tpu" in ln]
+    assert imported == ["flow_pipeline_tpu.sink.ddl"]
+
+
+def test_the_spread_table_kind_ends_a_run_on_a_sink_without_its_tables(
+        monkeypatch):
+    """What the parent of PR 47 is: a sink whose ``TABLE_COLUMNS`` has
+    neither typed table. The kind's cell ends as its files load, by
+    ``Abort`` (exit 3, one line), and gives no result."""
+    from benchmark.drive import Abort
+    from flow_pipeline_tpu.sink import ddl
+
+    kind = manifest._load_module(os.path.join(
+        ROOT, "benchmark", "tables", "ranked_spread.py"), manifest.TABLE_API)
+    kind.require_typed_tables()  # this tree's sink has both
+    monkeypatch.setattr(ddl, "TABLE_COLUMNS", {
+        k: v for k, v in ddl.TABLE_COLUMNS.items() if k != "portscan"})
+    with pytest.raises(Abort, match="no such \\['portscan'\\]"):
+        kind.require_typed_tables()
+
+
+def test_the_spread_roofline_counts_four_words_an_index():
+    from benchmark import spread_roofline as sr
+
+    with open(os.path.join(ROOT, "benchmark/configs/estate-spread.json")) \
+            as f:
+        cfg = json.load(f)
+    # two detectors x depth 2 x 32,768 rows: a cell read and written,
+    # the index and the value, a word each
+    assert sr.scatter_bytes(cfg) == 2 * 2 * 32768 * 4 * 4
+    assert sr.scatter_least_seconds(cfg, "TPU v5 lite") \
+        == sr.scatter_bytes(cfg) / 819e9
+    assert sr.scatter_least_seconds(cfg, "cpu") is None
+    with open(os.path.join(ROOT, "benchmark/configs/default-estate.json")) \
+            as f:
+        assert sr.scatter_bytes(json.load(f)) == 0
+
+
+def test_a_detectors_scopes_are_told_apart_by_its_name():
+    from benchmark import spread_scopes
+
+    text = """
+%fused_computation.7 (p.1: s32[8], p.2: s32[8]) -> s32[8] {
+  %p.1 = s32[8]{0} parameter(0)
+  %t.8 = s32[8]{0} maximum(%p.1, %p.1), metadata={op_name="jit(step)/spread_regs_portscan/scatter-max"}
+  ROOT %s.9 = s32[8]{0} scatter(%p.1, %t.8)
+}
+
+ENTRY %main (a.1: s32[8]) -> s32[8] {
+  %a.1 = s32[8]{0} parameter(0), metadata={op_name="jit(step)/hh_chain_sort/sort"}
+  %f.7 = s32[8]{0} fusion(%c.3, %a.1), kind=kLoop, calls=%fused_computation.7
+  %f.2 = s32[8]{0} fusion(%a.1), kind=kLoop, metadata={op_name="jit(step)/spread_table_superspreaders/top_k"}
+  %c.3 = s32[8]{0} copy(%f.2)
+  %f.4 = s32[8]{0} fusion(%a.1), kind=kLoop, metadata={op_name="jit(step)/spread_regs_superspreaders/scatter-max"}
+  %c.6 = s32[8]{0} copy(%a.1)
+}
+"""
+    assert spread_scopes.scope_names(text) == {
+        "t.8": "spread_regs_portscan", "s.9": "spread_regs_portscan",
+        "f.7": "spread_regs_portscan",
+        "f.2": "spread_table_superspreaders",
+        "c.3": "spread_table_superspreaders",
+        "f.4": "spread_regs_superspreaders"}

@@ -32,6 +32,8 @@ TINY_SLIDING = "benchmark/tests/fixtures/BENCHMARK.tiny-sliding.json"
 TINY_2PART = "benchmark/tests/fixtures/BENCHMARK.tiny-2part.json"
 # and hh-backbone-catchup's (ISSUE 42)
 TINY_BACKBONE = "benchmark/tests/fixtures/BENCHMARK.tiny-backbone.json"
+# and estate-spread-catchup's (ISSUE 47)
+TINY_SPREAD = "benchmark/tests/fixtures/BENCHMARK.tiny-spread.json"
 
 
 class Cell(NamedTuple):
@@ -67,6 +69,11 @@ TWOPART_CELL = "tiny-2part-catchup"
 BACKBONE_CELL = "tiny-backbone-catchup"
 BACKBONE = "hh-backbone-catchup"
 BACKBONE_KIND = "ranked_bytes_sampled"
+# the spread detectors inside the fused step, on a stream with spreaders:
+# run once, traced, with both controls
+SPREAD_CELL = "tiny-spread-catchup"
+SPREAD = "estate-spread-catchup"
+SPREAD_KIND = "ranked_spread"
 # the live and the four-chip twin, traced too (ISSUE 35: the spans of the
 # serve path and of a publish of four stacked replicas)
 LIVE_CELL, MESH_CELL = "tiny-live", "tiny-mesh4-catchup"
@@ -83,13 +90,15 @@ TRACED = {TRACED_CELL: CELLS[TRACED_CELL],
                              "bf16,bf16:ranked_bytes"),
           BACKBONE_CELL: Cell(BACKBONE, "FusedPipeline", 2**31 + 11, 0,
                               TINY_BACKBONE,
-                              f"bf16,bf16:{BACKBONE_KIND}")}
+                              f"bf16,bf16:{BACKBONE_KIND}"),
+          SPREAD_CELL: Cell(SPREAD, "FusedPipeline", 2**31 + 11, 0,
+                            TINY_SPREAD, f"bf16,bf16:{SPREAD_KIND}")}
 # they read the `XLA Modules` line of a /device:TPU plane: a CPU trace has
 # none, and a CPU number never goes under a device metric's name
 TPU_PLANE_ONLY = ("step_device_ms_p50", "fused_step_roofline",
                   "slide_fold_device_ms_per_slide", "slide_fold_roofline",
                   "step_device_ms_p50.2part", "fused_step_roofline.2part",
-                  "hh_step_roofline")
+                  "hh_step_roofline", "spread_update_roofline")
 
 
 # ISSUE 35's readers of the program's spans inside the layers that only
@@ -233,7 +242,7 @@ def test_traced_dry_run_reads_every_layer_metric(dry_run, cell, metric):
 
 
 @pytest.mark.parametrize("cell", [AS_CELL, SLIDING_CELL, TWOPART_CELL,
-                                  BACKBONE_CELL])
+                                  BACKBONE_CELL, SPREAD_CELL])
 def test_a_twin_is_the_ledgers_cell_at_the_tiny_size(cell):
     """Every per-layer metric the ledger's cell reports, and no other:
     the fixture's own in the ledger's order, then those added since."""
@@ -288,16 +297,17 @@ def test_an_inside_metric_lists_the_cells_that_have_its_span(metric):
         assert entry["moves"] == "query_staleness_p50_s"
         return
     assert entry["moves"] == "sustained_flows_per_s"
-    # each list ends with the cell ISSUE 42 added: it has every span
+    # each list ends with the cells ISSUEs 42 and 47 added: they have
+    # every span
     if metric == "split_parts_ms_p50":  # the fused pipeline's cut
         assert entry["workloads"] == [*ONE_CHIP, TRACED[TWOPART_CELL].ledger,
-                                      BACKBONE]
+                                      BACKBONE, SPREAD]
     elif metric == "close_extract_ms_per_close":  # a tumbling close
         assert sorted(entry["workloads"]) == sorted(
             [*(c for c in ALL_LEDGER_CELLS if c != "estate-sliding-catchup"),
-             BACKBONE])
+             BACKBONE, SPREAD])
     else:
-        assert entry["workloads"] == [*ALL_LEDGER_CELLS, BACKBONE]
+        assert entry["workloads"] == [*ALL_LEDGER_CELLS, BACKBONE, SPREAD]
 
 
 # the two-partition twin lists its ledger cell's metrics and no other:
@@ -336,7 +346,7 @@ def test_the_live_share_is_listed_for_the_fused_steps_cells():
         "name": LIVE_SHARE, "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "fused device step",
         "moves": "sustained_flows_per_s",
-        "workloads": [*ONE_CHIP, BACKBONE]}
+        "workloads": [*ONE_CHIP, BACKBONE, SPREAD]}
 
 
 @pytest.mark.parametrize("cell", TILED)
@@ -497,6 +507,78 @@ def test_the_backbone_twins_controls_come_out_not_correct(dry_run, control):
     else:
         assert {"flows5m_mismatched_groups",
                 "flows5m_scaled_mismatches"} <= failed
+
+
+def test_the_spread_twin_runs_both_detectors_inside_the_fused_step(dry_run):
+    """`-spread.enabled` through cli.processor_main on a stream with
+    spreaders: the detectors' rows (typed tables of their own in the
+    sink) are the reference's distinct counts within the limit over
+    exactly the flows consumed, every large source among them; no host
+    fold ran between dispatches; the new scopes, span and counter have
+    their readers."""
+    line = _result(dry_run, SPREAD_CELL, trace=1)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["window"]["dataplane"] == TRACED[SPREAD_CELL].dataplane
+    checks = {c["name"]: c for c in line["checks"]}
+    assert {"flows5m_mismatched_groups", "flows5m_scaled_mismatches",
+            "unaccounted_flows", "topk_bytes_max_rel_err",
+            "spread_max_rel_err", "spread_heavy_rms_rel_err",
+            "spread_missing_keys", "commit_offset_gap", "commits_ahead_of_flush",
+            "query_mismatches"} == set(checks)
+    assert 0 < checks["spread_max_rel_err"]["value"] \
+        < checks["spread_max_rel_err"]["limit"]
+    assert 0 < checks["spread_heavy_rms_rel_err"]["value"] \
+        < checks["spread_heavy_rms_rel_err"]["limit"]
+    assert all(c["value"] == 0 for n, c in checks.items()
+               if n not in ("spread_max_rel_err", "spread_heavy_rms_rel_err",
+                            "topk_bytes_max_rel_err"))
+    assert {"superspreaders", "portscan"} <= set(
+        line["window"]["late_by_model"])
+    value = {name: m["value"] for name, m in line["metrics"].items()}
+    assert value["compiles_in_window"] == 0
+    assert value["spread_fold_ms_p50"] == 0     # nothing folds on the host
+    assert value["step_spread_regs_ms"] > 0 and \
+        value["step_spread_table_ms"] > 0
+    assert value["spread_decode_ms_per_close"] > 0
+    # both detectors' planes, a byte a register: 2 x (2 x 1024 x 64)
+    assert value["spread_plane_mb_p50"] == pytest.approx(0.262144)
+    assert value["checkpoint_raw_mb_p50"] > value["spread_plane_mb_p50"]
+    assert "spread_update_roofline" not in value  # a share of the chip's
+
+
+@pytest.mark.parametrize("control", TRACED[SPREAD_CELL].control.split(","))
+def test_the_spread_twins_controls_come_out_not_correct(dry_run, control):
+    """A source's flow count in the place of its distinct count."""
+    line = _result(dry_run, SPREAD_CELL, trace=1)
+    found = next(c for c in line["controls"] if c["control"] == control)
+    assert found["correct"] is False
+    failed = {c["name"]: c["value"] for c in found["checks"] if not c["ok"]}
+    assert failed["spread_max_rel_err"] > 3.0
+    assert failed["spread_heavy_rms_rel_err"] > 3.0
+    if ":" in control:
+        assert {"spread_max_rel_err", "spread_heavy_rms_rel_err"} <= set(
+            failed) <= {"spread_max_rel_err", "spread_heavy_rms_rel_err",
+                        "spread_missing_keys"}
+    else:
+        assert {"flows5m_mismatched_groups",
+                "topk_bytes_max_rel_err"} <= set(failed)
+
+
+def test_the_spread_readers_are_listed_for_their_cell_alone():
+    new = {e["name"]: e for e in _manifest("BENCHMARK.json")["per_layer"]
+           if e["name"] in ("step_spread_regs_ms", "step_spread_table_ms",
+                            "spread_update_roofline",
+                            "spread_decode_ms_per_close",
+                            "spread_plane_mb_p50")}
+    assert len(new) == 5
+    for e in new.values():
+        assert e["workloads"] == [SPREAD]
+        assert e["moves"] == "sustained_flows_per_s"
+    assert new["spread_update_roofline"]["unit"] == "%"
+    assert {new[n]["source"] for n in ("step_spread_regs_ms",
+                                       "step_spread_table_ms",
+                                       "spread_update_roofline")} == {
+        "device_trace"}
 
 
 def test_the_detectors_own_runs_are_counted_where_polls_cross_sub_windows(

@@ -39,6 +39,8 @@ MESH = ["-processor.mesh", "4"]
 NO_AUDIT = ["-obs.audit", "off"]
 NO_PREFETCH = ["-feed.prefetch", "0"]
 STAGED = ["-ingest.fused", "off"]  # needs no native library
+SPREAD = ["-spread.enabled=true", "-spread.width", "256",
+          "-spread.regs", "16"]
 
 HOST_NEEDS_GROUPING = (
     "sketch.backend=host needs the host-grouped pipeline (CPU backend or "
@@ -54,6 +56,12 @@ FUSED_NOT_SELECTED = (
     "ingest_fused='on' but the host sketch pipeline was not selected — it "
     "needs a fusable model set and host-grouped pre-aggregation (CPU "
     "backend or -processor.hostassist on)")
+
+
+def spread_planes(home):
+    return ("spread detectors superspreaders, portscan: register planes "
+            + {"device": "on the device, updated inside the fused step",
+               "host": "in host memory, folded between device steps"}[home])
 
 
 def audit_nothing(mode="sample"):
@@ -93,6 +101,7 @@ class Row:
     hh_sketch: str = "table"
     audit: str = "off"
     lateness: int = 0           # every holding model's, afterwards
+    spread: str = "none"        # where the spread detectors' planes live
     words: tuple = ()
     exc: tuple | None = None    # (type, a part of its message)
 
@@ -119,6 +128,9 @@ ROWS = [
         pipeline="FusedPipeline", words=[audit_off()]),
     row("cell:estate-mesh4-catchup", MESH, backend="tpu",
         pipeline="ShardedPipeline", words=[audit_off()]),
+    row("cell:estate-spread-catchup", SPREAD, backend="tpu",
+        pipeline="FusedPipeline", spread="device",
+        words=[spread_planes("device"), audit_off()]),
     # tier-1's stand-in for the one-chip cells (benchmark/tests, and
     # every test that wants the chip's dataplane on the CPU)
     row("cells-on-cpu:hostassist-off", OFF, pipeline="FusedPipeline",
@@ -145,6 +157,18 @@ ROWS = [
     row("cpu-default:lateness", LATE, pipeline="HostGroupPipeline",
         executor=True, audit="sample",
         words=late("HostGroupPipeline", *HELD)),
+    # the spread detectors' planes go where the pipeline keeps them
+    row("cpu-default:spread", SPREAD, pipeline="HostGroupPipeline",
+        executor=True, audit="sample", spread="host",
+        words=[spread_planes("host")]),
+    row("spread:hostassist-off", SPREAD, OFF, LATE,
+        pipeline="FusedPipeline", lateness=7, spread="device",
+        words=[spread_planes("device"), audit_off()]),
+    row("spread:host-sketch", SPREAD, HOST, STAGED,
+        pipeline="HostSketchPipeline", executor=True, hh_sketch="mixed",
+        audit="sample", spread="host", words=[spread_planes("host")]),
+    row("spread:unfused", SPREAD, ["-processor.fused=false"],
+        spread="host", words=[spread_planes("host"), audit_off()]),
     row("hostassist-on:tpu", ON, backend="tpu",
         pipeline="HostGroupPipeline", executor=True, audit="sample"),
     row("exact-only:cpu", EXACT_ONLY, pipeline="HostGroupPipeline",
@@ -301,6 +325,9 @@ def test_a_worker_runs_what_the_row_says(r, monkeypatch, said):
     assert (worker.flusher is not None) == r.executor
     assert tuple(said) == r.words
     assert _lateness(models) <= {r.lateness}
+    assert {m.model.on_device for m in models.values()
+            if getattr(getattr(m, "model", None), "snapshot_kind", None)
+            == "windowed_spread"} <= {r.spread == "device"}
     assert _build_info(config.build_role)["hh_sketch"] == r.hh_sketch
     audit = getattr(fused, "audit", None)  # None without an hh family
     assert (audit.mode if audit is not None else "off") == (

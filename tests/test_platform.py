@@ -140,7 +140,7 @@ class TestChipSmoke:
             "platform": "cpu", "kind": "cpu", "count": 1}}
         stages = {r["stage"]: r for r in recs[:-1]}
         assert list(stages) == ["native", "pipeline", "oracle", "cache",
-                                "cms_kernels", "mesh4"]
+                                "cms_kernels", "spread_kernels", "mesh4"]
         assert all(r["pass"] and r["platform"] == "cpu"
                    for r in stages.values())
         assert stages["pipeline"]["dataplane"] == "FusedPipeline"
@@ -154,6 +154,17 @@ class TestChipSmoke:
         assert all(set(forms) == {"every_slot", "ops_cms"}
                    for rec in live.values()
                    for forms in rec["ms_a_call"].values())
+        # PR 47: the spread detectors' register update against its numpy
+        # twin, both detectors, every row set and both register dtypes
+        spread = stages["spread_kernels"]
+        assert spread["bit_exact"] and spread["device_dtype"] == "int32"
+        assert sorted(spread["detectors"]) == ["portscan", "superspreaders"]
+        assert all(
+            0 < rec["groups"] <= rec["rows"] and rec["registers_raised"]
+            and set(rec["ms_a_call"]) == {
+                f"{dtype}.{rows}" for dtype in ("int32", "uint8")
+                for rows in ("groups", "every_row")}
+            for rec in spread["detectors"].values())
         # one device: mesh4 says so instead of passing silently
         assert stages["mesh4"]["skipped"]
         assert "saw 1" in stages["mesh4"]["reason"]

@@ -44,7 +44,10 @@ from test_ingest import CollectSink, _stream_to_bus
 WORKER_SPANS = {
     "poll_wait": None,
     "apply": None,
-    "spread_fold": "apply",
+    # PR 47: the spread detectors fold nothing on the host under the
+    # fused step ("spread_fold" has no emitter left); a close and a
+    # publish decode their planes
+    "spread_decode": "apply",
     "lane_build": "apply",
     "h2d": "apply",
     "step_dispatch": "apply",
@@ -82,6 +85,7 @@ SPAN_ARGS = {
     "poll_wait": ("depth",), "apply": ("rows", "age_ms"),
     "fetch": ("rows", "partition"), "split_parts": ("parts",),
     "window_close": ("model", "slot", "rows"),
+    "spread_decode": ("model", "rows"),
     "flush_rows": ("table", "rows"),
     "sink_put": ("sink", "table", "rows"),
     "sink_records": ("rows",), "sink_execute": ("rows",),

@@ -109,6 +109,72 @@ class TestSQLite:
         ]
 
 
+    @pytest.mark.parametrize("table", ["superspreaders", "portscan"])
+    def test_spread_rows_land_in_a_typed_table_in_rank_order(self, table):
+        """The detectors' rows (models/spread.py: a source, its decoded
+        spread, the admission metric) have tables of their own since
+        PR 47, ranked like the top-K tables, not the journal's JSON."""
+        sink = SQLiteSink()
+        sink.write(table, {
+            "timeslot": np.array([300, 300, 300], np.uint64),
+            "src_addr": np.array([[0x20010DB8, 1, 0, 7],
+                                  [0x20010DB8, 1, 0, 9],
+                                  [0, 0, 0, 0]], np.uint32),
+            "spread": np.array([812.5, 40.25, 0.0], np.float32),
+            "pairs": np.array([1200.0, 64.0, 0.0], np.float32),
+            "valid": np.array([True, True, False]),
+        })
+        assert sink.query(f"SELECT timeslot, rank, src_addr, spread, pairs "
+                          f"FROM {table} ORDER BY rank") == [
+            (300, 0, "2001:db8:0:1::7", 812.5, 1200.0),
+            (300, 1, "2001:db8:0:1::9", 40.25, 64.0)]
+        assert sink.query("SELECT COUNT(*) FROM journal") == [(0,)]
+
+
+class TestDialects:
+    """One column list a table, shared by the three SQL sinks
+    (sink/ddl.py::TABLE_COLUMNS): the spread detectors' tables beside
+    ``top_pairs`` (PR 42)."""
+
+    @pytest.mark.parametrize("table, cols", [
+        ("top_pairs", ["timeslot", "rank", "src_addr", "dst_addr", "bytes",
+                       "packets", "count"]),
+        ("superspreaders", ["timeslot", "rank", "src_addr", "spread",
+                            "pairs"]),
+        ("portscan", ["timeslot", "rank", "src_addr", "spread", "pairs"]),
+    ])
+    def test_a_ranked_table_has_its_columns_in_every_dialect(self, table,
+                                                             cols):
+        from flow_pipeline_tpu.sink import clickhouse, ddl, postgres
+
+        assert ddl.TABLE_COLUMNS[table] == cols
+        assert table in ddl.RANKED_TABLES
+        clickhouse_ddl = getattr(ddl, f"CLICKHOUSE_{table.upper()}")
+        for text in (ddl.SQLITE_TABLES[table], postgres.DDL[table],
+                     clickhouse_ddl):
+            assert f"CREATE TABLE IF NOT EXISTS {table} (" in text
+            body = text.split("(", 1)[1]
+            assert [ln.split()[0] for ln in body.replace(",", "\n")
+                    .splitlines() if ln.split()
+                    and ln.split()[0] in cols] == cols
+        assert "ORDER BY (timeslot, rank)" in clickhouse_ddl
+        # the ClickHouse sink creates it with the others at start-up
+        import inspect
+
+        assert f"ddl.CLICKHOUSE_{table.upper()}" in inspect.getsource(
+            clickhouse)
+        sql, args = insert_sql(table, [dict.fromkeys(cols, 1)])
+        assert sql.startswith(f'INSERT INTO "{table}"')
+        assert args == [1] * len(cols)
+
+    def test_a_spread_row_without_a_rank_gets_one(self):
+        from flow_pipeline_tpu.sink import ddl
+
+        rows = ddl.assign_ranks("portscan", [{"spread": 9.0},
+                                             {"spread": 4.0}])
+        assert [r["rank"] for r in rows] == [0, 1]
+
+
 class TestPostgresSQL:
     def test_insert_sql_multirow_single_statement(self):
         sql, args = insert_sql("flows_5m", [

@@ -3,6 +3,7 @@
     python3 -m benchmark.run --workload estate-catchup --seed <n> \
         --seconds 51 --trace 1 --keep
     python3 tools/step_ops.py [--scope hh_table_merge] [--out table.json]
+        [--config estate-spread]
 
 The benchmark's result line names the ten longest ops of the whole trace
 by XLA's instruction numbering (``_while.25``); its kernel-scope metrics
@@ -11,8 +12,9 @@ sum a scope. This prints what lies between: for every instruction of the
 (median over the step's executions in the trace), so that the next cut
 at a scope is sized from the chip. It reads the newest
 ``.bench_run/*/trace`` of this checkout with the benchmark's own readers
-(``benchmark/kernel_scopes.py``) and compiles the default processor's
-step once more for its text (a compile-cache hit after a traced run), so
+(``benchmark/kernel_scopes.py``) and compiles the step once more for its
+text: the default processor's, or with ``--config`` the one that
+``benchmark/configs/<name>.json``'s ``processor_flags`` build (a compile-cache hit after a traced run), so
 it needs the device the run had. A measurement aid: no test and no cell
 runs it.
 """
@@ -108,6 +110,9 @@ def main(argv=None) -> int:
     ap.add_argument("--scope", default="", help="only this kernel scope")
     ap.add_argument("--batch", default="32768",
                     help="-processor.batch of the run (a tiny dry run's)")
+    ap.add_argument("--config", default="",
+                    help="the run's configuration, a name under "
+                         "benchmark/configs/ (its processor_flags)")
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
     import jax
@@ -131,8 +136,13 @@ def main(argv=None) -> int:
         steps = ks._host_steps(planes, step_re)
     steps = [s for s in steps if s]
     flags = cli._processor_flags(cli._common_flags(FlagSet("processor")))
-    text = FusedPipeline(cli._build_models(flags.parse(
-        ["-processor.batch", args.batch]))).compiled_step_text()
+    argv = ["-processor.batch", args.batch]
+    if args.config:
+        with open(os.path.join(ROOT, "benchmark", "configs",
+                               args.config + ".json")) as f:
+            argv = json.load(f)["processor_flags"]
+    text = FusedPipeline(cli._build_models(
+        flags.parse(argv))).compiled_step_text()
     scopes, names = ks.scope_map(text), _op_names(text)
     per_step = []
     for owned in steps:
