@@ -2,12 +2,14 @@
 
 from __future__ import annotations
 
+import itertools
 import sys
 from typing import Any
 
 import numpy as np
 
 from ..schema.batch import words_to_addr
+from . import ddl
 
 
 def _addr_str(words) -> str:
@@ -20,6 +22,47 @@ def _addr_str(words) -> str:
     import ipaddress
 
     return str(ipaddress.IPv6Address(raw))
+
+
+def _value(v):
+    if isinstance(v, np.ndarray):  # [4] address words
+        return _addr_str(v)
+    return v.item() if isinstance(v, np.generic) else v
+
+
+def rows_to_columns(rows: dict) -> dict[str, list]:
+    """Columnar flush output (dict of arrays, an optional ``valid`` mask)
+    -> the valid rows as one Python list a column, with printable
+    addresses: the form a SQL sink's statement is zipped from. The mask
+    is applied once a column and a numeric column converts in one
+    ``tolist()`` (the ``int`` / ``float`` that ``.item()`` gives), so a
+    close of 6x10^4 rows costs a handful of array calls, not a Python
+    step a row and column."""
+    keep = None
+    if "valid" in rows:
+        keep = np.asarray(rows["valid"], dtype=bool)
+    columns = {}
+    for name, col in rows.items():
+        if name == "valid":
+            continue
+        if isinstance(col, np.ndarray):
+            if keep is not None:
+                col = col[keep]
+            if col.ndim == 1 and col.dtype != object:
+                columns[name] = col.tolist()
+                continue
+        elif keep is not None:
+            col = itertools.compress(col, keep.tolist())
+        # [n, 4] address words (the ranked tables' few hundred rows),
+        # objects, plain sequences: a value at a time
+        columns[name] = [_value(v) for v in col]
+    return columns
+
+
+def column_records(columns: dict[str, list]):
+    """``rows_to_columns``' columns, a dict a row."""
+    names = list(columns)
+    return (dict(zip(names, row)) for row in zip(*columns.values()))
 
 
 def rows_to_records(rows: Any) -> list[dict]:
@@ -36,23 +79,19 @@ def rows_to_records(rows: Any) -> list[dict]:
                     r[k] = v.item()
             out.append(r)
         return out
-    names = list(rows.keys())
-    n = len(rows[names[0]]) if names else 0
-    records = []
-    for i in range(n):
-        if "valid" in rows and not rows["valid"][i]:
-            continue
-        rec = {}
-        for name in names:
-            if name == "valid":
-                continue
-            v = rows[name][i]
-            if isinstance(v, np.ndarray):  # [4] address words
-                rec[name] = _addr_str(v)
-            else:
-                rec[name] = v.item() if isinstance(v, np.generic) else v
-        records.append(rec)
-    return records
+    return list(column_records(rows_to_columns(rows)))
+
+
+def sink_batch(table: str, rows: Any) -> tuple[Any, int]:
+    """What a SQL sink builds its statement from, and how many rows: a
+    close's columns (``rows_to_columns``) where dict-of-arrays rows go to
+    a typed table; records otherwise (list input: alerts, a dead-letter
+    replay; a table the DDL lacks: sqlite's journal)."""
+    if isinstance(rows, list) or table not in ddl.TABLE_COLUMNS:
+        records = rows_to_records(rows)
+        return records, len(records)
+    columns = rows_to_columns(rows)
+    return columns, ddl.column_rows(columns)
 
 
 class MemorySink:

@@ -26,7 +26,7 @@ from typing import Optional
 import numpy as np
 
 from . import ddl
-from .base import rows_to_records
+from .base import column_records, sink_batch
 from ..obs.trace import TRACER
 from ..schema.batch import words_to_addr
 
@@ -122,14 +122,34 @@ class ClickHouseSink:
 
     def write(self, table: str, rows) -> None:
         with TRACER.span("sink_records") as span:
-            records = rows_to_records(rows)
-            span["rows"] = len(records)
-        if not records:
+            batch, n = sink_batch(table, rows)
+            span["rows"] = n
+        if not n:
             return
-        with TRACER.span("sink_execute", rows=len(records)):
-            self._insert(table, records)
+        with TRACER.span("sink_execute", rows=n):
+            self._insert(table, batch)
 
-    def _insert(self, table: str, records: list) -> None:
+    def _insert(self, table: str, batch) -> None:
+        """``batch`` is records or a close's columns (``base.sink_batch``):
+        either way one JSON object a row, ranks assigned, of the DDL's
+        columns that the rows carry."""
+        if isinstance(batch, list):
+            records = self._records(table, batch)
+        else:
+            ranked = ddl.ranked_columns(table, batch)
+            columns = {c: ranked[c] for c in ddl.TABLE_COLUMNS[table]
+                       if c in ranked}
+            if table == "flows_5m":
+                columns = {self._FLOWS_5M_COLS[k]: v
+                           for k, v in columns.items()}
+                slots = columns.get("Timeslot") or \
+                    [0] * ddl.column_rows(ranked)
+                columns["Date"] = [int(t) // 86400 for t in slots]
+            records = column_records(columns)
+        body = "\n".join(json.dumps(r, default=str) for r in records).encode()
+        self._post(f"INSERT INTO {table} FORMAT JSONEachRow", body)
+
+    def _records(self, table: str, records: list) -> list:
         ddl.assign_ranks(table, records)
         cols = ddl.TABLE_COLUMNS.get(table)
         if cols is not None:
@@ -144,8 +164,7 @@ class ClickHouseSink:
             ]
             for r in records:
                 r.setdefault("Date", int(r.get("Timeslot", 0)) // 86400)
-        body = "\n".join(json.dumps(r, default=str) for r in records).encode()
-        self._post(f"INSERT INTO {table} FORMAT JSONEachRow", body)
+        return records
 
     # address columns every archive row ships; each must EXIST (an absent
     # column 400s JSONEachRow as unknown) and be type IPv6 (older DDLs

@@ -366,6 +366,34 @@ def assign_ranks(table: str, records: list[dict]) -> list[dict]:
     return records
 
 
+def column_rows(columns: dict) -> int:
+    """Rows in a close's columns (sink/base.py::rows_to_columns)."""
+    return len(next(iter(columns.values()), ()))
+
+
+def ranked_columns(table: str, columns: dict) -> dict:
+    """``assign_ranks`` for a close's columns: every row has the same
+    keys, so the rank is one ``range``."""
+    if table in RANKED_TABLES and "rank" not in columns:
+        return {**columns, "rank": range(column_rows(columns))}
+    return columns
+
+
+def statement_rows(table: str, batch):
+    """One value tuple a row in ``TABLE_COLUMNS`` order, ranks assigned,
+    for a SQL statement's parameters: from a close's columns one ``zip``
+    (a column the rows lack is ``None``, as ``dict.get`` gives; a key the
+    DDL lacks is left out), from records (a list of dicts) a look-up a
+    value."""
+    cols = TABLE_COLUMNS[table]
+    if isinstance(batch, list):
+        assign_ranks(table, batch)
+        return [tuple(r.get(c) for c in cols) for r in batch]
+    absent = [None] * column_rows(batch)
+    batch = ranked_columns(table, batch)
+    return zip(*(batch.get(c, absent) for c in cols))
+
+
 SQLITE_TABLES = {
     "flows": """
 CREATE TABLE IF NOT EXISTS flows (

@@ -7,16 +7,17 @@ reference's row-at-a-time Exec is why it caps at a few thousand rows/sec
 (ref: README.md:86-88).
 
 SQL generation is separated from execution so tests cover the statements
-without a server: ``insert_sql(table, records)`` returns (sql, args).
+without a server: ``insert_sql(table, batch)`` returns (sql, args).
 """
 
 from __future__ import annotations
 
+import itertools
 from typing import Optional
 
 from ..obs.trace import TRACER
 from . import ddl
-from .base import rows_to_records
+from .base import sink_batch
 
 _IMPORT_ERROR: Optional[str] = None
 try:  # pragma: no cover - driver presence depends on environment
@@ -47,18 +48,19 @@ def available() -> bool:
     return psycopg2 is not None
 
 
-def insert_sql(table: str, records: list[dict]) -> tuple[str, list]:
+def insert_sql(table: str, batch) -> tuple[str, list]:
     """One multi-row INSERT statement for a known table: VALUES (...), (...),
     ... with flattened args — a single round trip per flush, not one per row
     (the reference's row-at-a-time Exec is its throughput ceiling). Quoted
-    identifiers come from the static column table, never from user data."""
+    identifiers come from the static column table, never from user data.
+    ``batch`` is records or a close's columns (``base.sink_batch``)."""
     cols = _COLUMNS[table]
-    ddl.assign_ranks(table, records)
+    args = list(itertools.chain.from_iterable(
+        ddl.statement_rows(table, batch)))
     collist = ", ".join(f'"{c}"' for c in cols)
     row_ph = "(" + ", ".join(["%s"] * len(cols)) + ")"
-    placeholders = ", ".join([row_ph] * len(records))
+    placeholders = ", ".join([row_ph] * (len(args) // len(cols)))
     sql = f'INSERT INTO "{table}" ({collist}) VALUES {placeholders}'
-    args = [r.get(c) for r in records for c in cols]
     return sql, args
 
 
@@ -78,12 +80,12 @@ class PostgresSink:
 
     def write(self, table: str, rows) -> None:
         with TRACER.span("sink_records") as span:
-            records = rows_to_records(rows)
-            span["rows"] = len(records)
-        if not records or table not in _COLUMNS:
+            batch, n = sink_batch(table, rows)
+            span["rows"] = n
+        if not n or table not in _COLUMNS:
             return
-        with TRACER.span("sink_execute", rows=len(records)):
-            sql, args = insert_sql(table, records)
+        with TRACER.span("sink_execute", rows=n):
+            sql, args = insert_sql(table, batch)
             with self._conn, self._conn.cursor() as cur:
                 cur.execute(sql, args)
 

@@ -14,7 +14,7 @@ import threading
 
 from ..obs.trace import TRACER
 from . import ddl
-from .base import rows_to_records
+from .base import sink_batch
 
 
 class SQLiteSink:
@@ -55,24 +55,23 @@ class SQLiteSink:
         # (engine/worker.py::_write_rows); the span is here and not in
         # rows_to_records, which the query threads call too
         with TRACER.span("sink_records") as span:
-            records = rows_to_records(rows)
-            span["rows"] = len(records)
-        if not records:
+            batch, n = sink_batch(table, rows)
+            span["rows"] = n
+        if not n:
             return
-        with TRACER.span("sink_execute", rows=len(records)), self._lock:
+        with TRACER.span("sink_execute", rows=n), self._lock:
             cols = ddl.TABLE_COLUMNS.get(table)
             if cols is None:
                 self._conn.executemany(
                     "INSERT INTO journal (table_name, record) VALUES (?, ?)",
-                    [(table, json.dumps(r, default=str)) for r in records],
+                    [(table, json.dumps(r, default=str)) for r in batch],
                 )
             else:
-                ddl.assign_ranks(table, records)
                 placeholders = ",".join("?" for _ in cols)
                 collist = ",".join(f'"{c}"' for c in cols)
                 self._conn.executemany(
                     f'INSERT INTO "{table}" ({collist}) VALUES ({placeholders})',
-                    [tuple(r.get(c) for c in cols) for r in records],
+                    ddl.statement_rows(table, batch),
                 )
             self._conn.commit()
 
